@@ -13,12 +13,6 @@ Public API (mirrors reference ``deepspeed/__init__.py:54,:251``):
 __version__ = "0.1.0"
 __git_branch__ = "main"
 
-# jax API drift shim (jax.shard_map on 0.4.x jaxlibs) — must run before any
-# submodule builds a shard_map program
-from .utils import jax_compat as _jax_compat
-
-_jax_compat.install()
-
 from . import comm  # noqa: F401
 from . import pipe  # noqa: F401
 from . import zero  # noqa: F401

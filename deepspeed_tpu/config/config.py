@@ -328,10 +328,10 @@ class KVPoolConfig(ConfigModel):
     # the pool through the block table, then the unchanged dense attention.
     # "fused": the split-KV flash-decode Pallas kernel
     # (ops/pallas/paged_attention.py) walks the block table IN-KERNEL — no
-    # dense view is materialized. Shape-probed at engine construction
-    # (fused_decode_supported); unsupported shapes warn once and fall back
-    # to "gather". Prefill/insert/speculative-verify always run the gather
-    # machinery either way.
+    # dense view is materialized. Put to the compiler at the engine's
+    # geometry at construction (fused_decode_supported); a refusal logs the
+    # compiler's reason once and serves through "gather". Prefill/insert/
+    # speculative-verify always run the gather machinery either way.
     attention_backend: str = "gather"
 
     def _validate(self):

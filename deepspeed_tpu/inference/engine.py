@@ -161,16 +161,8 @@ class InferenceEngine:
         kernel becomes {kernel_q int8, kernel_scale} — the model reads weights
         from HBM at 8 bits and dequantizes inside the fused matmul
         (``models/layers.py linear_apply``)."""
-        from ..models.layers import set_quantized_matmul_enabled
         from ..ops.quantizer import quantize_per_channel
 
-        # the Pallas dequant-matmul has no sharding rule: under tp > 1 the
-        # SPMD partitioner would replicate the model-axis-sharded quantized
-        # weight per device, erasing the HBM win — keep the XLA dequant path
-        # (which partitions correctly) for tensor-parallel serving
-        tp = self._config.tensor_parallel.tp_size \
-            if self._config.tensor_parallel.enabled else 1
-        set_quantized_matmul_enabled(tp <= 1)
         bits = self._config.quant.bits
         group_size = self._config.quant.group_size
         counts = {"packed": 0, "int8": 0}
@@ -217,6 +209,51 @@ class InferenceEngine:
                  f"{sum(counts.values())} block kernels "
                  f"(group_size={group_size}{packed_note}{fallback_note})",
                  ranks=[0])
+        self._select_quantized_matmul(bits)
+
+    def _select_quantized_matmul(self, bits):
+        """Decide up front whether the Pallas dequant-matmul serves this
+        engine, and say why not: a Mosaic call cannot be partitioned, so a
+        program over more than one device takes the XLA dequant path (which
+        partitions correctly); on one TPU the kernel is put to the compiler
+        at every distinct weight geometry of the model."""
+        import logging
+
+        from ..models.layers import set_quantized_matmul_enabled
+        from ..ops.pallas import target_platform
+        from ..ops.pallas.quantized_matmul import quantized_matmul_supported
+
+        ok, reason = True, ""
+        if self.mesh.size > 1:
+            ok, reason = False, (
+                f"the program spans {self.mesh.size} devices and Mosaic "
+                "kernels cannot be automatically partitioned")
+        elif target_platform() == "tpu":
+            geoms = set()
+
+            def walk(tree):
+                if isinstance(tree, dict):
+                    q = tree.get("kernel_q4", tree.get("kernel_q"))
+                    if q is not None and q.ndim == 3:  # [layers, k(/2), n]
+                        k = q.shape[-2] * (2 if "kernel_q4" in tree else 1)
+                        geoms.add((k, q.shape[-1],
+                                   tree["kernel_scale"].shape[-3],
+                                   4 if "kernel_q4" in tree else 8))
+                    for v in tree.values():
+                        walk(v)
+
+            walk(self.params["blocks"])
+            for k, n, groups, b in sorted(geoms):
+                ok, reason = quantized_matmul_supported(
+                    k, n, groups, bits=b, dtype=self.dtype)
+                if not ok:
+                    reason = f"[{k}x{n}] int{b}: {reason}"
+                    break
+        set_quantized_matmul_enabled(ok)
+        if not ok:
+            log_dist(f"int{bits} Pallas dequant-matmul refused ({reason}); "
+                     "serving through the XLA dequant path", ranks=[0],
+                     level=logging.WARNING)
 
     def load_checkpoint(self, load_dir, tag=None):
         """Load trained weights (npz layout from the training engine); TP
@@ -501,9 +538,8 @@ class InferenceEngine:
         lowered, jaxpr = sv.trace_decode()
         report = audit_lowered(lowered, n, loop_trip_count=loop_trip_count,
                                sanitizer_config=cfg)
-        if jaxpr is not None:
-            report["sanitizer"] = merge_reports(
-                report["sanitizer"], sanitize_jaxpr(jaxpr, config=cfg))
+        report["sanitizer"] = merge_reports(
+            report["sanitizer"], sanitize_jaxpr(jaxpr, config=cfg))
         return report
 
     def prefill_chunk_report(self, chunk_tokens=None):
@@ -523,9 +559,8 @@ class InferenceEngine:
         n = max(self.mesh.devices.size, 1)
         lowered, jaxpr = sv.trace_prefill_chunk(chunk_tokens)
         report = audit_lowered(lowered, n, sanitizer_config=cfg)
-        if jaxpr is not None:
-            report["sanitizer"] = merge_reports(
-                report["sanitizer"], sanitize_jaxpr(jaxpr, config=cfg))
+        report["sanitizer"] = merge_reports(
+            report["sanitizer"], sanitize_jaxpr(jaxpr, config=cfg))
         return report
 
     def verify_program_report(self, spec_k=None):
@@ -545,9 +580,8 @@ class InferenceEngine:
         n = max(self.mesh.devices.size, 1)
         lowered, jaxpr = sv.trace_verify(spec_k)
         report = audit_lowered(lowered, n, sanitizer_config=cfg)
-        if jaxpr is not None:
-            report["sanitizer"] = merge_reports(
-                report["sanitizer"], sanitize_jaxpr(jaxpr, config=cfg))
+        report["sanitizer"] = merge_reports(
+            report["sanitizer"], sanitize_jaxpr(jaxpr, config=cfg))
         return report
 
     @property

@@ -18,6 +18,7 @@ existing job scripts port.
 
 import argparse
 import os
+import re
 import subprocess
 import sys
 
@@ -44,7 +45,9 @@ def parse_args(args=None):
                              "simulated on this machine; CPU pods / tests)")
     parser.add_argument("--local_devices_per_proc", type=int, default=0,
                         help="with --num_local_procs: virtual CPU devices per "
-                             "worker (0 = leave platform env untouched)")
+                             "worker (required: local workers run on the CPU "
+                             "platform — an accelerator belongs to one "
+                             "process)")
     parser.add_argument("--ssh", action="store_true",
                         help="with --hostfile: launch the command on every "
                              "host over ssh (reference PDSH runner role)")
@@ -140,13 +143,21 @@ class SshRunner:
         return _wait_kill_on_failure(procs)
 
 
-def launch_local_procs(cmd, num_procs, env, devices_per_proc=0,
+def launch_local_procs(cmd, num_procs, env, devices_per_proc,
                        master_port=None):
     """Spawn ``num_procs`` local workers with the rendezvous env — multi-host
     simulated on one machine (the reference test-harness pattern,
-    ``tests/unit/common.py:183``), also the real path for CPU pods."""
+    ``tests/unit/common.py:183``), also the real path for CPU pods. Every
+    worker is pinned to the CPU platform with ``devices_per_proc`` virtual
+    devices: N workers inheriting the host's accelerator would all try to
+    own it, and a chip belongs to one process at a time."""
     import socket
 
+    if devices_per_proc < 1:
+        raise ValueError(
+            "--num_local_procs needs --local_devices_per_proc N (virtual CPU "
+            "devices per worker): local workers cannot share the host's "
+            "accelerator — one process drives all local chips")
     if master_port is None:
         s = socket.socket()
         s.bind(("127.0.0.1", 0))
@@ -160,12 +171,13 @@ def launch_local_procs(cmd, num_procs, env, devices_per_proc=0,
             "DS_TPU_COORDINATOR": "127.0.0.1",
             "DS_TPU_PROCESS_ID": str(rank),
             "MASTER_PORT": str(master_port),
+            "JAX_PLATFORMS": "cpu",
+            "XLA_FLAGS": (re.sub(
+                r"--xla_force_host_platform_device_count=\d+", "",
+                wenv.get("XLA_FLAGS", "")) +
+                f" --xla_force_host_platform_device_count="
+                f"{devices_per_proc}").strip(),
         })
-        if devices_per_proc:
-            wenv["JAX_PLATFORMS"] = "cpu"
-            wenv["XLA_FLAGS"] = (wenv.get("XLA_FLAGS", "") +
-                                 f" --xla_force_host_platform_device_count="
-                                 f"{devices_per_proc}").strip()
         procs.append(subprocess.Popen(cmd, env=wenv))
     return _wait_kill_on_failure(procs)
 

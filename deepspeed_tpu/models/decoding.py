@@ -191,7 +191,9 @@ def _attn_paged_fused(cfg, p_attn, h, kc, vc, ks, vs, table, pos, rope=None):
         if cfg.position_embedding == "alibi" else None
     out = paged_flash_decode(q[:, 0], k[:, 0], v[:, 0], kc, vc, table, pos,
                              k_scale=ks, v_scale=vs, scale=cfg.attn_scale,
-                             alibi_slopes=slopes)
+                             alibi_slopes=slopes,
+                             interpret=cfg.attention_interpret,
+                             mesh=cfg.mesh)
     out = L.linear_apply(p_attn["o"], out.reshape(b, q_len, -1))
     return out, k[:, 0], v[:, 0]
 
@@ -493,11 +495,14 @@ def _attn_with_cache(cfg, p_attn, h, k_cache, v_cache, pos, kv_len, rope=None,
     # logits materialization — on the fresh k/v (cast through the cache
     # dtype to keep the dense path's numerics), repeated BEFORE any cache
     # read so no [b, max_len, heads, dh] tensor materializes. prefill_flash:
-    # True/False force, None = TPU backend only (the CPU fallback is the
-    # chunked-XLA flash, correct everywhere).
+    # True/False force, None = on when kernels are lowered for a TPU. What
+    # flash_attention then runs (Pallas kernel, or the XLA scan with a
+    # logged reason for unaligned buckets / other platforms) is its call.
     flash_wanted = cfg.prefill_flash
     if flash_wanted is None:
-        flash_wanted = jax.default_backend() == "tpu"
+        from ..ops.pallas import target_platform
+
+        flash_wanted = target_platform() == "tpu"
     if (flash_wanted and prefill and not per_row and q_len > 1
             and is_local is None and cfg.position_embedding != "alibi"):
         from ..ops.flash_attention import flash_attention
@@ -508,7 +513,9 @@ def _attn_with_cache(cfg, p_attn, h, k_cache, v_cache, pos, kv_len, rope=None,
                               L._repeat_kv(v.astype(v_cache.dtype), n_rep),
                               causal=True, scale=cfg.attn_scale,
                               block_q=cfg.flash_block_q,
-                              block_kv=cfg.flash_block_kv)
+                              block_kv=cfg.flash_block_kv,
+                              interpret=cfg.attention_interpret,
+                              mesh=cfg.mesh)
         out = L.linear_apply(p_attn["o"], out.reshape(b, q_len, -1))
         return out, k_cache, v_cache
 

@@ -143,13 +143,13 @@ def linear_apply_rowparallel(p, x, axis):
     return y
 
 
-# Pallas dequant-matmul dispatch switch. The inference engine turns the
-# kernel OFF for tensor-parallel serving: an opaque pallas_call has no
-# sharding rule, so under tp > 1 the SPMD partitioner would replicate the
-# model-axis-sharded quantized weight on every device — erasing exactly the
-# per-device HBM win quantization exists for (the XLA dequant+dot path
-# partitions correctly). Set via set_quantized_matmul_enabled before trace.
-_QMM_MODE = "on"  # "on" | "off" | "interpret" (interpret = CPU-testable)
+# Pallas dequant-matmul switch, decided ONCE by the inference engine when it
+# quantizes the weights (inference/engine.py _quantize_weights): on only if
+# the program runs on one device (a Mosaic call has no partitioning rule) and
+# the kernel compiled for the TPU at every weight geometry of the model —
+# otherwise the XLA dequant+dot path serves and the engine logs why. The
+# DS_TPU_QMM environment variable (off | interpret) overrides it for tests.
+_QMM_MODE = "on"
 
 
 def set_quantized_matmul_enabled(flag):
@@ -158,31 +158,40 @@ def set_quantized_matmul_enabled(flag):
 
 
 def _quantized_matmul_or_none(p, x, bits):
-    """Fused Pallas dequant-matmul when eligible — the packed weight is what
-    streams from HBM; unpack, group-scale, and the MXU dot happen per-tile
-    in VMEM. Measured necessity: XLA does NOT fuse the int4 nibble unpack
-    into the matmul (2026-08-01 serving bench: int4 decode 3-4x slower than
-    bf16), so dequantizing outside the kernel round-trips the full-size
-    weight through HBM every decode step."""
+    """Fused Pallas dequant-matmul — the packed weight is what streams from
+    HBM; unpack, group-scale, and the MXU dot happen per-tile in VMEM (XLA
+    does not fuse the int4 nibble unpack into the matmul). None (the caller
+    runs XLA dequant+dot) when the engine switched the kernel off, and, with
+    the reason logged once, where no kernel can run or the shape has no
+    legal tiling."""
     import os
 
+    from ..ops.pallas import note_fallback, unavailable_reason
+
     mode = os.environ.get("DS_TPU_QMM", _QMM_MODE)
+    if mode in ("off", "0"):
+        return None
     interpret = mode == "interpret"
-    if mode == "off" or mode == "0" \
-            or (not interpret and jax.default_backend() != "tpu"):
+    reason = unavailable_reason(interpret)
+    if reason is not None:
+        note_fallback("quantized_matmul", reason)
         return None
-    key = "kernel_q4" if bits == 4 else "kernel_q"
-    q = p[key]
-    if q.ndim != 2:
-        return None
+    q = p["kernel_q4" if bits == 4 else "kernel_q"]
     xm = x.reshape(-1, x.shape[-1])
-    if xm.shape[0] > 2048:
-        return None  # prefill-sized token counts: VMEM accumulator too large
+    if q.ndim != 2 or xm.shape[0] > 2048:
+        # stacked (MoE expert) kernels; prefill-sized token counts whose
+        # [m, bn] fp32 accumulator would not fit VMEM
+        note_fallback("quantized_matmul",
+                      f"weight rank {q.ndim} / {xm.shape[0]} token rows "
+                      "outside the kernel's decode-sized 2-D contract")
+        return None
     from ..ops.pallas.quantized_matmul import quantized_matmul
 
     y = quantized_matmul(xm, q, p["kernel_scale"], bits=bits,
                          interpret=interpret)
     if y is None:
+        note_fallback("quantized_matmul",
+                      f"no legal tiling for weight {tuple(q.shape)}")
         return None
     return y.reshape(x.shape[:-1] + (y.shape[-1],))
 
@@ -197,9 +206,9 @@ def linear_apply(p, x, compute_dtype=None):
             if "bias" in p:
                 y = y + p["bias"].astype(y.dtype)
             return y
-        # XLA fallback (CPU / tp>1 / non-tileable shapes): unpack + dequant
-        # and let XLA fuse what it can into the matmul; the weight still
-        # streams from HBM at its quantized width when fusion succeeds
+        # XLA path (kernel switched off or unable, see above): unpack +
+        # dequant and let XLA fuse what it can into the matmul; the weight
+        # still streams from HBM at its quantized width when fusion succeeds
         from ..ops.quantizer import dequantize_per_channel, unpack_int4
 
         qk = unpack_int4(p["kernel_q4"]) if bits == 4 else p["kernel_q"]

@@ -452,11 +452,15 @@ def block_apply(cfg, p, x, mask=None, rope=None, alibi=None, deterministic=True,
             out = checkpoint_name(out, "attn_out")
             return o_proj(out)
         # pallas paths: plain attention only — padding mask / alibi / dropout
-        # force the dense fallback
+        # take the dense path. Every kernel runs per shard of cfg.mesh
+        # (ops/pallas shard_kernel): GSPMD cannot partition a Mosaic call.
         kernel_ok = (alibi is None and mask is None
                      and (deterministic or cfg.attn_dropout == 0.0))
         if cfg.attention_impl == "block_sparse" and kernel_ok:
-            out = _block_sparse_attn(cfg, s)(q, k, v)
+            from ..ops.flash_attention import shard_attention
+
+            out = shard_attention(_block_sparse_attn(cfg, s), cfg.mesh,
+                                  q, k, v)
             out = checkpoint_name(out, "attn_out")
             return o_proj(out)
         flash_ok = cfg.attention_impl in ("flash", "jax_flash") and kernel_ok
@@ -465,7 +469,7 @@ def block_apply(cfg, p, x, mask=None, rope=None, alibi=None, deterministic=True,
                 from ..ops.flash_attention import jax_flash_attention
 
                 out = jax_flash_attention(q, k, v, causal=cfg.causal,
-                                          scale=cfg.attn_scale)
+                                          scale=cfg.attn_scale, mesh=cfg.mesh)
             else:
                 from ..ops.flash_attention import flash_attention
 
@@ -474,7 +478,9 @@ def block_apply(cfg, p, x, mask=None, rope=None, alibi=None, deterministic=True,
                                       block_q=cfg.flash_block_q,
                                       block_kv=cfg.flash_block_kv,
                                       block_q_bwd=cfg.flash_block_q_bwd,
-                                      block_kv_bwd=cfg.flash_block_kv_bwd)
+                                      block_kv_bwd=cfg.flash_block_kv_bwd,
+                                      interpret=cfg.attention_interpret,
+                                      mesh=cfg.mesh)
         else:
             dense_mask = mask if mask is not None else (
                 L.causal_mask(s, s) if cfg.causal else None)
@@ -955,7 +961,7 @@ class CausalLM:
             return fused_cross_entropy(
                 x.reshape(-1, cfg.d_model), emb, labels.reshape(-1), bias,
                 n_chunks=cfg.fused_ce_chunks, impl=cfg.fused_ce_impl,
-                interpret=cfg.attention_interpret)
+                interpret=cfg.attention_interpret, mesh=cfg.mesh)
         return cross_entropy_loss(self.head(params, x), labels)
 
     def apply(self, params, input_ids, positions=None, attention_mask=None,
@@ -1039,7 +1045,7 @@ class MaskedLM(CausalLM):
                 h.reshape(-1, cfg.d_model), params["wte"]["weight"],
                 labels.reshape(-1), params["mlm_bias"]["bias"],
                 n_chunks=cfg.fused_ce_chunks, impl=cfg.fused_ce_impl,
-                interpret=cfg.attention_interpret)
+                interpret=cfg.attention_interpret, mesh=cfg.mesh)
         logits = L.embedding_attend(params["wte"], h) \
             + params["mlm_bias"]["bias"].astype(cfg.compute_dtype)
         return cross_entropy_loss(logits, labels)

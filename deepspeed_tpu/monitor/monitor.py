@@ -139,7 +139,7 @@ class MonitorMaster(Monitor):
     full disk under the CSV dir) must cost its own events, not the training
     step: each backend's write is isolated, and the first failure logs one
     warning naming the backend — later failures of the same backend are
-    silent (a wedged writer at ``steps_per_print`` cadence would otherwise
+    silent (a stuck writer at ``steps_per_print`` cadence would otherwise
     flood the log)."""
 
     def __init__(self, config):

@@ -40,9 +40,9 @@ def _pad_emb(emb, padded_vocab):
     return jnp.pad(emb, ((0, padded_vocab - vocab), (0, 0)))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
 def fused_cross_entropy(x, emb, labels, bias=None, ignore_index=-100,
-                        n_chunks=8, impl="xla", interpret=False):
+                        n_chunks=8, impl="xla", interpret=False, mesh=None):
     """Token-mean CE of ``softmax(x @ emb^T + bias)`` against ``labels``.
 
     x: [tokens, d] (compute dtype); emb: [V, d]; ``bias``: optional [V] logit
@@ -52,10 +52,12 @@ def fused_cross_entropy(x, emb, labels, bias=None, ignore_index=-100,
     ``impl="pallas"`` streams the forward through the Pallas kernel
     (``ops/pallas/cross_entropy.py`` — chunk logits never touch HBM); the
     backward is the chunked XLA path either way (its cost is two MXU GEMMs
-    XLA already runs at peak).
+    XLA already runs at peak). ``mesh``: the mesh the surrounding program is
+    partitioned over — the kernel then runs per token shard (rows are
+    independent; GSPMD cannot partition a Mosaic call).
     """
     loss, _ = _ce_fwd_impl(x, emb, labels, bias, ignore_index, n_chunks,
-                           impl, interpret)
+                           impl, interpret, mesh)
     return loss
 
 
@@ -66,7 +68,7 @@ def _pad_bias(bias, padded_vocab):
 
 
 def _ce_fwd_impl(x, emb, labels, bias, ignore_index, n_chunks, impl="xla",
-                 interpret=False):
+                 interpret=False, mesh=None):
     if impl not in ("xla", "pallas"):
         # checked here (not in the custom_vjp primal, which grad bypasses)
         # so a typo'd config can never silently bench the wrong kernel
@@ -75,10 +77,17 @@ def _ce_fwd_impl(x, emb, labels, bias, ignore_index, n_chunks, impl="xla",
     valid = labels != ignore_index
     safe_labels = jnp.where(valid, labels, 0).astype(jnp.int32)
     if impl == "pallas":
+        from .pallas import shard_kernel
         from .pallas.cross_entropy import pallas_ce_forward
 
-        lse, lab_logit = pallas_ce_forward(x, emb, safe_labels, bias,
-                                           interpret=interpret)
+        tokens = {0: ("data", "expert")}  # rows are independent
+        operands, dims = [x, emb, safe_labels], [tokens, {}, tokens]
+        if bias is not None:
+            operands.append(bias)
+            dims.append({})
+        lse, lab_logit = shard_kernel(
+            lambda *ops: pallas_ce_forward(*ops, interpret=interpret),
+            mesh, operands, dims, [tokens, tokens])
         n_valid = jnp.maximum(jnp.sum(valid), 1)
         loss = jnp.sum((lse - lab_logit) * valid) / n_valid
         return loss, (lse, n_valid)
@@ -125,13 +134,13 @@ def _ce_fwd_impl(x, emb, labels, bias, ignore_index, n_chunks, impl="xla",
 
 
 def _ce_vjp_fwd(x, emb, labels, bias, ignore_index, n_chunks, impl,
-                interpret):
+                interpret, mesh):
     loss, (lse, n_valid) = _ce_fwd_impl(x, emb, labels, bias, ignore_index,
-                                        n_chunks, impl, interpret)
+                                        n_chunks, impl, interpret, mesh)
     return loss, (x, emb, labels, bias, lse, n_valid)
 
 
-def _ce_vjp_bwd(ignore_index, n_chunks, impl, interpret, residuals, g):
+def _ce_vjp_bwd(ignore_index, n_chunks, impl, interpret, mesh, residuals, g):
     x, emb, labels, bias, lse, n_valid = residuals
     tokens, d = x.shape
     vocab = emb.shape[0]

@@ -4,11 +4,12 @@ Replaces the reference's fused attention kernels (``csrc/transformer/softmax_ker
 for training, ``csrc/transformer/inference/csrc/softmax.cu`` "softmax_context" for
 inference). Two implementations behind one signature:
 
-- ``flash_attention``: online-softmax attention, chunked over the KV axis with
-  ``lax.scan`` so the [batch, heads, q, kv] score matrix is never materialized —
-  O(seq) memory like FlashAttention. Pure XLA; runs anywhere.
 - ``pallas_flash_attention`` (``ops/pallas/flash_attention.py``): the hand-tiled TPU
-  kernel used when available; same semantics.
+  kernel; what ``flash_attention`` runs on a TPU target (or in interpret mode).
+- ``_chunked_attention``: online-softmax attention, chunked over the KV axis with
+  ``lax.scan`` so the [batch, heads, q, kv] score matrix is never materialized —
+  O(seq) memory like FlashAttention. Pure XLA; what ``flash_attention`` runs where
+  the kernel cannot, with the reason logged once.
 
 Inputs q,k,v: [batch, seq, heads, head_dim]; returns the same layout.
 """
@@ -24,8 +25,7 @@ NEG_INF = -1e30
 
 def parse_block_spec(spec):
     """Parse a "bq x bkv[: bq_bwd x bkv_bwd]" tile-size string (the
-    BENCH_FLASH_BLOCKS / BENCH_BLOCKS knob shared by bench.py and
-    tools/bench_attention.py). Returns (bq, bkv, bq_bwd, bkv_bwd) with the
+    BENCH_BLOCKS knob of tools/bench_attention.py). Returns (bq, bkv, bq_bwd, bkv_bwd) with the
     backward pair None when omitted."""
     fwd, _, bwd = spec.partition(":")
     bq, bkv = (int(x) for x in fwd.split("x"))
@@ -36,77 +36,100 @@ def parse_block_spec(spec):
     return bq, bkv, bqb, bkvb
 
 
+def shard_attention(kernel, mesh, q, k, v):
+    """``kernel(q, k, v)`` on [b, s, h, d] operands, per shard of ``mesh``:
+    attention is independent across batch rows and heads, so the kernel runs
+    per (data x expert, model) shard (``ops/pallas shard_kernel``)."""
+    from .pallas import shard_kernel
+
+    batch_heads = {0: ("data", "expert"), 2: ("model",)}
+    return shard_kernel(kernel, mesh, (q, k, v), [batch_heads] * 3,
+                        [batch_heads])
+
+
+def _pallas_unusable(q, k, interpret):
+    """Why the Pallas kernels cannot take this call, or None if they can."""
+    from .pallas import unavailable_reason
+
+    if q.shape[1] % 128 or k.shape[1] % 128:
+        return (f"sequence lengths {q.shape[1]}/{k.shape[1]} are not "
+                "multiples of 128")
+    return unavailable_reason(interpret)
+
+
 def flash_attention(q, k, v, causal=True, scale=None, block_size=512,
                     block_q=None, block_kv=None, block_q_bwd=None,
-                    block_kv_bwd=None):
-    """Online-softmax attention, scanned over KV blocks.
+                    block_kv_bwd=None, interpret=False, mesh=None):
+    """Flash attention: the Pallas TPU kernel where it can run, else the
+    XLA online-softmax scan (``_chunked_attention``) with a logged reason.
 
-    For each query block the running (max, sum, acc) triple is updated per KV chunk —
-    the same recurrence the FlashAttention kernel uses, expressed as ``lax.scan`` so
-    XLA keeps the working set in registers/VMEM. ``block_*`` override the
-    Pallas kernel's tile sizes (tuning knobs; ignored by the XLA fallback).
+    The kernel needs a TPU target (or ``interpret=True``, the CPU tests'
+    Pallas interpreter) and 128-aligned sequence lengths. ``mesh``: the
+    device mesh the surrounding program is partitioned over — on more than
+    one device the kernel runs inside a ``shard_map`` (batch over ``data``,
+    heads over ``model``), because GSPMD cannot partition a Mosaic call.
+    ``block_*`` override the kernel's tile sizes (ignored by the XLA scan).
     """
-    if _tpu_kernel_eligible(q, k):
-        from .pallas.flash_attention import pallas_flash_attention
+    reason = _pallas_unusable(q, k, interpret)
+    if reason is not None:
+        from .pallas import note_fallback
 
-        s_q, s_kv = q.shape[1], k.shape[1]
-        if block_q is None and block_kv is None and s_kv <= 1024:
-            # Measured default (2026-08-01 on-chip sweep, PERF.md): at
-            # s_kv <= 1024 a SINGLE kv block per grid step drops the
-            # online-softmax rescale loop entirely — fwd 512x{s_kv} +
-            # bwd 512x{s_kv} tiles beat the generic 256x512/256x256 by
-            # +22% end-to-end training throughput at the bench shape.
-            # Longer sequences keep the generic tiles until the 2k-8k tile
-            # sweep (bench_attention) lands.
-            block_q = min(512, s_q)
-            block_kv = s_kv
-            block_q_bwd = block_q_bwd or min(512, s_q)
-            block_kv_bwd = block_kv_bwd or s_kv
-        return pallas_flash_attention(q, k, v, causal=causal, scale=scale,
-                                      block_q=min(block_q or 256, s_q),
-                                      block_kv=min(block_kv or 512, s_kv),
-                                      block_q_bwd=block_q_bwd,
-                                      block_kv_bwd=block_kv_bwd)
-    return _chunked_attention(q, k, v, causal=causal, scale=scale,
-                              block_size=block_size)
+        note_fallback("flash_attention", reason)
+        return _chunked_attention(q, k, v, causal=causal, scale=scale,
+                                  block_size=block_size)
+    from .pallas.flash_attention import pallas_flash_attention
 
+    s_q, s_kv = q.shape[1], k.shape[1]
+    if block_q is None and block_kv is None and s_kv <= 1024:
+        # at s_kv <= 1024 a SINGLE kv block per grid step drops the
+        # online-softmax rescale loop entirely (fwd 512x{s_kv} + bwd
+        # 512x{s_kv} tiles; the builders' round-4 winner shape, PERF.md
+        # "Historical"). Longer sequences keep the generic tiles.
+        block_q = min(512, s_q)
+        block_kv = s_kv
+        block_q_bwd = block_q_bwd or min(512, s_q)
+        block_kv_bwd = block_kv_bwd or s_kv
 
-def _tpu_kernel_eligible(q, k):
-    """One gate for every Pallas dispatcher (in-repo and official kernels):
-    TPU backend + 128-aligned sequence lengths. Shared so the impls can't
-    drift — a rule change here applies to both."""
-    return (jax.default_backend() == "tpu"
-            and q.shape[1] % 128 == 0 and k.shape[1] % 128 == 0)
+    def kernel(q, k, v):
+        return pallas_flash_attention(
+            q, k, v, causal, scale, min(block_q or 256, s_q),
+            min(block_kv or 512, s_kv), interpret, block_q_bwd, block_kv_bwd)
+
+    return shard_attention(kernel, mesh, q, k, v)
 
 
-def jax_flash_attention(q, k, v, causal=True, scale=None):
+def jax_flash_attention(q, k, v, causal=True, scale=None, mesh=None):
     """The official JAX TPU flash kernel behind our [b, s, h, d] signature.
 
     ``jax.experimental.pallas.ops.tpu.flash_attention`` is the
     production-tuned Mosaic kernel (fwd + custom-vjp bwd, [b, h, s, d]
-    layout). Exposed as ``attention_impl="jax_flash"`` so the bench can
-    compare it head-to-head with the in-repo kernel and XLA attention —
-    whichever wins becomes the recommended default. Off-TPU (CPU tests)
-    this falls back to the same chunked-XLA path as ``flash_attention``,
-    so parity tests exercise identical semantics.
+    layout). Exposed as ``attention_impl="jax_flash"`` for head-to-head
+    comparison with the in-repo kernel. It has no interpret mode here: off
+    a TPU target it takes the same XLA scan as ``flash_attention`` (logged).
 
     Known integration asymmetry: under ``remat`` the in-repo kernel saves
     its lse residual by checkpoint name ("minimal" policy), so its backward
     skips the forward recompute; the official kernel's residuals are
-    internal to its custom vjp and get recomputed. Sweep rows measure that
-    real user-facing cost; ``tools/bench_attention.py`` (no remat) is the
-    raw kernel-vs-kernel comparison.
+    internal to its custom vjp and get recomputed.
     """
-    if _tpu_kernel_eligible(q, k):
-        from jax.experimental.pallas.ops.tpu.flash_attention import (
-            flash_attention as _jax_flash)
+    reason = _pallas_unusable(q, k, interpret=False)
+    if reason is not None:
+        from .pallas import note_fallback
 
-        sm_scale = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
+        note_fallback("jax_flash_attention", reason)
+        return _chunked_attention(q, k, v, causal=causal, scale=scale)
+    from jax.experimental.pallas.ops.tpu.flash_attention import (
+        flash_attention as _jax_flash)
+
+    sm_scale = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
+
+    def kernel(q, k, v):
         out = _jax_flash(
             q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
             v.transpose(0, 2, 1, 3), causal=causal, sm_scale=sm_scale)
         return out.transpose(0, 2, 1, 3)
-    return _chunked_attention(q, k, v, causal=causal, scale=scale)
+
+    return shard_attention(kernel, mesh, q, k, v)
 
 
 def _chunked_attention(q, k, v, causal=True, scale=None, block_size=512):

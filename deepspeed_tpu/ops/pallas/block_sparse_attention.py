@@ -26,7 +26,6 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import CompilerParams as _CompilerParams
 
 NEG_INF = -1e30
 LANES = 128
@@ -340,7 +339,7 @@ class BlockSparseAttention:
                 jax.ShapeDtypeStruct((b * h, s_q, d), q.dtype),
                 jax.ShapeDtypeStruct((b * h, s_q, LANES), jnp.float32),
             ],
-            compiler_params=_CompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
             interpret=self.interpret,
         )(jnp.asarray(self._fwd_idx), jnp.asarray(self._fwd_cnt), qr, kr, vr)
@@ -394,7 +393,7 @@ class BlockSparseAttention:
                 ],
             ),
             out_shape=jax.ShapeDtypeStruct((b * h, s_q, d), q.dtype),
-            compiler_params=_CompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
             interpret=self.interpret,
         )(jnp.asarray(self._fwd_idx), jnp.asarray(self._fwd_cnt),
@@ -442,7 +441,7 @@ class BlockSparseAttention:
                 jax.ShapeDtypeStruct((b * h, s_kv, d), k.dtype),
                 jax.ShapeDtypeStruct((b * h, s_kv, d), v.dtype),
             ],
-            compiler_params=_CompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
             interpret=self.interpret,
         )(jnp.asarray(self._bwd_idx), jnp.asarray(self._bwd_cnt),

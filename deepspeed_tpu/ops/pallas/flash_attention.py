@@ -32,7 +32,6 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import CompilerParams as _CompilerParams
 
 NEG_INF = -1e30
 LANES = 128
@@ -183,7 +182,7 @@ def _flash_fwd_single(qr, kr, vr, bh, s_q, s_kv, d, causal, scale, bq,
         ],
         out_specs=out_specs,
         out_shape=out_shape,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")
         ),
         interpret=interpret,
@@ -267,7 +266,7 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_kv, interpret,
             pltpu.VMEM((bq, LANES), jnp.float32),
             pltpu.VMEM((bq, d), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
@@ -529,7 +528,7 @@ def _flash_bwd(q, k, v, out, lse, g, causal, scale, block_q, block_kv, interpret
             out_specs=pl.BlockSpec((1, bq, d), lambda i, j: (i, j, 0),
                                    memory_space=pltpu.VMEM),
             out_shape=jax.ShapeDtypeStruct((b * h, s_q, d), q.dtype),
-            compiler_params=_CompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel")
             ),
             interpret=interpret,
@@ -554,7 +553,7 @@ def _flash_bwd(q, k, v, out, lse, g, causal, scale, block_q, block_kv, interpret
                 pltpu.VMEM((bq, d), jnp.float32),
                 pltpu.VMEM((bq, LANES), jnp.float32),
             ],
-            compiler_params=_CompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")
             ),
             interpret=interpret,
@@ -582,7 +581,7 @@ def _flash_bwd(q, k, v, out, lse, g, causal, scale, block_q, block_kv, interpret
                 jax.ShapeDtypeStruct((b * h, s_kv, d), k.dtype),
                 jax.ShapeDtypeStruct((b * h, s_kv, d), v.dtype),
             ],
-            compiler_params=_CompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel")
             ),
             interpret=interpret,
@@ -613,7 +612,7 @@ def _flash_bwd(q, k, v, out, lse, g, causal, scale, block_q, block_kv, interpret
                 pltpu.VMEM((bkv, d), jnp.float32),
                 pltpu.VMEM((bkv, d), jnp.float32),
             ],
-            compiler_params=_CompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")
             ),
             interpret=interpret,

@@ -17,10 +17,12 @@ decode attention play (arXiv:2207.00032), TPU-native.
 Shape/structure notes (the TPU way, same idioms as
 ``ops/pallas/flash_attention.py``):
 
-- grid = (slots, kv_heads, kv_splits, blocks_per_split). The kv-head
-  dimension rides the grid so GQA costs nothing: each cell runs the
-  ``n_heads // kv_heads`` query rows of ONE kv head against that head's
-  slice of the pool — the q block is ``[hq, dh]``, dense in the MXU.
+- grid = (slots, kv_splits, blocks_per_split). A grid cell streams one
+  physical block WHOLE — all its kv heads, ``[bs, kvh, dh]`` — because
+  Mosaic only accepts a block whose last two dims are the array's own (or
+  8/128-aligned): one head of many, ``(1, bs, 1, dh)``, is refused. GQA
+  still costs nothing: the ``n_heads // kv_heads`` query rows of a group
+  each read the same resident tile.
 - split-KV: each of the ``kv_splits`` grid cells owns a contiguous run of
   table columns and produces a PARTIAL (max, sum, accumulator) triple; the
   partials combine outside the kernel (a tiny ``[S, kvh, splits, hq]``
@@ -45,10 +47,10 @@ Shape/structure notes (the TPU way, same idioms as
   path reads bit-identical dequantized values, at half the pool HBM
   traffic of gathering an already-dequantized view.
 
-Tier-1 runs this kernel under ``interpret=True`` on CPU (the same
-discipline as the flash kernels' interpret tests), so correctness — ragged
-cursors, GQA, alibi, int8, garbage-block exclusion — is pinned without
-chips.
+Tier-1 runs this kernel under ``interpret=True`` on CPU (the models'
+``attention_interpret``, the same switch as the flash kernels' interpret
+tests), so correctness — ragged cursors, GQA, alibi, int8, garbage-block
+exclusion — is pinned without chips.
 """
 
 import functools
@@ -59,7 +61,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import CompilerParams
 
 NEG_INF = -1e30
 LANES = 128
@@ -74,61 +75,71 @@ def _fit_splits(requested, n_columns):
     return s
 
 
-def fused_decode_supported(cfg, block_size, *, mp_world_size=1,
-                           backend=None, kv_dtype=""):
+def fused_decode_supported(cfg, block_size, *, n_slots=8, blocks_per_slot=8,
+                           tp=1, kv_dtype=""):
     """Capability probe for the fused backend: ``(ok, reason)``.
 
-    GQA, rope and alibi are supported natively (rope is applied to q/k
-    before the cache, so the pool already holds post-rope keys; alibi is an
-    in-kernel bias; GQA rides the grid). What is NOT:
-
-    - banded local-attention layers (GPT-Neo style): the per-layer band
-      mask isn't implemented in-kernel — the gather path stays correct;
-    - on a real TPU backend, lane/sublane alignment: ``head_dim`` must fill
-      the 128-lane minor dim and ``block_size`` the 8-sublane tile, a
-      model-sharded mesh needs the gather path (``pallas_call`` carries no
-      SPMD partitioning rule, so GSPMD would replicate the pool), and int8
-      pools stay on the gather path until a chip session validates the
-      per-(token, head) scale tiles' (bs, 1) layout under Mosaic — the
-      probe must never approve a shape the compiler then rejects, or the
-      warn-and-fall-back contract becomes a hard failure at first
-      dispatch. Interpret mode (every non-TPU backend, which is how tier-1
-      pins the kernel on CPU) has none of these constraints.
+    Two structural refusals (what the kernel does not implement: banded
+    local-attention layers, ragged GQA groups), then the question goes to
+    the compiler: the kernel is lowered for the target platform at the
+    engine's per-device geometry (``cfg`` heads / ``tp``, ``block_size``,
+    pool dtype) — and compiled, when the backend is a TPU — and a refusal
+    comes back as the compiler's own message. A hand-kept rule list here
+    once approved a pool blocking Mosaic rejects; the probe must never
+    approve a shape that then fails at first dispatch. With
+    ``cfg.attention_interpret`` the Pallas interpreter runs the kernel and
+    has no layout constraints.
     """
+    from . import compiler_verdict, unavailable_reason
+
     if cfg.local_attention_window > 0:
         return False, ("local_attention_window > 0: banded layer masks are "
                        "not implemented in the fused kernel")
     if cfg.n_heads % cfg.kv_heads:
         return False, (f"n_heads {cfg.n_heads} not a multiple of kv_heads "
                        f"{cfg.kv_heads}")
-    backend = backend if backend is not None else jax.default_backend()
-    if backend == "tpu":
-        if cfg.head_dim % LANES:
-            return False, (f"head_dim {cfg.head_dim} not a multiple of the "
-                           f"{LANES}-lane minor dim (TPU)")
-        if block_size % 8:
-            return False, (f"kv_pool.block_size {block_size} not a multiple "
-                           "of the 8-sublane tile (TPU)")
-        if mp_world_size > 1:
-            return False, ("tensor-parallel mesh: pallas_call has no SPMD "
-                           "partitioning rule — the gather path shards the "
-                           "kv-head axis instead")
-        if kv_dtype == "int8":
-            return False, ("kv_dtype=int8 on TPU: the in-kernel dequant's "
-                           "per-(token, head) scale tiles are not yet "
-                           "chip-validated under Mosaic — gather path "
-                           "until a live-TPU session clears them")
-    return True, ""
+    if cfg.attention_interpret:
+        return True, ""
+    reason = unavailable_reason()
+    if reason is not None:
+        return False, reason
+    shard = tp if cfg.kv_heads % tp == 0 else 1
+    kvh, nh, dh = cfg.kv_heads // shard, cfg.n_heads // shard, cfg.head_dim
+    int8 = kv_dtype == "int8"
+    sds = jax.ShapeDtypeStruct
+    pool = sds((n_slots * blocks_per_slot + 1, block_size, kvh, dh),
+               jnp.int8 if int8 else cfg.compute_dtype)
+    scale = sds(pool.shape[:-1] + (1,), jnp.float32) if int8 else None
+    row = sds((n_slots, kvh, dh), cfg.compute_dtype)
+    slopes = jnp.ones((nh,), jnp.float32) \
+        if cfg.position_embedding == "alibi" else None
+
+    def call(q, k_new, v_new, kc, vc, table, pos, ks, vs):
+        return paged_flash_decode(q, k_new, v_new, kc, vc, table, pos,
+                                  k_scale=ks, v_scale=vs,
+                                  scale=cfg.attn_scale, alibi_slopes=slopes)
+
+    ok, reason = compiler_verdict(
+        call, sds((n_slots, nh, dh), cfg.compute_dtype), row, row, pool, pool,
+        sds((n_slots, blocks_per_slot), jnp.int32),
+        sds((n_slots,), jnp.int32), scale, scale)
+    return ok, reason and f"TPU compiler: {reason}"
 
 
 def _decode_kernel(table_ref, pos_ref, q_ref, k_ref, v_ref, *rest, scale,
-                   block_size, blocks_per_split, int8, alibi,
-                   m_prev_bcast):
-    """One (slot, kv_head, split, block) cell: stream one physical block,
-    fold it into the split's running (m, l, acc) triple, emit the partial
-    at the split's last block. ``table_ref``/``pos_ref`` are the
-    scalar-prefetched block table and cursors (the index maps already used
-    them to aim the DMA; the body re-reads the cursor for the mask)."""
+                   block_size, blocks_per_split, int8, alibi, stat_lanes):
+    """One (slot, split, block) cell: stream one physical block — every kv
+    head of it — fold it into the split's running (m, l, acc) triples, emit
+    the partials at the split's last block. ``table_ref``/``pos_ref`` are
+    the scalar-prefetched block table and cursors (the index maps already
+    used them to aim the DMA; the body re-reads the cursor for the mask).
+
+    Layout: the k/v tile is [bs, kvh, dh] with (kvh, dh) on the (sublane,
+    lane) dims, so each of the ``hq`` query rows of a kv group is one
+    [kvh, dh] tile and a block is consumed with broadcast-multiplies and
+    reductions over the lane dim (q·k) and the leading dim (softmax sums,
+    p·v) — VPU work with no relayout. One query row per slot leaves the MXU
+    nothing to chew on, and the step is bound by streaming the pool."""
     idx = 0
     if int8:
         ks_ref, vs_ref = rest[idx], rest[idx + 1]
@@ -138,9 +149,11 @@ def _decode_kernel(table_ref, pos_ref, q_ref, k_ref, v_ref, *rest, scale,
         slopes_ref = rest[idx]
         idx += 1
     o_ref, m_ref, l_ref, m_scr, l_scr, acc_scr = rest[idx:]
+    hq, kvh = q_ref.shape[1], q_ref.shape[2]
 
     s = pl.program_id(0)
-    jb = pl.program_id(3)
+    sp = pl.program_id(1)
+    jb = pl.program_id(2)
 
     @pl.when(jb == 0)
     def _init():
@@ -149,58 +162,58 @@ def _decode_kernel(table_ref, pos_ref, q_ref, k_ref, v_ref, *rest, scale,
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     pos = pos_ref[s]                       # valid pool window = [0, pos)
-    sp = pl.program_id(2)
     base = (sp * blocks_per_split + jb) * block_size
 
     @pl.when(base < pos)
     def _step():
         # the allowlisted attention-f32 island (see sanitizer
-        # ATTENTION_F32_ALLOW): QK logits and the PV accumulator run fp32
-        # on purpose — softmax numerics — with narrow dot INPUTS (the
-        # MXU's native mode, same as the flash kernels)
+        # ATTENTION_F32_ALLOW): logits, softmax and the PV accumulator run
+        # fp32 on purpose — softmax numerics
         with jax.named_scope("paged_flash_decode"):
-            q = q_ref[0, 0]                # [hq, dh]
-            k = k_ref[0, :, 0]             # [bs, dh]
-            v = v_ref[0, :, 0]
+            k = k_ref[0]                   # [bs, kvh, dh]
+            v = v_ref[0]
             if int8:
                 # dequantize ON the tile — elementwise-identical to
                 # dequantize_blockwise (f32 payload * per-(token,head)
                 # scale, then the compute-dtype cast the gather view takes)
-                k = (k.astype(jnp.float32) * ks_ref[0, :, 0]).astype(q.dtype)
-                v = (v.astype(jnp.float32) * vs_ref[0, :, 0]).astype(q.dtype)
-            sc = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale  # [hq, bs] f32
-            col = jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
-            if alibi:
-                # slopes * (kv_pos - cursor): the same int-difference-then-
-                # fp32-multiply as the gather path's per-row alibi
-                dist = (base + col - pos).astype(jnp.float32)
-                sc = sc + slopes_ref[0][:, None] * dist
-            sc = jnp.where(base + col < pos, sc, NEG_INF)
-            m_prev = m_scr[:, :1]
-            m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
-            p = jnp.exp(sc - m_new)
-            corr = jnp.exp(m_prev - m_new)
-            l_scr[...] = l_scr[...] * corr + jnp.broadcast_to(
-                jnp.sum(p, axis=-1, keepdims=True), l_scr.shape)
-            acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
-                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
+                k = (k.astype(jnp.float32) * ks_ref[0]).astype(q_ref.dtype)
+                v = (v.astype(jnp.float32) * vs_ref[0]).astype(q_ref.dtype)
+            k = k.astype(jnp.float32)
+            v = v.astype(jnp.float32)
+            row = jax.lax.broadcasted_iota(
+                jnp.int32, (block_size, kvh, 1), 0)
+            live = base + row < pos
+            for j in range(hq):            # query rows of each kv group
+                q = q_ref[0, j].astype(jnp.float32)            # [kvh, dh]
+                sc = jnp.sum(k * q[None], axis=-1,
+                             keepdims=True) * scale            # [bs, kvh, 1]
+                if alibi:
+                    # slopes * (kv_pos - cursor): the same int-difference-
+                    # then-fp32-multiply as the gather path's per-row alibi
+                    dist = (base + row - pos).astype(jnp.float32)
+                    sc = sc + slopes_ref[j][None] * dist
+                sc = jnp.where(live, sc, NEG_INF)
+                m_prev = m_scr[j][:, :1]                       # [kvh, 1]
+                m_new = jnp.maximum(m_prev, jnp.max(sc, axis=0))
+                p = jnp.exp(sc - m_new[None])                  # [bs, kvh, 1]
+                corr = jnp.exp(m_prev - m_new)
+                l_scr[j] = l_scr[j] * corr + jnp.broadcast_to(
+                    jnp.sum(p, axis=0), l_scr.shape[1:])
+                acc_scr[j] = acc_scr[j] * corr + jnp.sum(p * v, axis=0)
+                m_scr[j] = jnp.broadcast_to(m_new, m_scr.shape[1:])
 
     @pl.when(jb == blocks_per_split - 1)
     def _emit():
         # partials, not normalized output: splits with no valid positions
         # emit (m=-inf, l=0, acc=0) and drop out of the combine exactly
-        o_ref[0, 0, 0] = acc_scr[...]
-        m_ref[0, 0, 0] = m_scr[...][:, :m_prev_bcast]
-        l_ref[0, 0, 0] = l_scr[...][:, :m_prev_bcast]
+        o_ref[0, 0] = acc_scr[...]
+        m_ref[0, 0] = m_scr[...][..., :stat_lanes]
+        l_ref[0, 0] = l_scr[...][..., :stat_lanes]
 
 
 def paged_flash_decode(q, k_new, v_new, kc, vc, table, pos, *, k_scale=None,
                        v_scale=None, scale=None, alibi_slopes=None,
-                       kv_splits=4, interpret=None):
+                       kv_splits=4, interpret=False, mesh=None):
     """Fused paged decode attention: softmax(q·K/√d)·V for ONE query row per
     slot, where K/V live in the paged pool and the kernel walks the block
     table itself.
@@ -216,10 +229,47 @@ def paged_flash_decode(q, k_new, v_new, kc, vc, table, pos, *, k_scale=None,
       index map reads it to aim each block DMA — no dense view exists);
     - ``pos``: [S] int32 cursors; pool positions [0, pos) are attended,
       everything past the cursor (ragged mid-block tails, unbound
-      garbage-block columns) is masked/skipped.
+      garbage-block columns) is masked/skipped;
+    - ``interpret``: run under the Pallas interpreter (the models'
+      ``attention_interpret``; CPU tests);
+    - ``mesh``: the mesh the decode program is partitioned over. On more
+      than one device the kernel runs inside a ``shard_map`` with the kv
+      heads (and their query groups) split over ``model`` when they
+      divide it — GSPMD cannot partition a Mosaic call.
 
     Returns [S, n_heads, dh] in ``q.dtype``.
     """
+    from . import shard_kernel
+
+    # heads split over `model` only when the KV heads divide it (the pool's
+    # own sharding rule) — q's heads must follow their kv group
+    tp = mesh.shape.get("model", 1) if mesh is not None else 1
+    head_axes = ("model",) if kc.shape[2] % tp == 0 else ()
+    at = lambda dim: {dim: head_axes}
+    slopes = None if alibi_slopes is None \
+        else jnp.asarray(alibi_slopes, jnp.float32)
+    int8, alibi = k_scale is not None, slopes is not None
+    operands = [q, k_new, v_new, kc, vc, table, pos]
+    dim_axes = [at(1), at(1), at(1), at(2), at(2), {}, {}]
+    if int8:
+        operands += [k_scale, v_scale]
+        dim_axes += [at(2), at(2)]
+    if alibi:
+        operands.append(slopes)
+        dim_axes.append(at(0))
+
+    def per_shard(q, k_new, v_new, kc, vc, table, pos, *rest):
+        ks, vs = rest[:2] if int8 else (None, None)
+        return _paged_flash_decode(
+            q, k_new, v_new, kc, vc, table, pos, ks, vs, scale,
+            rest[-1] if alibi else None, kv_splits, interpret)
+
+    return shard_kernel(per_shard, mesh, operands, dim_axes, [at(1)])
+
+
+def _paged_flash_decode(q, k_new, v_new, kc, vc, table, pos, k_scale,
+                        v_scale, scale, alibi_slopes, kv_splits, interpret):
+    """One device's share of ``paged_flash_decode`` (local head counts)."""
     s_dim, n_heads, dh = q.shape
     n_blocks, block_size, kvh, _ = kc.shape
     nb_cols = table.shape[1]
@@ -227,65 +277,64 @@ def paged_flash_decode(q, k_new, v_new, kc, vc, table, pos, *, k_scale=None,
     scale = scale if scale is not None else 1.0 / math.sqrt(dh)
     int8 = k_scale is not None
     alibi = alibi_slopes is not None
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
 
     splits = _fit_splits(kv_splits, nb_cols)
     bps = nb_cols // splits
-    grid = (s_dim, kvh, splits, bps)
-    qr = q.reshape(s_dim, kvh, hq, dh)
+    grid = (s_dim, splits, bps)
+    # head h = g * hq + j (kv group g, row j): kernel layout [S, hq, kvh, dh]
+    # puts (kvh, dh) on the tile dims next to the pool block's
+    qt = q.reshape(s_dim, kvh, hq, dh).transpose(0, 2, 1, 3)
 
-    def kv_index(s, g, sp, jb, table_ref, pos_ref):
+    def kv_index(s, sp, jb, table_ref, pos_ref):
         # THE point of the kernel: the block-table indirection lives here.
         # Unbound columns hold the reserved garbage block — always a valid
-        # pool row, compute-skipped in the body.
-        return (table_ref[s, sp * bps + jb], 0, g, 0)
+        # pool row, compute-skipped in the body. A block is fetched whole
+        # (all kv heads): Mosaic wants a block's last two dims to be the
+        # array's own (or 8/128-aligned), which one head of many is not.
+        return (table_ref[s, sp * bps + jb], 0, 0, 0)
 
-    def q_index(s, g, sp, jb, table_ref, pos_ref):
-        return (s, g, 0, 0)
+    def q_index(s, sp, jb, table_ref, pos_ref):
+        return (s, 0, 0, 0)
 
-    def out_index(s, g, sp, jb, table_ref, pos_ref):
-        return (s, g, sp, 0, 0)
+    def out_index(s, sp, jb, table_ref, pos_ref):
+        return (s, sp, 0, 0, 0)
 
     in_specs = [
-        pl.BlockSpec((1, 1, hq, dh), q_index),
-        pl.BlockSpec((1, block_size, 1, dh), kv_index),
-        pl.BlockSpec((1, block_size, 1, dh), kv_index),
+        pl.BlockSpec((1, hq, kvh, dh), q_index),
+        pl.BlockSpec((1, block_size, kvh, dh), kv_index),
+        pl.BlockSpec((1, block_size, kvh, dh), kv_index),
     ]
-    operands = [qr, kc, vc]
+    operands = [qt, kc, vc]
     if int8:
-        in_specs += [pl.BlockSpec((1, block_size, 1, 1), kv_index),
-                     pl.BlockSpec((1, block_size, 1, 1), kv_index)]
+        in_specs += [pl.BlockSpec((1, block_size, kvh, 1), kv_index),
+                     pl.BlockSpec((1, block_size, kvh, 1), kv_index)]
         operands += [k_scale, v_scale]
     if alibi:
-        slopes = jnp.asarray(alibi_slopes, jnp.float32).reshape(kvh, hq)
         in_specs.append(pl.BlockSpec(
-            (1, hq), lambda s, g, sp, jb, t, p: (g, 0)))
-        operands.append(slopes)
+            (hq, kvh, 1), lambda s, sp, jb, t, p: (0, 0, 0)))
+        operands.append(alibi_slopes.reshape(kvh, hq).T[..., None])
 
     # the m/l partials keep a LANES-broadcast minor dim in scratch (TPU vreg
     # layout; see flash_attention.py). Interpret mode emits a single lane to
     # HBM; a real TPU emits the full broadcast — a 1-lane minor output dim
-    # is a layout Mosaic tiling commonly rejects, and the probe must never
-    # approve a shape the compiler then refuses
+    # is a layout Mosaic tiling commonly rejects
     stat_lanes = 1 if interpret else LANES
     out_shape = [
-        jax.ShapeDtypeStruct((s_dim, kvh, splits, hq, dh), jnp.float32),
-        jax.ShapeDtypeStruct((s_dim, kvh, splits, hq, stat_lanes),
+        jax.ShapeDtypeStruct((s_dim, splits, hq, kvh, dh), jnp.float32),
+        jax.ShapeDtypeStruct((s_dim, splits, hq, kvh, stat_lanes),
                              jnp.float32),
-        jax.ShapeDtypeStruct((s_dim, kvh, splits, hq, stat_lanes),
+        jax.ShapeDtypeStruct((s_dim, splits, hq, kvh, stat_lanes),
                              jnp.float32),
     ]
     out_specs = [
-        pl.BlockSpec((1, 1, 1, hq, dh), out_index),
-        pl.BlockSpec((1, 1, 1, hq, stat_lanes), out_index),
-        pl.BlockSpec((1, 1, 1, hq, stat_lanes), out_index),
+        pl.BlockSpec((1, 1, hq, kvh, dh), out_index),
+        pl.BlockSpec((1, 1, hq, kvh, stat_lanes), out_index),
+        pl.BlockSpec((1, 1, hq, kvh, stat_lanes), out_index),
     ]
 
     kernel = functools.partial(
         _decode_kernel, scale=scale, block_size=block_size,
-        blocks_per_split=bps, int8=int8, alibi=alibi,
-        m_prev_bcast=stat_lanes)
+        blocks_per_split=bps, int8=int8, alibi=alibi, stat_lanes=stat_lanes)
     acc, m_p, l_p = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -294,38 +343,38 @@ def paged_flash_decode(q, k_new, v_new, kc, vc, table, pos, *, k_scale=None,
             in_specs=in_specs,
             out_specs=out_specs,
             scratch_shapes=[
-                pltpu.VMEM((hq, LANES), jnp.float32),
-                pltpu.VMEM((hq, LANES), jnp.float32),
-                pltpu.VMEM((hq, dh), jnp.float32),
+                pltpu.VMEM((hq, kvh, LANES), jnp.float32),
+                pltpu.VMEM((hq, kvh, LANES), jnp.float32),
+                pltpu.VMEM((hq, kvh, dh), jnp.float32),
             ],
         ),
         out_shape=out_shape,
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(table, pos, *operands)
 
     # -- combine across the split-KV grid (tiny fp32 reduction) ------------
-    m_p = m_p[..., 0]                                    # [S, kvh, sp, hq]
+    m_p = m_p[..., 0]                                    # [S, sp, hq, kvh]
     l_p = l_p[..., 0]
-    m_c = jnp.max(m_p, axis=2)                           # [S, kvh, hq]
-    w = jnp.exp(m_p - m_c[:, :, None, :])                # empty splits -> 0
-    l_c = jnp.sum(l_p * w, axis=2)
-    acc_c = jnp.sum(acc * w[..., None], axis=2)          # [S, kvh, hq, dh]
+    m_c = jnp.max(m_p, axis=1)                           # [S, hq, kvh]
+    w = jnp.exp(m_p - m_c[:, None])                      # empty splits -> 0
+    l_c = jnp.sum(l_p * w, axis=1)
+    acc_c = jnp.sum(acc * w[..., None], axis=1)          # [S, hq, kvh, dh]
 
     # -- fold the CURRENT token's fresh k/v row (compute dtype, position
     # pos — the row the gather path writes into the view pre-attention;
     # alibi distance is 0 there). Elementwise mul+sum, not a dot: this is
-    # [S, kvh, hq] of work, VPU noise.
-    qf = qr.astype(jnp.float32)
-    s_new = jnp.sum(qf * k_new.astype(jnp.float32)[:, :, None, :],
-                    axis=-1) * scale                     # [S, kvh, hq]
+    # [S, hq, kvh] of work, VPU noise.
+    s_new = jnp.sum(qt.astype(jnp.float32)
+                    * k_new.astype(jnp.float32)[:, None],
+                    axis=-1) * scale                     # [S, hq, kvh]
     m_t = jnp.maximum(m_c, s_new)
     corr = jnp.exp(m_c - m_t)
     w_new = jnp.exp(s_new - m_t)
     l_t = l_c * corr + w_new
     acc_t = acc_c * corr[..., None] \
-        + w_new[..., None] * v_new.astype(jnp.float32)[:, :, None, :]
-    out = acc_t / jnp.maximum(l_t, 1e-30)[..., None]
-    return out.reshape(s_dim, n_heads, dh).astype(q.dtype)
+        + w_new[..., None] * v_new.astype(jnp.float32)[:, None]
+    out = acc_t / jnp.maximum(l_t, 1e-30)[..., None]     # [S, hq, kvh, dh]
+    return out.transpose(0, 2, 1, 3).reshape(s_dim, n_heads, dh) \
+        .astype(q.dtype)

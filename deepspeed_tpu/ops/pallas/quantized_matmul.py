@@ -34,7 +34,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import CompilerParams as _CompilerParams
 
 
 def _int8_kernel(x_ref, q_ref, s_ref, o_ref, *, n_groups, dot_dtype):
@@ -60,8 +59,11 @@ def _int4_kernel(xe_ref, xo_ref, q_ref, s_ref, o_ref, *, n_groups, dot_dtype):
     kb = pl.program_id(1)
     u = q_ref[...]                                   # [bk2, bn] uint8
     bk2, bn = u.shape
-    lo = (u & jnp.uint8(0xF)).astype(jnp.int8) - 8   # even input rows
-    hi = (u >> 4).astype(jnp.int8) - 8               # odd input rows
+    # nibble arithmetic in int32: Mosaic on v5e has no i8 vector subtract
+    # ("failed to legalize operation 'arith.subi'" on vector<..xi8>)
+    u = u.astype(jnp.int32)
+    lo = (u & 0xF) - 8                               # even input rows
+    hi = ((u >> 4) & 0xF) - 8                        # odd input rows
     s = s_ref[...].astype(jnp.float32)               # [nG, bn]
     # nibble row p holds input rows 2p (lo) and 2p+1 (hi); both belong to
     # group p // (g/2), so one [nG, g/2, bn] broadcast scales either nibble
@@ -153,7 +155,7 @@ def quantized_matmul(x, q, scale, *, bits, block_k=512, block_n=512,
         grid=grid,
         out_specs=out_spec,
         out_shape=out_shape,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )
@@ -190,3 +192,23 @@ def quantized_matmul(x, q, scale, *, bits, block_k=512, block_n=512,
     else:
         return None
     return y[:m].astype(x.dtype)
+
+
+def quantized_matmul_supported(k, n, groups, *, bits, dtype, m=8):
+    """``(ok, reason)``: does the kernel compile for the TPU at a ``[k, n]``
+    weight with ``groups`` scale groups (decode-sized ``m``)? Untileable
+    shapes and compiler refusals both come back as the reason."""
+    from . import compiler_verdict
+
+    sds = jax.ShapeDtypeStruct
+    q = sds((k // 2, n), jnp.uint8) if bits == 4 else sds((k, n), jnp.int8)
+
+    def call(x, q, scale):
+        y = quantized_matmul(x, q, scale, bits=bits)
+        if y is None:
+            raise ValueError(f"no legal tiling for k={k} n={n} "
+                             f"group_size={k // groups}.")
+        return y
+
+    return compiler_verdict(call, sds((m, k), dtype), q,
+                            sds((groups, 1, n), jnp.float32))

@@ -172,13 +172,14 @@ def build_mesh(mesh_config=None, devices=None):
     dims = resolve_mesh_dims(mesh_config, len(devices))
     axis_names = tuple(dims.keys())
     shape = tuple(dims.values())
-    # mesh_utils gives ICI-aware device orderings on real TPU slices; fall back to a
-    # plain reshape for CPU/virtual devices.
-    try:
+    # mesh_utils gives ICI-aware device orderings on TPU slices and its
+    # errors there are real (a shape the slice cannot host); CPU / virtual
+    # devices have no topology to respect and take a plain reshape.
+    if devices[0].platform == "tpu":
         from jax.experimental import mesh_utils
 
         device_array = mesh_utils.create_device_mesh(shape, devices=devices)
-    except Exception:
+    else:
         device_array = np.asarray(devices).reshape(shape)
     return Mesh(device_array, axis_names)
 

@@ -383,23 +383,22 @@ def compile_with_partitioned_hlo(lowered):
     import jax
 
     def _reset_cache():
-        # the cache object is a lazily-initialized global: flipping the dir
+        # the cache object is a lazily-initialized global: flipping the
         # config alone does not evict an already-initialized instance
-        try:
-            from jax._src import compilation_cache as _cc
+        from jax._src import compilation_cache as _cc
 
-            _cc.reset_cache()
-        except Exception:
-            pass
+        _cc.reset_cache()
 
     d = tempfile.mkdtemp(prefix="collective_audit_")
     # a persistent-compile-cache HIT skips the pass pipeline entirely — no
     # dump gets written — so the cache must be hard-off for this one compile
     # (observed: the second audit of an identical program returned no
-    # snapshot; compiler_options are NOT part of the cache key).
-    cache_dir_prev = jax.config.jax_compilation_cache_dir
+    # snapshot; compiler_options are NOT part of the cache key). The cache
+    # is switched off, not re-pointed: its directory stays whatever
+    # JAX_COMPILATION_CACHE_DIR or utils/compile_cache.py chose.
+    cache_prev = jax.config.jax_enable_compilation_cache
     try:
-        jax.config.update("jax_compilation_cache_dir", None)
+        jax.config.update("jax_enable_compilation_cache", False)
         _reset_cache()
         compiled = lowered.compile(compiler_options={
             "xla_dump_to": d,
@@ -408,15 +407,15 @@ def compile_with_partitioned_hlo(lowered):
         files = glob.glob(os.path.join(d, "*after_spmd-partitioning*"))
         if not files:
             raise RuntimeError(
-                "XLA dumped no after_spmd-partitioning snapshot (flag "
-                "unsupported by this jaxlib?); cannot audit wire dtypes")
+                "XLA dumped no after_spmd-partitioning snapshot; cannot "
+                "audit wire dtypes")
         # the audited step is by far the largest module in the dump dir
         path = max(files, key=os.path.getsize)
         with open(path) as f:
             text = f.read()
     finally:
-        jax.config.update("jax_compilation_cache_dir", cache_dir_prev)
-        _reset_cache()  # re-initialize with the restored dir on next use
+        jax.config.update("jax_enable_compilation_cache", cache_prev)
+        _reset_cache()  # re-initialize on next use
         shutil.rmtree(d, ignore_errors=True)
     return compiled, text
 

@@ -54,8 +54,6 @@ class FlopsProfiler:
         else:
             self._compiled = lowered.compile()
         cost = self._compiled.cost_analysis()
-        if isinstance(cost, list):  # older jax returns [dict]
-            cost = cost[0] if cost else {}
         self._flops = float(cost.get("flops", 0.0)) if cost else 0.0
         self._bytes = float(cost.get("bytes accessed", 0.0)) if cost else 0.0
         return self

@@ -1720,6 +1720,35 @@ class DeepSpeedEngine:
         # engine's jitted wrappers frees its executables
         gc.collect()
 
+    def lower_train_step(self, batch=None):
+        """The fused train step as a ``jax.stages.Lowered`` — for audits and
+        smoke checks that read the program (``.compile().as_text()``,
+        ``.compile().memory_analysis()``). ``batch``: a host micro-batch to
+        shape the step for (gradient_accumulation_steps == 1); default: the
+        shape of the last batch ``train_batch`` ran. Lowering only traces
+        avals — nothing executes and nothing is donated; compiling the
+        result goes through the persistent compilation cache like the
+        step's own first dispatch."""
+        if self._train_step_fn is None:
+            self._build_train_step()
+        struct = self._last_batch_struct
+        if batch is not None:
+            if self.gradient_accumulation_steps_ > 1:
+                raise ConfigError(
+                    "lower_train_step(batch) shapes a single micro-batch; "
+                    "with gradient accumulation call it after train_batch")
+            struct = jax.tree_util.tree_map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                               sharding=a.sharding),
+                self._shard_batch(batch))
+        if struct is None:
+            raise ConfigError("lower_train_step: pass a batch, or run "
+                              "train_batch once first")
+        return self._train_step_fn.lower(
+            self.params, self.optimizer_state, struct, self._scale,
+            self._good_steps, self._rng, jnp.asarray(0.0, jnp.float32),
+            jnp.asarray(1.0, jnp.float32))
+
     def collective_wire_stats(self, refresh=False):
         """Per-step collective wire bytes of the compiled train step, by
         kind and payload dtype (``profiling/collectives.py``).
@@ -1750,12 +1779,7 @@ class DeepSpeedEngine:
             return None
         from ..profiling.collectives import audit_lowered
 
-        # lower() only traces avals — live trees are fine (nothing executes,
-        # nothing is donated), the batch rides as ShapeDtypeStructs
-        lowered = self._train_step_fn.lower(
-            self.params, self.optimizer_state, self._last_batch_struct,
-            self._scale, self._good_steps, self._rng,
-            jnp.asarray(0.0, jnp.float32), jnp.asarray(1.0, jnp.float32))
+        lowered = self.lower_train_step()
         trip = getattr(self.module.config, "n_layers", 1) \
             if getattr(self.module.config, "scan_layers", False) else 1
         from ..profiling.sanitizer import ATTENTION_F32_ALLOW

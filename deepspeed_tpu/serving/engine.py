@@ -99,25 +99,30 @@ class ServingEngine:
         # decode-attention backend: "dense" (no paging), "gather" (dense
         # per-slot view through the block table), or "fused" (the split-KV
         # flash-decode kernel walks the table in-kernel). A requested
-        # "fused" is shape-probed ONCE here; unsupported shapes warn and
-        # fall back to the gather path — serving never hard-fails on a
-        # kernel constraint.
+        # "fused" is put to the compiler ONCE here at this engine's
+        # geometry; a refusal is logged with the compiler's reason and the
+        # gather path serves. ``attn_backend`` (also in snapshot()
+        # ["kv_pool"]) always names the path that actually runs.
         self.attn_backend = "dense"
         if self.paged:
             self.attn_backend = self.cfg.kv_pool.attention_backend
             if self.attn_backend == "fused":
+                import logging
+
                 from ..ops.pallas.paged_attention import \
                     fused_decode_supported
 
                 ok, reason = fused_decode_supported(
                     engine.module.config, self.pool_mgr.block_size,
-                    mp_world_size=max(engine.mp_world_size, 1),
+                    n_slots=self.n_slots,
+                    blocks_per_slot=self.pool_mgr.blocks_per_slot,
+                    tp=max(engine.mp_world_size, 1),
                     kv_dtype=self.cfg.kv_pool.kv_dtype)
                 if not ok:
                     log_dist(
                         "ServingEngine: kv_pool.attention_backend='fused' "
-                        f"unsupported for this shape ({reason}); falling "
-                        "back to the gather path", ranks=[0])
+                        f"refused ({reason}); serving through the gather "
+                        "path", ranks=[0], level=logging.WARNING)
                     self.attn_backend = "gather"
         if self.paged and self.cfg.scrub_freed_slots:
             # block-granularity scrub: zero each physical block as its last
@@ -677,26 +682,30 @@ class ServingEngine:
                                              out_shardings=(rep, rep))
 
     def trace_decode(self):
-        """``(lowered, jaxpr-or-None)`` of the decode program over the live
+        """``(lowered, jaxpr)`` of the decode program over the live
         slot pool — the entry point for the static sanitizer /
         ``tools/program_lint.py``. ONE trace serves both views (tracing only
         builds avals: nothing executes, and the donation annotations ride
-        along for the audit); jax versions without ``jit(...).trace`` fall
-        back to ``lower()`` and a None jaxpr."""
+        along for the audit)."""
         if self._decode_jit is None:
             self._build_pool_programs()
-        trace = getattr(self._decode_jit, "trace", None)
-        if trace is not None:
-            t = trace(self.engine.params, self._state)
-            return t.lower(), t.jaxpr
-        return self._decode_jit.lower(self.engine.params, self._state), None
+        t = self._decode_jit.trace(self.engine.params, self._state)
+        return t.lower(), t.jaxpr
 
     def lower_decode(self):
         """The lowered (uncompiled) decode program (see ``trace_decode``)."""
         return self.trace_decode()[0]
 
+    def lower_prefill(self, padded_len):
+        """The lowered (uncompiled) prefill program of one prompt bucket —
+        lets a caller see which attention the bucket got (a Mosaic
+        ``tpu_custom_call`` for the flash kernel, or the XLA scan)."""
+        return self._prefill_program(int(padded_len)).lower(
+            self.engine.params, jnp.zeros((1, int(padded_len)), jnp.int32),
+            np.int32(padded_len))
+
     def trace_prefill_chunk(self, chunk_tokens=None):
-        """``(lowered, jaxpr-or-None)`` of the chunked suffix-prefill program
+        """``(lowered, jaxpr)`` of the chunked suffix-prefill program
         (one full chunk's bucket) — the ``program_lint --program
         prefill-chunked`` entry point, mirroring ``trace_decode``. This is
         the SAME compiled program a chunk dispatches (and a shared-prefix
@@ -712,14 +721,11 @@ class ServingEngine:
                            self.engine.dtype)
         args = (self.engine.params, jnp.zeros((1, padded), jnp.int32), cache,
                 np.int32(0), np.int32(min(chunk, padded)))
-        trace = getattr(fn, "trace", None)
-        if trace is not None:
-            t = trace(*args)
-            return t.lower(), t.jaxpr
-        return fn.lower(*args), None
+        t = fn.trace(*args)
+        return t.lower(), t.jaxpr
 
     def trace_verify(self, spec_k=None):
-        """``(lowered, jaxpr-or-None)`` of the speculative verify program —
+        """``(lowered, jaxpr)`` of the speculative verify program —
         the ``program_lint --program verify`` entry point, mirroring
         ``trace_decode``. Traces the SAME jitted closure a verify step
         dispatches: k+1 positions per slot against the donated paged pool
@@ -734,11 +740,8 @@ class ServingEngine:
         args = (self.engine.params, self._state,
                 jnp.zeros((self.n_slots, kk), jnp.int32),
                 jnp.zeros((self.n_slots,), jnp.int32))
-        trace = getattr(self._verify_jit, "trace", None)
-        if trace is not None:
-            t = trace(*args)
-            return t.lower(), t.jaxpr
-        return self._verify_jit.lower(*args), None
+        t = self._verify_jit.trace(*args)
+        return t.lower(), t.jaxpr
 
     def compile_counts(self):
         """Compiled-program census, pinned by the tier-1 no-recompile test:

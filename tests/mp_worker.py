@@ -14,15 +14,7 @@ os.environ["JAX_PLATFORMS"] = "cpu"
 
 import jax  # noqa: E402
 
-jax.config.update("jax_platforms", "cpu")
-
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-
-# jax.shard_map compat on 0.4.x jaxlibs (same shim conftest installs for
-# in-process tests): worker bodies build shard_map engine programs
-from deepspeed_tpu.utils import jax_compat as _jax_compat  # noqa: E402
-
-_jax_compat.install()
 
 import deepspeed_tpu.comm as dist  # noqa: E402
 

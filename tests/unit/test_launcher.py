@@ -67,11 +67,7 @@ def test_launcher_local_procs_end_to_end(tmp_path):
 
     script = tmp_path / "worker.py"
     script.write_text(
-        "import os\n"
-        "os.environ['XLA_FLAGS'] = (os.environ.get('XLA_FLAGS','') + "
-        "' --xla_force_host_platform_device_count=2').strip()\n"
         "import jax\n"
-        "jax.config.update('jax_platforms', 'cpu')\n"
         "import deepspeed_tpu.comm as dist\n"
         "dist.init_distributed()\n"
         "assert dist.get_world_size() == 2, dist.get_world_size()\n"
@@ -84,7 +80,8 @@ def test_launcher_local_procs_end_to_end(tmp_path):
     env = dict(_os.environ, PYTHONPATH=repo)
     rc = subprocess.call(
         [sys.executable, "-m", "deepspeed_tpu.launcher.runner",
-         "--num_local_procs", "2", str(script)],
+         "--num_local_procs", "2", "--local_devices_per_proc", "2",
+         str(script)],
         env=env, cwd=repo, timeout=240)
     assert rc == 0
 
@@ -117,7 +114,8 @@ def test_launcher_kills_peers_when_one_worker_dies(tmp_path):
     t0 = time.time()
     rc = subprocess.call(
         [sys.executable, "-m", "deepspeed_tpu.launcher.runner",
-         "--num_local_procs", "2", str(script)],
+         "--num_local_procs", "2", "--local_devices_per_proc", "2",
+         str(script)],
         env=dict(_os.environ, PYTHONPATH=repo), cwd=repo, timeout=120)
     assert rc == 3
     assert time.time() - t0 < 60  # did not wait for the sleeping peer

@@ -438,7 +438,8 @@ def decode_report_fused(devices8):
 
     model = CausalLM(TransformerConfig(
         vocab_size=512, max_seq_len=64, n_layers=4, n_heads=4,
-        d_model=128, d_ff=256, compute_dtype=jnp.bfloat16))
+        d_model=128, d_ff=256, compute_dtype=jnp.bfloat16,
+        attention_interpret=True))
     engine = deepspeed_tpu.init_inference(
         model=model,
         config={"dtype": "bfloat16", "max_tokens": 64,

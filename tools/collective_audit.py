@@ -204,11 +204,10 @@ def print_report(report, top_exposed=0):
 
 
 def child(args):
-    os.environ.setdefault("BENCH_FORCE_CPU", "1")
     sys.path.insert(0, os.path.join(REPO, "tools"))
-    from _common import maybe_force_cpu, stamp_record
+    from _common import setup_compile_cache, stamp_record
 
-    maybe_force_cpu()
+    setup_compile_cache()
     t0 = time.time()
     report = build_and_audit(args.preset, args.devices, args.micro,
                              args.gather_dtype, args.grad_reduce_dtype,
@@ -248,11 +247,10 @@ def main():
     if args.child:
         return child(args)
 
-    # re-exec with the virtual device count (XLA reads the flag at backend
-    # init — same dance as scale_projection)
-    # No collective-timeout flags here (unlike scale_projection): the audit
-    # only COMPILES — nothing executes, no rendezvous can time out — and
-    # older jaxlibs hard-abort on the unknown flags.
+    # re-exec on the CPU platform with the virtual device count (XLA reads
+    # the flag at backend init — same dance as scale_projection). No
+    # collective-timeout flags here: the audit only COMPILES — nothing
+    # executes, no rendezvous can time out.
     env = dict(os.environ)
     flags = re.sub(r"--xla_force_host_platform_device_count=\d+", "",
                    env.get("XLA_FLAGS", ""))

@@ -1,0 +1,277 @@
+"""On-chip acceptance of every Pallas kernel a config value can select: each
+is compiled by Mosaic at one production geometry and compared with its XLA
+reference on the same device.
+
+    python tools/kernel_check.py          # on the chip machine (one process)
+
+One JSON line per kernel: ``{"kernel", "geometry", "max_err", "tol", "ok"}``,
+then a summary; exit code 1 if any kernel failed to compile or to match. A
+TPU is required: off-chip these kernels only run under the interpreter,
+which tier-1 already covers (``tests/unit/test_*attention*.py`` etc.), and
+``tests/unit/test_tpu_lowering.py`` covers lowering. Tolerances are bf16
+ones: both sides round to bf16 somewhere, in a different order.
+"""
+
+import json
+import os
+import sys
+import traceback
+
+import numpy as np
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+
+def _err(got, want):
+    """Max abs error over a pytree, scaled by the reference's magnitude."""
+    import jax
+
+    worst = 0.0
+    for g, w in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        g, w = np.asarray(g, np.float32), np.asarray(w, np.float32)
+        if not np.all(np.isfinite(g)):
+            return float("inf")
+        worst = max(worst, float(np.max(np.abs(g - w))
+                                 / max(np.max(np.abs(w)), 1e-6)))
+    return worst
+
+
+def _qkv(b, s, h, d, seed=0):
+    import jax.numpy as jnp
+
+    rng = np.random.RandomState(seed)
+    return tuple(jnp.asarray(rng.randn(b, s, h, d) * 0.5, jnp.bfloat16)
+                 for _ in range(3))
+
+
+def _attention_case(attend, b, s, h, d, mask=None):
+    """(kernel, reference, operands): fwd + bwd of ``attend(q, k, v)`` vs
+    exact fp32 attention."""
+    import jax
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.models import layers as L
+
+    q, k, v = _qkv(b, s, h, d)
+    g = jnp.asarray(np.random.RandomState(1).randn(b, s, h, d), jnp.bfloat16)
+    mask = L.causal_mask(s, s) if mask is None else mask
+
+    def ref(q, k, v):
+        return L.dot_product_attention(
+            q.astype(jnp.float32), k.astype(jnp.float32),
+            v.astype(jnp.float32), mask=mask)
+
+    def both(f):
+        def run(q, k, v, g):
+            out, vjp = jax.vjp(f, q, k, v)
+            return out, vjp(g.astype(out.dtype))
+        return run
+
+    return both(attend), both(ref), (q, k, v, g)
+
+
+def flash_single_block():
+    from deepspeed_tpu.ops.flash_attention import flash_attention
+
+    return ("b4 s1024 h16 d64 bf16, tiles 512x1024 fwd+bwd",
+            *_attention_case(flash_attention, 4, 1024, 16, 64), 3e-2)
+
+
+def flash_general():
+    from deepspeed_tpu.ops.flash_attention import flash_attention
+
+    return ("b2 s2048 h8 d128 bf16, tiles 256x512 fwd+bwd",
+            *_attention_case(flash_attention, 2, 2048, 8, 128), 3e-2)
+
+
+def jax_flash():
+    from deepspeed_tpu.ops.flash_attention import jax_flash_attention
+
+    return ("b4 s1024 h16 d64 bf16 fwd+bwd",
+            *_attention_case(jax_flash_attention, 4, 1024, 16, 64), 3e-2)
+
+
+def block_sparse():
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.models import layers as L
+    from deepspeed_tpu.ops.pallas.block_sparse_attention import \
+        BlockSparseAttention
+    from deepspeed_tpu.ops.sparse_attention import BSLongformerSparsityConfig
+
+    s, blk = 2048, 128
+    attn = BlockSparseAttention(
+        BSLongformerSparsityConfig(block=blk, num_sliding_window_blocks=3),
+        s, causal=True)
+    dense = jnp.asarray(np.kron(attn.layout, np.ones((blk, blk), bool)))
+    mask = (dense & L.causal_mask(s, s)[0, 0])[None, None]
+    return (f"b2 s2048 h8 d128 bf16 bslongformer block 128 "
+            f"(density {attn.density:.2f}) fwd+bwd",
+            *_attention_case(attn, 2, s, 8, 128, mask=mask), 3e-2)
+
+
+def pallas_ce():
+    import jax
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.ops.cross_entropy import fused_cross_entropy
+
+    rng = np.random.RandomState(0)
+    tokens, d, vocab = 4096, 1024, 50304
+    x = jnp.asarray(rng.randn(tokens, d) * 0.5, jnp.bfloat16)
+    emb = jnp.asarray(rng.randn(vocab, d) * 0.02, jnp.float32)
+    labels = jnp.asarray(rng.randint(0, vocab, (tokens,)), jnp.int32)
+
+    def run(impl):
+        f = lambda x, e, labels: fused_cross_entropy(
+            x, e, labels, None, -100, 8, impl, False)
+        return jax.value_and_grad(f, argnums=(0, 1))
+
+    return ("4096 tokens x d1024 x vocab 50304 bf16, loss + grads",
+            run("pallas"), run("xla"), (x, emb, labels), 2e-2)
+
+
+def _paged_case(nh, kvh, dh, int8=False, alibi=False):
+    import jax
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.models import layers as L
+    from deepspeed_tpu.ops.pallas.paged_attention import paged_flash_decode
+
+    rng = np.random.RandomState(0)
+    S, NB, bs = 8, 64, 16                      # 1024-token window per slot
+    n_blocks = S * NB + 1
+    dt = jnp.bfloat16
+    if int8:
+        kc = jnp.asarray(rng.randint(-127, 127, (n_blocks, bs, kvh, dh)),
+                         jnp.int8)
+        vc = jnp.asarray(rng.randint(-127, 127, (n_blocks, bs, kvh, dh)),
+                         jnp.int8)
+        ks = jnp.asarray(np.abs(rng.randn(n_blocks, bs, kvh, 1)) * .01,
+                         jnp.float32)
+        vs = jnp.asarray(np.abs(rng.randn(n_blocks, bs, kvh, 1)) * .01,
+                         jnp.float32)
+    else:
+        kc = jnp.asarray(rng.randn(n_blocks, bs, kvh, dh), dt)
+        vc = jnp.asarray(rng.randn(n_blocks, bs, kvh, dh), dt)
+        ks = vs = None
+    table = jnp.asarray(1 + rng.permutation(S * NB).reshape(S, NB), jnp.int32)
+    pos = jnp.asarray([1, 15, 16, 17, 500, 777, 1000, 1023], jnp.int32)
+    q = jnp.asarray(rng.randn(S, nh, dh) * 0.3, dt)
+    k_new = jnp.asarray(rng.randn(S, kvh, dh), dt)
+    v_new = jnp.asarray(rng.randn(S, kvh, dh), dt)
+    slopes = L.alibi_slopes(nh) if alibi else None
+
+    def fused(q, k_new, v_new, kc, vc, table, pos, ks, vs):
+        return paged_flash_decode(q, k_new, v_new, kc, vc, table, pos,
+                                  k_scale=ks, v_scale=vs, alibi_slopes=slopes)
+
+    def ref(q, k_new, v_new, kc, vc, table, pos, ks, vs):
+        # dense per-slot view through the table, fresh row written at the
+        # cursor, exact fp32 softmax over [0, pos]
+        f32 = jnp.float32
+        view = lambda c, s: (c.astype(f32) * (1.0 if s is None else s))[
+            table].reshape(S, NB * bs, kvh, dh)
+        put = jax.vmap(lambda c, r, p: jax.lax.dynamic_update_slice(
+            c, r[None], (p, 0, 0)))
+        kk = put(view(kc, ks), k_new.astype(f32), pos)
+        vv = put(view(vc, vs), v_new.astype(f32), pos)
+        kv_idx = jnp.arange(NB * bs)[None, None, :]
+        mask = (kv_idx <= pos[:, None, None])[:, None]
+        bias = None
+        if alibi:
+            dist = (kv_idx - pos[:, None, None]).astype(f32)
+            bias = slopes[None, :, None, None] * dist[:, None]
+        n_rep = nh // kvh
+        return L.dot_product_attention(
+            q.astype(f32)[:, None], L._repeat_kv(kk, n_rep),
+            L._repeat_kv(vv, n_rep), mask=mask, alibi_bias=bias)[:, 0]
+
+    geom = (f"8 slots x 1024-token window, block 16, {nh}/{kvh} heads x "
+            f"{dh}, {'int8' if int8 else 'bf16'} pool"
+            + (", alibi" if alibi else ""))
+    return geom, fused, ref, (q, k_new, v_new, kc, vc, table, pos, ks, vs), \
+        3e-2
+
+
+def _qmm_case(bits):
+    import jax
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.ops.pallas.quantized_matmul import quantized_matmul
+    from deepspeed_tpu.ops.quantizer import (dequantize_per_channel,
+                                             pack_int4, quantize_per_channel,
+                                             unpack_int4)
+
+    rng = np.random.RandomState(0)
+    args = []
+    for k, n in ((2048, 8192), (8192, 2048)):   # OPT-1.3B fc / proj
+        w = jnp.asarray(rng.randn(k, n) * 0.05, jnp.float32)
+        q, scale = quantize_per_channel(w, bits=bits, group_size=64)
+        args.append((jnp.asarray(rng.randn(8, k), jnp.bfloat16),
+                     pack_int4(q) if bits == 4 else q, scale))
+
+    def fused(*args):
+        return [quantized_matmul(x, p, s, bits=bits) for x, p, s in args]
+
+    def ref(*args):
+        return [x.astype(jnp.float32) @ dequantize_per_channel(
+            unpack_int4(p) if bits == 4 else p, s, jnp.float32)
+            for x, p, s in args]
+
+    return (f"int{bits} m8, [2048x8192] and [8192x2048], group 64, bf16",
+            fused, ref, tuple(args), 2e-2)
+
+
+CASES = {
+    "flash fwd+bwd (single kv block)": flash_single_block,
+    "flash fwd+bwd (general)": flash_general,
+    "jax_flash fwd+bwd": jax_flash,
+    "block_sparse fwd+bwd": block_sparse,
+    "pallas CE forward": pallas_ce,
+    "paged decode (BLOOM class 16x128, alibi)":
+        lambda: _paged_case(16, 16, 128, alibi=True),
+    "paged decode (OPT class 32x64)": lambda: _paged_case(32, 32, 64),
+    "paged decode (GQA 32/8x128, int8 pool)":
+        lambda: _paged_case(32, 8, 128, int8=True),
+    "quantized matmul int8": lambda: _qmm_case(8),
+    "quantized matmul int4": lambda: _qmm_case(4),
+}
+
+
+def main():
+    import jax
+
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        raise SystemExit(f"kernel_check compiles for a TPU; platform is "
+                         f"{dev.platform!r} - refusing to run")
+    from _common import setup_compile_cache
+
+    setup_compile_cache()
+    print(json.dumps({"device_kind": dev.device_kind, "jax": jax.__version__}),
+          flush=True)
+    failed = []
+    for name, case in CASES.items():
+        try:
+            geometry, kernel, reference, operands, tol = case()
+            err = _err(jax.jit(kernel)(*operands),
+                       jax.jit(reference)(*operands))
+            row = {"kernel": name, "geometry": geometry,
+                   "max_err": round(err, 5), "tol": tol, "ok": err <= tol}
+        except Exception as e:
+            traceback.print_exc()
+            row = {"kernel": name, "ok": False,
+                   "error": " ".join(str(e).split())[:600]}
+        if not row["ok"]:
+            failed.append(name)
+        print(json.dumps(row), flush=True)
+    print(f"# {len(CASES) - len(failed)}/{len(CASES)} kernels compiled and "
+          f"matched on {dev.device_kind}"
+          + (f"; FAILED: {failed}" if failed else ""), flush=True)
+    return 1 if failed else 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -81,7 +81,10 @@ def lint_decode(args):
         vocab_size=preset["vocab_size"], max_seq_len=max_len,
         n_layers=preset["n_layers"], n_heads=preset["n_heads"],
         d_model=preset["d_model"], d_ff=preset["d_ff"],
-        compute_dtype=jnp.bfloat16))
+        compute_dtype=jnp.bfloat16,
+        # this lint always runs on the CPU platform: the fused backend's
+        # kernel is audited as the interpreter lowers it
+        attention_interpret=True))
     serving = {"n_slots": args.slots, "max_len": max_len,
                "virtual_clock": True}
     if args.paged:
@@ -238,20 +241,14 @@ def _planted_program(clean=False):
                          in_shardings=(shard, rep, shard, None),
                          out_shardings=(shard, rep))
             example = (w, big_rep, x, 1.0)
-        # one trace serves both views; old jax without jit(...).trace keeps
-        # the HLO half (same guard as ServingEngine.trace_decode)
-        trace_fn = getattr(fn, "trace", None)
-        if trace_fn is not None:
-            traced = trace_fn(*example)
-            lowered, jaxpr = traced.lower(), traced.jaxpr
-        else:
-            lowered, jaxpr = fn.lower(*example), None
+        # one trace serves both views (HLO audit + jaxpr sanitizer)
+        traced = fn.trace(*example)
+        lowered, jaxpr = traced.lower(), traced.jaxpr
     cfg = _sanitizer_config("bf16")
     report = audit_lowered(lowered, n, sanitizer_config=cfg)
-    if jaxpr is not None:
-        report["sanitizer"] = merge_reports(
-            report["sanitizer"],
-            sanitize_jaxpr(jaxpr, example_args=example, config=cfg))
+    report["sanitizer"] = merge_reports(
+        report["sanitizer"],
+        sanitize_jaxpr(jaxpr, example_args=example, config=cfg))
     report.update({"preset": "planted-clean" if clean else "planted",
                    "devices": n})
     return report
@@ -294,13 +291,12 @@ def print_findings(name, report, top=15):
 
 
 def child(args):
-    os.environ.setdefault("BENCH_FORCE_CPU", "1")
     sys.path.insert(0, os.path.join(REPO, "tools"))
     if REPO not in sys.path:
         sys.path.insert(0, REPO)
-    from _common import maybe_force_cpu, stamp_record
+    from _common import setup_compile_cache, stamp_record
 
-    maybe_force_cpu()
+    setup_compile_cache()
     t0 = time.time()
     programs = {}
     if args.program in ("train", "all"):
