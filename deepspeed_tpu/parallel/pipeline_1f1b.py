@@ -19,11 +19,13 @@ gradients *manually* with per-tick ``jax.vjp`` calls:
   a stage never needs both in one tick; producers always run exactly one tick
   before consumers (``F(s,m)+1 = F(s+1,m)``, ``B(s+1,m)+1 = B(s,m)``), so a
   received activation/cotangent is consumed immediately — no queues.
-- each tick does ``lax.cond(is_fwd)`` / ``lax.cond(is_bwd)``: XLA conditionals
-  execute only the taken branch at runtime, so a tick costs one fwd OR one
-  recompute+bwd, and the branches contain no collectives (the two ``ppermute``
-  rotations — activations forward, cotangents backward — run unconditionally
-  outside the conds; the reference's Send/Recv{Activation,Grad} instructions).
+- each tick is ONE ``lax.switch`` over idle / fwd / bwd: XLA conditionals
+  execute only the taken arm at runtime, so a tick costs one fwd OR one
+  recompute+bwd. The only collectives inside the arms are the manual-TP block's
+  model-axis psums, which every device of a TP group meets in the same arm; the
+  two ``ppermute`` rotations — activations forward, cotangents backward — run
+  unconditionally after the switch, on its outputs (the reference's
+  Send/Recv{Activation,Grad} instructions).
 - the stage keeps a ring buffer of S saved *stage inputs* (its only residual);
   the backward tick recomputes the stage forward under ``jax.vjp`` — the same
   per-stage recompute the reference gets from activation checkpointing with
@@ -233,35 +235,29 @@ def build_1f1b_train_step(model, mesh, n_microbatches, blocks_param_specs=None):
                 boff = 2 * S - 1 - stage
                 m_b = jnp.clip((t - boff) // 2, 0, M - 1)
                 do_b = (t >= boff) & ((t - boff) % 2 == 0) & ((t - boff) // 2 < M)
+                no_g = jnp.zeros(mb_shape, jnp.float32)
 
-                side_f = jax.tree_util.tree_map(lambda a: a[m_f], side_ms)
-                h_in = jnp.where(stage == 0, xs[m_f].astype(compute_dtype),
-                                 carry["h_recv"])
+                def idle(acc):
+                    return zeros_mb, no_g, acc
 
                 # ---- forward tick: run local layers, bank the stage input
-                def fwd_case(ops):
-                    buf_h, buf_side = ops
+                def fwd_case(acc):
+                    side_f = jax.tree_util.tree_map(lambda a: a[m_f], side_ms)
+                    h_in = jnp.where(stage == 0, xs[m_f].astype(compute_dtype),
+                                     carry["h_recv"])
                     h_out, _ = stage_fwd(blocks_w, h_in, side_f, m_f)
                     buf_h = jax.lax.dynamic_update_index_in_dim(
-                        buf_h, h_in, m_f % S, 0)
+                        acc["buf_h"], h_in, m_f % S, 0)
                     buf_side = jax.tree_util.tree_map(
                         lambda b, v: jax.lax.dynamic_update_index_in_dim(
-                            b, v, m_f % S, 0), buf_side, side_f)
-                    return h_out, buf_h, buf_side
-
-                def no_fwd(ops):
-                    buf_h, buf_side = ops
-                    return zeros_mb, buf_h, buf_side
-
-                h_out, buf_h, buf_side = jax.lax.cond(
-                    do_f, fwd_case, no_fwd, (carry["buf_h"], carry["buf_side"]))
+                            b, v, m_f % S, 0), acc["buf_side"], side_f)
+                    return h_out, no_g, dict(acc, buf_h=buf_h, buf_side=buf_side)
 
                 # ---- backward tick: recompute stage fwd under vjp, chain cotangents
-                def bwd_case(ops):
-                    gW, g_head, gx, loss_acc, aux_acc = ops
-                    h_saved = carry["buf_h"][m_b % S]
+                def bwd_case(acc):
+                    h_saved = acc["buf_h"][m_b % S]
                     side_b = jax.tree_util.tree_map(
-                        lambda b: b[m_b % S], carry["buf_side"])
+                        lambda b: b[m_b % S], acc["buf_side"])
                     (h2, aux_v), f_vjp = jax.vjp(
                         lambda wb, h: stage_fwd(wb, h, side_b, m_b),
                         blocks_w, h_saved)
@@ -287,35 +283,33 @@ def build_1f1b_train_step(model, mesh, n_microbatches, blocks_param_specs=None):
                     g_wh, g_h2, ls = jax.lax.cond(stage == S - 1, head_case,
                                                   mid_case, None)
                     g_wb, g_h_in = f_vjp((g_h2, aux_cot))
-                    gW = jax.tree_util.tree_map(
-                        lambda a, b: a + b.astype(jnp.float32), gW, g_wb)
-                    g_head = jax.tree_util.tree_map(jnp.add, g_head, g_wh)
-                    gx = jax.lax.dynamic_update_index_in_dim(
-                        gx, g_h_in.astype(jnp.float32), m_b, 0)
-                    return (gW, g_head, gx, loss_acc + ls, aux_acc + aux_v,
-                            g_h_in.astype(jnp.float32))
+                    g_h_in = g_h_in.astype(jnp.float32)
+                    return zeros_mb, g_h_in, dict(
+                        acc,
+                        gW=jax.tree_util.tree_map(
+                            lambda a, b: a + b.astype(jnp.float32),
+                            acc["gW"], g_wb),
+                        g_head=jax.tree_util.tree_map(
+                            jnp.add, acc["g_head"], g_wh),
+                        gx=jax.lax.dynamic_update_index_in_dim(
+                            acc["gx"], g_h_in, m_b, 0),
+                        loss=acc["loss"] + ls, aux=acc["aux"] + aux_v)
 
-                def no_bwd(ops):
-                    gW, g_head, gx, loss_acc, aux_acc = ops
-                    return (gW, g_head, gx, loss_acc, aux_acc,
-                            jnp.zeros(mb_shape, jnp.float32))
-
-                gW, g_head, gx, loss_acc, aux_acc, g_send = jax.lax.cond(
-                    do_b, bwd_case, no_bwd,
-                    (carry["gW"], carry["g_head"], carry["gx"],
-                     carry["loss"], carry["aux"]))
+                # F and B ticks of a stage have opposite parity, so ONE switch
+                # holds both: the arms' model-axis psums (tp_manual) then have
+                # one order on every device. As two conds they were independent
+                # of each other, and XLA:CPU, which runs whatever thunk is ready
+                # with each collective a blocking rendezvous, entered them in
+                # different orders on the two devices of a TP pair and hung.
+                h_out, g_send, acc = jax.lax.switch(
+                    do_f + 2 * do_b, (idle, fwd_case, bwd_case),
+                    {k: v for k, v in carry.items()
+                     if k not in ("h_recv", "g_recv")})
 
                 # ---- rotate: activations forward, cotangents backward
                 h_recv = jax.lax.ppermute(h_out, PIPE_AXIS, fwd_perm)
                 g_recv = jax.lax.ppermute(g_send, PIPE_AXIS, bwd_perm)
-
-                new_carry = {
-                    "h_recv": h_recv, "g_recv": g_recv,
-                    "buf_h": buf_h, "buf_side": buf_side,
-                    "gW": gW, "g_head": g_head, "gx": gx,
-                    "loss": loss_acc, "aux": aux_acc,
-                }
-                return new_carry, None
+                return dict(acc, h_recv=h_recv, g_recv=g_recv), None
 
             carry, _ = jax.lax.scan(tick, carry0, jnp.arange(2 * (M + S - 1)))
 

@@ -16,9 +16,11 @@ if "xla_force_host_platform_device_count" not in _flags:
     _flags += " --xla_force_host_platform_device_count=8"
 if "xla_cpu_collective_call_terminate_timeout_seconds" not in _flags:
     # 8 emulated devices share this box's cores; under load the default 40s
-    # collective rendezvous can fire spuriously and SIGABRT the whole suite
-    _flags += (" --xla_cpu_collective_call_terminate_timeout_seconds=600"
-               " --xla_cpu_collective_timeout_seconds=600")
+    # collective rendezvous can fire spuriously and SIGABRT the worker. Kept
+    # under TEST_LIMIT_S so that XLA's account of WHICH collective is stuck
+    # comes before the per-test limit's stack dump.
+    _flags += (" --xla_cpu_collective_call_terminate_timeout_seconds=180"
+               " --xla_cpu_collective_timeout_seconds=180")
 os.environ["XLA_FLAGS"] = _flags.strip()
 os.environ.setdefault("JAX_ENABLE_X64", "0")
 
@@ -31,20 +33,43 @@ import jax  # noqa: E402
 from deepspeed_tpu.utils.compile_cache import setup_compile_cache  # noqa: E402
 
 setup_compile_cache(os.path.join(os.path.dirname(__file__), ".jax_cache"))
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
 jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
 
 import pytest  # noqa: E402
 
 
+# No test may hold its worker longer than this: eight to eleven times the
+# slowest tier-1 test (22-29 s over two runs on the 8-core box of PR 26), and
+# over the XLA collective timeouts above.
+TEST_LIMIT_S = 240
+_WATCHDOG_FD = pytest.StashKey[int]()
+
+
+def pytest_configure(config):
+    # fd 2 while no test's output is being captured: where the watchdog writes
+    config.stash[_WATCHDOG_FD] = os.dup(2)
+
+
 @pytest.fixture(autouse=True)
-def _collect_cycles():
-    """Engines captured in jit closures die by CYCLE collection, not refcount;
+def _bounded_and_collected(request):
+    """Bound the test, then collect cycles after it.
+
+    The bound is faulthandler's watchdog thread, not a signal: a thread stuck
+    inside XLA never returns to the interpreter to run a handler. On expiry
+    every thread's stack is printed and the process exits; xdist reports the
+    test as failed with that output and hands the queue to a new worker.
+
+    Engines captured in jit closures die by CYCLE collection, not refcount;
     collecting between tests keeps live-buffer accounting (e.g.
     test_destroy_releases_device_buffers) independent of test order."""
-    yield
+    import faulthandler
     import gc
 
+    faulthandler.dump_traceback_later(TEST_LIMIT_S, exit=True,
+                                      file=request.config.stash[_WATCHDOG_FD])
+    yield
+    faulthandler.cancel_dump_traceback_later()
     gc.collect()
 
 

@@ -224,7 +224,7 @@ def rank_consistency_pass_and_fail():
 # process sidesteps the bad deserialize/free paths entirely, and a crash
 # fails ONE test instead of killing the tier-1 run)
 # ---------------------------------------------------------------------------
-def _reshard_engine(meshcfg, elastic=None):
+def _reshard_engine(meshcfg, snapshot_interval=None):
     import jax.numpy as jnp
 
     import deepspeed_tpu
@@ -238,8 +238,14 @@ def _reshard_engine(meshcfg, elastic=None):
         "zero_optimization": {"stage": 2}, "mesh": meshcfg,
         "checkpoint": {"engine": "sharded"},
         "steps_per_print": 10 ** 9}
-    if elastic is not None:
-        config["elastic"] = elastic
+    if snapshot_interval is not None:
+        # max_interval == snapshot_interval: the budgeter stretches the cadence
+        # whenever a write outlasts a step, and the lost-steps bounds asserted
+        # below hold only for a cadence that cannot stretch (they held by the
+        # luck of slow, cold-compiled first steps)
+        config["elastic"] = {"enabled": True,
+                             "snapshot_interval": snapshot_interval,
+                             "max_interval": snapshot_interval}
     eng, _, _, _ = deepspeed_tpu.initialize(model=model, config=config)
     return eng
 
@@ -329,8 +335,7 @@ def elastic_chaos_resize_8_4_8():
     tmp = tempfile.mkdtemp(prefix="chaos838_")
     losses = {}
     rescaled = 0
-    eng = _reshard_engine(meshes[0],
-                          elastic={"enabled": True, "snapshot_interval": 1})
+    eng = _reshard_engine(meshes[0], snapshot_interval=1)
     agent = ElasticAgent(eng, tmp, save_interval=1000)
     for seg in range(len(meshes)):
         kill = kills[seg] if seg < len(kills) else None
@@ -350,9 +355,7 @@ def elastic_chaos_resize_8_4_8():
             agent._restore()
         if not agent._preempted:
             break
-        eng = _reshard_engine(meshes[seg + 1],
-                              elastic={"enabled": True,
-                                       "snapshot_interval": 1})
+        eng = _reshard_engine(meshes[seg + 1], snapshot_interval=1)
         agent = ElasticAgent(eng, tmp, save_interval=1000)
         resumed = agent.try_resume()
         assert resumed == kills[seg] + 1, (resumed, kills[seg])
@@ -389,8 +392,7 @@ def elastic_chaos_equal_scale_bitwise():
     ref_rng = np.asarray(ref._rng).copy()
 
     tmp = tempfile.mkdtemp(prefix="chaos_eq_")
-    eng = _reshard_engine({"data": 8},
-                          elastic={"enabled": True, "snapshot_interval": 1})
+    eng = _reshard_engine({"data": 8}, snapshot_interval=1)
     agent = ElasticAgent(eng, tmp, save_interval=1000)
     losses = []
     agent._install()
@@ -408,8 +410,7 @@ def elastic_chaos_equal_scale_bitwise():
     died_at = eng.global_steps
     assert died_at == kill_step + 1  # the in-flight step finished
 
-    eng2 = _reshard_engine({"data": 8},
-                           elastic={"enabled": True, "snapshot_interval": 1})
+    eng2 = _reshard_engine({"data": 8}, snapshot_interval=1)
     agent2 = ElasticAgent(eng2, tmp, save_interval=1000)
     resumed = agent2.try_resume()
     assert resumed == died_at  # snapshot_interval=1: zero lost steps
@@ -437,15 +438,13 @@ def elastic_chaos_cadence_bounds_lost_steps():
     from deepspeed_tpu.testing import sigterm_data_iter
 
     tmp = tempfile.mkdtemp(prefix="chaos_cad_")
-    eng = _reshard_engine({"data": 8},
-                          elastic={"enabled": True, "snapshot_interval": 2})
+    eng = _reshard_engine({"data": 8}, snapshot_interval=2)
     agent = ElasticAgent(eng, tmp, save_interval=1000)
     status, steps = agent.run(sigterm_data_iter(
         (_reshard_batch(s) for s in range(100)), at_step=6), total_steps=100)
     assert status == "preempted" and steps == 6
 
-    eng2 = _reshard_engine({"data": 8},
-                           elastic={"enabled": True, "snapshot_interval": 2})
+    eng2 = _reshard_engine({"data": 8}, snapshot_interval=2)
     resumed = ElasticAgent(eng2, tmp).try_resume()
     assert steps - resumed <= 2
     assert resumed >= 4

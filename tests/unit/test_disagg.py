@@ -31,54 +31,25 @@ arXiv:2207.00032), all assertable under the virtual clock:
   instant pair + the wide events' ``handoff`` latency component.
 """
 
+import functools
 import json
 import os
-import subprocess
-import sys
 
 import numpy as np
-import jax.numpy as jnp
 import pytest
 
 import deepspeed_tpu
 from deepspeed_tpu.config import ConfigError, ServingConfig
-from deepspeed_tpu.models import CausalLM, TransformerConfig, split_params_axes
+from deepspeed_tpu.models import CausalLM, split_params_axes
 from deepspeed_tpu.serving import (Request, RequestState, Router,
                                    SamplingParams, ServingEngine,
                                    VirtualClock)
-from deepspeed_tpu.telemetry import SpanTracer, load_jsonl
+from deepspeed_tpu.telemetry import load_jsonl
+
+from .conftest import make_full_replica, ref_tokens, tiny_cfg
 
 
-def tiny_cfg(**kw):
-    base = dict(vocab_size=64, max_seq_len=64, n_layers=2, n_heads=4,
-                d_model=16, d_ff=32, compute_dtype=jnp.float32)
-    base.update(kw)
-    return TransformerConfig(**base)
-
-
-@pytest.fixture(scope="module")
-def engine():
-    model = CausalLM(tiny_cfg())
-    return deepspeed_tpu.init_inference(
-        model, dtype="float32", max_tokens=64, prompt_bucket_size=16)
-
-
-def make_replica(engine, trace_dir=None, **kw):
-    """Paged + chunked + migrating replica — the full handoff surface."""
-    kw.setdefault("virtual_clock", True)
-    kw.setdefault("n_slots", 2)
-    kw.setdefault("chunked_prefill", {"enabled": True, "chunk_size": 8})
-    kw.setdefault("kv_pool", {"enabled": True, "block_size": 8,
-                              "on_demand_growth": True})
-    kw.setdefault("migration", {"enabled": True,
-                                "snapshot_interval_tokens": 2})
-    clock = VirtualClock()
-    tracer = None
-    if trace_dir is not None:
-        tracer = SpanTracer(enabled=True, clock=clock.now,
-                            output_path=str(trace_dir), job_name="disagg")
-    return ServingEngine(engine, serving_config=ServingConfig(**kw),
-                         clock=clock, tracer=tracer)
+make_replica = functools.partial(make_full_replica, job_name="disagg")
 
 
 def make_disagg(engine, n_prefill=1, n_decode=1, trace_dir=None,
@@ -90,13 +61,6 @@ def make_disagg(engine, n_prefill=1, n_decode=1, trace_dir=None,
     replicas = [make_replica(engine, trace_dir=trace_dir, pools=pools, **kw)
                 for _ in range(n_prefill + n_decode)]
     return Router(replicas, monitor=monitor)
-
-
-def ref_tokens(engine, req):
-    out = np.asarray(engine.generate(req.prompt[None, :],
-                                     max_new_tokens=req.max_new_tokens,
-                                     greedy=True))
-    return out[0, req.prompt_len:]
 
 
 def stay_put_tokens(engine, req, **kw):
@@ -522,19 +486,13 @@ def test_chaos_serve_disagg_tool_smoke(tmp_path):
     lands in the prefill pool and a stall in the decode pool, handoffs
     still flow (exit 2 guards against a silently-mixed run), artifact
     stamped with the topology block, exit 0."""
-    tool = os.path.join(os.path.dirname(__file__), "..", "..", "tools",
-                        "chaos_serve.py")
+    from tools import chaos_serve
+
     out = str(tmp_path / "chaos_disagg.json")
-    env = dict(os.environ,
-               JAX_PLATFORMS="cpu",
-               XLA_FLAGS=(os.environ.get("XLA_FLAGS", "")
-                          + " --xla_force_host_platform_device_count=8"))
-    r = subprocess.run(
-        [sys.executable, tool, "--prefill-replicas", "2",
-         "--decode-replicas", "2", "--rebalance", "--requests", "8",
-         "--kills", "1", "--stalls", "1", "--seed", "0", "--out", out],
-        capture_output=True, text=True, timeout=560, env=env)
-    assert r.returncode == 0, r.stdout[-2000:] + r.stderr[-2000:]
+    assert chaos_serve.main(
+        ["--prefill-replicas", "2", "--decode-replicas", "2", "--rebalance",
+         "--requests", "8", "--kills", "1", "--stalls", "1", "--seed", "0",
+         "--out", out]) == 0
     report = json.loads(open(out).read())
     assert report["topology"]["roles"] == \
         ["prefill", "prefill", "decode", "decode"]

@@ -39,6 +39,14 @@ from tests.mp_harness import run_distributed
 pytestmark = pytest.mark.faults
 
 
+def _run_body(name):
+    """One world_size=1 worker on the suite's compile cache: each body builds
+    the same engine several times over and compiles it once."""
+    run_distributed(
+        f"tests.mp_targets:{name}", world_size=1, local_devices=8, timeout=200,
+        env={"JAX_COMPILATION_CACHE_DIR": jax.config.jax_compilation_cache_dir})
+
+
 # ---------------------------------------------------------------------------
 # the formerly-quarantined rescale test + 8->4x2->8 chaos (subprocess workers:
 # tensor-parallel programs — see the module docstring)
@@ -46,8 +54,7 @@ pytestmark = pytest.mark.faults
 def test_agent_resumes_at_different_scale():
     """dp8 -> dp4 x tp2 rescale resume + the sharded-concat miscompile
     guard. Body: tests/mp_targets.py elastic_rescale_and_concat_guard."""
-    run_distributed("tests.mp_targets:elastic_rescale_and_concat_guard",
-                    world_size=1, local_devices=8, timeout=420)
+    _run_body("elastic_rescale_and_concat_guard")
 
 
 def test_chaos_resize_8_4_8_continuity():
@@ -55,8 +62,7 @@ def test_chaos_resize_8_4_8_continuity():
     snapshots; per-step losses within 2e-5 of the uninterrupted run; ZeRO
     state resharded automatically both ways. Body: tests/mp_targets.py
     elastic_chaos_resize_8_4_8."""
-    run_distributed("tests.mp_targets:elastic_chaos_resize_8_4_8",
-                    world_size=1, local_devices=8, timeout=560)
+    _run_body("elastic_chaos_resize_8_4_8")
 
 
 def test_chaos_equal_scale_bitwise_and_cadence_bound():
@@ -65,8 +71,7 @@ def test_chaos_equal_scale_bitwise_and_cadence_bound():
     loses at most 2 steps) — chained in ONE worker to keep the tier-1 window
     lean. Bodies: tests/mp_targets.py elastic_chaos_equal_scale_bitwise ->
     elastic_chaos_cadence_bounds_lost_steps."""
-    run_distributed("tests.mp_targets:elastic_chaos_equal_scale_bitwise",
-                    world_size=1, local_devices=8, timeout=560)
+    _run_body("elastic_chaos_equal_scale_bitwise")
 
 
 # ---------------------------------------------------------------------------

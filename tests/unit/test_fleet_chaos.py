@@ -26,62 +26,29 @@ The acceptance invariants of the serving fleet's recovery primitive
   never kills the same replica twice.
 """
 
+import functools
+
 import numpy as np
 import jax.numpy as jnp
 import pytest
 
 import deepspeed_tpu
 from deepspeed_tpu.config import ServingConfig
-from deepspeed_tpu.models import CausalLM, TransformerConfig, split_params_axes
+from deepspeed_tpu.models import CausalLM, split_params_axes
 from deepspeed_tpu.serving import (REJECT_REPLICA_FAILED, Request,
                                    RequestState, Router, SamplingParams,
                                    ServingEngine, VirtualClock)
 from deepspeed_tpu.testing.fault_injection import ReplicaChaosSchedule
 
-
-def tiny_cfg(**kw):
-    base = dict(vocab_size=64, max_seq_len=64, n_layers=2, n_heads=4,
-                d_model=16, d_ff=32, compute_dtype=jnp.float32)
-    base.update(kw)
-    return TransformerConfig(**base)
+from .conftest import make_full_replica, ref_tokens, tiny_cfg
 
 
-@pytest.fixture(scope="module")
-def engine():
-    model = CausalLM(tiny_cfg())
-    return deepspeed_tpu.init_inference(
-        model, dtype="float32", max_tokens=64, prompt_bucket_size=16)
-
-
-def make_replica(engine, trace_dir=None, **kw):
-    """Paged + chunked + migrating replica — the full recovery surface."""
-    kw.setdefault("virtual_clock", True)
-    kw.setdefault("n_slots", 2)
-    kw.setdefault("chunked_prefill", {"enabled": True, "chunk_size": 8})
-    kw.setdefault("kv_pool", {"enabled": True, "block_size": 8,
-                              "on_demand_growth": True})
-    kw.setdefault("migration", {"enabled": True,
-                                "snapshot_interval_tokens": 2})
-    clock = VirtualClock()
-    tracer = None
-    if trace_dir is not None:
-        from deepspeed_tpu.telemetry.tracer import SpanTracer
-        tracer = SpanTracer(enabled=True, clock=clock.now,
-                            output_path=str(trace_dir), job_name="chaos")
-    return ServingEngine(engine, serving_config=ServingConfig(**kw),
-                         clock=clock, tracer=tracer)
+make_replica = functools.partial(make_full_replica, job_name="chaos")
 
 
 def make_router(engine, n=2, trace_dir=None, **kw):
     return Router([make_replica(engine, trace_dir=trace_dir, **kw)
                    for _ in range(n)])
-
-
-def ref_tokens(engine, req):
-    out = np.asarray(engine.generate(req.prompt[None, :],
-                                     max_new_tokens=req.max_new_tokens,
-                                     greedy=True))
-    return out[0, req.prompt_len:]
 
 
 def stay_put_tokens(engine, req, **kw):
@@ -596,26 +563,17 @@ def test_wide_events_carry_recovery_fields(engine, tmp_path):
 def test_chaos_serve_tool_smoke(tmp_path):
     """tier-1 smoke of tools/chaos_serve.py on the tiny preset: one seeded
     kill + one stall over a 3-replica fleet, artifact stamped, exit 0 (fault
-    survival + bitwise continuity + determinism + shed gates). Runs as a
-    subprocess, mirroring the chaos_train smoke — the tool builds and
-    destroys its own engine."""
+    survival + bitwise continuity + determinism + shed gates). main() is
+    called in process: the tool builds and destroys its own engine, and a
+    child would spend its first seconds importing JAX to do the same."""
     import json
-    import os
-    import subprocess
-    import sys
 
-    tool = os.path.join(os.path.dirname(__file__), "..", "..", "tools",
-                        "chaos_serve.py")
+    from tools import chaos_serve
+
     out = str(tmp_path / "chaos_serve.json")
-    env = dict(os.environ,
-               JAX_PLATFORMS="cpu",
-               XLA_FLAGS=(os.environ.get("XLA_FLAGS", "")
-                          + " --xla_force_host_platform_device_count=8"))
-    r = subprocess.run(
-        [sys.executable, tool, "--replicas", "3", "--requests", "8",
-         "--kills", "1", "--stalls", "1", "--seed", "1", "--out", out],
-        capture_output=True, text=True, timeout=560, env=env)
-    assert r.returncode == 0, r.stdout[-2000:] + r.stderr[-2000:]
+    assert chaos_serve.main(
+        ["--replicas", "3", "--requests", "8", "--kills", "1",
+         "--stalls", "1", "--seed", "1", "--out", out]) == 0
     report = json.loads(open(out).read())
     assert report["kills_fired"] == 1
     assert report["stalls_fired"] == 1

@@ -29,17 +29,15 @@ import subprocess
 import sys
 
 import numpy as np
-import jax.numpy as jnp
 import pytest
 
 import deepspeed_tpu
-from deepspeed_tpu.config import ServingConfig
-from deepspeed_tpu.models import CausalLM, TransformerConfig, split_params_axes
-from deepspeed_tpu.serving import (Request, RequestState, Router,
-                                   ServingEngine, VirtualClock)
-from deepspeed_tpu.telemetry import (LatencyDigest, SpanTracer,
-                                     digest_from_wide_events, evaluate_slo,
-                                     load_jsonl)
+from deepspeed_tpu.models import CausalLM, split_params_axes
+from deepspeed_tpu.serving import Request, Router, VirtualClock
+from deepspeed_tpu.telemetry import (LatencyDigest, digest_from_wide_events,
+                                     evaluate_slo, load_jsonl)
+
+from .conftest import make_replica, ref_tokens, tiny_cfg
 
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
@@ -204,33 +202,11 @@ def test_unhealthy_finish_retracts_queue_wait_digest():
 # fleet fixtures
 # ---------------------------------------------------------------------------
 
-def tiny_cfg(**kw):
-    base = dict(vocab_size=64, max_seq_len=64, n_layers=2, n_heads=4,
-                d_model=16, d_ff=32, compute_dtype=jnp.float32)
-    base.update(kw)
-    return TransformerConfig(**base)
-
-
-@pytest.fixture(scope="module")
-def engine():
-    model = CausalLM(tiny_cfg())
-    return deepspeed_tpu.init_inference(
-        model, dtype="float32", max_tokens=64, prompt_bucket_size=16)
-
-
 def make_fleet(engine, tmp, n=2, monitor=None, **kw):
     """N traced replicas (virtual clocks) behind a Router; the Router
     re-homes the per-replica trace dirs under <tmp>/fleet and writes the
     merged fleet files there at the end of serve()."""
-    kw.setdefault("n_slots", 2)
-    replicas = []
-    for _ in range(n):
-        clock = VirtualClock()
-        tracer = SpanTracer(enabled=True, clock=clock.now,
-                            output_path=str(tmp), job_name="fleet")
-        replicas.append(ServingEngine(
-            engine, serving_config=ServingConfig(virtual_clock=True, **kw),
-            clock=clock, tracer=tracer))
+    replicas = [make_replica(engine, tmp, "fleet", **kw) for _ in range(n)]
     return Router(replicas, monitor=monitor), os.path.join(str(tmp), "fleet")
 
 
@@ -250,13 +226,6 @@ def last_csv(tmp, name):
 def load_wide(base):
     return {r["request_id"]: r
             for r in load_jsonl(os.path.join(base, "requests.jsonl"))}
-
-
-def ref_tokens(engine, req):
-    out = np.asarray(engine.generate(req.prompt[None, :],
-                                     max_new_tokens=req.max_new_tokens,
-                                     greedy=True))
-    return out[0, req.prompt_len:]
 
 
 PREEMPT_KW = dict(

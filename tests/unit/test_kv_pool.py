@@ -25,50 +25,13 @@ import jax.numpy as jnp
 import pytest
 
 import deepspeed_tpu
-from deepspeed_tpu.config import ServingConfig
-from deepspeed_tpu.models import CausalLM, TransformerConfig, split_params_axes
+from deepspeed_tpu.models import CausalLM, split_params_axes
 from deepspeed_tpu.serving import (GARBAGE_BLOCK, KVPoolManager, Request,
-                                   RequestState, SamplingParams,
-                                   ServingEngine, VirtualClock)
+                                   RequestState, SamplingParams)
 from deepspeed_tpu.serving.kv_pool import KVPoolManager as _Mgr  # noqa: F401
 
-
-def tiny_cfg(**kw):
-    base = dict(vocab_size=64, max_seq_len=64, n_layers=2, n_heads=4,
-                d_model=16, d_ff=32, compute_dtype=jnp.float32)
-    base.update(kw)
-    return TransformerConfig(**base)
-
-
-@pytest.fixture(scope="module")
-def engine():
-    model = CausalLM(tiny_cfg())
-    return deepspeed_tpu.init_inference(
-        model, dtype="float32", max_tokens=64, prompt_bucket_size=16)
-
-
-def make_paged(engine, kv_pool=None, **kw):
-    kw.setdefault("virtual_clock", True)
-    kw.setdefault("n_slots", 2)
-    pool = dict(enabled=True, block_size=16)
-    pool.update(kv_pool or {})
-    return ServingEngine(engine,
-                         serving_config=ServingConfig(kv_pool=pool, **kw),
-                         clock=VirtualClock())
-
-
-def make_dense(engine, **kw):
-    kw.setdefault("virtual_clock", True)
-    kw.setdefault("n_slots", 2)
-    return ServingEngine(engine, serving_config=ServingConfig(**kw),
-                         clock=VirtualClock())
-
-
-def staggered_requests(rng, n, arrival_gap=0.5, max_new=(3, 9), plen=(4, 14)):
-    return [Request(
-        prompt=rng.randint(0, 64, (int(rng.randint(*plen)),)).astype(np.int32),
-        max_new_tokens=int(rng.randint(*max_new)),
-        arrival_time=i * arrival_gap) for i in range(n)]
+from .conftest import (make_paged, make_replica, staggered_requests,
+                       tiny_cfg)
 
 
 # ---------------------------------------------------------------------------
@@ -141,7 +104,7 @@ def test_paged_greedy_parity_vs_generate_and_dense(engine):
 
     sv = make_paged(engine, n_slots=2)
     list(sv.serve(paged_reqs))
-    dv = make_dense(engine, n_slots=2)
+    dv = make_replica(engine, n_slots=2)
     list(dv.serve(dense_reqs))
 
     assert all(r.state is RequestState.FINISHED for r in paged_reqs)
@@ -176,7 +139,7 @@ def test_paged_seeded_sampling_streams_unchanged(engine):
 
     paged, dense = mk(), mk()
     list(make_paged(engine, n_slots=2).serve(paged))
-    list(make_dense(engine, n_slots=2).serve(dense))
+    list(make_replica(engine, n_slots=2).serve(dense))
     for p, d in zip(paged, dense):
         assert p.tokens == d.tokens
     # and the sampled stream actually sampled (not greedy collapse)
@@ -195,7 +158,7 @@ def test_paged_admits_2x_slots_for_same_kv_hbm(engine):
             0, 64, (8,)).astype(np.int32), max_new_tokens=8)
         for i in range(7)]
 
-    dense = make_dense(engine, n_slots=2)
+    dense = make_replica(engine, n_slots=2)
     paged = make_paged(engine, n_slots=8, max_prefills_per_step=8,
                        kv_pool={"block_size": 16, "n_blocks": 8})
     # equal KV HBM: the paged pool's k array is byte-for-byte the dense

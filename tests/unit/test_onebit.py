@@ -135,26 +135,35 @@ def test_onebit_adam_converges_after_freeze(devices8):
     sm = make_compressed_allreduce(mesh, "data", bits=1)
     we = jnp.zeros((world * dim,), jnp.float32)
     se = jnp.zeros((dim,), jnp.float32)
+    shards = data.reshape(world, 16, dim)
+    grads_all = jax.vmap(local_grads, in_axes=(None, 0))
+
+    # one program per stage: called eagerly, the shard_map and the optimizer
+    # dispatch op by op, and 40 such steps were 135 s of this file's 197
+    @jax.jit
+    def exact_step(params, state):
+        g_mean = {"w": jnp.mean(grads_all(params["w"], shards), axis=0)}
+        return opt.update(g_mean, state, params)
+
+    @jax.jit
+    def compressed_step(params, state, we, se):
+        # compressed momentum path: each device folds ITS local grad
+        m_locals = jax.vmap(
+            lambda g: opt.local_momentum({"w": g}, state)["w"])(
+                grads_all(params["w"], shards))
+        m_red, we, se = sm(m_locals.reshape(-1), we, se)
+        m_tree = {"w": m_red.reshape(world, dim)[0]}
+        return opt.apply_compressed(m_tree, state, params) + (we, se)
 
     def loss(w):
         return float(jnp.mean((w - target) ** 2))
 
     losses = [loss(params["w"])]
-    shards = data.reshape(world, 16, dim)
-    grads_all = jax.jit(jax.vmap(local_grads, in_axes=(None, 0)))
-    momenta_all = jax.jit(jax.vmap(
-        lambda g, st: opt.local_momentum({"w": g}, st)["w"], in_axes=(0, None)))
     for step in range(40):
-        g_local = grads_all(params["w"], shards)  # [world, dim], one dispatch
         if step < opt.freeze_step:
-            g_mean = {"w": jnp.mean(g_local, axis=0)}
-            params, state = opt.update(g_mean, state, params)
+            params, state = exact_step(params, state)
         else:
-            # compressed momentum path: each device folds ITS local grad
-            m_locals = momenta_all(g_local, state)
-            m_red, we, se = sm(m_locals.reshape(-1), we, se)
-            m_tree = {"w": m_red.reshape(world, dim)[0]}
-            params, state = opt.apply_compressed(m_tree, state, params)
+            params, state, we, se = compressed_step(params, state, we, se)
         losses.append(loss(params["w"]))
 
     assert losses[10] < losses[0]          # warmup learns

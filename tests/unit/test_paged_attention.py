@@ -20,52 +20,36 @@ The acceptance invariants of the fused attention backend (ROADMAP item 1):
   gather path instead of failing.
 """
 
+import functools
+
 import numpy as np
 import jax
 import jax.numpy as jnp
 import pytest
 
 import deepspeed_tpu
-from deepspeed_tpu.config import ServingConfig
-from deepspeed_tpu.models import CausalLM, TransformerConfig, split_params_axes
+from deepspeed_tpu.models import CausalLM, split_params_axes
 from deepspeed_tpu.ops.pallas.paged_attention import (fused_decode_supported,
                                                       paged_flash_decode)
-from deepspeed_tpu.serving import (Request, RequestState, SamplingParams,
-                                   ServingEngine, VirtualClock)
+from deepspeed_tpu.serving import Request, RequestState, SamplingParams
+
+from . import conftest
+from .conftest import staggered_requests
 
 
-def tiny_cfg(**kw):
-    # attention_interpret: the fused kernel runs under the Pallas
-    # interpreter here — the explicit switch every kernel test sets
-    base = dict(vocab_size=64, max_seq_len=64, n_layers=2, n_heads=4,
-                d_model=16, d_ff=32, compute_dtype=jnp.float32,
-                attention_interpret=True)
-    base.update(kw)
-    return TransformerConfig(**base)
+# attention_interpret: the fused kernel runs under the Pallas interpreter
+# here — the explicit switch every kernel test sets
+tiny_cfg = functools.partial(conftest.tiny_cfg, attention_interpret=True)
 
 
 @pytest.fixture(scope="module")
 def engine():
-    model = CausalLM(tiny_cfg())
-    return deepspeed_tpu.init_inference(
-        model, dtype="float32", max_tokens=64, prompt_bucket_size=16)
+    return conftest.tiny_engine(attention_interpret=True)
 
 
 def make_serving(engine, backend, kv_pool=None, **kw):
-    kw.setdefault("virtual_clock", True)
-    kw.setdefault("n_slots", 2)
-    pool = dict(enabled=True, block_size=16, attention_backend=backend)
-    pool.update(kv_pool or {})
-    return ServingEngine(engine,
-                         serving_config=ServingConfig(kv_pool=pool, **kw),
-                         clock=VirtualClock())
-
-
-def staggered_requests(rng, n, arrival_gap=0.5, max_new=(3, 9), plen=(4, 14)):
-    return [Request(
-        prompt=rng.randint(0, 64, (int(rng.randint(*plen)),)).astype(np.int32),
-        max_new_tokens=int(rng.randint(*max_new)),
-        arrival_time=i * arrival_gap) for i in range(n)]
+    return conftest.make_paged(
+        engine, {"attention_backend": backend, **(kv_pool or {})}, **kw)
 
 
 # ---------------------------------------------------------------------------

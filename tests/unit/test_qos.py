@@ -27,14 +27,14 @@ fleet), all assertable under the virtual clock:
   up-then-down profile on the single-burst workload).
 """
 
+import functools
+
 import numpy as np
-import jax.numpy as jnp
 import pytest
 
-import deepspeed_tpu
 from deepspeed_tpu.config import (ConfigError, DegradedConfig, ServingConfig,
                                   SLOConfig, TenantsConfig)
-from deepspeed_tpu.models import CausalLM, TransformerConfig, split_params_axes
+from deepspeed_tpu.models import CausalLM, split_params_axes
 from deepspeed_tpu.serving import (CLASS_BATCH, CLASS_INTERACTIVE,
                                    DEGRADED_LADDER, DegradedModeController,
                                    REJECT_DEGRADED, Request, RequestQueue,
@@ -43,38 +43,10 @@ from deepspeed_tpu.serving import (CLASS_BATCH, CLASS_INTERACTIVE,
                                    VirtualClock)
 from deepspeed_tpu.telemetry.digest import LatencyDigest
 
-
-def tiny_cfg(**kw):
-    base = dict(vocab_size=64, max_seq_len=64, n_layers=2, n_heads=4,
-                d_model=16, d_ff=32, compute_dtype=jnp.float32)
-    base.update(kw)
-    return TransformerConfig(**base)
+from .conftest import make_full_replica, ref_tokens, tiny_cfg
 
 
-@pytest.fixture(scope="module")
-def engine():
-    model = CausalLM(tiny_cfg())
-    return deepspeed_tpu.init_inference(
-        model, dtype="float32", max_tokens=64, prompt_bucket_size=16)
-
-
-def make_replica(engine, trace_dir=None, **kw):
-    kw.setdefault("virtual_clock", True)
-    kw.setdefault("n_slots", 2)
-    kw.setdefault("chunked_prefill", {"enabled": True, "chunk_size": 8})
-    kw.setdefault("kv_pool", {"enabled": True, "block_size": 8,
-                              "on_demand_growth": True})
-    kw.setdefault("migration", {"enabled": True,
-                                "snapshot_interval_tokens": 2})
-    clock = VirtualClock()
-    tracer = None
-    if trace_dir is not None:
-        from deepspeed_tpu.telemetry import SpanTracer
-
-        tracer = SpanTracer(enabled=True, clock=clock.now,
-                            output_path=str(trace_dir), job_name="qos")
-    return ServingEngine(engine, serving_config=ServingConfig(**kw),
-                         clock=clock, tracer=tracer)
+make_replica = functools.partial(make_full_replica, job_name="qos")
 
 
 def qos_replica(engine, **kw):
@@ -86,13 +58,6 @@ def qos_replica(engine, **kw):
 def host_req(tid, cls, prompt_len=8, max_new=8):
     return Request(prompt=np.ones(prompt_len, np.int32), max_new_tokens=max_new,
                    tenant_id=tid, tenant_class=cls)
-
-
-def ref_tokens(engine, req):
-    out = np.asarray(engine.generate(req.prompt[None, :],
-                                     max_new_tokens=req.max_new_tokens,
-                                     greedy=True))
-    return out[0, req.prompt_len:]
 
 
 # --------------------------------------------------------------- config

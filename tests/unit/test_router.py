@@ -23,38 +23,15 @@ The acceptance invariants of the millions-of-users serving topology
 """
 
 import numpy as np
-import jax.numpy as jnp
 import pytest
 
 import deepspeed_tpu
-from deepspeed_tpu.config import ServingConfig
-from deepspeed_tpu.models import CausalLM, TransformerConfig, split_params_axes
+from deepspeed_tpu.models import CausalLM, split_params_axes
 from deepspeed_tpu.serving import (Request, RequestState, Router,
                                    SamplingParams, ServingEngine,
                                    VirtualClock)
 
-
-def tiny_cfg(**kw):
-    base = dict(vocab_size=64, max_seq_len=64, n_layers=2, n_heads=4,
-                d_model=16, d_ff=32, compute_dtype=jnp.float32)
-    base.update(kw)
-    return TransformerConfig(**base)
-
-
-@pytest.fixture(scope="module")
-def engine():
-    """One tiny fp32 engine shared by the module (weights + generate cache);
-    each test builds its own ServingEngine replicas over it."""
-    model = CausalLM(tiny_cfg())
-    return deepspeed_tpu.init_inference(
-        model, dtype="float32", max_tokens=64, prompt_bucket_size=16)
-
-
-def make_replica(engine, **kw):
-    kw.setdefault("virtual_clock", True)
-    kw.setdefault("n_slots", 2)
-    return ServingEngine(engine, serving_config=ServingConfig(**kw),
-                         clock=VirtualClock())
+from .conftest import make_replica, ref_tokens, tiny_cfg
 
 
 def make_router(engine, n=2, router=None, **kw):
@@ -63,13 +40,6 @@ def make_router(engine, n=2, router=None, **kw):
     if router:
         cfg = cfg.replace(**router)
     return Router(replicas, config=cfg)
-
-
-def ref_tokens(engine, req):
-    out = np.asarray(engine.generate(req.prompt[None, :],
-                                     max_new_tokens=req.max_new_tokens,
-                                     greedy=True))
-    return out[0, req.prompt_len:]
 
 
 # ---------------------------------------------------------------------------

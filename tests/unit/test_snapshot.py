@@ -30,8 +30,9 @@ pytestmark = pytest.mark.faults
 # jaxlib 0.4.x crash-class discipline (PR 3 root cause): engines here are
 # deliberately LEAKED, never destroy()ed — freeing CPU-collective
 # executables deserialized from the warm compile cache aborts the process,
-# and toggling the compilation cache mid-suite is another trigger. The
-# engine-churning chaos_train tool runs as a subprocess for the same reason.
+# and toggling the compilation cache mid-suite is another trigger. (jaxlib
+# 0.9.0 frees them cleanly: the engine-churning chaos_train tool runs in
+# process below, warm cache or cold.)
 
 
 def _engine(tmp_path=None, elastic=None, telemetry=False):
@@ -290,25 +291,13 @@ def test_stale_pending_shadow_is_never_resurrected(tmp_path, devices8):
 def test_chaos_train_tool_smoke(tmp_path):
     """tier-1 smoke of tools/chaos_train.py on the tiny preset: one seeded
     kill at equal scale, artifact stamped, exit 0 (survival + continuity +
-    lost-steps gates). Runs as a subprocess — the tool destroys engines
-    between segments, which is the warm-cache free-path crash class
-    in-process (see the module header)."""
-    import subprocess
-    import sys
+    lost-steps gates)."""
+    from tools import chaos_train
 
-    tool = os.path.join(os.path.dirname(__file__), "..", "..", "tools",
-                        "chaos_train.py")
     out = str(tmp_path / "chaos.json")
-    env = dict(os.environ,
-               JAX_PLATFORMS="cpu",
-               XLA_FLAGS=(os.environ.get("XLA_FLAGS", "")
-                          + " --xla_force_host_platform_device_count=8"))
-    r = subprocess.run(
-        [sys.executable, tool, "--steps", "6", "--kills", "1", "--seed", "1",
-         "--meshes", "8", "--ckpt-dir", str(tmp_path / "ckpt"),
-         "--out", out],
-        capture_output=True, text=True, timeout=560, env=env)
-    assert r.returncode == 0, r.stdout[-2000:] + r.stderr[-2000:]
+    assert chaos_train.main(
+        ["--steps", "6", "--kills", "1", "--seed", "1", "--meshes", "8",
+         "--ckpt-dir", str(tmp_path / "ckpt"), "--out", out]) == 0
     report = json.loads(open(out).read())
     assert report["preemptions_survived"] == 1
     assert report["max_lost_steps"] <= 1  # the snapshot cadence
