@@ -14,6 +14,8 @@ reference keeps training vs inference kernels separate; a parity test pins
 prefill logits == training-forward logits.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -356,32 +358,63 @@ def verify_with_paged_cache(model, params, input_ids, pool, table, pos,
                                     pos, block_size, draft_len=draft_len)
 
 
-def insert_block_kv(pool, dense_cache, block_id, src_start, block_size):
-    """Copy ONE token block from a freshly-prefilled dense cache into
-    physical block ``block_id`` of the pool (quantizing when the pool is
-    int8). ``block_id``/``src_start`` are TRACED scalars — one compiled
-    program covers every (block, offset) pair. The whole block is
-    overwritten, so nothing from its previous occupant survives (the paged
-    analogue of ``insert_slot_kv``'s whole-row guarantee)."""
-    out = dict(pool)
+def write_pool_blocks(pool, src, block_ids, src_blocks, *, lanes=False,
+                      mesh=None, interpret=False):
+    """THE block writer: ``pool[name][:, block_ids[i]] = src[name][:,
+    src_blocks[i]]`` for every leaf of ``pool`` and every i whose id lies in
+    ``[0, n_blocks)``; an id outside it is padding and writes nothing, so a
+    caller pads its ``[blocks_per_slot]`` arrays with DISTINCT ids from
+    ``n_blocks`` up and one compiled program serves every request size.
+    ``src`` leaves are ``[L, n_src, bs, kvh, *]`` in the pool's own dtypes:
+    bytes are moved, never recomputed. Whole blocks are overwritten, so
+    nothing of a previous occupant survives; every other block keeps its
+    bytes, and a donated pool is updated in place.
+
+    ``lanes``: the device keeps the pool's block axis in the lanes
+    (``ops/pallas/kv_block_write.blocks_in_lanes`` of the live pool). There
+    one block is one lane of every tile row of the pool and a scatter over
+    the block axis costs copies of the whole pool, so the column kernel
+    runs, per shard of ``mesh``. Everywhere else the XLA scatter does."""
+    from ..ops.pallas import kv_block_write as kw
+    from ..ops.pallas import note_fallback, shard_kernel, unavailable_reason
+
+    if lanes:
+        tp = mesh.shape.get("model", 1) if mesh is not None else 1
+        reason = unavailable_reason(interpret) or kw.unfit_reason(pool, tp)
+        if reason is None:
+            plan = kw.column_plan(block_ids, src_blocks, pool["k"].shape[1])
+            kernel = functools.partial(kw.write_block_columns,
+                                       interpret=interpret)
+            heads = {3: ("model",)}
+            return {name: shard_kernel(
+                kernel, mesh, (a, src[name].astype(a.dtype)) + plan,
+                [heads, heads, {}, {}, {}], [heads])
+                for name, a in pool.items()}
+        note_fallback("kv_block_write", reason)
+    return {name: a.at[:, block_ids].set(
+        src[name][:, src_blocks].astype(a.dtype), mode="drop",
+        unique_indices=True) for name, a in pool.items()}
+
+
+def insert_block_kv(pool, dense_cache, block_ids, src_blocks, block_size,
+                    **writer):
+    """Copy token blocks ``src_blocks`` of a freshly-prefilled dense b=1
+    cache ``[L, 1, max_len, kvh, dh]`` into physical blocks ``block_ids`` of
+    the pool, quantizing the cache ONCE (per (token, head), the pool's int8
+    layout) when the pool is int8. Both id arrays are TRACED and padded
+    (``write_pool_blocks``): one compiled program covers every request."""
+    src = {}
     for name in ("k", "v"):
-        rows = jax.lax.dynamic_slice_in_dim(
-            dense_cache[name], src_start, block_size, axis=2)  # [L,1,bs,kvh,dh]
-        rows = jnp.swapaxes(rows, 1, 2)[:, :, 0]               # [L,bs,kvh,dh]
+        d = dense_cache[name]
+        rows = d.reshape(d.shape[0], d.shape[2] // block_size, block_size,
+                         *d.shape[3:])
         if name + "_scale" in pool:
             from ..comm.collectives import quantize_blockwise
 
-            q, scale = quantize_blockwise(rows, block=rows.shape[-1])
-            out[name] = jax.lax.dynamic_update_slice(
-                pool[name], q[:, None], (0, block_id, 0, 0, 0))
-            out[name + "_scale"] = jax.lax.dynamic_update_slice(
-                pool[name + "_scale"], scale[:, None],
-                (0, block_id, 0, 0, 0))
-        else:
-            out[name] = jax.lax.dynamic_update_slice(
-                pool[name], rows[:, None].astype(pool[name].dtype),
-                (0, block_id, 0, 0, 0))
-    return out
+            rows, src[name + "_scale"] = quantize_blockwise(
+                rows, block=rows.shape[-1])
+        src[name] = rows
+    return write_pool_blocks(pool, src, block_ids, src_blocks, **writer)
 
 
 def reset_block_kv(pool, block_id):
@@ -418,28 +451,9 @@ def extract_slot_blocks(pool, table_row):
     order. No dequantization: a dequant -> requant round trip reproduces
     the int8 payload but can perturb the recomputed scale in its last ulp,
     which would break the migrated-stream-is-bitwise contract. Padded
-    table entries (GARBAGE_BLOCK) gather the garbage block; the injector
-    ignores them via its own id padding."""
+    table entries (GARBAGE_BLOCK) gather the garbage block; the writer
+    (``write_pool_blocks``) ignores them via its own id padding."""
     return {name: a[:, table_row] for name, a in pool.items()}
-
-
-def inject_block_kv(pool, raw_blocks, block_id, src_block):
-    """Copy ONE raw migrated block (``extract_slot_blocks`` payload, pool
-    dtype end to end — scales included) into physical block ``block_id``.
-    ``block_id``/``src_block`` are TRACED scalars, so one compiled program
-    covers every (target, source) pair; padded targets point at the
-    reserved garbage block, same convention as the prefill insert loop.
-    The whole block is overwritten — nothing from its previous occupant
-    survives, and because no quantize/dequantize runs, the target pool
-    bytes are identical to the source pool bytes (the bitwise-migration
-    contract's device half)."""
-    out = dict(pool)
-    for name, a in pool.items():
-        rows = jax.lax.dynamic_slice_in_dim(
-            raw_blocks[name], src_block, 1, axis=1)        # [L,1,bs,kvh,*]
-        out[name] = jax.lax.dynamic_update_slice(
-            a, rows.astype(a.dtype), (0, block_id, 0, 0, 0))
-    return out
 
 
 def _attn_with_cache(cfg, p_attn, h, k_cache, v_cache, pos, kv_len, rope=None,

@@ -30,10 +30,11 @@ from ..config.base import ConfigError
 from ..inference.engine import lru_compiled
 from ..models.decoding import (extract_slot_blocks, forward_with_cache,
                                forward_with_paged_cache, gather_slot_cache,
-                               init_cache, init_paged_cache, inject_block_kv,
+                               init_cache, init_paged_cache,
                                insert_block_kv, insert_slot_kv,
                                reset_block_kv, reset_slot_kv, sample_token,
-                               verify_with_paged_cache)
+                               verify_with_paged_cache, write_pool_blocks)
+from ..ops.pallas.kv_block_write import blocks_in_lanes
 from ..utils.logging import log_dist
 from .clock import VirtualClock, WallClock
 from .kv_pool import GARBAGE_BLOCK, KVPoolManager, prefix_chain_keys
@@ -408,6 +409,11 @@ class ServingEngine:
         bs = self.pool_mgr.block_size if paged else 0
         pool_keys = ("k", "v", "k_scale", "v_scale") \
             if paged and self.cfg.kv_pool.kv_dtype == "int8" else ("k", "v")
+        # how write_pool_blocks reaches the pool: by the layout the device
+        # gave it, read off the live array
+        writer = dict(lanes=paged and blocks_in_lanes(self._state["k"]),
+                      mesh=self.engine.mesh,
+                      interpret=model.config.attention_interpret)
 
         def decode(params, state):
             # one token for EVERY slot, each at its own cursor; inactive
@@ -553,21 +559,16 @@ class ServingEngine:
                 "eos": put(state["eos"], eos),
             })
 
-        def insert_blocks(state, dense_k, dense_v, block_ids, src_starts):
+        def insert_blocks(state, dense_k, dense_v, block_ids, src_blocks):
             # copy a request's private blocks from its freshly-prefilled
-            # dense cache into the pool in ONE dispatch: a fori_loop over
-            # the (traced) padded [blocks_per_slot] id/offset arrays, so
-            # TTFT pays one jitted call instead of one per block. Padding
-            # entries point at the garbage block (their copy is dead) —
-            # total device work is O(max_len), the dense insert's cost.
+            # dense cache into the pool in ONE dispatch that writes only
+            # those blocks: the (traced) [blocks_per_slot] id arrays are
+            # padded with ids past the pool, which write nothing, so one
+            # compiled program serves every request size
             pool = {k: state[k] for k in pool_keys}
-
-            def body(i, p):
-                return insert_block_kv(p, {"k": dense_k, "v": dense_v},
-                                       block_ids[i], src_starts[i], bs)
-
-            pool = jax.lax.fori_loop(0, block_ids.shape[0], body, pool)
-            return dict(state, **pool)
+            return dict(state, **insert_block_kv(
+                pool, {"k": dense_k, "v": dense_v}, block_ids, src_blocks,
+                bs, **writer))
 
         def seed_cache(state, table_row):
             # shared-prefix hit: materialize the slot's dense cache view
@@ -617,23 +618,17 @@ class ServingEngine:
             return dict(state, **reset_block_kv(
                 {k: state[k] for k in pool_keys}, block_id))
 
-        def migrate_in(state, raw_blocks, block_ids):
-            # live KV migration splice for int8 pools: copy a migrated
-            # request's RAW physical blocks — payload AND scales — into
-            # freshly-allocated pool blocks in ONE dispatch (the fori_loop
-            # mirror of insert_blocks; padding ids point at the garbage
-            # block, so their copy is dead). Raw, never dequantized: a
-            # dequant -> requant round trip can perturb the recomputed
-            # scale in its last ulp (see serving/migration.py). Non-int8
-            # pools migrate through the EXISTING insert_blocks program —
-            # their dense view IS the raw bytes.
+        def migrate_in(state, raw_blocks, block_ids, src_blocks):
+            # live KV migration splice for int8 pools: a migrated request's
+            # RAW physical blocks, payload AND scales, through the same
+            # writer as insert_blocks. Raw, never dequantized: a dequant ->
+            # requant round trip can perturb the recomputed scale in its
+            # last ulp (see serving/migration.py). Non-int8 pools migrate
+            # through the EXISTING insert_blocks program: their dense view
+            # IS the raw bytes.
             pool = {k: state[k] for k in pool_keys}
-
-            def body(i, p):
-                return inject_block_kv(p, raw_blocks, block_ids[i], i)
-
-            pool = jax.lax.fori_loop(0, block_ids.shape[0], body, pool)
-            return dict(state, **pool)
+            return dict(state, **write_pool_blocks(
+                pool, raw_blocks, block_ids, src_blocks, **writer))
 
         def sample_first(logits, key, temp, top_k, top_p):
             # same in-graph guard as decode: the first token samples from
@@ -1365,6 +1360,20 @@ class ServingEngine:
                             trace_id=req.trace_id,
                             n_tokens=len(req.tokens))
 
+    def _writer_ids(self, targets, sources):
+        """The block writer's two ``[blocks_per_slot]`` id arrays for one
+        dispatch: source block ``sources[i]`` lands on pool block
+        ``targets[i]``. Every entry past them is padding: a distinct id past
+        the pool, which writes nothing (``write_pool_blocks``). Counted
+        here, so ``kv_insert_blocks`` is the blocks really written."""
+        mgr = self.pool_mgr
+        ids = mgr.n_blocks + np.arange(mgr.blocks_per_slot, dtype=np.int32)
+        srcs = np.zeros((mgr.blocks_per_slot,), np.int32)
+        ids[:len(targets)] = targets
+        srcs[:len(targets)] = list(sources)
+        self.metrics.record_kv_insert(len(targets))
+        return jnp.asarray(ids), jnp.asarray(srcs)
+
     def _insert_paged(self, req, slot, cache, shared_len, shared_blocks,
                       tok, chain_key, s, eos, remaining):
         """Bind a paged slot: allocate the request's footprint in blocks,
@@ -1383,14 +1392,10 @@ class ServingEngine:
         self._unreserve(req)
         private = mgr.alloc(needed - len(shared_blocks))
         blocks = list(shared_blocks) + private
-        ids = np.full((mgr.blocks_per_slot,), GARBAGE_BLOCK, np.int32)
-        srcs = np.zeros((mgr.blocks_per_slot,), np.int32)
-        for i, bid in enumerate(private):
-            ids[i] = bid
-            srcs[i] = (len(shared_blocks) + i) * mgr.block_size
+        ids, srcs = self._writer_ids(
+            private, range(len(shared_blocks), len(blocks)))
         self._state = self._insert_block_jit(
-            self._state, cache["k"], cache["v"], jnp.asarray(ids),
-            jnp.asarray(srcs))
+            self._state, cache["k"], cache["v"], ids, srcs)
         row = np.full((mgr.blocks_per_slot,), GARBAGE_BLOCK, np.int32)
         row[:len(blocks)] = blocks
         self._state = self._insert_jit(
@@ -1486,7 +1491,8 @@ class ServingEngine:
         the dedicated raw program so payload AND scales move verbatim."""
         mgr = self.pool_mgr
         bs = mgr.block_size
-        ids = np.full((mgr.blocks_per_slot,), GARBAGE_BLOCK, np.int32)
+        span = range(n_shared, n_shared + n_inject)
+        ids, srcs = self._writer_ids(blocks[span.start:span.stop], span)
         if self.cfg.kv_pool.kv_dtype == "int8":
             raw = {}
             for name, a in snap.blocks.items():
@@ -1494,10 +1500,7 @@ class ServingEngine:
                                + a.shape[2:], a.dtype)
                 pad[:, :a.shape[1]] = a
                 raw[name] = jax.device_put(pad, self._cache_sharding)
-            for i in range(n_shared, n_shared + n_inject):
-                ids[i] = blocks[i]
-            self._state = self._migrate_in_jit(self._state, raw,
-                                               jnp.asarray(ids))
+            self._state = self._migrate_in_jit(self._state, raw, ids, srcs)
             return
         dense = {}
         for name in ("k", "v"):
@@ -1507,13 +1510,8 @@ class ServingEngine:
             d[:, 0, :a.shape[1] * bs] = \
                 a.reshape((a.shape[0], -1) + a.shape[3:])
             dense[name] = jax.device_put(d, self._cache_sharding)
-        srcs = np.zeros((mgr.blocks_per_slot,), np.int32)
-        for i in range(n_shared, n_shared + n_inject):
-            ids[i] = blocks[i]
-            srcs[i] = i * bs
         self._state = self._insert_block_jit(
-            self._state, dense["k"], dense["v"], jnp.asarray(ids),
-            jnp.asarray(srcs))
+            self._state, dense["k"], dense["v"], ids, srcs)
 
     def _splice_snapshot(self, req, snap, ids_full, shared_len,
                          shared_blocks):

@@ -128,6 +128,11 @@ class ServingMetrics:
         # priority preemptions: evictions of a batch-class stream by an
         # interactive arrival (a subset of ``preempted``)
         self.priority_evictions = 0
+        # paged pool block writes (prefill inserts and migration splices):
+        # dispatches of the writer and the REAL blocks they wrote, padding
+        # not counted — blocks a dispatch is what an insert should cost
+        self.kv_insert_dispatches = 0
+        self.kv_insert_blocks = 0
         # goodput accounting, in DEVICE TOKENS of work (the virtual cost
         # model's currency: one prefill dispatch costs its padded length,
         # one decode step yields one token per active slot). useful = fresh
@@ -335,6 +340,10 @@ class ServingMetrics:
                 self.accepted_tokens_per_step, 4),
         }
 
+    def record_kv_insert(self, n_blocks):
+        self.kv_insert_dispatches += 1
+        self.kv_insert_blocks += int(n_blocks)
+
     def record_snapshot(self):
         self.kv_snapshots += 1
 
@@ -497,6 +506,8 @@ class ServingMetrics:
             "slot_occupancy": self._active_slots / max(self.n_slots, 1),
             "active_slots_peak": self.active_slots_peak,
             "preempted": self.preempted,
+            "kv_insert_dispatches": self.kv_insert_dispatches,
+            "kv_insert_blocks": self.kv_insert_blocks,
             "health": {
                 "nonfinite_logit_steps": self.nonfinite_logit_steps,
                 "unhealthy_slots": self.unhealthy_slots,

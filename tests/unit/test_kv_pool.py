@@ -248,8 +248,7 @@ def test_int8_kv_within_pinned_tolerance(engine):
     logits, cache = forward_with_cache(model, params, jnp.asarray(ids),
                                        cache, 0, max_len)
     pool = init_paged_cache(cfg, 5, bs, engine.dtype, "int8")
-    for i in range(4):
-        pool = insert_block_kv(pool, cache, i + 1, i * bs, bs)
+    pool = insert_block_kv(pool, cache, 1 + jnp.arange(4), jnp.arange(4), bs)
     table = jnp.asarray([[1, 2, 3, 4]], jnp.int32)
 
     tok = jnp.argmax(logits[:, plen - 1], -1).astype(jnp.int32)

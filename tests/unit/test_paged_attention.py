@@ -199,8 +199,8 @@ def _forward_parity(cfg_kw, kv_dtype=None, tol=1e-5, steps=5):
         pool = init_paged_cache(cfg, 9, bs, jnp.float32, kv_dtype)
         for s in range(2):
             c1 = {k: v[:, s:s + 1] for k, v in cache.items()}
-            for i in range(4):
-                pool = insert_block_kv(pool, c1, 1 + s * 4 + i, i * bs, bs)
+            pool = insert_block_kv(pool, c1, 1 + s * 4 + jnp.arange(4),
+                                   jnp.arange(4), bs)
         return pool
 
     pg, pf = mkpool(), mkpool()

@@ -138,6 +138,63 @@ def test_paged_decode_lowers(nh, kvh, dh, int8):
     assert n_mosaic(text) == 1
 
 
+def _block_write_operands(int8, sharding=None):
+    L, n_blocks, bs, kvh, dh, max_len = 24, 1537, 16, 32, 64, 2048
+    shape = (L, n_blocks, bs, kvh, dh)
+    sds = lambda shape, dt: SDS(shape, dt, sharding=sharding)
+    pool = {n: sds(shape, jnp.int8 if int8 else jnp.bfloat16)
+            for n in ("k", "v")}
+    if int8:
+        pool.update({n + "_scale": sds(shape[:-1] + (1,), jnp.float32)
+                     for n in ("k", "v")})
+    cache = {n: sds((L, 1, max_len, kvh, dh), jnp.bfloat16)
+             for n in ("k", "v")}
+    ids = SDS((max_len // bs,), jnp.int32)
+    return pool, cache, ids, ids
+
+
+@pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
+def test_kv_block_write_lowers(int8):
+    """The column kernel at the serve cell's pool (OPT-1.3B, 1537 blocks of
+    16): one Mosaic call a pool leaf."""
+    from deepspeed_tpu.models.decoding import insert_block_kv
+
+    text = lower_for_tpu(
+        lambda p, c, i, s: insert_block_kv(p, c, i, s, 16, lanes=True),
+        *_block_write_operands(int8))
+    assert n_mosaic(text) == (4 if int8 else 2)
+
+
+def test_kv_block_write_gives_way_loudly_not_silently():
+    """Off the TPU (and without the interpreter) a pool said to keep its
+    blocks in the lanes is still written, by the XLA scatter, and the
+    dispatcher says so."""
+    import logging
+
+    from deepspeed_tpu.models.decoding import write_pool_blocks
+    from deepspeed_tpu.ops import pallas as plx
+    from deepspeed_tpu.utils.logging import logger
+
+    plx.note_fallback.cache_clear()
+    seen = []
+    handler = logging.Handler(logging.WARNING)
+    handler.emit = lambda record: seen.append(record.getMessage())
+    logger.addHandler(handler)
+    rng = np.random.RandomState(0)
+    pool = {"k": jnp.asarray(rng.randn(2, 9, 8, 2, 16), jnp.float32)}
+    src = {"k": jnp.asarray(rng.randn(2, 4, 8, 2, 16), jnp.float32)}
+    ids, srcs = jnp.asarray([3, 9, 7, 10]), jnp.asarray([1, 0, 2, 0])
+    try:
+        got = write_pool_blocks(pool, src, ids, srcs, lanes=True)
+    finally:
+        logger.removeHandler(handler)
+    want = np.asarray(pool["k"]).copy()
+    want[:, [3, 7]] = np.asarray(src["k"])[:, [1, 2]]
+    np.testing.assert_array_equal(np.asarray(got["k"]), want)
+    assert len(seen) == 1 and "kv_block_write" in seen[0] \
+        and "'cpu'" in seen[0], seen
+
+
 @pytest.mark.parametrize("bits", [8, 4])
 def test_quantized_matmul_lowers(bits):
     from deepspeed_tpu.ops.pallas.quantized_matmul import \
@@ -262,6 +319,21 @@ def test_tp4_fused_decode_lowers(devices8):
             assert n_mosaic(text) == 1
         finally:
             eng.destroy()
+
+
+def test_tp4_kv_block_write_lowers(devices8):
+    """The pool split over ``model`` on its kv heads: the kernel runs per
+    shard, 8 of 32 heads a chip (compiled for v5e 2x2 the program holds no
+    collective: PERF.md, PR 27)."""
+    from deepspeed_tpu.models.decoding import insert_block_kv
+
+    mesh = build_mesh(MeshConfig(model=4), devices=devices8[:4])
+    heads = NamedSharding(mesh, P(None, None, None, "model", None))
+    text = lower_for_tpu(
+        lambda p, c, i, s: insert_block_kv(p, c, i, s, 16, lanes=True,
+                                           mesh=mesh),
+        *_block_write_operands(False, sharding=heads))
+    assert n_mosaic(text) == 2
 
 
 def test_quantized_matmul_refused_up_front_on_a_mesh(devices8):

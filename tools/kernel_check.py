@@ -224,6 +224,41 @@ def _qmm_case(bits):
             fused, ref, tuple(args), 2e-2)
 
 
+def _block_write_case(int8):
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.models.decoding import insert_block_kv
+
+    rng = np.random.RandomState(0)
+    L, n_blocks, bs, kvh, dh, max_len = 4, 1537, 16, 32, 64, 2048
+    shape = (L, n_blocks, bs, kvh, dh)
+    if int8:
+        pool = {"k": jnp.asarray(rng.randint(-127, 128, shape), jnp.int8),
+                "k_scale": jnp.asarray(rng.rand(*shape[:-1], 1), jnp.float32)}
+        pool.update(v=-pool["k"], v_scale=2 * pool["k_scale"])
+    else:
+        pool = {"k": jnp.asarray(rng.randn(*shape), jnp.bfloat16)}
+        pool["v"] = -pool["k"]
+    cache = {n: jnp.asarray(rng.randn(L, 1, max_len, kvh, dh), jnp.bfloat16)
+             for n in ("k", "v")}
+    # 37 blocks off a fragmented free list (6 of the 13 columns, the last
+    # one among them), sources past a 3-block shared prefix, then padding
+    nb = max_len // bs
+    ids = n_blocks + np.arange(nb, dtype=np.int32)
+    ids[:37] = np.concatenate([np.arange(120, 140), np.arange(700, 710),
+                               [1536, 5, 900, 901, 1300, 1301, 1302]])
+    srcs = np.zeros((nb,), np.int32)
+    srcs[:37] = 3 + np.arange(37)
+
+    def run(lanes):
+        return lambda pool, cache, ids, srcs: insert_block_kv(
+            pool, cache, ids, srcs, bs, lanes=lanes)
+
+    return (f"{'int8' if int8 else 'bf16'} pool [4, 1537, 16, 32, 64], 37 of "
+            "128 entries real, exact", run(True), run(False),
+            (pool, cache, jnp.asarray(ids), jnp.asarray(srcs)), 0.0)
+
+
 CASES = {
     "flash fwd+bwd (single kv block)": flash_single_block,
     "flash fwd+bwd (general)": flash_general,
@@ -237,6 +272,8 @@ CASES = {
         lambda: _paged_case(32, 8, 128, int8=True),
     "quantized matmul int8": lambda: _qmm_case(8),
     "quantized matmul int4": lambda: _qmm_case(4),
+    "kv block write (bf16 pool)": lambda: _block_write_case(False),
+    "kv block write (int8 pool)": lambda: _block_write_case(True),
 }
 
 
