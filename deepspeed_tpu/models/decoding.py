@@ -27,12 +27,8 @@ def init_cache(cfg, batch_size, max_len, dtype=None):
     """Allocate the KV cache: k/v stacked over layers (matches the stacked block
     params, so layer scan indexes both together)."""
     dtype = dtype or cfg.compute_dtype
-    kvh = cfg.kv_heads
-    shape = (cfg.n_layers, batch_size, max_len, kvh, cfg.head_dim)
-    return {
-        "k": jnp.zeros(shape, dtype),
-        "v": jnp.zeros(shape, dtype),
-    }
+    return {name: jnp.zeros((cfg.n_layers, batch_size, max_len) + row, dtype)
+            for name, row in cfg.cache_geometry.items()}
 
 
 def insert_slot_kv(pool, slot_cache, slot):
@@ -83,16 +79,14 @@ def init_paged_cache(cfg, n_blocks, block_size, dtype=None, kv_dtype=None):
     k/v: [L, n_blocks, block_size, kvh, dh] int8, k_scale/v_scale:
     [L, n_blocks, block_size, kvh, 1] f32."""
     dtype = dtype or cfg.compute_dtype
-    kvh = cfg.kv_heads
-    shape = (cfg.n_layers, n_blocks, block_size, kvh, cfg.head_dim)
+    shapes = {name: (cfg.n_layers, n_blocks, block_size) + row
+              for name, row in cfg.cache_geometry.items()}
     if kv_dtype == "int8":
-        return {
-            "k": jnp.zeros(shape, jnp.int8),
-            "v": jnp.zeros(shape, jnp.int8),
-            "k_scale": jnp.zeros(shape[:-1] + (1,), jnp.float32),
-            "v_scale": jnp.zeros(shape[:-1] + (1,), jnp.float32),
-        }
-    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+        pool = {name: jnp.zeros(s, jnp.int8) for name, s in shapes.items()}
+        pool.update({name + "_scale": jnp.zeros(s[:-1] + (1,), jnp.float32)
+                     for name, s in shapes.items()})
+        return pool
+    return {name: jnp.zeros(s, dtype) for name, s in shapes.items()}
 
 
 def _dequant_layer(q, scale, dtype):
@@ -202,7 +196,8 @@ def _attn_paged_fused(cfg, p_attn, h, kc, vc, ks, vs, table, pos, rope=None):
 
 def forward_with_paged_cache(model, params, input_ids, pool, table, pos,
                              block_size, draft_len=None,
-                             attention_backend="gather"):
+                             attention_backend="gather",
+                             return_routing=False):
     """One decode step ([S, 1] tokens) reading/writing KV through a TRACED
     block table — the paged twin of ``forward_with_cache``'s per-row decode.
 
@@ -229,8 +224,25 @@ def forward_with_paged_cache(model, params, input_ids, pool, table, pos,
     (``ops/pallas/paged_attention.py``): the block-table walk happens
     inside the kernel's index map and the dense per-slot view is never
     materialized. Decode-only (q_len == 1, no verify) — callers gate on
-    ``fused_decode_supported`` and fall back to the gather path."""
+    ``fused_decode_supported`` and fall back to the gather path.
+
+    ``return_routing`` (expert models with ``moe_routing="dropfree"``): also
+    return what the step's expert layers chose, [L_moe, S, 1, 2k] int32 (ids
+    and the bits of their weights: ``moe/dropfree.py``)."""
     cfg = model.config
+    if cfg.latent_attention:
+        from . import latent
+
+        if draft_len is not None or "k_scale" in pool \
+                or attention_backend != "gather":
+            raise ValueError(
+                "latent attention decodes in the absorbed form through the "
+                "gather backend over a pool in the engine's dtype: "
+                "speculative verify, an int8 pool and the fused backend are "
+                "not implemented")
+        logits, pool, ids = latent.forward_with_paged_cache(
+            model, params, input_ids, pool, table, pos, block_size)
+        return (logits, pool, ids) if return_routing else (logits, pool)
     b, q_len = input_ids.shape
     int8 = "k_scale" in pool
     view_dtype = cfg.compute_dtype
@@ -434,14 +446,14 @@ def gather_slot_cache(cfg, pool, table_row, dtype):
     prefill on a shared-prefix hit: positions below the shared length hold
     the canonical prefix KV, everything above is garbage the suffix prefill
     overwrites or the causal mask hides."""
-    g = pool["k"][:, table_row]                    # [L, NB, bs, kvh, dh]
-    gv = pool["v"][:, table_row]
-    if "k_scale" in pool:
-        g = _dequant_layer(g, pool["k_scale"][:, table_row], dtype)
-        gv = _dequant_layer(gv, pool["v_scale"][:, table_row], dtype)
-    L_, nb, bs, kvh, dh = g.shape
-    return {"k": g.reshape(L_, 1, nb * bs, kvh, dh).astype(dtype),
-            "v": gv.reshape(L_, 1, nb * bs, kvh, dh).astype(dtype)}
+    out = {}
+    for name in ("k", "v"):
+        g = pool[name][:, table_row]               # [L, NB, bs, kvh, dh]
+        if name + "_scale" in pool:
+            g = _dequant_layer(g, pool[name + "_scale"][:, table_row], dtype)
+        L_, nb, bs, kvh, dh = g.shape
+        out[name] = g.reshape(L_, 1, nb * bs, kvh, dh).astype(dtype)
+    return out
 
 
 def extract_slot_blocks(pool, table_row):
@@ -641,7 +653,8 @@ def _block_cached(cfg, p, x, k_cache, v_cache, pos, kv_len, rope=None,
 
 
 def forward_with_cache(model, params, input_ids, cache, pos, kv_len,
-                       prefill=False, row_writes="block"):
+                       prefill=False, row_writes="block", last_index=None,
+                       return_routing=False):
     """Run the model on ``input_ids`` [b, q] writing k/v into ``cache`` at ``pos``.
 
     Used for both prefill (q = prompt length, pos = 0) and decode (q = 1,
@@ -653,8 +666,21 @@ def forward_with_cache(model, params, input_ids, cache, pos, kv_len,
     (callers with pos > 0 must leave it False).
     ``row_writes="reverse"`` (per-row pos only) makes multi-row writes safe
     against by-design window overruns — see ``_attn_with_cache``.
+    ``last_index`` (traced; latent-attention models): the logits of that one
+    row only, [b, 1, vocab]. ``return_routing`` (drop-free expert models):
+    also return what the expert layers chose, [L_moe, b, q, 2k] int32.
     """
     cfg = model.config
+    if cfg.latent_attention:
+        from . import latent
+
+        logits, cache, ids = latent.forward_with_cache(
+            model, params, input_ids, cache, pos, kv_len, prefill=prefill,
+            last_index=last_index)
+        return (logits, cache, ids) if return_routing else (logits, cache)
+    if last_index is not None or return_routing:
+        raise ValueError("last_index and return_routing are the latent "
+                         "expert model's (models/latent.py)")
     b, q_len = input_ids.shape
     if jnp.ndim(pos) == 1:
         positions = pos[:, None] + jnp.arange(q_len)[None, :]  # [b, q]

@@ -221,6 +221,37 @@ def gpt2_moe_config(size="tiny", **overrides):
     return TransformerConfig(**base)
 
 
+def kanana2_config(size="30b-a3b", **overrides):
+    """kakaocorp/kanana-2-30b-a3b-instruct-2601 (``model_type`` deepseek_v3;
+    huggingface.co/kakaocorp/kanana-2-30b-a3b-instruct-2601 config.json):
+    latent attention (MLA) without a q LoRA, decoupled interleaved RoPE on 64
+    dims, one leading dense SwiGLU layer, then layers of 128 sigmoid-routed
+    experts (top-6 of ``s + b``, weights from ``s``, normalised, x 2.448)
+    beside 2 shared experts; untied head; RMSNorm eps 1e-6; no biases."""
+    presets = {
+        "tiny": dict(n_layers=3, d_model=64, n_heads=4, d_ff=128,
+                     moe_d_ff=32, n_experts=8, moe_top_k=2,
+                     n_shared_experts=2, kv_lora_rank=32,
+                     qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+                     max_seq_len=256, vocab_size=512),
+        "30b-a3b": dict(n_layers=48, d_model=2048, n_heads=32, d_ff=6144,
+                        moe_d_ff=768, n_experts=128, moe_top_k=6,
+                        n_shared_experts=2, kv_lora_rank=512,
+                        qk_nope_head_dim=128, qk_rope_head_dim=64,
+                        v_head_dim=128),
+    }
+    base = dict(
+        vocab_size=128256, max_seq_len=32768, activation="swiglu",
+        norm="rmsnorm", position_embedding="rope", rope_base=1000000.0,
+        rotary_interleaved=True, tie_embeddings=False, use_bias=False,
+        prenorm=True, layernorm_eps=1e-6, first_k_dense=1,
+        moe_routing="dropfree", moe_routed_scale=2.448,
+    )
+    base.update(presets[size])
+    base.update(overrides)
+    return TransformerConfig(**base)
+
+
 def bert_config(size="base", **overrides):
     """Encoder presets (BERT paper table 1 geometry): post-norm, bidirectional,
     learned positions + segment embeddings, gelu, embed LN."""
@@ -254,6 +285,7 @@ MODEL_CONFIGS = {
     "falcon": falcon_config,
     "bert": bert_config,
     "gpt2_moe": gpt2_moe_config,
+    "kanana2": kanana2_config,
 }
 
 

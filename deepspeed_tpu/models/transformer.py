@@ -178,6 +178,28 @@ class TransformerConfig:
     # 2201.05596): a dense MLP runs alongside the experts; outputs are blended
     # by a learned 2-way softmax coefficient
     moe_use_residual: bool = False
+    # Which routing the model PUBLISHES (a property of the checkpoint, not a
+    # user's switch): "capacity" = GShard softmax top-k with per-group
+    # capacity and drops (the reference's; gpt2_moe), "dropfree" = every
+    # chosen token-expert pair is computed (moe/dropfree.py: sigmoid scores,
+    # a per-expert selection bias that picks but does not weigh, weights
+    # normalised over the chosen and scaled by ``moe_routed_scale``; sort by
+    # expert, grouped product over ragged groups).
+    moe_routing: str = "capacity"  # capacity | dropfree
+    moe_routed_scale: float = 1.0  # routed_scaling_factor (dropfree)
+    moe_d_ff: typing.Optional[int] = None  # one routed expert's width (None = d_ff)
+    n_shared_experts: int = 0  # always-on experts: one SwiGLU of n * moe_d_ff
+    # the first ``first_k_dense`` layers keep a dense FFN of width d_ff; the
+    # rest are expert layers (params["dense_blocks"] beside params["blocks"])
+    first_k_dense: int = 0
+    # Latent attention (MLA, DeepSeek-V2/V3; models/latent.py): 0 = off. K and
+    # V are expanded from one ``kv_lora_rank``-wide latent a token (+ one
+    # ``qk_rope_head_dim`` rotated key shared by all heads), which is all the
+    # cache holds. No q LoRA (q_lora_rank null in the supported configs).
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
 
     def __post_init__(self):
         # a typo here would silently run the exact fp32 path and let a
@@ -199,6 +221,23 @@ class TransformerConfig:
             raise ValueError(
                 f"attention_impl must be one of xla|flash|jax_flash|"
                 f"block_sparse, got {self.attention_impl!r}")
+        if self.moe_routing not in ("capacity", "dropfree"):
+            raise ValueError(
+                f"moe_routing must be 'capacity' or 'dropfree', got "
+                f"{self.moe_routing!r}")
+        if self.n_experts > 0 and self.moe_routing == "dropfree" \
+                and not self.kv_lora_rank:
+            raise ValueError(
+                "moe_routing='dropfree' is implemented beside latent "
+                "attention only (kv_lora_rank > 0): the cache paths that "
+                "carry its routing are models/latent.py's")
+        if self.first_k_dense and not 0 < self.first_k_dense < self.n_layers:
+            raise ValueError(
+                f"first_k_dense {self.first_k_dense} must leave at least one "
+                f"expert layer of n_layers {self.n_layers}")
+        if self.first_k_dense and self.n_experts < 1:
+            raise ValueError("first_k_dense needs an expert model "
+                             "(n_experts > 0)")
 
     @property
     def attn_logits_jnp_dtype(self):
@@ -214,6 +253,27 @@ class TransformerConfig:
     def kv_heads(self):
         return self.n_kv_heads or self.n_heads
 
+    @property
+    def latent_attention(self):
+        return self.kv_lora_rank > 0
+
+    @property
+    def expert_d_ff(self):
+        return self.moe_d_ff or self.d_ff
+
+    @property
+    def cache_geometry(self):
+        """``{leaf: (kv_heads, width)}`` of one cached token in one layer:
+        what every cache allocator (dense, paged, block writer) sizes its
+        ``k`` and ``v`` leaves by. Latent attention caches one normed latent
+        row (``k``) and one rotated shared key (``v``) a token, not per-head
+        K and V."""
+        if self.latent_attention:
+            return {"k": (1, self.kv_lora_rank),
+                    "v": (1, self.qk_rope_head_dim)}
+        return {"k": (self.kv_heads, self.head_dim),
+                "v": (self.kv_heads, self.head_dim)}
+
     def num_params(self):
         """Analytic parameter count (embedding + blocks + final norm)."""
         d, f, v = self.d_model, self.d_ff, self.vocab_size
@@ -223,13 +283,25 @@ class TransformerConfig:
         q_dim = self.n_heads * self.head_dim
         kv_dim = self.kv_heads * self.head_dim
         per_block = d * q_dim + 2 * d * kv_dim + q_dim * d
-        if self.activation == "swiglu":
-            per_block += 3 * d * f
-        else:
-            per_block += 2 * d * f
+        if self.latent_attention:
+            H, r = self.n_heads, self.kv_lora_rank
+            dn, dr, dv = (self.qk_nope_head_dim, self.qk_rope_head_dim,
+                          self.v_head_dim)
+            per_block = (d * H * (dn + dr) + d * (r + dr) + r
+                         + r * H * (dn + dv) + H * dv * d)
+        ffn = (3 if self.activation == "swiglu" else 2) * d * f
         per_block += 4 * d if self.use_bias else 0
         per_block += 2 * d  # two norms (scale+bias counted roughly)
-        total = self.n_layers * per_block + v * d
+        if self.n_experts > 0 and self.moe_routing == "dropfree":
+            fe = self.expert_d_ff
+            expert_ffn = (self.n_experts * 3 * d * fe
+                          + self.n_shared_experts * 3 * d * fe
+                          + d * self.n_experts + self.n_experts)
+            kd = self.first_k_dense
+            total = (self.n_layers * per_block + kd * ffn
+                     + (self.n_layers - kd) * expert_ffn + v * d)
+        else:
+            total = self.n_layers * (per_block + ffn) + v * d
         if self.position_embedding == "learned":
             total += self.max_seq_len * d
         if not self.tie_embeddings:
@@ -287,18 +359,28 @@ def _mlp_apply(cfg, p, x, tp_manual=False):
 def block_init(rng, cfg):
     k_attn, k_mlp = jax.random.split(rng)
     out_std = cfg.initializer_range / (2.0 * cfg.n_layers) ** 0.5
-    if cfg.n_experts > 0:
+    if cfg.n_experts > 0 and cfg.moe_routing == "dropfree":
+        from ..moe.dropfree import dropfree_moe_init
+
+        mlp = dropfree_moe_init(k_mlp, cfg)
+    elif cfg.n_experts > 0:
         from ..moe import moe_mlp_init
 
         mlp = moe_mlp_init(k_mlp, cfg)
     else:
         mlp = _mlp_init(k_mlp, cfg)
-    return {
-        "ln_1": _norm_init(cfg),
-        "attn": L.attention_init(
+    if cfg.latent_attention:
+        from .latent import latent_attention_init
+
+        attn = latent_attention_init(k_attn, cfg, out_std)
+    else:
+        attn = L.attention_init(
             k_attn, cfg.d_model, cfg.n_heads, cfg.kv_heads, cfg.use_bias,
             cfg.initializer_range, out_stddev=out_std, head_dim=cfg.head_dim,
-        ),
+        )
+    return {
+        "ln_1": _norm_init(cfg),
+        "attn": attn,
         "ln_2": _norm_init(cfg),
         "mlp": mlp,
     }
@@ -397,6 +479,15 @@ def block_apply(cfg, p, x, mask=None, rope=None, alibi=None, deterministic=True,
 
     def attn(h):
         pa = p["attn"]
+        if cfg.latent_attention:
+            if tp_manual or cfg.sequence_parallel or mask is not None:
+                raise NotImplementedError(
+                    "latent attention runs plain causal attention on one "
+                    "model shard (no manual TP, ring attention or padding "
+                    "mask)")
+            from .latent import attention_uncached
+
+            return attention_uncached(cfg, pa, h, rope)
         if tp_manual:
             h = L.tp_copy(h, "model")  # completes dL/dh with a backward psum
         if "kernel" in pa["q"] and cfg.fused_qkv:
@@ -516,6 +607,10 @@ def block_apply(cfg, p, x, mask=None, rope=None, alibi=None, deterministic=True,
                 raise NotImplementedError(
                     "MoE layers do not compose with the manual-TP block "
                     "(1F1B x TP); use the GPipe schedule for MoE pipelines")
+            if cfg.moe_routing == "dropfree":
+                from ..moe.dropfree import dropfree_moe_apply
+
+                return dropfree_moe_apply(cfg, p["mlp"], h)[0]
             from ..moe import moe_mlp_apply
 
             moe_rng = (jax.random.fold_in(dropout_rng, 4)
@@ -580,7 +675,10 @@ def stack_init(rng, cfg):
     one leaf per block param with shape [n_layers, ...]. This is what makes
     scan-over-layers (and per-layer ZeRO-3 gathering) natural."""
     rngs = jax.random.split(rng, cfg.n_layers)
-    stacked = jax.vmap(lambda r: block_init(r, cfg))(rngs)
+    # under first_k_dense these are the expert layers alone (the leading
+    # dense ones: dense_stack_init); the keys stay one a layer of the model
+    stacked = jax.vmap(lambda r: block_init(r, cfg))(
+        rngs[cfg.first_k_dense:])
 
     def prepend_layers(param):
         return Param(param.value, ("layers",) + param.axes)
@@ -588,6 +686,20 @@ def stack_init(rng, cfg):
     return jax.tree_util.tree_map(
         prepend_layers, stacked, is_leaf=lambda x: isinstance(x, Param)
     )
+
+
+def dense_stack_init(rng, cfg):
+    """The ``first_k_dense`` leading layers of a model whose later layers
+    are experts: the same block with a dense FFN of width ``d_ff``, stacked
+    on their own (``params["dense_blocks"]``) because their leaves are not
+    the expert layers' leaves. Keys as ``stack_init`` splits them."""
+    from .latent import dense_cfg
+
+    rngs = jax.random.split(rng, cfg.n_layers)[:cfg.first_k_dense]
+    stacked = jax.vmap(lambda r: block_init(r, dense_cfg(cfg)))(rngs)
+    return jax.tree_util.tree_map(
+        lambda p: Param(p.value, ("layers",) + p.axes), stacked,
+        is_leaf=lambda x: isinstance(x, Param))
 
 
 _SPARSE_ATTN_CACHE = {}
@@ -629,10 +741,33 @@ def local_attention_flags(cfg):
 
 def stack_apply(cfg, stacked_params, x, mask=None, rope=None, alibi=None,
                 deterministic=True, dropout_rng=None, kv_mask=None,
-                pld_theta=None):
+                pld_theta=None, dense_params=None):
     """Run the L blocks; returns ``(x, aux_loss)``. scan_layers=True: one compiled
     block iterated L times (compile-time constant in depth); False: unrolled python
-    loop (better for very shallow nets / per-layer sharding experiments)."""
+    loop (better for very shallow nets / per-layer sharding experiments).
+    ``dense_params``: the stacked ``first_k_dense`` leading dense blocks of a
+    model whose layers are not alike; they run unrolled before the scan."""
+    if cfg.first_k_dense:
+        if cfg.pipeline_stages > 1 or cfg.zero3_per_layer_gather \
+                or cfg.local_attention_window > 0 or pld_theta is not None:
+            raise NotImplementedError(
+                "first_k_dense (a stack of unlike layers) does not compose "
+                "with pipeline stages, the per-layer ZeRO-3 gather, banded "
+                "local attention or progressive layer drop")
+        from .latent import dense_cfg
+
+        dcfg = dense_cfg(cfg)
+        for i in range(cfg.first_k_dense):
+            p_i = jax.tree_util.tree_map(lambda a: a[i], dense_params)
+            dense_block = lambda p, h: block_apply(
+                dcfg, p, h, mask=mask, rope=rope, alibi=alibi,
+                deterministic=deterministic, kv_mask=kv_mask,
+                dropout_rng=jax.random.fold_in(dropout_rng, i)
+                if dropout_rng is not None else None)[0]
+            if cfg.remat:
+                dense_block = jax.checkpoint(dense_block,
+                                             policy=_remat_policy(cfg))
+            x = dense_block(p_i, x)
     if cfg.sequence_parallel:
         if cfg.mesh is None:
             raise ValueError("sequence_parallel requires cfg.mesh to be set")
@@ -736,9 +871,10 @@ def stack_apply(cfg, stacked_params, x, mask=None, rope=None, alibi=None,
         local_pattern is not None
         and cfg.attention_impl in ("flash", "jax_flash", "block_sparse"))
     if unrolled:
-        for i in range(cfg.n_layers):
+        for i in range(cfg.n_layers - cfg.first_k_dense):
             p_i = jax.tree_util.tree_map(lambda a: a[i], stacked_params)
-            rng_i = jax.random.fold_in(dropout_rng, i) if dropout_rng is not None else None
+            rng_i = jax.random.fold_in(dropout_rng, cfg.first_k_dense + i) \
+                if dropout_rng is not None else None
             m_i = local_mask if (local_pattern is not None and local_pattern[i]) \
                 else mask
             h_new, aux_i = body(p_i, x, rng_i, m_i)
@@ -767,7 +903,7 @@ def stack_apply(cfg, stacked_params, x, mask=None, rope=None, alibi=None,
         xs_in = stacked_params
 
     (x, _, aux), _ = jax.lax.scan(
-        scan_fn, (x, jnp.zeros((), jnp.int32), aux), xs_in
+        scan_fn, (x, jnp.full((), cfg.first_k_dense, jnp.int32), aux), xs_in
     )
     return x, aux
 
@@ -859,6 +995,8 @@ class CausalLM:
             "wte": L.embedding_init(k_emb, cfg.vocab_size, cfg.d_model, cfg.initializer_range),
             "blocks": stack_init(k_blocks, cfg),
         }
+        if cfg.first_k_dense:
+            params["dense_blocks"] = dense_stack_init(k_blocks, cfg)
         if cfg.final_layernorm:
             params["ln_f"] = _norm_init(cfg)
         if cfg.position_embedding == "learned":
@@ -922,8 +1060,9 @@ class CausalLM:
 
         rope = None
         if cfg.position_embedding == "rope":
-            rope = L.rotary_embedding(positions, cfg.rotary_dim or cfg.head_dim,
-                                      cfg.rope_base)
+            rope = L.rotary_embedding(
+                positions, cfg.qk_rope_head_dim if cfg.latent_attention
+                else cfg.rotary_dim or cfg.head_dim, cfg.rope_base)
         alibi = None
         if cfg.position_embedding == "alibi":
             alibi = L.alibi_bias(cfg.n_heads, s, s)
@@ -931,7 +1070,8 @@ class CausalLM:
         x, aux = stack_apply(cfg, params["blocks"], x, mask=mask, rope=rope,
                              alibi=alibi, deterministic=deterministic,
                              dropout_rng=dropout_rng, kv_mask=kv_mask,
-                             pld_theta=pld_theta)
+                             pld_theta=pld_theta,
+                             dense_params=params.get("dense_blocks"))
         if cfg.final_layernorm:
             x = _norm_apply(cfg, params["ln_f"], x)
         return x, aux

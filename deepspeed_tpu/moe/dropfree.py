@@ -1,0 +1,179 @@
+"""Drop-free expert layer: every chosen token-expert pair is computed.
+
+The routing DeepSeek-V3-style checkpoints publish (``TransformerConfig.
+moe_routing="dropfree"``), beside the capacity form of ``sharded_moe.py``:
+
+- scores ``s`` in float32: ``sigmoid(W_g u)``;
+- a per-expert bias ``b`` (``e_score_correction_bias``: trained, zero at
+  init) picks but does not weigh: the ``top_k`` largest of ``s + b`` are
+  chosen, their weights are ``s`` of the chosen, normalised over the chosen
+  and scaled by ``moe_routed_scale``;
+- no capacity and no drops: the token-expert pairs are ordered by expert and
+  ONE grouped product over the ragged groups computes gate and up together,
+  a second one down; a group may be empty;
+- ``n_shared_experts`` always-on experts (one SwiGLU of their joint width)
+  are added for every token.
+
+The capacity form builds ``[b, s, E, C]`` one-hot tensors; drop-free with it
+means ``C = s`` and E / top_k times the products the tokens need. Here the
+work is the pairs', and in decode the bytes are those of the experts hit.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from ..models.layers import Param, linear_apply, linear_init, normal_init
+
+F32 = jnp.float32
+
+
+def dropfree_moe_init(rng, cfg):
+    """``router`` (kernel, and the selection bias: zero, as the published
+    init has it), ``gate_up`` [E, d, 2f] (gate columns first), ``down``
+    [E, f, d], and ``shared``, one SwiGLU of width ``n_shared_experts * f``."""
+    E, d, f = cfg.n_experts, cfg.d_model, cfg.expert_d_ff
+    k_router, k_gu, k_down, k_shared = jax.random.split(rng, 4)
+    std = cfg.initializer_range
+    out_std = std / (2.0 * cfg.n_layers) ** 0.5
+    params = {
+        "router": {"kernel": Param(normal_init(k_router, (d, E), std),
+                                   ("embed", "expert_logits")),
+                   "bias": Param(jnp.zeros((E,), F32), ("expert_logits",))},
+        "gate_up": Param(normal_init(k_gu, (E, d, 2 * f), std),
+                         ("expert", "embed", "mlp")),
+        "down": Param(normal_init(k_down, (E, f, d), out_std),
+                      ("expert", "mlp", "embed")),
+    }
+    if cfg.n_shared_experts:
+        ks = jax.random.split(k_shared, 3)
+        fs = cfg.n_shared_experts * f
+        params["shared"] = {
+            "gate": linear_init(ks[0], d, fs, ("embed", "mlp"), False, std),
+            "up": linear_init(ks[1], d, fs, ("embed", "mlp"), False, std),
+            "down": linear_init(ks[2], fs, d, ("mlp", "embed"), False,
+                                out_std),
+        }
+    return params
+
+
+def scores_of(p_router, x):
+    """x [T, d] -> float32 scores [T, E], whatever x is."""
+    return jax.nn.sigmoid(jnp.dot(
+        x.astype(F32), p_router["kernel"].astype(F32),
+        precision=jax.lax.Precision.HIGHEST))
+
+
+def choose(cfg, p_router, scores):
+    """The ``top_k`` largest of ``scores + b`` (b picks, it does not weigh)."""
+    return jax.lax.top_k(scores + p_router["bias"].astype(F32),
+                         cfg.moe_top_k)[1].astype(jnp.int32)
+
+
+def pair_weights(cfg, scores, ids):
+    """Weights of the chosen pairs: from the scores, never from scores + b;
+    normalised over the chosen, then scaled."""
+    w = jnp.take_along_axis(scores, ids, axis=-1)
+    return w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20) \
+        * cfg.moe_routed_scale
+
+
+def grouped_product(rows, w, group_sizes):
+    """``rows`` [M, K], sorted by group, times ``w[g]`` [K, N] for the rows
+    of group g (``group_sizes`` [E] int32, summing to M; zeros allowed) ->
+    [M, N] in ``rows.dtype``, accumulated in float32.
+
+    ``jax.lax.ragged_dot``, settled on the chip (v5e, PR 29): for one layer's
+    192 decode pairs over 128 experts it takes 1.8 ms where reading the 106
+    experts hit takes 1.2 ms at the published bandwidth, and 4.7 ms for a
+    1024-token chunk's 6,144 pairs where reading all 128 takes 1.5 ms; no
+    kernel of this repo's own is needed beside it. The scope names it in the
+    device trace."""
+    with jax.named_scope("moe_grouped_matmul"):
+        return jax.lax.ragged_dot(
+            rows, w.astype(rows.dtype), group_sizes,
+            precision=_precision(rows.dtype),
+            preferred_element_type=F32).astype(rows.dtype)
+
+
+def _precision(dtype):
+    # a float32 model (the CPU tests, the reference's twin) multiplies in
+    # full float32; bf16 inputs are exact in one pass
+    return jax.lax.Precision.HIGHEST if dtype == F32 else None
+
+
+def dropfree_moe_apply(cfg, p, x, ids=None, stacked=None):
+    """x [b, s, d] (compute dtype) -> (y [b, s, d], routed [b, s, 2k] int32:
+    the k chosen expert ids, then the bits of their k float32 weights; see
+    ``routed_ids`` / ``routed_weights``).
+
+    ``ids`` forces the chosen experts (the weights still come from this
+    layer's own scores): tests; the served path never passes it.
+
+    ``stacked = (experts, layer)``: the expert weights come not from ``p``
+    but from ``experts["gate_up"]`` / ``["down"]`` stacked over the expert
+    layers, [L, E, ...], and this is layer ``layer`` (traced) of them. The
+    stack is seen as L * E groups of which only this layer's have rows, so
+    the grouped product reads the experts hit in place: slicing a layer out
+    of the stack first (what a scan over the weights does, the grouped
+    product being no fusion's operand) copies every expert of the layer
+    every step, 1.2 GB at kanana2's widths, 22 of a 60 ms decode step on
+    the chip (PR 29)."""
+    b, s, d = x.shape
+    E, k, f = cfg.n_experts, cfg.moe_top_k, cfg.expert_d_ff
+    flat = x.reshape(b * s, d)
+    scores = scores_of(p["router"], flat)
+    ids = choose(cfg, p["router"], scores) if ids is None \
+        else ids.reshape(b * s, k)
+    weights = pair_weights(cfg, scores, ids)
+
+    # order the T*k pairs by expert; a stable sort keeps token order inside
+    # a group, so the result does not depend on how ties are broken
+    pair_expert = ids.reshape(-1)
+    order = jnp.argsort(pair_expert, stable=True)
+    pair_token = order // k
+    if stacked is None:
+        gate_up, down, first = p["gate_up"], p["down"], 0
+    else:
+        experts, layer = stacked
+        gate_up = experts["gate_up"].reshape((-1,) + experts["gate_up"].shape[2:])
+        down = experts["down"].reshape((-1,) + experts["down"].shape[2:])
+        first = layer * E
+    group_sizes = jnp.zeros((gate_up.shape[0],), jnp.int32).at[
+        first + pair_expert].add(1)
+    rows = flat[pair_token]                                   # [T*k, d]
+    h = grouped_product(rows, gate_up, group_sizes)           # [T*k, 2f]
+    h = jax.nn.silu(h[:, :f]) * h[:, f:]
+    out = grouped_product(h, down, group_sizes)               # [T*k, d]
+    w_sorted = weights.reshape(-1)[order]
+    out = out.astype(F32) * w_sorted[:, None]
+    # back to token order: pair i of token t sits at row inverse[t * k + i]
+    inverse = jnp.zeros_like(order).at[order].set(
+        jnp.arange(order.shape[0], dtype=order.dtype))
+    y = out[inverse].reshape(b * s, k, d).sum(axis=1)
+    if "shared" in p:
+        sp = jax.tree_util.tree_map(lambda a: a.astype(x.dtype), p["shared"])
+        shared = linear_apply(sp["down"], jax.nn.silu(
+            linear_apply(sp["gate"], flat)) * linear_apply(sp["up"], flat))
+        y = y + shared.astype(F32)
+    routed = jnp.concatenate(
+        [ids, jax.lax.bitcast_convert_type(weights, jnp.int32)], axis=-1)
+    return y.astype(x.dtype).reshape(b, s, d), routed.reshape(b, s, 2 * k)
+
+
+def routed_ids(routed):
+    """The expert ids of a ``routed`` array [..., 2k] (numpy or jax)."""
+    return routed[..., :routed.shape[-1] // 2]
+
+
+def routed_weights(routed):
+    """The float32 pair weights of a host ``routed`` array [..., 2k]."""
+    return np.ascontiguousarray(
+        np.asarray(routed)[..., routed.shape[-1] // 2:]).view(np.float32)
+
+
+def load_counts(ids, n_experts):
+    """ids [..., T, k] -> pairs per expert [..., E] int32 (the load counters'
+    raw material, computed where the ids are)."""
+    flat = ids.reshape(ids.shape[:-2] + (-1,))
+    return jnp.sum(jax.nn.one_hot(flat, n_experts, dtype=jnp.int32), axis=-2)

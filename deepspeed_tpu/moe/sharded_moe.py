@@ -1,5 +1,17 @@
 """Mixture-of-Experts: top-k gating with capacity + expert-parallel dispatch.
 
+Which routing forms exist, and who picks: ``TransformerConfig.moe_routing``
+is a property of the model (a preset sets it; ``models/transformer.py``
+``block_init`` / ``block_apply`` and ``models/latent.py`` read it).
+``"capacity"`` is this module: softmax top-k with a per-group capacity and
+drops, the reference's semantics (``gpt2_moe``, training with a capacity
+factor). ``"dropfree"`` is ``moe/dropfree.py``: sigmoid top-k of the scores
+plus a selection bias that picks but does not weigh, weights normalised and
+scaled, shared experts, every chosen pair computed through a grouped product
+over ragged groups (``kanana2``; beside latent attention only). The
+deterministic path below is drop-free only by setting ``capacity = s``,
+E / top_k times the products the tokens need.
+
 TPU-native equivalent of the reference's ``deepspeed/moe/sharded_moe.py``:
 ``TopKGate`` (reference ``:420``), ``top1gating``/``top2gating`` (``:179``/``:277``)
 and the ``_AllToAll`` autograd function (``:90``). The reference dispatches tokens

@@ -54,7 +54,9 @@ class _PrefillJob:
     and/or preemption resume). The job owns its reserved slot and the
     partially-filled dense b=1 cache between chunks; ``pos`` is the next
     prompt position to prefill (``ids`` = prompt, or prompt + already-
-    generated tokens on a resume replay)."""
+    generated tokens on a resume replay). ``ahead``: the next chunk when it
+    was dispatched a step early (``_dispatch_chunk_ahead``): its size and
+    what its program handed out; ``cache`` is then already that chunk's."""
 
     req: object
     slot: int
@@ -65,6 +67,7 @@ class _PrefillJob:
     shared_blocks: list
     resume: bool             # replaying a preempted request: no first-token
     #                          sampling, stream/metrics continue where left
+    ahead: tuple = None      # (n, padded, program outputs) of the next chunk
 
     @property
     def done(self):
@@ -92,6 +95,14 @@ class ServingEngine:
                 f"max_tokens {engine.config.max_tokens}")
         self.clock = clock if clock is not None else (
             VirtualClock() if self.cfg.virtual_clock else WallClock())
+        mcfg = engine.module.config
+        # a model that routes drop-free hands its expert ids out of the
+        # prefill and decode programs (load counters; Request.record_routing)
+        self._routing = getattr(mcfg, "n_experts", 0) > 0 \
+            and getattr(mcfg, "moe_routing", "") == "dropfree"
+        self._latent = bool(getattr(mcfg, "latent_attention", False))
+        if self._latent:
+            self._refuse_for_latent(engine)
         # paged KV pool (kv_pool.enabled): block allocator + prefix cache on
         # the host, block-table gathers on the device (serving/kv_pool.py)
         self.paged = bool(self.cfg.kv_pool.enabled)
@@ -201,6 +212,10 @@ class ServingEngine:
         # arms the Serving/spec_* monitor events (coherent with
         # snapshot()["speculative"], the PR 4 trace==metrics discipline)
         self.metrics.speculative_armed = self.spec
+        self.metrics.moe_armed = self._routing
+        # expert loads of this step's prefill chunk, still on the device:
+        # read after the step's token read-back, never before it
+        self._pending_loads = []
         # per-tenant SLO grading reads the class ttft overrides
         if self.cfg.tenants.enabled:
             self.metrics.tenants_cfg = self.cfg.tenants
@@ -245,7 +260,7 @@ class ServingEngine:
         mesh = engine.mesh
         from ..parallel import MODEL_AXIS
 
-        kvh = engine.module.config.kv_heads
+        kvh = engine.module.config.cache_geometry["k"][0]
         kv_axis = MODEL_AXIS if kvh % max(engine.mp_world_size, 1) == 0 \
             else None
         self._cache_sharding = NamedSharding(
@@ -288,6 +303,31 @@ class ServingEngine:
                 f"clock={'virtual' if isinstance(self.clock, VirtualClock) else 'wall'}",
                 ranks=[0])
 
+    def _refuse_for_latent(self, engine):
+        """What this engine cannot do with a latent-attention model refuses
+        here, by name, instead of computing something else: the cache holds
+        one latent row a token, which only the paged pool in the engine's
+        dtype, read by the gather backend on one model shard, knows."""
+        cfg = self.cfg
+        why = None
+        if not cfg.kv_pool.enabled:
+            why = "the dense slot pool (serving.kv_pool.enabled=false)"
+        elif cfg.kv_pool.kv_dtype == "int8":
+            why = "an int8 pool (serving.kv_pool.kv_dtype='int8')"
+        elif cfg.kv_pool.attention_backend != "gather":
+            why = ("the fused decode kernel (serving.kv_pool."
+                   f"attention_backend={cfg.kv_pool.attention_backend!r})")
+        elif cfg.speculative.enabled:
+            why = "speculative verify (serving.speculative.enabled)"
+        elif engine.mp_world_size > 1:
+            why = f"tensor parallel {engine.mp_world_size} (tp_size > 1)"
+        elif cfg.migration.snapshot_interval_tokens > 0:
+            why = ("live KV migration (serving.migration."
+                   "snapshot_interval_tokens > 0)")
+        if why is not None:
+            raise ValueError(
+                f"ServingEngine: latent attention does not implement {why}")
+
     @property
     def chunk_size(self):
         """Effective chunked-prefill chunk size: the per-pool override when
@@ -305,6 +345,10 @@ class ServingEngine:
         cannot change any committed token."""
         if role not in ("mixed", "prefill", "decode"):
             raise ValueError(f"unknown pool role {role!r}")
+        if role != "mixed" and self._latent:
+            raise ValueError(
+                "ServingEngine: latent attention does not implement the "
+                f"disaggregated hand-off (pool role {role!r})")
         self.pool_role = role
         self.chunk_size_override = int(chunk_size)
         if speculation:
@@ -314,6 +358,26 @@ class ServingEngine:
             f"(chunk_size={self.chunk_size}"
             f"{'*' if self.chunk_size_override else ''}, "
             f"speculation={'on' if self._spec_on else 'off'})", ranks=[0])
+
+    def block_until_idle(self):
+        """Wait for every program this engine has dispatched. ``step()``
+        returns with its tokens read, and a chunk dispatched ahead for the
+        next step may still be running: a profiler started or stopped then
+        would cut it."""
+        jax.block_until_ready(
+            (self._state, [job.cache for job in self._prefill_jobs]))
+
+    def pool_layouts(self):
+        """``{leaf: major_to_minor}`` of the live cache leaves as the device
+        keeps them: what decides which block writer runs and what a write
+        costs (PR 27); None where the backend tells no layout."""
+        out = {}
+        for name in ("k", "v"):
+            layout = getattr(getattr(self._state[name], "format", None),
+                             "layout", None)
+            out[name] = None if layout is None \
+                else tuple(layout.major_to_minor)
+        return out
 
     def _kv_pool_stats(self):
         """``KVPoolManager.stats()`` + the active attention backend — the
@@ -368,10 +432,21 @@ class ServingEngine:
                     logits, true_len - 1, 1, axis=1)[:, 0]
                 return last, c
 
+            def prefill_routed(params, ids, true_len):
+                c = init_cache(model.config, 1, max_len, dtype)
+                logits, c, routed = forward_with_cache(
+                    model, params, ids, c, 0, max_len, prefill=True,
+                    last_index=true_len - 1, return_routing=True)
+                return logits[:, 0], c, self._routed_out(routed, true_len)
+
+            cache_sh = {"k": self._cache_sharding, "v": self._cache_sharding}
             with self.engine.mesh:
+                if self._routing:
+                    return jax.jit(prefill_routed, out_shardings=(
+                        self._rep_sharding, cache_sh,
+                        (self._rep_sharding, self._rep_sharding)))
                 return jax.jit(prefill, out_shardings=(
-                    self._rep_sharding,
-                    {"k": self._cache_sharding, "v": self._cache_sharding}))
+                    self._rep_sharding, cache_sh))
 
         return lru_compiled(self._prefill_programs, padded_len, build,
                             int(self.engine.config.compile_cache_size or 0),
@@ -391,16 +466,57 @@ class ServingEngine:
                     logits, true_len - 1, 1, axis=1)[:, 0]
                 return last, c
 
+            def suffix_routed(params, ids, cache, start_pos, true_len):
+                logits, c, routed = forward_with_cache(
+                    model, params, ids, cache, start_pos, max_len,
+                    last_index=true_len - 1, return_routing=True)
+                return logits[:, 0], c, self._routed_out(routed, true_len)
+
+            cache_sh = {"k": self._cache_sharding, "v": self._cache_sharding}
             with self.engine.mesh:
+                if self._routing:
+                    return jax.jit(suffix_routed, donate_argnums=(2,),
+                                   out_shardings=(
+                                       self._rep_sharding, cache_sh,
+                                       (self._rep_sharding,
+                                        self._rep_sharding)))
                 return jax.jit(suffix_prefill, donate_argnums=(2,),
-                               out_shardings=(
-                                   self._rep_sharding,
-                                   {"k": self._cache_sharding,
-                                    "v": self._cache_sharding}))
+                               out_shardings=(self._rep_sharding, cache_sh))
 
         return lru_compiled(self._suffix_programs, padded_len, build,
                             int(self.engine.config.compile_cache_size or 0),
                             "serving suffix prefill")
+
+    def _routed_out(self, routed, true_len):
+        """What a prefill program of a routing model hands out beside its
+        logits: the chosen expert ids [L_moe, q, k] and, made here where the
+        ids are, the pairs per expert over the ``true_len`` real rows
+        [L_moe, E] (bucket padding routes too, and is not counted)."""
+        from ..moe.dropfree import load_counts, routed_ids
+
+        routed = routed[:, 0]
+        real = jnp.arange(routed.shape[1])[None, :, None] < true_len
+        counts = load_counts(
+            jnp.where(real, routed_ids(routed),
+                      self.engine.module.config.n_experts),
+            self.engine.module.config.n_experts)
+        return routed, counts
+
+    def _prefill_dispatch(self, program, req, start, n, *args):
+        """Run one prefill program (a whole prompt, a suffix or a chunk)
+        and book what a routing model hands out beside logits and cache."""
+        return self._book_prefill(req, start, n, program(*args))
+
+    def _book_prefill(self, req, start, n, out):
+        """``(logits, cache)`` of a prefill program's outputs; a routing
+        model's loads and, where asked for, its choices are booked."""
+        if not self._routing:
+            return out
+        logits, cache, (routed, counts) = out
+        self._pending_loads.append(counts)
+        if req.record_routing:
+            req.routing.append((start, n, routed))
+        return logits, cache
 
     def _build_pool_programs(self):
         model, max_len = self.engine.module, self.max_len
@@ -422,11 +538,15 @@ class ServingEngine:
             # paged: the reserved garbage block their table row points at)
             # and are masked below
             split = jax.vmap(jax.random.split)(state["rng"])  # [S, 2, 2]
+            routed = ()
             if paged:
-                logits, cache = forward_with_paged_cache(
+                # a routing model also hands out what its expert layers
+                # chose, [L_moe, S, 1, 2k]
+                logits, cache, *routed = forward_with_paged_cache(
                     model, params, state["tok"][:, None],
                     {k: state[k] for k in pool_keys}, state["table"],
-                    state["pos"], bs, attention_backend=attn_backend)
+                    state["pos"], bs, attention_backend=attn_backend,
+                    return_routing=self._routing)
             else:
                 logits, cache = forward_with_cache(
                     model, params, state["tok"][:, None],
@@ -456,7 +576,10 @@ class ServingEngine:
             })
             if paged:
                 new_state["table"] = state["table"]
-            return (nxt, done_now, nonfinite), new_state
+            # the routing is an output of its own, [L_moe, S, 2k], read back
+            # with the tokens
+            return (nxt, done_now, nonfinite,
+                    *(r[:, :, 0] for r in routed)), new_state
 
         def verify(params, state, drafts, draft_len):
             # speculative decoding's ONE target forward: k+1 positions per
@@ -644,7 +767,8 @@ class ServingEngine:
         rep, st = self._rep_sharding, self._state_shardings
         with self.engine.mesh:
             self._decode_jit = jax.jit(decode, donate_argnums=(1,),
-                                       out_shardings=((rep, rep, rep), st))
+                                       out_shardings=(
+                                           (rep,) * (3 + self._routing), st))
             if paged:
                 self._insert_jit = jax.jit(insert_meta, donate_argnums=(0,),
                                            out_shardings=st)
@@ -870,6 +994,8 @@ class ServingEngine:
                 gap = head.arrival_time - self.clock.now()
                 if gap > 0:
                     self.clock.sleep(gap)
+        if self._pending_loads:
+            self._drain_prefill_loads()
         if self.degraded_ctl is not None:
             self.degraded_ctl.observe(self.clock.now())
         self.metrics.observe_step(self.queue.depth, len(self._slots))
@@ -1066,15 +1192,12 @@ class ServingEngine:
             # slot now, seed the partial cache, and let the step loop drive
             # chunks interleaved with decode steps (_advance_prefill)
             slot = self._free_slots.pop()
-            if shared_len:
-                mgr = self.pool_mgr
-                row = np.full((mgr.blocks_per_slot,), GARBAGE_BLOCK, np.int32)
-                row[:len(shared_blocks)] = shared_blocks
-                cache = self._seed_cache_jit(self._state, jnp.asarray(row))
-            else:
-                cache = self._fresh_cache_jit()
+            if req.record_routing:
+                req.routing = []   # a resume replays every position
+            # the dense cache is made when the job's first chunk runs
+            # (_advance_prefill): a job waiting behind others holds none
             self._prefill_jobs.append(_PrefillJob(
-                req=req, slot=slot, cache=cache,
+                req=req, slot=slot, cache=None,
                 ids=np.asarray(ids_full, np.int32), pos=shared_len,
                 shared_len=shared_len, shared_blocks=shared_blocks,
                 resume=resume))
@@ -1104,8 +1227,9 @@ class ServingEngine:
                 cache = self._seed_cache_jit(self._state, jnp.asarray(row))
                 ids = np.zeros((1, padded), np.int32)
                 ids[0, :len(suffix)] = suffix
-                logits, cache = self._suffix_program(padded)(
-                    self.engine.params, jnp.asarray(ids), cache,
+                logits, cache = self._prefill_dispatch(
+                    self._suffix_program(padded), req, shared_len,
+                    len(suffix), self.engine.params, jnp.asarray(ids), cache,
                     np.int32(shared_len), np.int32(len(suffix)))
                 # the prefix-cache win in virtual time: only the suffix pays
                 self.clock.advance(
@@ -1125,7 +1249,8 @@ class ServingEngine:
                                   padded_len=padded):
                 ids = np.zeros((1, padded), np.int32)
                 ids[0, :req.prompt_len] = req.prompt
-                logits, cache = self._prefill_program(padded)(
+                logits, cache = self._prefill_dispatch(
+                    self._prefill_program(padded), req, 0, req.prompt_len,
                     self.engine.params, jnp.asarray(ids),
                     np.int32(req.prompt_len))
                 self.clock.advance(
@@ -1226,13 +1351,8 @@ class ServingEngine:
         job's cursor against its donated partial cache, bucketed so every
         full chunk shares one compiled program."""
         job = self._prefill_jobs[0]
-        remaining = len(job.ids) - job.pos
-        n = min(self.chunk_size, remaining) \
-            if self.chunked else remaining
-        # ceiling shrinks by the already-prefilled prefix (same overrun
-        # guard as the shared-prefix suffix path: a bucket past max_len
-        # would make XLA clamp the q-block write start)
-        padded = self.engine._bucket_prompt_len(n, self.max_len - job.pos)
+        (n, padded, out), job.ahead = \
+            job.ahead or self._dispatch_chunk(job), None
         req = job.req
         req.chunks += 1
         req.padding_tokens += padded - n
@@ -1247,11 +1367,7 @@ class ServingEngine:
                               trace_id=req.trace_id, n=n,
                               padded_len=padded, start=job.pos,
                               resume=job.resume):
-            ids = np.zeros((1, padded), np.int32)
-            ids[0, :n] = job.ids[job.pos:job.pos + n]
-            logits, job.cache = self._suffix_program(padded)(
-                self.engine.params, jnp.asarray(ids), job.cache,
-                np.int32(job.pos), np.int32(n))
+            logits, _ = self._book_prefill(req, job.pos, n, out)
             self.clock.advance(
                 padded * self.cfg.virtual_prefill_cost_per_token)
         job.pos += n
@@ -1259,6 +1375,55 @@ class ServingEngine:
         if job.done:
             self._prefill_jobs.popleft()
             self._complete_job(job, logits, events)
+
+    def _dispatch_chunk(self, job):
+        """Dispatch the job's next chunk. Its cache is the program's from
+        here on (the old one was donated); the chunk is booked, and the
+        job's cursor moved, where ``_advance_prefill`` takes it. Returns
+        the chunk's length, its bucket and what the program handed out."""
+        remaining = len(job.ids) - job.pos
+        n = min(self.chunk_size, remaining) \
+            if self.chunked else remaining
+        # ceiling shrinks by the already-prefilled prefix (same overrun
+        # guard as the shared-prefix suffix path: a bucket past max_len
+        # would make XLA clamp the q-block write start)
+        padded = self.engine._bucket_prompt_len(n, self.max_len - job.pos)
+        ids = np.zeros((1, padded), np.int32)
+        ids[0, :n] = job.ids[job.pos:job.pos + n]
+        if job.cache is None:
+            job.cache = self._job_cache(job)
+        out = self._suffix_program(padded)(
+            self.engine.params, jnp.asarray(ids), job.cache,
+            np.int32(job.pos), np.int32(n))
+        job.cache = out[1]
+        self.metrics.record_prefill_chunk(job.pos, n)
+        return n, padded, out
+
+    def _dispatch_chunk_ahead(self):
+        """Chunked prefill, called with this step's decode dispatched and
+        its tokens not yet read: dispatch the chunk the NEXT step will run.
+        The device then goes from this decode straight into that chunk while
+        the host reads the tokens, books them and admits; without it the
+        device idles through all of that, every step, and a step's length
+        is the host's to disturb. Nothing the host does in between bears on
+        the chunk: the oldest job stays the oldest until it is done, and a
+        job that is dropped is dropped with what it holds."""
+        if not (self.chunked and self._prefill_jobs):
+            return
+        job = self._prefill_jobs[0]
+        if job.ahead is None and self._decode_steps_since_chunk + 1 >= \
+                self.cfg.chunked_prefill.decode_steps_between_chunks:
+            job.ahead = self._dispatch_chunk(job)
+
+    def _job_cache(self, job):
+        """The dense b=1 cache a job's chunks carry: seeded from the shared
+        prefix blocks (whose references the job holds) or zeroed."""
+        if not job.shared_len:
+            return self._fresh_cache_jit()
+        mgr = self.pool_mgr
+        row = np.full((mgr.blocks_per_slot,), GARBAGE_BLOCK, np.int32)
+        row[:len(job.shared_blocks)] = job.shared_blocks
+        return self._seed_cache_jit(self._state, jnp.asarray(row))
 
     def _complete_job(self, job, logits, events):
         req = job.req
@@ -1432,6 +1597,10 @@ class ServingEngine:
         gathers only — no new compiled program, no device mutation — so a
         capture can run on any step boundary without perturbing the
         stay-put stream."""
+        if self._latent:
+            raise ValueError(
+                "ServingEngine: latent attention does not implement live KV "
+                "migration (a snapshot of latent blocks and its splice)")
         if not self.paged or req.slot is None \
                 or self._slots.get(req.slot) is not req:
             return None
@@ -1898,13 +2067,16 @@ class ServingEngine:
     def _decode_once(self, events):
         with self.tracer.span("decode_step", cat="serving",
                               active=len(self._slots)):
-            ((toks, done_now, nonfinite),
-             self._state) = self._decode_jit(self.engine.params, self._state)
+            out, self._state = self._decode_jit(self.engine.params,
+                                                self._state)
             self.clock.advance(self.cfg.virtual_decode_step_cost)
         self.metrics.record_decode_dispatch()
-        toks = np.asarray(toks)
-        done_now = np.asarray(done_now)
-        nonfinite = np.asarray(nonfinite)
+        self._dispatch_chunk_ahead()
+        # one read-back for all the step hands out (a routing model: its
+        # expert choices too)
+        toks, done_now, nonfinite, *routed = jax.device_get(out)
+        if routed:
+            self._book_decode_routing(routed[0])
         now = self.clock.now()
         self.metrics.record_health_step(
             sum(1 for s in self._slots if nonfinite[s] > 0))
@@ -1933,6 +2105,33 @@ class ServingEngine:
             self._finish(req, reason, now, deactivate=(reason == FINISH_STOP))
             events.append(TokenEvent(req.request_id, t, len(req.tokens) - 1,
                                      True, reason, now))
+
+    def _book_decode_routing(self, routed):
+        """``routed`` [L_moe, S, 2k]: what this decode step's expert layers
+        chose, every slot's (a freed slot routes its dead token too: the
+        pairs were computed and their experts read). Counters, and the
+        record of the requests that asked for theirs."""
+        cfg = self.engine.module.config
+        k = cfg.moe_top_k
+        counts = np.stack([np.bincount(layer[:, :k].reshape(-1),
+                                       minlength=cfg.n_experts)
+                           for layer in routed])
+        self.metrics.record_moe_loads(counts, decode=True)
+        for slot, req in self._slots.items():
+            # this step fed the slot's last token, at the position before
+            # the one the new token takes
+            live = req.prompt_len + len(req.tokens)
+            self.metrics.latent_kv_tokens_read += live
+            if req.record_routing:
+                req.routing.append((live - 1, 1, routed[:, slot, None]))
+
+    def _drain_prefill_loads(self):
+        """Book the expert loads of the prefill programs dispatched in this
+        step. Called after the decode step's token read-back: the arrays are
+        ready by then, so reading them waits for nothing."""
+        for counts in self._pending_loads:
+            self.metrics.record_moe_loads(np.asarray(counts))
+        self._pending_loads = []
 
     def _shed_unhealthy(self, req, events, now, n_bad):
         """The unhealthy_slot hook, shared by the decode and verify paths:

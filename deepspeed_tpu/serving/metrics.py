@@ -165,6 +165,24 @@ class ServingMetrics:
         self.migrations_out = 0
         self.migrations_in = 0
         self.migrated_saved_tokens = 0
+        # drop-free expert routing and the latent cache (models/latent.py,
+        # moe/dropfree.py), lifetime counters read as deltas. A dispatch is
+        # one expert layer in one program run (a decode step or a prefill
+        # chunk); its load is pairs per expert over the experts with work.
+        # Armed by the engine for a model that routes (snapshot()["moe"]).
+        self.moe_armed = False
+        self.moe_dispatches = 0
+        self.moe_pairs = 0            # token-expert pairs computed
+        self.moe_experts_hit = 0      # distinct experts with work, summed
+        self.moe_decode_dispatches = 0   # the two above, decode steps alone
+        self.moe_decode_pairs = 0
+        self.moe_decode_experts_hit = 0
+        self.moe_max_expert_load = 0  # summed over dispatches: / dispatches
+        self.latent_kv_tokens_read = 0  # live cache rows the decode steps read
+        self.prefill_chunks = 0
+        self.prefill_chunk_tokens = 0
+        # (first position, positions) of the newest chunks, newest last
+        self.recent_prefill_chunks = collections.deque(maxlen=4)
 
     # -- recording ----------------------------------------------------------
     def _mark_started(self):
@@ -344,6 +362,45 @@ class ServingMetrics:
         self.kv_insert_dispatches += 1
         self.kv_insert_blocks += int(n_blocks)
 
+    def record_moe_loads(self, counts, decode=False):
+        """``counts`` [layers, E]: pairs per expert of each expert layer in
+        one program run (made on the device beside the ids; read back with
+        the step's tokens)."""
+        hit = counts > 0
+        self.moe_dispatches += int(counts.shape[0])
+        self.moe_pairs += int(counts.sum())
+        self.moe_experts_hit += int(hit.sum())
+        self.moe_max_expert_load += int(counts.max(axis=-1).sum())
+        if decode:
+            self.moe_decode_dispatches += int(counts.shape[0])
+            self.moe_decode_pairs += int(counts.sum())
+            self.moe_decode_experts_hit += int(hit.sum())
+
+    def record_prefill_chunk(self, start, n):
+        """One chunk of ``n`` prompt positions written at ``start``,
+        counted where its program is dispatched."""
+        self.prefill_chunks += 1
+        self.prefill_chunk_tokens += int(n)
+        self.recent_prefill_chunks.append((int(start), int(n)))
+
+    def moe_snapshot(self):
+        d = max(self.moe_dispatches, 1)
+        return {
+            "dispatches": self.moe_dispatches,
+            "moe_pairs": self.moe_pairs,
+            "moe_experts_hit": self.moe_experts_hit,
+            "decode_dispatches": self.moe_decode_dispatches,
+            "decode_pairs": self.moe_decode_pairs,
+            "decode_experts_hit": self.moe_decode_experts_hit,
+            "max_expert_load_sum": self.moe_max_expert_load,
+            "moe_max_expert_load": self.moe_max_expert_load / d,
+            "moe_mean_expert_load": self.moe_pairs
+            / max(self.moe_experts_hit, 1),
+            "latent_kv_tokens_read": self.latent_kv_tokens_read,
+            "prefill_chunks": self.prefill_chunks,
+            "prefill_chunk_tokens": self.prefill_chunk_tokens,
+        }
+
     def record_snapshot(self):
         self.kv_snapshots += 1
 
@@ -512,6 +569,7 @@ class ServingMetrics:
                 "nonfinite_logit_steps": self.nonfinite_logit_steps,
                 "unhealthy_slots": self.unhealthy_slots,
             },
+            **({"moe": self.moe_snapshot()} if self.moe_armed else {}),
             **({"degraded": self.degraded_snapshot()}
                if self.degraded_snapshot is not None else {}),
             **({"kv_pool": self.kv_pool()} if self.kv_pool is not None

@@ -161,6 +161,12 @@ class Request:
     handoffs: int = 0
     handoff_pending: bool = False
     rebalances: int = 0
+    # drop-free expert models: ask for the expert ids the serving programs
+    # chose for this request (``expert_ids()``); ``routing`` collects them
+    # and their weights as ``(first position, n positions, routed [L_moe, n,
+    # 2k])`` pieces, on the device until asked for
+    record_routing: bool = False
+    routing: list = dataclasses.field(default_factory=list)
 
     def __post_init__(self):
         self.prompt = np.asarray(self.prompt, np.int32).reshape(-1)
@@ -172,6 +178,36 @@ class Request:
     @property
     def prompt_len(self):
         return int(self.prompt.shape[0])
+
+    def _routed(self):
+        """[L_moe, positions, 2k] int32 (``moe/dropfree.py``), or None."""
+        if not self.routing:
+            return None
+        n = max(start + size for start, size, _ in self.routing)
+        first = np.asarray(self.routing[0][2])
+        out = np.zeros((first.shape[0], n, first.shape[-1]), np.int32)
+        out[..., :first.shape[-1] // 2] = -1
+        for start, size, routed in self.routing:
+            out[:, start:start + size] = np.asarray(routed)[:, :size]
+        return out
+
+    def expert_ids(self):
+        """[L_moe, positions, k] int32: the experts the prefill and decode
+        programs that served this request chose at every position they
+        computed (prompt, then every generated token that was fed back), -1
+        where a prefix-cache hit computed nothing. None if none recorded."""
+        from ..moe.dropfree import routed_ids
+
+        routed = self._routed()
+        return None if routed is None else routed_ids(routed)
+
+    def expert_weights(self):
+        """[L_moe, positions, k] float32: the weights the serving programs
+        gave those experts (0 where nothing was computed)."""
+        from ..moe.dropfree import routed_weights
+
+        routed = self._routed()
+        return None if routed is None else routed_weights(routed)
 
     def reset_for_retry(self):
         """Clear the terminal state an ``unhealthy_slot`` shed left so the

@@ -207,6 +207,49 @@ def test_quantized_matmul_lowers(bits):
     assert not ok and "no legal tiling" in reason
 
 
+def test_latent_expert_serving_programs_lower_without_a_kernel():
+    """kanana2's decode step and prefill chunk at the published widths lower
+    for the TPU with no Mosaic call: the grouped expert product is
+    ``jax.lax.ragged_dot`` (settled on the chip, PR 29: ``moe/dropfree.py``)
+    and both attention forms are XLA's, so there is no Pallas twin to keep
+    in step and nothing that gives way off the TPU."""
+    from deepspeed_tpu.models import decoding as D
+    from deepspeed_tpu.models.layers import Param
+
+    model = get_model("kanana2", "30b-a3b", n_layers=3,
+                      compute_dtype=jnp.bfloat16)
+    cfg = model.config
+    params = jax.tree_util.tree_map(
+        lambda a: SDS(a.shape, jnp.bfloat16),
+        jax.eval_shape(lambda r: jax.tree_util.tree_map(
+            lambda p: p.value, model.init(r),
+            is_leaf=lambda x: isinstance(x, Param)), jax.random.PRNGKey(0)))
+    slots, bs, max_len = 32, 128, 16384
+    pool = {n: SDS((3, 257, bs) + row, jnp.bfloat16)
+            for n, row in cfg.cache_geometry.items()}
+    assert pool["k"].shape[-2:] == (1, 512) and pool["v"].shape[-2:] == (1, 64)
+
+    def decode(params, tok, pool, table, pos):
+        return D.forward_with_paged_cache(model, params, tok, pool, table,
+                                          pos, bs, return_routing=True)
+
+    text = lower_for_tpu(decode, params, SDS((slots, 1), jnp.int32), pool,
+                         SDS((slots, max_len // bs), jnp.int32),
+                         SDS((slots,), jnp.int32))
+    assert n_mosaic(text) == 0 and "ragged_dot" in text
+
+    cache = {n: SDS((3, 1, max_len) + row, jnp.bfloat16)
+             for n, row in cfg.cache_geometry.items()}
+
+    def chunk(params, ids, cache, start, last):
+        return D.forward_with_cache(model, params, ids, cache, start, max_len,
+                                    last_index=last, return_routing=True)
+
+    text = lower_for_tpu(chunk, params, SDS((1, 1024), jnp.int32), cache,
+                         SDS((), jnp.int32), SDS((), jnp.int32))
+    assert n_mosaic(text) == 0 and "ragged_dot" in text
+
+
 def test_compiler_verdict_carries_the_compilers_words():
     """The blocking the paged kernel shipped with — one kv head of many per
     block — is what Mosaic refuses; the verdict hands back its sentence."""

@@ -259,7 +259,41 @@ def _block_write_case(int8):
             (pool, cache, jnp.asarray(ids), jnp.asarray(srcs)), 0.0)
 
 
+def _grouped_product_case():
+    """Not a kernel of this repo's: ``jax.lax.ragged_dot`` as the drop-free
+    expert layer calls it (``moe/dropfree.py``), against every expert
+    computed for every row and masked. One layer's decode pairs at the
+    published widths: 192 rows over 128 experts, some groups empty."""
+    import jax
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.moe.dropfree import grouped_product
+
+    E, K, N, M = 128, 2048, 1536, 192
+    rng = np.random.RandomState(0)
+    sizes = np.bincount(rng.randint(0, E, M), minlength=E).astype(np.int32)
+    rows = jnp.asarray(rng.randn(M, K), jnp.bfloat16)
+    w = jnp.asarray(rng.randn(E, K, N) * 0.02, jnp.bfloat16)
+
+    def ref(rows, w, sizes):
+        ends = jnp.cumsum(sizes)
+        idx = jnp.arange(M)
+
+        def one(e, acc):
+            mine = (idx >= ends[e] - sizes[e]) & (idx < ends[e])
+            return acc + jnp.where(mine[:, None], jnp.dot(
+                rows, w[e], preferred_element_type=jnp.float32), 0.0)
+
+        return jax.lax.fori_loop(0, E, one, jnp.zeros((M, N), jnp.float32))
+
+    return (f"192 rows x [128, 2048, 1536], {int((sizes == 0).sum())} empty "
+            "groups", grouped_product, ref, (rows, w, jnp.asarray(sizes)),
+            0.02)
+
+
 CASES = {
+    "grouped expert product (ragged_dot, kanana2 decode)":
+        _grouped_product_case,
     "flash fwd+bwd (single kv block)": flash_single_block,
     "flash fwd+bwd (general)": flash_general,
     "jax_flash fwd+bwd": jax_flash,
