@@ -139,11 +139,16 @@ def serve_leg(n_dev, peaks, rehearse, cache_log):
     share, weight_bytes = device_share(engine.params)
     say("server", model="opt-1.3b", layers=cfg.n_layers, d_model=cfg.d_model,
         heads=cfg.n_heads, head_dim=cfg.head_dim, vocab=cfg.vocab_size,
-        tp=n_dev, attn_backend=sv.attn_backend, init_s=round(init_s, 2),
+        tp=n_dev, attn_backend=sv.attn_backend,
+        attn_reason=sv.attn_reason, pool_layout=sv.pool_layouts(),
+        init_s=round(init_s, 2),
         decode_compile_s=round(decode_compile_s, 2),
         weight_gb=round(weight_bytes / 1e9, 3),
         max_device_share_of_weights=round(share, 3))
-    checks = {"attn_backend_is_gather": sv.attn_backend == "gather"}
+    # on the chip the engine chooses the decode kernel (the compiler's
+    # verdict at this geometry); the CPU rehearsal has none to choose
+    checks = {"attn_backend_is_engines_choice": sv.attn_backend
+              == ("view" if rehearse else "kernel")}
     if n_dev > 1:
         # vocab/heads/mlp dims split n ways; norms and biases replicate
         checks["weights_sharded"] = share < 1.0 / n_dev + 0.1

@@ -324,15 +324,19 @@ class KVPoolConfig(ConfigModel):
     # queue (resuming bitwise-identical) instead of OOM/shed. False = the
     # PR 7 whole-footprint reservation.
     on_demand_growth: bool = False
-    # decode-attention backend. "gather" (default): per-layer dense view of
-    # the pool through the block table, then the unchanged dense attention.
-    # "fused": the split-KV flash-decode Pallas kernel
-    # (ops/pallas/paged_attention.py) walks the block table IN-KERNEL — no
-    # dense view is materialized. Put to the compiler at the engine's
-    # geometry at construction (fused_decode_supported); a refusal logs the
-    # compiler's reason once and serves through "gather". Prefill/insert/
-    # speculative-verify always run the gather machinery either way.
-    attention_backend: str = "gather"
+    # SELECTS NOTHING (kept so that configurations written before PR 30
+    # still load). Which decode attention runs is the engine's choice, from
+    # what it can observe: the flash-decode kernel
+    # (ops/pallas/paged_attention.py, walks the block table and reads the
+    # live blocks only) for one query row a slot, no banded local layers, a
+    # pool in the engine's dtype, where the compiler takes the kernel at the
+    # engine's geometry (fused_decode_supported, asked once at
+    # construction); else the n_slots x max_len gather view. A choice that
+    # depends on platform and shape is not a user's string (ROADMAP D1): a
+    # value here is accepted and logged once as having no effect, and
+    # snapshot()["kv_pool"] names the path that ran (attention_backend:
+    # "kernel" or "view", attention_reason, decode_dispatches by path).
+    attention_backend: str = ""
 
     def _validate(self):
         if self.block_size < 1:
@@ -344,10 +348,10 @@ class KVPoolConfig(ConfigModel):
         if self.kv_dtype not in ("", "int8"):
             raise ConfigError(
                 f"kv_pool.kv_dtype must be '' or 'int8', got {self.kv_dtype!r}")
-        if self.attention_backend not in ("gather", "fused"):
+        if self.attention_backend not in ("", "gather", "fused"):
             raise ConfigError(
-                f"kv_pool.attention_backend must be 'gather' or 'fused', "
-                f"got {self.attention_backend!r}")
+                f"kv_pool.attention_backend selects nothing; '', 'gather' "
+                f"and 'fused' are accepted, got {self.attention_backend!r}")
 
 
 class ChunkedPrefillConfig(ConfigModel):

@@ -74,86 +74,94 @@ def init_paged_cache(cfg, n_blocks, block_size, dtype=None, kv_dtype=None):
     addresses the same block row in EVERY layer, so host allocation is one
     decision per token block, not per layer).
 
+    Leaves are ``[L, n_blocks, block_size] + cfg.pool_geometry[leaf]``: for
+    per-head K and V that is ``[L, n_blocks, block_size, kv_heads *
+    head_dim]``, a token's whole row minor-most, for every model whatever
+    its head size. The TPU lays an array out by its shape: a leaf ending in
+    ``[.., kv_heads, 64]`` got its BLOCK axis in the lanes (one block spread
+    over the whole pool: an insert cost the pool, PR 27; a decode step
+    shuffled it, PR 30); ending in the merged row it keeps a block of
+    tokens contiguous (64 KB at OPT-1.3B's widths), which one DMA fetches
+    and one scatter writes. ``serving.ServingEngine.pool_layouts()`` reads
+    what the device chose.
+
     ``kv_dtype="int8"`` stores blocks as int8 payloads with per-(token, head)
-    fp32 scales (``comm/collectives.py`` blockwise kernels, ZeRO++ idiom) —
-    k/v: [L, n_blocks, block_size, kvh, dh] int8, k_scale/v_scale:
-    [L, n_blocks, block_size, kvh, 1] f32."""
+    fp32 scales (``comm/collectives.py`` blockwise kernels, ZeRO++ idiom):
+    k/v ``[.., kv_heads * head_dim]`` int8, k_scale/v_scale ``[..,
+    kv_heads]`` f32."""
     dtype = dtype or cfg.compute_dtype
     shapes = {name: (cfg.n_layers, n_blocks, block_size) + row
-              for name, row in cfg.cache_geometry.items()}
+              for name, row in cfg.pool_geometry.items()}
     if kv_dtype == "int8":
         pool = {name: jnp.zeros(s, jnp.int8) for name, s in shapes.items()}
-        pool.update({name + "_scale": jnp.zeros(s[:-1] + (1,), jnp.float32)
-                     for name, s in shapes.items()})
+        pool.update({name + "_scale": jnp.zeros(
+            s[:-1] + (s[-1] // cfg.head_dim,), jnp.float32)
+            for name, s in shapes.items()})
         return pool
     return {name: jnp.zeros(s, dtype) for name, s in shapes.items()}
 
 
-def _dequant_layer(q, scale, dtype):
-    """int8 payload + per-(token, head) scale -> ``dtype``. ``scale`` keeps
-    its trailing 1-axis: it is the [..., n // block] axis
-    ``dequantize_blockwise`` blocks the last payload axis by (block == dh,
-    one scale per head vector)."""
+def _dequant_rows(q, scale, dtype):
+    """int8 payload ``[.., kvh * dh]`` + per-(token, head) scale ``[..,
+    kvh]`` -> ``dtype``: ``dequantize_blockwise`` reads the block (one head
+    vector) off the two shapes."""
     from ..comm.collectives import dequantize_blockwise
 
     return dequantize_blockwise(q, scale, dtype=dtype)
 
 
-def _paged_view(kc, sc, table, view_dtype):
-    """Gather a slot-major dense view of the pool through the block table.
+def _paged_view(pool, name, layer, table, heads, view_dtype):
+    """Gather a slot-major dense view of one layer of the pool through the
+    block table.
 
-    kc: [n_blocks, bs, kvh, dh] (one layer); table: [S, NB] physical block
-    ids; returns [S, NB * bs, kvh, dh] — row ``s`` holds slot s's KV window
-    in position order (block j covers positions [j*bs, (j+1)*bs)), exactly
-    the dense cache layout, so the attention math downstream is the SAME
-    program as the dense per-row path."""
-    nb, bs, kvh, dh = kc.shape
-    s_dim, per_slot = table.shape
-    g = kc[table]                                    # [S, NB, bs, kvh, dh]
-    if sc is not None:
-        g = _dequant_layer(g, sc[table], view_dtype)
-    return g.reshape(s_dim, per_slot * bs, kvh, dh)
+    ``pool[name]``: [L, n_blocks, bs, kvh * dh]; table: [S, NB] physical
+    block ids; returns [S, NB * bs, kvh, dh]: row ``s`` holds slot s's KV
+    window in position order (block j covers positions [j*bs, (j+1)*bs)),
+    exactly the dense cache layout, so the attention math downstream is the
+    SAME program as the dense per-row path."""
+    g = pool[name][layer, table]                     # [S, NB, bs, kvh * dh]
+    if name + "_scale" in pool:
+        g = _dequant_rows(g, pool[name + "_scale"][layer, table], view_dtype)
+    s_dim, per_slot, bs, width = g.shape
+    return g.reshape(s_dim, per_slot * bs, heads, width // heads)
 
 
-def _paged_writeback(kc, sc, view, table, pos, block_size, valid=None):
-    """Scatter the row each slot just wrote (at its cursor) from the dense
-    view back into the pool at (table[s, pos // bs], pos % bs). Freed slots
-    carry an all-garbage-block table row, so their dead writes land in the
-    reserved garbage block instead of corrupting a reallocated block.
+def _paged_write_rows(pool, layer, rows, table, pos, block_size, valid=None):
+    """Write each slot's fresh rows ``rows`` (``{"k": [S, kvh, dh], "v":
+    ..}``) into layer ``layer`` of the pool at (table[s, pos // bs], pos %
+    bs): row-sized updates of the leaves, in place in the layer loop's
+    carry. Freed slots carry an all-garbage-block table row, so their dead
+    writes land in the reserved garbage block instead of corrupting a
+    reallocated block. Returns the pool with the rows written.
 
     ``valid`` ([S] bool, optional): rows whose write must instead be
     redirected to the reserved garbage block 0 (speculative verify's padded
-    draft rows — they can lie past the slot's bound blocks or the KV window,
+    draft rows: they can lie past the slot's bound blocks or the KV window,
     and a clamped block index would silently corrupt a REAL block)."""
-    rows = jax.vmap(
-        lambda c, p: jax.lax.dynamic_slice(
-            c, (p, 0, 0), (1,) + c.shape[1:]))(view, pos)[:, 0]  # [S, kvh, dh]
-    return _paged_writeback_rows(kc, sc, rows, table, pos, block_size,
-                                 valid=valid)
-
-
-def _paged_writeback_rows(kc, sc, rows, table, pos, block_size, valid=None):
-    """``_paged_writeback`` for callers that already hold the fresh
-    [S, kvh, dh] rows (the fused kernel path never materializes a view to
-    slice them from)."""
     j = jnp.clip(pos // block_size, 0, table.shape[1] - 1)
     bi = jnp.take_along_axis(table, j[:, None], axis=1)[:, 0]
     if valid is not None:
         bi = jnp.where(valid, bi, 0)  # block 0 = the reserved garbage block
     off = pos % block_size
-    if sc is not None:
-        from ..comm.collectives import quantize_blockwise
+    pool = dict(pool)
+    with jax.named_scope("paged_row_write"):
+        for name, r in rows.items():
+            flat = r.reshape(r.shape[0], -1)
+            if name + "_scale" in pool:
+                from ..comm.collectives import quantize_blockwise
 
-        q, scale = quantize_blockwise(rows, block=rows.shape[-1])
-        return kc.at[bi, off].set(q), sc.at[bi, off].set(scale)
-    return kc.at[bi, off].set(rows.astype(kc.dtype)), None
+                flat, scale = quantize_blockwise(flat, block=r.shape[-1])
+                pool[name + "_scale"] = \
+                    pool[name + "_scale"].at[layer, bi, off].set(scale)
+            pool[name] = pool[name].at[layer, bi, off].set(
+                flat.astype(pool[name].dtype))
+    return pool
 
 
 def _project_qkv(cfg, p_attn, h, rope=None):
     """The q/k/v projection + rotary application shared by every cached
-    attention path — ONE implementation, so the fused paged backend can
-    never diverge from the gather/dense path's projection semantics (the
-    bitwise-parity contract starts here)."""
+    attention path: ONE implementation, so the kernel path can never
+    diverge from the view/dense path's projection semantics."""
     b, q_len, _ = h.shape
     q = L.linear_apply(p_attn["q"], h).reshape(b, q_len, cfg.n_heads,
                                                cfg.head_dim)
@@ -170,23 +178,23 @@ def _project_qkv(cfg, p_attn, h, rope=None):
     return q, k, v
 
 
-def _attn_paged_fused(cfg, p_attn, h, kc, vc, ks, vs, table, pos, rope=None):
-    """The fused-backend twin of ``_attn_with_cache`` for paged decode
+def _attn_paged_kernel(cfg, p_attn, h, pool, layer, table, pos, rope=None):
+    """The kernel-path twin of ``_attn_with_cache`` for paged decode
     (q_len == 1): project q/k/v for the current token, then attend straight
-    against the POOL through the split-KV flash-decode kernel — the block
-    table walks inside the kernel's index map, so no dense per-slot view is
-    ever materialized. Returns ``(out [S, 1, d], k_row, v_row)`` with the
-    fresh [S, kvh, dh] rows for the caller's pool writeback (the kernel
-    already folded them into the softmax in compute dtype, exactly the
-    value the gather path attends at the cursor)."""
+    against the POOL (both leaves whole, ``layer`` a scalar) through the
+    flash-decode kernel, which walks the block table and reads the live
+    blocks only. Returns ``(out [S, 1, d], k_row, v_row)`` with the fresh
+    [S, kvh, dh] rows for the caller's row write (the kernel already folded
+    them into the softmax in compute dtype, exactly the value the view path
+    attends at the cursor)."""
     from ..ops.pallas.paged_attention import paged_flash_decode
 
     b, q_len, _ = h.shape
     q, k, v = _project_qkv(cfg, p_attn, h, rope=rope)
     slopes = L.alibi_slopes(cfg.n_heads) \
         if cfg.position_embedding == "alibi" else None
-    out = paged_flash_decode(q[:, 0], k[:, 0], v[:, 0], kc, vc, table, pos,
-                             k_scale=ks, v_scale=vs, scale=cfg.attn_scale,
+    out = paged_flash_decode(q[:, 0], k[:, 0], v[:, 0], pool["k"], pool["v"],
+                             table, pos, layer=layer, scale=cfg.attn_scale,
                              alibi_slopes=slopes,
                              interpret=cfg.attention_interpret,
                              mesh=cfg.mesh)
@@ -195,36 +203,42 @@ def _attn_paged_fused(cfg, p_attn, h, kc, vc, ks, vs, table, pos, rope=None):
 
 
 def forward_with_paged_cache(model, params, input_ids, pool, table, pos,
-                             block_size, draft_len=None,
-                             attention_backend="gather",
+                             block_size, draft_len=None, kernel=False,
                              return_routing=False):
     """One decode step ([S, 1] tokens) reading/writing KV through a TRACED
-    block table — the paged twin of ``forward_with_cache``'s per-row decode.
+    block table: the paged twin of ``forward_with_cache``'s per-row decode.
 
-    Per layer: gather the slot-major dense view through ``table``
-    (dequantizing int8 blocks), run the UNCHANGED dense per-row attention on
-    it (``_block_cached``), then scatter each slot's newly-written row back
-    into the pool. Because the gathered view is bit-identical to the dense
-    cache at every unmasked position and the math in between is the same
-    program, greedy paged decode is bitwise-equal to the dense slot pool
-    (tier-1 pins it). Returns (logits [S, 1, vocab], new pool).
+    The pool (leaves ``[L, n_blocks, bs, kv_heads * head_dim]``, see
+    ``init_paged_cache``) is the CARRY of the layer loop: a layer reads its
+    share through the scalar layer index and writes each slot's new row by
+    a row-sized update, so a donated pool is updated in place and no slice
+    of a leaf is copied (scanned as ``xs`` the pool was sliced and re-joined,
+    4.8 GB a step at OPT-1.3B's pool). Returns (logits [S, 1, vocab], new
+    pool). Two ways to attend, and the CALLER'S choice between them is made
+    from what it can observe, never by a user's option (``ServingEngine``
+    asks ``fused_decode_supported`` once, at its geometry):
+
+    - ``kernel=True``: ``ops/pallas/paged_attention.py`` walks the block
+      table and reads the live blocks only. One query row a slot, no banded
+      local layers, a pool in the engine's dtype.
+    - the VIEW (default, and whatever the kernel does not take): per layer,
+      gather the slot-major dense ``n_slots x max_len`` view through
+      ``table`` (dequantizing int8 blocks), run the UNCHANGED dense per-row
+      attention on it (``_block_cached``), then write each slot's new row
+      back. Because the gathered view is bit-identical to the dense cache at
+      every unmasked position and the math in between is the same program,
+      greedy paged decode is bitwise-equal to the dense slot pool (tier-1
+      pins it).
 
     ``draft_len`` [S] switches the program into speculative VERIFY mode
     (see ``verify_with_paged_cache``): ``input_ids`` becomes [S, k+1]
     (the slot's last token + k draft candidates at per-slot cursors), all
     k+1 rows are written and all k+1 logit rows returned. Row i's write
     could ever become live only while ``i <= draft_len`` and the position
-    is inside the KV window — padded rows compute garbage that the causal
-    mask hides in-view and whose pool writeback redirects to the garbage
+    is inside the KV window: padded rows compute garbage that the causal
+    mask hides in-view and whose pool write redirects to the garbage
     block, and the in-view writes run in reverse row order so a
     window-clamped padded write can never shadow a real row.
-
-    ``attention_backend="fused"`` replaces the per-layer gather + dense
-    attention + scatter with the split-KV flash-decode kernel
-    (``ops/pallas/paged_attention.py``): the block-table walk happens
-    inside the kernel's index map and the dense per-slot view is never
-    materialized. Decode-only (q_len == 1, no verify) — callers gate on
-    ``fused_decode_supported`` and fall back to the gather path.
 
     ``return_routing`` (expert models with ``moe_routing="dropfree"``): also
     return what the step's expert layers chose, [L_moe, S, 1, 2k] int32 (ids
@@ -233,28 +247,24 @@ def forward_with_paged_cache(model, params, input_ids, pool, table, pos,
     if cfg.latent_attention:
         from . import latent
 
-        if draft_len is not None or "k_scale" in pool \
-                or attention_backend != "gather":
+        if draft_len is not None or "k_scale" in pool or kernel:
             raise ValueError(
-                "latent attention decodes in the absorbed form through the "
-                "gather backend over a pool in the engine's dtype: "
-                "speculative verify, an int8 pool and the fused backend are "
-                "not implemented")
+                "latent attention decodes in the absorbed form through its "
+                "own view over a pool in the engine's dtype: speculative "
+                "verify, an int8 pool and the decode kernel are not "
+                "implemented")
         logits, pool, ids = latent.forward_with_paged_cache(
             model, params, input_ids, pool, table, pos, block_size)
         return (logits, pool, ids) if return_routing else (logits, pool)
     b, q_len = input_ids.shape
-    int8 = "k_scale" in pool
-    view_dtype = cfg.compute_dtype
-    fused = attention_backend == "fused"
-    if fused and (draft_len is not None or q_len != 1):
+    if kernel and (draft_len is not None or q_len != 1):
         raise ValueError(
-            "attention_backend='fused' is decode-only (one query row per "
-            "slot); speculative verify runs the gather path")
-    if fused and cfg.local_attention_window > 0:
+            "the decode kernel is decode-only (one query row per slot); "
+            "speculative verify runs the view path")
+    if kernel and (cfg.local_attention_window > 0 or "k_scale" in pool):
         raise ValueError(
-            "attention_backend='fused' does not implement banded local-"
-            "attention masks (fused_decode_supported gates this)")
+            "the decode kernel implements neither banded local-attention "
+            "masks nor an int8 pool (fused_decode_supported gates this)")
     positions = pos[:, None] + jnp.arange(q_len)[None, :]
     kv_len = table.shape[1] * block_size
     if draft_len is not None:
@@ -276,74 +286,55 @@ def forward_with_paged_cache(model, params, input_ids, pool, table, pos,
         rope = L.rotary_embedding(positions, cfg.rotary_dim or cfg.head_dim,
                                   cfg.rope_base)
 
-    def block_step(h, p_i, kc, vc, ks, vs, loc):
-        if fused:
+    def block_step(h, pool, p_i, layer, loc):
+        if kernel:
             def attn_impl(p_attn, hh):
-                return _attn_paged_fused(cfg, p_attn, hh, kc, vc, ks, vs,
-                                         table, pos, rope=rope)
+                return _attn_paged_kernel(cfg, p_attn, hh, pool, layer,
+                                          table, pos, rope=rope)
 
             h, k_row, v_row = _block_cached(cfg, p_i, h, None, None, pos,
                                             kv_len, rope=rope,
                                             attn_impl=attn_impl)
-            kc, ks = _paged_writeback_rows(kc, ks, k_row, table, pos,
-                                           block_size)
-            vc, vs = _paged_writeback_rows(vc, vs, v_row, table, pos,
-                                           block_size)
-            return h, kc, vc, ks, vs
-        kview = _paged_view(kc, ks, table, view_dtype)
-        vview = _paged_view(vc, vs, table, view_dtype)
+            return h, _paged_write_rows(pool, layer,
+                                        {"k": k_row, "v": v_row}, table, pos,
+                                        block_size)
+        kview = _paged_view(pool, "k", layer, table, cfg.kv_heads,
+                            cfg.compute_dtype)
+        vview = _paged_view(pool, "v", layer, table, cfg.kv_heads,
+                            cfg.compute_dtype)
         h, kview, vview = _block_cached(cfg, p_i, h, kview, vview, pos,
                                         kv_len, rope=rope, is_local=loc,
                                         row_writes=row_writes)
+        # the row each slot just wrote (at its cursor) goes from the view
+        # back into the pool
+        row_at = jax.vmap(lambda c, p: jax.lax.dynamic_slice(
+            c, (p, 0, 0), (1,) + c.shape[1:])[0])
         for i in range(q_len):
             p_row = pos if i == 0 else pos + i
-            v_row = None if valid is None else valid[:, i]
-            kc, ks = _paged_writeback(kc, ks, kview, table, p_row,
-                                      block_size, valid=v_row)
-            vc, vs = _paged_writeback(vc, vs, vview, table, p_row,
-                                      block_size, valid=v_row)
-        return h, kc, vc, ks, vs
+            pool = _paged_write_rows(
+                pool, layer,
+                {"k": row_at(kview, p_row), "v": row_at(vview, p_row)},
+                table, p_row, block_size,
+                valid=None if valid is None else valid[:, i])
+        return h, pool
 
-    scales = (pool["k_scale"], pool["v_scale"]) if int8 else None
+    xs = [params["blocks"], jnp.arange(cfg.n_layers)]
     if cfg.local_attention_window > 0:
         from .transformer import local_attention_flags
 
-        is_local_arr = jnp.asarray(local_attention_flags(cfg))
-    else:
-        is_local_arr = None
+        xs.append(jnp.asarray(local_attention_flags(cfg)))
 
-    def scan_fn(carry, layer):
-        h = carry
-        if int8:
-            if is_local_arr is not None:
-                p_i, kc, vc, ks, vs, loc = layer
-            else:
-                (p_i, kc, vc, ks, vs), loc = layer, None
-        else:
-            ks = vs = None
-            if is_local_arr is not None:
-                p_i, kc, vc, loc = layer
-            else:
-                (p_i, kc, vc), loc = layer, None
-        h, kc, vc, ks, vs = block_step(h, p_i, kc, vc, ks, vs, loc)
-        out = (kc, vc, ks, vs) if int8 else (kc, vc)
-        return h, out
+    def scan_fn(carry, layer_xs):
+        p_i, layer, *loc = layer_xs
+        return block_step(*carry, p_i, layer, loc[0] if loc else None), None
 
-    xs = [params["blocks"], pool["k"], pool["v"]]
-    if int8:
-        xs += [scales[0], scales[1]]
-    if is_local_arr is not None:
-        xs += [is_local_arr]
-    h, new = jax.lax.scan(scan_fn, x, tuple(xs))
+    (h, pool), _ = jax.lax.scan(scan_fn, (x, dict(pool)), tuple(xs))
     h = _norm_apply(cfg, params["ln_f"], h)
     if cfg.tie_embeddings:
         logits = L.embedding_attend(params["wte"], h)
     else:
         logits = L.linear_apply(params["lm_head"], h)
-    new_pool = {"k": new[0], "v": new[1]}
-    if int8:
-        new_pool["k_scale"], new_pool["v_scale"] = new[2], new[3]
-    return logits, new_pool
+    return logits, pool
 
 
 def verify_with_paged_cache(model, params, input_ids, pool, table, pos,
@@ -377,7 +368,7 @@ def write_pool_blocks(pool, src, block_ids, src_blocks, *, lanes=False,
     ``[0, n_blocks)``; an id outside it is padding and writes nothing, so a
     caller pads its ``[blocks_per_slot]`` arrays with DISTINCT ids from
     ``n_blocks`` up and one compiled program serves every request size.
-    ``src`` leaves are ``[L, n_src, bs, kvh, *]`` in the pool's own dtypes:
+    ``src`` leaves are ``[L, n_src, bs, *row]`` in the pool's own dtypes:
     bytes are moved, never recomputed. Whole blocks are overwritten, so
     nothing of a previous occupant survives; every other block keeps its
     bytes, and a donated pool is updated in place.
@@ -397,7 +388,7 @@ def write_pool_blocks(pool, src, block_ids, src_blocks, *, lanes=False,
             plan = kw.column_plan(block_ids, src_blocks, pool["k"].shape[1])
             kernel = functools.partial(kw.write_block_columns,
                                        interpret=interpret)
-            heads = {3: ("model",)}
+            heads = {3: ("model",)}  # kv heads, merged with dh or alone
             return {name: shard_kernel(
                 kernel, mesh, (a, src[name].astype(a.dtype)) + plan,
                 [heads, heads, {}, {}, {}], [heads])
@@ -412,55 +403,58 @@ def insert_block_kv(pool, dense_cache, block_ids, src_blocks, block_size,
                     **writer):
     """Copy token blocks ``src_blocks`` of a freshly-prefilled dense b=1
     cache ``[L, 1, max_len, kvh, dh]`` into physical blocks ``block_ids`` of
-    the pool, quantizing the cache ONCE (per (token, head), the pool's int8
-    layout) when the pool is int8. Both id arrays are TRACED and padded
-    (``write_pool_blocks``): one compiled program covers every request."""
+    the pool, each token's row reshaped to the pool's own (per-head K and V:
+    merged, ``kvh * dh``), quantizing the cache ONCE (per (token, head), the
+    pool's int8 layout) when the pool is int8. Both id arrays are TRACED and
+    padded (``write_pool_blocks``): one compiled program covers every
+    request."""
     src = {}
     for name in ("k", "v"):
         d = dense_cache[name]
-        rows = d.reshape(d.shape[0], d.shape[2] // block_size, block_size,
-                         *d.shape[3:])
+        rows = d.reshape((d.shape[0], d.shape[2] // block_size, block_size)
+                         + pool[name].shape[3:])
         if name + "_scale" in pool:
             from ..comm.collectives import quantize_blockwise
 
             rows, src[name + "_scale"] = quantize_blockwise(
-                rows, block=rows.shape[-1])
+                rows, block=d.shape[-1])
         src[name] = rows
     return write_pool_blocks(pool, src, block_ids, src_blocks, **writer)
 
 
 def reset_block_kv(pool, block_id):
-    """Zero physical block ``block_id`` (block-granularity hygiene scrub —
+    """Zero physical block ``block_id`` (block-granularity hygiene scrub:
     ``scrub_freed_slots`` generalized from the dense pool's whole-row
     scrub; int8 scales zero too, so a dequantized read is exactly 0)."""
     out = {}
     for name, a in pool.items():
         z = jnp.zeros(a.shape[:1] + (1,) + a.shape[2:], a.dtype)
-        out[name] = jax.lax.dynamic_update_slice(a, z, (0, block_id, 0, 0, 0))
+        out[name] = jax.lax.dynamic_update_slice(
+            a, z, (0, block_id) + (0,) * (a.ndim - 2))
     return out
 
 
 def gather_slot_cache(cfg, pool, table_row, dtype):
     """Materialize one slot's dense [L, 1, NB*bs, kvh, dh] cache view from
-    its block-table row (dequantizing int8 blocks) — seeds the suffix
+    its block-table row (dequantizing int8 blocks): seeds the suffix
     prefill on a shared-prefix hit: positions below the shared length hold
     the canonical prefix KV, everything above is garbage the suffix prefill
     overwrites or the causal mask hides."""
     out = {}
-    for name in ("k", "v"):
-        g = pool[name][:, table_row]               # [L, NB, bs, kvh, dh]
+    for name, row in cfg.cache_geometry.items():
+        g = pool[name][:, table_row]               # [L, NB, bs, *pool row]
         if name + "_scale" in pool:
-            g = _dequant_layer(g, pool[name + "_scale"][:, table_row], dtype)
-        L_, nb, bs, kvh, dh = g.shape
-        out[name] = g.reshape(L_, 1, nb * bs, kvh, dh).astype(dtype)
+            g = _dequant_rows(g, pool[name + "_scale"][:, table_row], dtype)
+        out[name] = g.reshape((g.shape[0], 1, g.shape[1] * g.shape[2])
+                              + row).astype(dtype)
     return out
 
 
 def extract_slot_blocks(pool, table_row):
     """RAW gather of one slot's physical blocks for live migration: every
     pool leaf at its stored dtype — k/v payloads (int8 or dense) AND the
-    int8 scales when present — stacked [L, NB, bs, kvh, dh|1] in table-row
-    order. No dequantization: a dequant -> requant round trip reproduces
+    int8 scales when present — stacked [L, NB, bs, kvh * dh | kvh] in
+    table-row order. No dequantization: a dequant -> requant round trip reproduces
     the int8 payload but can perturb the recomputed scale in its last ulp,
     which would break the migrated-stream-is-bitwise contract. Padded
     table entries (GARBAGE_BLOCK) gather the garbage block; the writer
@@ -615,10 +609,10 @@ def _block_cached(cfg, p, x, k_cache, v_cache, pos, kv_len, rope=None,
     """One block with cache. x: [b, q, d] compute dtype.
 
     ``attn_impl(p_attn_cast, h) -> (out, aux1, aux2)`` overrides the dense
-    ``_attn_with_cache`` (the fused paged backend routes the flash-decode
-    kernel through here so the norm/residual/MLP structure — and therefore
-    parity with the gather path — is shared by construction); the two aux
-    values replace the (k_cache, v_cache) return slots."""
+    ``_attn_with_cache`` (the paged decode kernel is routed through here so
+    the norm/residual/MLP structure, and therefore parity with the view
+    path, is shared by construction); the two aux values replace the
+    (k_cache, v_cache) return slots."""
     cast = lambda a: a.astype(cfg.compute_dtype) \
         if jnp.issubdtype(a.dtype, jnp.floating) else a
     p_cast = {

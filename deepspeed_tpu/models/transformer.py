@@ -274,6 +274,20 @@ class TransformerConfig:
         return {"k": (self.kv_heads, self.head_dim),
                 "v": (self.kv_heads, self.head_dim)}
 
+    @property
+    def pool_geometry(self):
+        """``{leaf: row shape}`` of one token in one layer of the PAGED
+        pool. Per-head K and V are stored merged, ``(kv_heads * head_dim,)``:
+        a token's whole row is the leaf's minor-most axis, so the device
+        keeps a block of tokens contiguous whatever the head size (a leaf
+        that ends in a 64-wide axis gets its largest axis, the blocks, in
+        the lanes: PERF.md, PRs 27 and 30). The latent leaves already keep
+        their row or their tokens there and stay ``(1, width)``."""
+        if self.latent_attention:
+            return self.cache_geometry
+        return {name: (heads * width,)
+                for name, (heads, width) in self.cache_geometry.items()}
+
     def num_params(self):
         """Analytic parameter count (embedding + blocks + final norm)."""
         d, f, v = self.d_model, self.d_ff, self.vocab_size

@@ -2,13 +2,18 @@
 pool that the device keeps with its block axis in the lanes.
 
 The TPU picks an array's device layout from its shape, to waste the fewest
-lanes. A pool leaf ``[L, n_blocks, block_size, kvh, dh]`` with ``dh`` under
-128 gets ``n_blocks`` as its minor-most axis: one 128-lane tile row holds
-one (layer, token, head, dh) element of 128 CONSECUTIVE BLOCKS, and one
+lanes. A pool leaf ``[L, n_blocks, block_size, *row]`` whose last axis is
+under 128 wide gets ``n_blocks`` as its minor-most axis: one 128-lane tile
+row holds one (layer, token, row element) of 128 CONSECUTIVE BLOCKS, and one
 block is one lane of every such row, spread over the whole pool. A
 per-block ``dynamic_update_slice`` therefore rewrites a 128-block column of
 the pool for every block (PERF.md, PR 27), and an XLA scatter over the
-block axis first copies the whole pool into a block-major layout.
+block axis first copies the whole pool into a block-major layout. Since PR
+30 the pool stores per-head K and V merged (``kv_heads * head_dim`` wide),
+which keeps a block contiguous at every published width: this kernel runs
+only for a pool the device still lays out that way (a latent pool with
+small blocks, a merged row narrower than a tile), as ``blocks_in_lanes``
+reads off the live array.
 
 The kernel works with that layout instead of against it. Seen as
 ``[rows, n_blocks]`` (a bitcast of the leaf as the device holds it) the pool
@@ -57,8 +62,8 @@ def unfit_reason(pool, tp=1):
     tile at the shape one shard holds (kv heads over ``tp`` when they
     divide)."""
     for a in pool.values():
-        L, _, bs, kvh, w = a.shape
-        rows = L * bs * (kvh // tp if kvh % tp == 0 else kvh) * w
+        # axis 3 carries the kv heads (merged with the head size or alone)
+        rows = a.size // a.shape[1] // (tp if a.shape[3] % tp == 0 else 1)
         if row_tile(rows, a.dtype) is None:
             return f"{rows} rows of {a.dtype} a shard divide into no row tile"
     return None
@@ -107,18 +112,18 @@ def write_block_columns(pool_leaf, src_leaf, cols, n_cols, perm,
     """``pool_leaf[:, b] = src_leaf[:, perm(b)]`` for every target block b of
     ``column_plan``; every other block keeps its bytes.
 
-    ``pool_leaf`` ``[L, n_blocks, bs, kvh, w]`` and ``src_leaf``
-    ``[L, n_src, bs, kvh, w]`` share a dtype; ``row_tile`` must fit
-    ``L * bs * kvh * w``. The result aliases ``pool_leaf``."""
-    L, n_blocks, bs, kvh, w = pool_leaf.shape
-    rows = L * bs * kvh * w
+    ``pool_leaf`` ``[L, n_blocks, bs, *row]`` and ``src_leaf``
+    ``[L, n_src, bs, *row]`` share a dtype; ``row_tile`` must fit
+    ``L * bs * prod(row)``. The result aliases ``pool_leaf``."""
+    n_blocks = pool_leaf.shape[1]
+    rows = pool_leaf.size // n_blocks
     tile = row_tile(rows, pool_leaf.dtype)
     n_src = src_leaf.shape[1]
     src_lanes = -(-n_src // LANES) * LANES
     # block axis last: a bitcast for the pool as the device holds it, one
     # small relayout for the source
-    pool2 = jnp.transpose(pool_leaf, (0, 2, 3, 4, 1)).reshape(rows, n_blocks)
-    src2 = jnp.transpose(src_leaf, (0, 2, 3, 4, 1)).reshape(rows, n_src)
+    pool2 = jnp.moveaxis(pool_leaf, 1, -1).reshape(rows, n_blocks)
+    src2 = jnp.moveaxis(src_leaf, 1, -1).reshape(rows, n_src)
     if src_lanes != n_src:
         src2 = jnp.pad(src2, ((0, 0), (0, src_lanes - n_src)))
     out2 = pl.pallas_call(
@@ -141,5 +146,5 @@ def write_block_columns(pool_leaf, src_leaf, cols, n_cols, perm,
         interpret=interpret,
         name="kv_block_write",
     )(cols, perm, src2, pool2)
-    return jnp.transpose(out2.reshape(L, bs, kvh, w, n_blocks),
-                         (0, 4, 1, 2, 3))
+    return jnp.moveaxis(out2.reshape(
+        pool_leaf.shape[:1] + pool_leaf.shape[2:] + (n_blocks,)), -1, 1)

@@ -1,56 +1,54 @@
-"""Split-KV paged flash-decode Pallas kernel: decode attention that walks the
-block table IN-KERNEL.
+"""Paged flash-decode Pallas kernel: decode attention that walks the block
+table IN-KERNEL and reads only the KV that is live.
 
-The serving decode hot path is 1 query row per slot against a long KV window
-stored as a paged pool (``serving/kv_pool.py``: ``[n_blocks, block_size, kvh,
-dh]`` physical blocks + a per-slot block table). The gather path
-(``models/decoding.py:_paged_view``) materializes a dense per-slot view of
-that pool per layer — correct and compile-once, but pure transient HBM
-traffic: every decode step writes (and immediately re-reads) an
-``[S, NB*bs, kvh, dh]`` tensor whose only purpose is to look like the dense
-cache. This kernel deletes that view: the block-table indirection happens in
-the BlockSpec index map (scalar-prefetched table + cursors, so the DMA
-engine chases ``table[s, j]`` directly), and the online-softmax inner loop
-masks each slot's ragged cursor in-register — DeepSpeed-Inference's fused
-decode attention play (arXiv:2207.00032), TPU-native.
+The serving decode hot path is 1 query row per slot against a KV window kept
+in a paged pool (``serving/kv_pool.py``): leaves ``[L, n_blocks, block_size,
+kv_heads * head_dim]`` and a per-slot block table. A token's row (all its kv
+heads side by side) is the leaf's minor-most axis, so the device keeps one
+block of tokens CONTIGUOUS, whatever the head size, and one DMA fetches it.
+The view path (``models/decoding.py:_paged_view``) gathers an ``n_slots x
+max_len`` dense view of one layer through the table, a layer at a time: at
+OPT-1.3B's serving geometry that wrote and read 26 GB a step for 3.2 GB that
+were live (PERF.md, PR 30). This kernel reads, for each slot, the blocks
+below its cursor and nothing else.
 
-Shape/structure notes (the TPU way, same idioms as
-``ops/pallas/flash_attention.py``):
+Structure:
 
-- grid = (slots, kv_splits, blocks_per_split). A grid cell streams one
-  physical block WHOLE — all its kv heads, ``[bs, kvh, dh]`` — because
-  Mosaic only accepts a block whose last two dims are the array's own (or
-  8/128-aligned): one head of many, ``(1, bs, 1, dh)``, is refused. GQA
-  still costs nothing: the ``n_heads // kv_heads`` query rows of a group
-  each read the same resident tile.
-- split-KV: each of the ``kv_splits`` grid cells owns a contiguous run of
-  table columns and produces a PARTIAL (max, sum, accumulator) triple; the
-  partials combine outside the kernel (a tiny ``[S, kvh, splits, hq]``
-  fp32 reduction) — the FlashDecoding shape, so long contexts parallelize
-  across the split grid instead of serializing one slot's whole window.
-- the freshly-projected k/v row of the CURRENT token never touches the
-  pool before attention: it folds into the softmax during the combine, in
-  compute dtype — exactly the value the gather path attends (the fresh row
-  is written to the view pre-attention there), so int8 pools see the same
-  unquantized current row on both paths and the writeback stays where it
-  was.
-- per-slot cursor masks: a slot's valid pool window is positions
-  ``[0, pos)`` (ragged mid-block cursors included); blocks wholly past the
-  cursor are compute-skipped (``pl.when``) and their DMA lands on whatever
-  block id the table holds there — freed/unbound columns hold the reserved
-  GARBAGE block, so the fetch is always in-range and its values are never
-  read into the softmax.
-- int8 pools dequantize IN-KERNEL: the int8 payload block and its
-  per-(token, head) fp32 scale stream to VMEM natively and the
-  ``payload.astype(f32) * scale`` happens on the tile — elementwise ops
-  identical to ``comm/collectives.py:dequantize_blockwise``, so the fused
-  path reads bit-identical dequantized values, at half the pool HBM
-  traffic of gathering an already-dequantized view.
+- grid = (slots,), run in order. The pool leaves stay in HBM (``pl.ANY``),
+  whole: the layer is a scalar operand, so the layer loop of the decode
+  program hands the kernel its carry and no slice of a leaf is ever copied.
+  Block table and cursors are scalar-prefetched.
+- a slot's window is walked in CHUNKS of ``chunk_tokens`` (several blocks):
+  one DMA a live block of K and of V into one of two VMEM buffers, the next
+  chunk (or the next live slot's first chunk) in flight while this one is
+  consumed. A loop bounded by the slot's cursor: copies, waits and steps
+  follow the live blocks, not ``n_slots x blocks_per_slot``.
+- every head at once, on the MXU: the slot's query rows come in
+  BLOCK-DIAGONAL form ``[n_heads, kvh * dh]`` (head h's vector in its kv
+  group's ``dh`` lanes, zeros elsewhere), so ``q_bd . K^T`` over a
+  ``[tokens, kvh * dh]`` tile is every head's scores in one product, with no
+  lane slicing at a 64-wide head, and ``P . V`` gives ``[n_heads, kvh * dh]``
+  of which head h's own ``dh`` lanes are its output (the rest is discarded
+  on the way out). The MXU does 1/kvh useful work; the step is bound by
+  streaming the pool, which it now does at the pool's own width.
+- float32 scores, softmax and accumulators, as on every attention path. The
+  probabilities enter the PV product as a bf16 pair (high part + remainder)
+  against a bf16 pool: two exact passes instead of an emulated f32 product.
+- the freshly-projected k/v row of the CURRENT token never touches the pool
+  before attention: it seeds the running (max, sum, accumulator), exactly
+  the value the view path attends at the cursor, so a slot whose cursor is 0
+  attends its own row alone and nothing divides by zero.
+- a slot's valid pool window is positions ``[0, pos)``. Blocks wholly past
+  the cursor are neither copied nor waited for; what a buffer still holds
+  there is masked out of the scores, and the V buffers start as zeros, so
+  a probability of exactly 0 never meets bytes that were not KV. Unbound
+  table columns hold the reserved GARBAGE block and are never live.
 
-Tier-1 runs this kernel under ``interpret=True`` on CPU (the models'
-``attention_interpret``, the same switch as the flash kernels' interpret
-tests), so correctness — ragged cursors, GQA, alibi, int8, garbage-block
-exclusion — is pinned without chips.
+An int8 pool, several query rows a slot (speculative verify) and banded
+local layers take the view path (``fused_decode_supported`` says why).
+
+Tier-1 runs the kernel under ``interpret=True`` on the CPU (the models'
+``attention_interpret``) and lowers it for the TPU at OPT-1.3B's geometry.
 """
 
 import functools
@@ -63,30 +61,21 @@ from jax.experimental.pallas import tpu as pltpu
 
 
 NEG_INF = -1e30
-LANES = 128
-
-
-def _fit_splits(requested, n_columns):
-    """Largest split count in [1, requested] dividing ``n_columns`` (the
-    block-table width) — a non-dividing request degrades, never crashes."""
-    s = max(1, min(int(requested), n_columns))
-    while n_columns % s:
-        s -= 1
-    return s
+CHUNK_TOKENS = 256
 
 
 def fused_decode_supported(cfg, block_size, *, n_slots=8, blocks_per_slot=8,
                            tp=1, kv_dtype=""):
-    """Capability probe for the fused backend: ``(ok, reason)``.
+    """May the decode program attend through the kernel? ``(ok, reason)``.
 
-    Two structural refusals (what the kernel does not implement: banded
-    local-attention layers, ragged GQA groups), then the question goes to
-    the compiler: the kernel is lowered for the target platform at the
-    engine's per-device geometry (``cfg`` heads / ``tp``, ``block_size``,
-    pool dtype) — and compiled, when the backend is a TPU — and a refusal
-    comes back as the compiler's own message. A hand-kept rule list here
-    once approved a pool blocking Mosaic rejects; the probe must never
-    approve a shape that then fails at first dispatch. With
+    Structural refusals first (what the kernel does not implement: banded
+    local-attention layers, ragged GQA groups, an int8 pool), then the
+    question goes to the compiler: the kernel is lowered for the target
+    platform at the engine's per-device geometry (``cfg`` heads / ``tp``,
+    ``block_size``, table width) and compiled, when the backend is a TPU;
+    a refusal comes back as the compiler's own message. A hand-kept rule
+    list here once approved a pool blocking Mosaic rejects; the probe must
+    never approve a shape that then fails at first dispatch. With
     ``cfg.attention_interpret`` the Pallas interpreter runs the kernel and
     has no layout constraints.
     """
@@ -94,10 +83,13 @@ def fused_decode_supported(cfg, block_size, *, n_slots=8, blocks_per_slot=8,
 
     if cfg.local_attention_window > 0:
         return False, ("local_attention_window > 0: banded layer masks are "
-                       "not implemented in the fused kernel")
+                       "not implemented in the decode kernel")
     if cfg.n_heads % cfg.kv_heads:
         return False, (f"n_heads {cfg.n_heads} not a multiple of kv_heads "
                        f"{cfg.kv_heads}")
+    if kv_dtype:
+        return False, (f"a {kv_dtype} pool: the decode kernel reads a pool "
+                       "in the engine's dtype")
     if cfg.attention_interpret:
         return True, ""
     reason = unavailable_reason()
@@ -105,276 +97,264 @@ def fused_decode_supported(cfg, block_size, *, n_slots=8, blocks_per_slot=8,
         return False, reason
     shard = tp if cfg.kv_heads % tp == 0 else 1
     kvh, nh, dh = cfg.kv_heads // shard, cfg.n_heads // shard, cfg.head_dim
-    int8 = kv_dtype == "int8"
     sds = jax.ShapeDtypeStruct
-    pool = sds((n_slots * blocks_per_slot + 1, block_size, kvh, dh),
-               jnp.int8 if int8 else cfg.compute_dtype)
-    scale = sds(pool.shape[:-1] + (1,), jnp.float32) if int8 else None
+    pool = sds((cfg.n_layers, n_slots * blocks_per_slot + 1, block_size,
+                kvh * dh), cfg.compute_dtype)
     row = sds((n_slots, kvh, dh), cfg.compute_dtype)
     slopes = jnp.ones((nh,), jnp.float32) \
         if cfg.position_embedding == "alibi" else None
 
-    def call(q, k_new, v_new, kc, vc, table, pos, ks, vs):
+    def call(q, k_new, v_new, kc, vc, table, pos, layer):
         return paged_flash_decode(q, k_new, v_new, kc, vc, table, pos,
-                                  k_scale=ks, v_scale=vs,
-                                  scale=cfg.attn_scale, alibi_slopes=slopes)
+                                  layer=layer, scale=cfg.attn_scale,
+                                  alibi_slopes=slopes)
 
     ok, reason = compiler_verdict(
         call, sds((n_slots, nh, dh), cfg.compute_dtype), row, row, pool, pool,
         sds((n_slots, blocks_per_slot), jnp.int32),
-        sds((n_slots,), jnp.int32), scale, scale)
+        sds((n_slots,), jnp.int32), sds((), jnp.int32))
     return ok, reason and f"TPU compiler: {reason}"
 
 
-def _decode_kernel(table_ref, pos_ref, q_ref, k_ref, v_ref, *rest, scale,
-                   block_size, blocks_per_split, int8, alibi, stat_lanes):
-    """One (slot, split, block) cell: stream one physical block — every kv
-    head of it — fold it into the split's running (m, l, acc) triples, emit
-    the partials at the split's last block. ``table_ref``/``pos_ref`` are
-    the scalar-prefetched block table and cursors (the index maps already
-    used them to aim the DMA; the body re-reads the cursor for the mask).
+def _decode_kernel(layer_ref, table_ref, pos_ref, q_ref, kn_ref, vn_ref,
+                   *rest, scale, block_size, chunk_blocks, head_dim, alibi):
+    """One slot: walk its live blocks chunk by chunk, fold each chunk into
+    the running (m, l, acc), emit the slot's normalized output rows.
 
-    Layout: the k/v tile is [bs, kvh, dh] with (kvh, dh) on the (sublane,
-    lane) dims, so each of the ``hq`` query rows of a kv group is one
-    [kvh, dh] tile and a block is consumed with broadcast-multiplies and
-    reductions over the lane dim (q·k) and the leading dim (softmax sums,
-    p·v) — VPU work with no relayout. One query row per slot leaves the MXU
-    nothing to chew on, and the step is bound by streaming the pool."""
-    idx = 0
-    if int8:
-        ks_ref, vs_ref = rest[idx], rest[idx + 1]
-        idx += 2
-    slopes_ref = None
+    ``q_ref`` [1, n_heads, W] is the block-diagonal query (W = kvh * dh),
+    ``kn_ref``/``vn_ref`` [1, 1, W] the current token's fresh row; ``k_hbm``/
+    ``v_hbm`` [L, n_blocks, bs, W] stay in HBM and are copied by block;
+    ``o_ref`` [1, hq, W]: row j holds, in kv group g's lanes, the output of
+    head ``g * hq + j``. ``kbuf``/``vbuf`` [2, chunk, W] are the two chunk
+    buffers, ``cur_ref`` the buffer the next chunk to consume lands in (it
+    outlives a grid step: the next live slot's first chunk is already in
+    flight when its step begins)."""
     if alibi:
-        slopes_ref = rest[idx]
-        idx += 1
-    o_ref, m_ref, l_ref, m_scr, l_scr, acc_scr = rest[idx:]
-    hq, kvh = q_ref.shape[1], q_ref.shape[2]
+        slopes_ref, rest = rest[0], rest[1:]
+    k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, acc_scr, cur_ref = rest
+    n_slots, n_cols = table_ref.shape
+    n_heads, width = q_ref.shape[1], q_ref.shape[2]
+    hq = o_ref.shape[1]
+    chunk = chunk_blocks * block_size
 
     s = pl.program_id(0)
-    sp = pl.program_id(1)
-    jb = pl.program_id(2)
-
-    @pl.when(jb == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
+    layer = layer_ref[0]
     pos = pos_ref[s]                       # valid pool window = [0, pos)
-    base = (sp * blocks_per_split + jb) * block_size
+    n_chunks = (pos + chunk - 1) // chunk
 
-    @pl.when(base < pos)
-    def _step():
-        # the allowlisted attention-f32 island (see sanitizer
-        # ATTENTION_F32_ALLOW): logits, softmax and the PV accumulator run
-        # fp32 on purpose — softmax numerics
-        with jax.named_scope("paged_flash_decode"):
-            k = k_ref[0]                   # [bs, kvh, dh]
-            v = v_ref[0]
-            if int8:
-                # dequantize ON the tile — elementwise-identical to
-                # dequantize_blockwise (f32 payload * per-(token,head)
-                # scale, then the compute-dtype cast the gather view takes)
-                k = (k.astype(jnp.float32) * ks_ref[0]).astype(q_ref.dtype)
-                v = (v.astype(jnp.float32) * vs_ref[0]).astype(q_ref.dtype)
-            k = k.astype(jnp.float32)
-            v = v.astype(jnp.float32)
-            row = jax.lax.broadcasted_iota(
-                jnp.int32, (block_size, kvh, 1), 0)
-            live = base + row < pos
-            for j in range(hq):            # query rows of each kv group
-                q = q_ref[0, j].astype(jnp.float32)            # [kvh, dh]
-                sc = jnp.sum(k * q[None], axis=-1,
-                             keepdims=True) * scale            # [bs, kvh, 1]
-                if alibi:
-                    # slopes * (kv_pos - cursor): the same int-difference-
-                    # then-fp32-multiply as the gather path's per-row alibi
-                    dist = (base + row - pos).astype(jnp.float32)
-                    sc = sc + slopes_ref[j][None] * dist
-                sc = jnp.where(live, sc, NEG_INF)
-                m_prev = m_scr[j][:, :1]                       # [kvh, 1]
-                m_new = jnp.maximum(m_prev, jnp.max(sc, axis=0))
-                p = jnp.exp(sc - m_new[None])                  # [bs, kvh, 1]
-                corr = jnp.exp(m_prev - m_new)
-                l_scr[j] = l_scr[j] * corr + jnp.broadcast_to(
-                    jnp.sum(p, axis=0), l_scr.shape[1:])
-                acc_scr[j] = acc_scr[j] * corr + jnp.sum(p * v, axis=0)
-                m_scr[j] = jnp.broadcast_to(m_new, m_scr.shape[1:])
+    def for_live_blocks(slot, c, buf, act):
+        """``act`` on the K and the V copy of every live block of chunk
+        ``c`` of ``slot``, aimed at buffer ``buf``: the same descriptors
+        start a chunk and wait for it."""
+        for j in range(chunk_blocks):
+            col = c * chunk_blocks + j
 
-    @pl.when(jb == blocks_per_split - 1)
-    def _emit():
-        # partials, not normalized output: splits with no valid positions
-        # emit (m=-inf, l=0, acc=0) and drop out of the combine exactly
-        o_ref[0, 0] = acc_scr[...]
-        m_ref[0, 0] = m_scr[...][..., :stat_lanes]
-        l_ref[0, 0] = l_scr[...][..., :stat_lanes]
+            @pl.when(col * block_size < pos_ref[slot])
+            def _():
+                blk = table_ref[slot, jnp.minimum(col, n_cols - 1)]
+                rows = pl.ds(j * block_size, block_size)
+                act(pltpu.make_async_copy(
+                    k_hbm.at[layer, blk], kbuf.at[buf, rows], sem.at[0, buf]))
+                act(pltpu.make_async_copy(
+                    v_hbm.at[layer, blk], vbuf.at[buf, rows], sem.at[1, buf]))
+
+    start = lambda slot, c, buf: for_live_blocks(
+        slot, c, buf, lambda copy: copy.start())
+    wait = lambda slot, c, buf: for_live_blocks(
+        slot, c, buf, lambda copy: copy.wait())
+
+    def start_next_live(after, buf):
+        """Start chunk 0 of the first slot past ``after`` whose cursor is
+        not 0 (the slots between have nothing in the pool to read)."""
+        nxt = jax.lax.fori_loop(
+            0, n_slots,
+            lambda i, found: jnp.where(
+                (n_slots - 1 - i > after) & (pos_ref[n_slots - 1 - i] > 0),
+                n_slots - 1 - i, found),
+            n_slots)
+
+        @pl.when(nxt < n_slots)
+        def _():
+            start(nxt, 0, buf)
+
+    @pl.when(s == 0)
+    def _first():
+        # a masked token's probability is exactly 0, and 0 x what a fresh
+        # V buffer holds must be 0: only KV (or these zeros) is ever in one.
+        # (K needs none: a masked score is replaced, whatever it was.)
+        vbuf[...] = jnp.zeros_like(vbuf)
+        cur_ref[0] = 0
+        start_next_live(-1, 0)
+
+    buf0 = cur_ref[0]
+    # the allowlisted attention-f32 island (sanitizer ATTENTION_F32_ALLOW):
+    # logits, softmax and the PV accumulator run fp32 on purpose
+    with jax.named_scope("paged_flash_decode"):
+        q = q_ref[0]                                           # [nh, W]
+        # the current token's own row (position pos, alibi distance 0)
+        m0 = jnp.sum(q.astype(jnp.float32) * kn_ref[0].astype(jnp.float32),
+                     axis=-1, keepdims=True) * scale           # [nh, 1]
+        acc_scr[...] = jnp.broadcast_to(vn_ref[0].astype(jnp.float32),
+                                        acc_scr.shape)
+
+        def fold(c, carry):
+            m_prev, l_prev = carry
+            buf = (buf0 + c) % 2
+
+            @pl.when(c + 1 < n_chunks)
+            def _():
+                start(s, c + 1, 1 - buf)
+
+            @pl.when(c + 1 == n_chunks)
+            def _():
+                start_next_live(s, 1 - buf)
+
+            wait(s, c, buf)
+            sc = jax.lax.dot_general(
+                q, kbuf[buf], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale    # [nh, chunk]
+            t = c * chunk + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
+            if alibi:
+                # slopes * (kv_pos - cursor): the same int difference, then
+                # fp32 multiply, as the view path's per-row alibi
+                sc = sc + slopes_ref[...] * (t - pos).astype(jnp.float32)
+            sc = jnp.where(t < pos, sc, NEG_INF)
+            m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
+            p = jnp.exp(sc - m_new)
+            corr = jnp.exp(m_prev - m_new)
+            v = vbuf[buf]                                      # [chunk, W]
+            if v.dtype == jnp.bfloat16:
+                hi = p.astype(jnp.bfloat16)
+                lo = (p - hi.astype(jnp.float32)).astype(jnp.bfloat16)
+                pv = jnp.dot(hi, v, preferred_element_type=jnp.float32) \
+                    + jnp.dot(lo, v, preferred_element_type=jnp.float32)
+            else:
+                pv = jnp.dot(p.astype(v.dtype), v,
+                             preferred_element_type=jnp.float32)
+            acc_scr[...] = acc_scr[...] * corr + pv
+            return m_new, l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
+
+        _, l_fin = jax.lax.fori_loop(0, n_chunks, fold,
+                                     (m0, jnp.ones_like(m0)))
+        cur_ref[0] = (buf0 + n_chunks) % 2
+
+        # head h keeps its own kv group's lanes: row j of the output holds
+        # head g * hq + j in the lanes of group g
+        acc = acc_scr[...] / l_fin
+        head = jax.lax.broadcasted_iota(jnp.int32, (n_heads, width), 0)
+        group = jax.lax.broadcasted_iota(
+            jnp.int32, (n_heads, width), 1) // head_dim
+        for j in range(hq):
+            o_ref[0, pl.ds(j, 1), :] = jnp.sum(
+                jnp.where(head == group * hq + j, acc, 0.0), axis=0,
+                keepdims=True)
 
 
-def paged_flash_decode(q, k_new, v_new, kc, vc, table, pos, *, k_scale=None,
-                       v_scale=None, scale=None, alibi_slopes=None,
-                       kv_splits=4, interpret=False, mesh=None):
-    """Fused paged decode attention: softmax(q·K/√d)·V for ONE query row per
-    slot, where K/V live in the paged pool and the kernel walks the block
-    table itself.
+def paged_flash_decode(q, k_new, v_new, kc, vc, table, pos, *, layer=None,
+                       scale=None, alibi_slopes=None,
+                       chunk_tokens=CHUNK_TOKENS, interpret=False, mesh=None):
+    """Paged decode attention: softmax(q·K/√d)·V for ONE query row per slot,
+    where K/V live in the paged pool and the kernel walks the block table
+    itself, reading only the blocks below each slot's cursor.
 
-    - ``q``: [S, n_heads, dh] (compute dtype) — this step's query rows;
-    - ``k_new``/``v_new``: [S, kvh, dh] — the freshly-projected k/v of the
-      current token (NOT yet in the pool; logically at position ``pos[s]``,
-      folded into the softmax in compute dtype during the combine);
-    - ``kc``/``vc``: [n_blocks, block_size, kvh, dh] — one layer of the
-      pool (int8 payloads when ``k_scale``/``v_scale`` [n_blocks, bs, kvh,
-      1] f32 are given: dequantized in-kernel);
-    - ``table``: [S, NB] int32 physical block ids (scalar-prefetched: the
-      index map reads it to aim each block DMA — no dense view exists);
-    - ``pos``: [S] int32 cursors; pool positions [0, pos) are attended,
-      everything past the cursor (ragged mid-block tails, unbound
-      garbage-block columns) is masked/skipped;
+    - ``q``: [S, n_heads, dh] (compute dtype), this step's query rows;
+    - ``k_new``/``v_new``: [S, kvh, dh], the freshly-projected k/v of the
+      current token (NOT yet in the pool; logically at position ``pos[s]``);
+    - ``kc``/``vc``: the pool leaves WHOLE, [L, n_blocks, block_size,
+      kvh * dh], with ``layer`` (a traced scalar) the layer to read; or one
+      layer [n_blocks, block_size, kvh * dh] with ``layer`` None;
+    - ``table``: [S, NB] int32 physical block ids; ``pos``: [S] int32
+      cursors. Pool positions [0, pos) are attended; everything past the
+      cursor (a ragged tail, unbound garbage-block columns) is never read;
+    - ``chunk_tokens``: tokens consumed a step of the kernel's inner loop
+      (rounded down to whole blocks);
     - ``interpret``: run under the Pallas interpreter (the models'
       ``attention_interpret``; CPU tests);
     - ``mesh``: the mesh the decode program is partitioned over. On more
       than one device the kernel runs inside a ``shard_map`` with the kv
-      heads (and their query groups) split over ``model`` when they
-      divide it — GSPMD cannot partition a Mosaic call.
+      heads (contiguous groups of the pool's merged axis) and their query
+      groups split over ``model`` when they divide it: GSPMD cannot
+      partition a Mosaic call.
 
     Returns [S, n_heads, dh] in ``q.dtype``.
     """
     from . import shard_kernel
 
-    # heads split over `model` only when the KV heads divide it (the pool's
-    # own sharding rule) — q's heads must follow their kv group
+    if layer is None:
+        kc, vc, layer = kc[None], vc[None], 0
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
     tp = mesh.shape.get("model", 1) if mesh is not None else 1
-    head_axes = ("model",) if kc.shape[2] % tp == 0 else ()
+    head_axes = ("model",) if k_new.shape[1] % tp == 0 else ()
     at = lambda dim: {dim: head_axes}
-    slopes = None if alibi_slopes is None \
-        else jnp.asarray(alibi_slopes, jnp.float32)
-    int8, alibi = k_scale is not None, slopes is not None
-    operands = [q, k_new, v_new, kc, vc, table, pos]
-    dim_axes = [at(1), at(1), at(1), at(2), at(2), {}, {}]
-    if int8:
-        operands += [k_scale, v_scale]
-        dim_axes += [at(2), at(2)]
-    if alibi:
-        operands.append(slopes)
+    operands = [q, k_new, v_new, kc, vc, table, pos, layer]
+    dim_axes = [at(1), at(1), at(1), at(3), at(3), {}, {}, {}]
+    if alibi_slopes is not None:
+        operands.append(jnp.asarray(alibi_slopes, jnp.float32))
         dim_axes.append(at(0))
 
-    def per_shard(q, k_new, v_new, kc, vc, table, pos, *rest):
-        ks, vs = rest[:2] if int8 else (None, None)
-        return _paged_flash_decode(
-            q, k_new, v_new, kc, vc, table, pos, ks, vs, scale,
-            rest[-1] if alibi else None, kv_splits, interpret)
+    def per_shard(q, k_new, v_new, kc, vc, table, pos, layer, slopes=None):
+        return _paged_flash_decode(q, k_new, v_new, kc, vc, table, pos,
+                                   layer, slopes, scale, chunk_tokens,
+                                   interpret)
 
     return shard_kernel(per_shard, mesh, operands, dim_axes, [at(1)])
 
 
-def _paged_flash_decode(q, k_new, v_new, kc, vc, table, pos, k_scale,
-                        v_scale, scale, alibi_slopes, kv_splits, interpret):
+def _paged_flash_decode(q, k_new, v_new, kc, vc, table, pos, layer, slopes,
+                        scale, chunk_tokens, interpret):
     """One device's share of ``paged_flash_decode`` (local head counts)."""
     s_dim, n_heads, dh = q.shape
-    n_blocks, block_size, kvh, _ = kc.shape
-    nb_cols = table.shape[1]
+    kvh = k_new.shape[1]
+    block_size, width = kc.shape[2], kc.shape[3]
     hq = n_heads // kvh
+    assert width == kvh * dh, (kc.shape, k_new.shape)
     scale = scale if scale is not None else 1.0 / math.sqrt(dh)
-    int8 = k_scale is not None
-    alibi = alibi_slopes is not None
+    alibi = slopes is not None
+    chunk_blocks = max(1, min(chunk_tokens // block_size, table.shape[1]))
 
-    splits = _fit_splits(kv_splits, nb_cols)
-    bps = nb_cols // splits
-    grid = (s_dim, splits, bps)
-    # head h = g * hq + j (kv group g, row j): kernel layout [S, hq, kvh, dh]
-    # puts (kvh, dh) on the tile dims next to the pool block's
-    qt = q.reshape(s_dim, kvh, hq, dh).transpose(0, 2, 1, 3)
+    # block-diagonal queries: head h = g * hq + j lives in group g's lanes
+    own = jnp.arange(n_heads)[:, None] // hq == jnp.arange(kvh)[None, :]
+    q_bd = jnp.where(own[None, :, :, None], q[:, :, None, :], 0) \
+        .reshape(s_dim, n_heads, width).astype(kc.dtype)
+    row = lambda a: a.reshape(s_dim, 1, width)
 
-    def kv_index(s, sp, jb, table_ref, pos_ref):
-        # THE point of the kernel: the block-table indirection lives here.
-        # Unbound columns hold the reserved garbage block — always a valid
-        # pool row, compute-skipped in the body. A block is fetched whole
-        # (all kv heads): Mosaic wants a block's last two dims to be the
-        # array's own (or 8/128-aligned), which one head of many is not.
-        return (table_ref[s, sp * bps + jb], 0, 0, 0)
-
-    def q_index(s, sp, jb, table_ref, pos_ref):
-        return (s, 0, 0, 0)
-
-    def out_index(s, sp, jb, table_ref, pos_ref):
-        return (s, sp, 0, 0, 0)
-
-    in_specs = [
-        pl.BlockSpec((1, hq, kvh, dh), q_index),
-        pl.BlockSpec((1, block_size, kvh, dh), kv_index),
-        pl.BlockSpec((1, block_size, kvh, dh), kv_index),
-    ]
-    operands = [qt, kc, vc]
-    if int8:
-        in_specs += [pl.BlockSpec((1, block_size, kvh, 1), kv_index),
-                     pl.BlockSpec((1, block_size, kvh, 1), kv_index)]
-        operands += [k_scale, v_scale]
+    per_slot = lambda *shape: pl.BlockSpec(
+        (1,) + shape, lambda s, *_: (s,) + (0,) * len(shape))
+    in_specs = [per_slot(n_heads, width), per_slot(1, width),
+                per_slot(1, width)]
+    operands = [q_bd, row(k_new), row(v_new)]
     if alibi:
-        in_specs.append(pl.BlockSpec(
-            (hq, kvh, 1), lambda s, sp, jb, t, p: (0, 0, 0)))
-        operands.append(alibi_slopes.reshape(kvh, hq).T[..., None])
+        in_specs.append(pl.BlockSpec((n_heads, 1), lambda s, *_: (0, 0)))
+        operands.append(slopes.reshape(n_heads, 1))
+    in_specs += [pl.BlockSpec(memory_space=pl.ANY)] * 2
+    operands += [kc, vc]
 
-    # the m/l partials keep a LANES-broadcast minor dim in scratch (TPU vreg
-    # layout; see flash_attention.py). Interpret mode emits a single lane to
-    # HBM; a real TPU emits the full broadcast — a 1-lane minor output dim
-    # is a layout Mosaic tiling commonly rejects
-    stat_lanes = 1 if interpret else LANES
-    out_shape = [
-        jax.ShapeDtypeStruct((s_dim, splits, hq, kvh, dh), jnp.float32),
-        jax.ShapeDtypeStruct((s_dim, splits, hq, kvh, stat_lanes),
-                             jnp.float32),
-        jax.ShapeDtypeStruct((s_dim, splits, hq, kvh, stat_lanes),
-                             jnp.float32),
-    ]
-    out_specs = [
-        pl.BlockSpec((1, 1, hq, kvh, dh), out_index),
-        pl.BlockSpec((1, 1, hq, kvh, stat_lanes), out_index),
-        pl.BlockSpec((1, 1, hq, kvh, stat_lanes), out_index),
-    ]
-
+    chunk = chunk_blocks * block_size
     kernel = functools.partial(
         _decode_kernel, scale=scale, block_size=block_size,
-        blocks_per_split=bps, int8=int8, alibi=alibi, stat_lanes=stat_lanes)
-    acc, m_p, l_p = pl.pallas_call(
+        chunk_blocks=chunk_blocks, head_dim=dh, alibi=alibi)
+    out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=grid,
+            num_scalar_prefetch=3,
+            grid=(s_dim,),
             in_specs=in_specs,
-            out_specs=out_specs,
+            out_specs=per_slot(hq, width),
             scratch_shapes=[
-                pltpu.VMEM((hq, kvh, LANES), jnp.float32),
-                pltpu.VMEM((hq, kvh, LANES), jnp.float32),
-                pltpu.VMEM((hq, kvh, dh), jnp.float32),
+                pltpu.VMEM((2, chunk, width), kc.dtype),
+                pltpu.VMEM((2, chunk, width), vc.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.VMEM((n_heads, width), jnp.float32),
+                pltpu.SMEM((1,), jnp.int32),
             ],
         ),
-        out_shape=out_shape,
+        out_shape=jax.ShapeDtypeStruct((s_dim, hq, width), jnp.float32),
+        # slots in order: a step starts the next live slot's first copies
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(table, pos, *operands)
-
-    # -- combine across the split-KV grid (tiny fp32 reduction) ------------
-    m_p = m_p[..., 0]                                    # [S, sp, hq, kvh]
-    l_p = l_p[..., 0]
-    m_c = jnp.max(m_p, axis=1)                           # [S, hq, kvh]
-    w = jnp.exp(m_p - m_c[:, None])                      # empty splits -> 0
-    l_c = jnp.sum(l_p * w, axis=1)
-    acc_c = jnp.sum(acc * w[..., None], axis=1)          # [S, hq, kvh, dh]
-
-    # -- fold the CURRENT token's fresh k/v row (compute dtype, position
-    # pos — the row the gather path writes into the view pre-attention;
-    # alibi distance is 0 there). Elementwise mul+sum, not a dot: this is
-    # [S, hq, kvh] of work, VPU noise.
-    s_new = jnp.sum(qt.astype(jnp.float32)
-                    * k_new.astype(jnp.float32)[:, None],
-                    axis=-1) * scale                     # [S, hq, kvh]
-    m_t = jnp.maximum(m_c, s_new)
-    corr = jnp.exp(m_c - m_t)
-    w_new = jnp.exp(s_new - m_t)
-    l_t = l_c * corr + w_new
-    acc_t = acc_c * corr[..., None] \
-        + w_new[..., None] * v_new.astype(jnp.float32)[:, None]
-    out = acc_t / jnp.maximum(l_t, 1e-30)[..., None]     # [S, hq, kvh, dh]
-    return out.transpose(0, 2, 1, 3).reshape(s_dim, n_heads, dh) \
-        .astype(q.dtype)
+        name="paged_flash_decode",
+    )(layer, table, pos, *operands)
+    # [S, hq, kvh, dh] -> head h = g * hq + j
+    return out.reshape(s_dim, hq, kvh, dh).transpose(0, 2, 1, 3) \
+        .reshape(s_dim, n_heads, dh).astype(q.dtype)

@@ -75,9 +75,12 @@ _HOST_SPACE_RE = re.compile(r"\{[\d,]*:\s*S\(5\)\}")  # host memory space
 _TRANSFER_OPS = ("infeed", "outfeed", "send", "recv")
 
 # the attention-logits einsum (bqhd,bkhd->bhqk) runs f32 on purpose — softmax
-# numerics — in every zoo model; programs configured bf16 allowlist it so the
+# numerics — in every zoo model, and so do the score and PV products of the
+# paged decode kernel (bf16 operands, f32 accumulators, under its named scope
+# ``paged_flash_decode``); programs configured bf16 allowlist both so the
 # dtype-leak rule flags real upcasts, not this known island
-ATTENTION_F32_ALLOW = ("dtype-leak:bqhd,bkhd->bhqk",)
+ATTENTION_F32_ALLOW = ("dtype-leak:bqhd,bkhd->bhqk",
+                       "dtype-leak:paged_flash_decode")
 
 DEFAULTS = {
     "compute_dtype": "bf16",        # program's configured compute dtype

@@ -108,34 +108,30 @@ class ServingEngine:
         self.paged = bool(self.cfg.kv_pool.enabled)
         self.pool_mgr = KVPoolManager(self.cfg.kv_pool, self.n_slots,
                                       self.max_len) if self.paged else None
-        # decode-attention backend: "dense" (no paging), "gather" (dense
-        # per-slot view through the block table), or "fused" (the split-KV
-        # flash-decode kernel walks the table in-kernel). A requested
-        # "fused" is put to the compiler ONCE here at this engine's
-        # geometry; a refusal is logged with the compiler's reason and the
-        # gather path serves. ``attn_backend`` (also in snapshot()
-        # ["kv_pool"]) always names the path that actually runs.
-        self.attn_backend = "dense"
+        # which decode attention runs is this engine's choice, from what it
+        # can observe: "dense" (no paging), "kernel" (the flash-decode
+        # kernel walks the block table and reads the live blocks only) or
+        # "view" (the n_slots x max_len gather view through the table, then
+        # the dense attention). The kernel is put to the compiler ONCE here,
+        # at this engine's geometry; where it is refused ``attn_reason``
+        # says why and the view serves. ``attn_backend`` (also in
+        # snapshot()["kv_pool"]) always names the path that runs; a
+        # speculative verify step takes the view whatever decode takes.
+        self.attn_backend, self.attn_reason = "dense", ""
+        self._decode_dispatches = {"kernel": 0, "view": 0}
         if self.paged:
-            self.attn_backend = self.cfg.kv_pool.attention_backend
-            if self.attn_backend == "fused":
-                import logging
+            self.attn_backend, self.attn_reason = self._choose_attention(
+                engine)
+        if self.paged and self.cfg.kv_pool.attention_backend:
+            import logging
 
-                from ..ops.pallas.paged_attention import \
-                    fused_decode_supported
-
-                ok, reason = fused_decode_supported(
-                    engine.module.config, self.pool_mgr.block_size,
-                    n_slots=self.n_slots,
-                    blocks_per_slot=self.pool_mgr.blocks_per_slot,
-                    tp=max(engine.mp_world_size, 1),
-                    kv_dtype=self.cfg.kv_pool.kv_dtype)
-                if not ok:
-                    log_dist(
-                        "ServingEngine: kv_pool.attention_backend='fused' "
-                        f"refused ({reason}); serving through the gather "
-                        "path", ranks=[0], level=logging.WARNING)
-                    self.attn_backend = "gather"
+            log_dist(
+                "ServingEngine: kv_pool.attention_backend="
+                f"{self.cfg.kv_pool.attention_backend!r} has no effect: the "
+                f"engine chose {self.attn_backend!r} from the model, the "
+                "pool and the compiler's verdict"
+                + (f" ({self.attn_reason})" if self.attn_reason else ""),
+                ranks=[0], level=logging.WARNING)
         if self.paged and self.cfg.scrub_freed_slots:
             # block-granularity scrub: zero each physical block as its last
             # reference drops (the dense pool's whole-row scrub generalized)
@@ -265,13 +261,19 @@ class ServingEngine:
             else None
         self._cache_sharding = NamedSharding(
             mesh, P(None, None, None, kv_axis, None))
+        # the paged pool stores a token's kv heads merged with the head size
+        # (and an int8 pool's scales one a head): the same axis, 3, splits
+        # into contiguous groups of heads
+        self._pool_sharding = NamedSharding(
+            mesh, P(None, None, None, kv_axis)) if self.paged \
+            else self._cache_sharding
         self._rep_sharding = NamedSharding(mesh, P())
         kv_names = ("k", "v", "k_scale", "v_scale") \
             if self.paged and self.cfg.kv_pool.kv_dtype == "int8" \
             else ("k", "v")
         extra = ("table",) if self.paged else ()
         self._state_shardings = {
-            name: self._cache_sharding if name in kv_names
+            name: self._pool_sharding if name in kv_names
             else self._rep_sharding
             for name in kv_names + extra + (
                 "pos", "tok", "active", "remaining", "rng", "temp", "top_k",
@@ -303,20 +305,34 @@ class ServingEngine:
                 f"clock={'virtual' if isinstance(self.clock, VirtualClock) else 'wall'}",
                 ranks=[0])
 
+    def _choose_attention(self, engine):
+        """``(path, reason)`` of the paged decode program: the kernel where
+        the model, the pool and the compiler allow it, else the view with
+        the reason (``ops/pallas/paged_attention.fused_decode_supported``)."""
+        if self._latent:
+            return "view", ("latent attention decodes in the absorbed form "
+                            "over its own view (models/latent.py)")
+        from ..ops.pallas.paged_attention import fused_decode_supported
+
+        ok, reason = fused_decode_supported(
+            engine.module.config, self.pool_mgr.block_size,
+            n_slots=self.n_slots,
+            blocks_per_slot=self.pool_mgr.blocks_per_slot,
+            tp=max(engine.mp_world_size, 1),
+            kv_dtype=self.cfg.kv_pool.kv_dtype)
+        return ("kernel", "") if ok else ("view", reason)
+
     def _refuse_for_latent(self, engine):
         """What this engine cannot do with a latent-attention model refuses
         here, by name, instead of computing something else: the cache holds
         one latent row a token, which only the paged pool in the engine's
-        dtype, read by the gather backend on one model shard, knows."""
+        dtype, read through its own view on one model shard, knows."""
         cfg = self.cfg
         why = None
         if not cfg.kv_pool.enabled:
             why = "the dense slot pool (serving.kv_pool.enabled=false)"
         elif cfg.kv_pool.kv_dtype == "int8":
             why = "an int8 pool (serving.kv_pool.kv_dtype='int8')"
-        elif cfg.kv_pool.attention_backend != "gather":
-            why = ("the fused decode kernel (serving.kv_pool."
-                   f"attention_backend={cfg.kv_pool.attention_backend!r})")
         elif cfg.speculative.enabled:
             why = "speculative verify (serving.speculative.enabled)"
         elif engine.mp_world_size > 1:
@@ -380,12 +396,16 @@ class ServingEngine:
         return out
 
     def _kv_pool_stats(self):
-        """``KVPoolManager.stats()`` + the active attention backend — the
-        kv_pool block every consumer reads (``snapshot()["kv_pool"]``,
-        Serving/* events, bench artifacts), so committed numbers always
-        record WHICH decode path produced them."""
+        """``KVPoolManager.stats()`` + the decode attention that runs, why
+        (where the view serves) and the decode dispatches by path (a
+        speculative verify step counts as ``view``): the kv_pool block every
+        consumer reads (``snapshot()["kv_pool"]``, Serving/* events, bench
+        artifacts), so committed numbers always record WHICH decode path
+        produced them."""
         st = self.pool_mgr.stats()
         st["attention_backend"] = self.attn_backend
+        st["attention_reason"] = self.attn_reason
+        st["decode_dispatches"] = dict(self._decode_dispatches)
         return st
 
     # ------------------------------------------------------------------ state
@@ -521,7 +541,7 @@ class ServingEngine:
     def _build_pool_programs(self):
         model, max_len = self.engine.module, self.max_len
         paged = self.paged
-        attn_backend = self.attn_backend
+        kernel = self.attn_backend == "kernel"
         bs = self.pool_mgr.block_size if paged else 0
         pool_keys = ("k", "v", "k_scale", "v_scale") \
             if paged and self.cfg.kv_pool.kv_dtype == "int8" else ("k", "v")
@@ -545,7 +565,7 @@ class ServingEngine:
                 logits, cache, *routed = forward_with_paged_cache(
                     model, params, state["tok"][:, None],
                     {k: state[k] for k in pool_keys}, state["table"],
-                    state["pos"], bs, attention_backend=attn_backend,
+                    state["pos"], bs, kernel=kernel,
                     return_routing=self._routing)
             else:
                 logits, cache = forward_with_cache(
@@ -1668,16 +1688,15 @@ class ServingEngine:
                 pad = np.zeros((a.shape[0], mgr.blocks_per_slot)
                                + a.shape[2:], a.dtype)
                 pad[:, :a.shape[1]] = a
-                raw[name] = jax.device_put(pad, self._cache_sharding)
+                raw[name] = jax.device_put(pad, self._pool_sharding)
             self._state = self._migrate_in_jit(self._state, raw, ids, srcs)
             return
         dense = {}
-        for name in ("k", "v"):
-            a = snap.blocks[name]
-            d = np.zeros((a.shape[0], 1, self.max_len) + a.shape[3:],
+        for name, row in self.engine.module.config.cache_geometry.items():
+            a = snap.blocks[name]                 # [L, NB, bs, kvh * dh]
+            d = np.zeros((a.shape[0], 1, self.max_len) + row,
                          np.dtype(self.engine.dtype))
-            d[:, 0, :a.shape[1] * bs] = \
-                a.reshape((a.shape[0], -1) + a.shape[3:])
+            d[:, 0, :a.shape[1] * bs] = a.reshape((a.shape[0], -1) + row)
             dense[name] = jax.device_put(d, self._cache_sharding)
         self._state = self._insert_block_jit(
             self._state, dense["k"], dense["v"], ids, srcs)
@@ -1991,6 +2010,7 @@ class ServingEngine:
             sum(1 for s in self._slots if nonfinite[s] > 0))
         self.metrics.record_verify_step()
         self.metrics.record_decode_dispatch()
+        self._decode_dispatches["view"] += 1
         for slot in sorted(self._slots):
             req = self._slots[slot]
             pos0 = req.prompt_len + len(req.tokens) - 1  # this step's cursor
@@ -2071,6 +2091,8 @@ class ServingEngine:
                                                 self._state)
             self.clock.advance(self.cfg.virtual_decode_step_cost)
         self.metrics.record_decode_dispatch()
+        if self.paged:
+            self._decode_dispatches[self.attn_backend] += 1
         self._dispatch_chunk_ahead()
         # one read-back for all the step hands out (a routing model: its
         # expert choices too)

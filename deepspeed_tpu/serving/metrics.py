@@ -605,10 +605,10 @@ class ServingMetrics:
                 ("Serving/prefix_hit_rate", float(kv["prefix_hit_rate"]),
                  self.steps),
                 # which decode-attention path produced these numbers
-                # (1 = the fused paged kernel, 0 = the gather path) —
+                # (1 = the table-walking kernel, 0 = the gather view) —
                 # coherent with snapshot()["kv_pool"]["attention_backend"]
                 ("Serving/kv_attention_fused",
-                 1.0 if kv.get("attention_backend") == "fused" else 0.0,
+                 1.0 if kv.get("attention_backend") == "kernel" else 0.0,
                  self.steps),
             ]
         if self.speculative_armed:

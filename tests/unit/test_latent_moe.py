@@ -426,8 +426,6 @@ def refused(**changes):
 @pytest.mark.parametrize("what,serving,kw", [
     ("dense slot pool", refused(kv_pool={"enabled": False}), {}),
     ("int8 pool", refused(kv_pool={"kv_dtype": "int8"}), {}),
-    ("fused decode kernel", refused(kv_pool={"attention_backend": "fused"}),
-     {}),
     ("speculative verify", refused(speculative={"enabled": True, "k": 2}),
      {}),
     ("live KV migration", refused(migration={
@@ -439,6 +437,28 @@ def test_what_the_engine_cannot_do_refuses_by_name(what, serving, kw):
     eng = engine(serving, **kw)
     with pytest.raises(ValueError, match=what):
         eng.serving
+    eng.destroy()
+
+
+@pytest.mark.parametrize("value", ["gather", "fused"])
+def test_attention_backend_option_selects_nothing_for_a_latent_model(value):
+    """``kv_pool.attention_backend`` names no path any more: a latent model
+    given either value serves through its own view, as without it, and the
+    snapshot says which path that is and why. The decode kernel asked for by
+    name (the engine never does) is refused."""
+    eng = engine(refused(kv_pool={"attention_backend": value}))
+    sv = eng.serving
+    assert sv.attn_backend == "view" and "latent attention" in sv.attn_reason
+    kv = sv.metrics.snapshot()["kv_pool"]
+    assert kv["attention_backend"] == "view"
+    assert kv["decode_dispatches"] == {"kernel": 0, "view": 0}
+    model = eng.module
+    pool = D.init_paged_cache(model.config, 3, 16, jnp.float32)
+    with pytest.raises(ValueError, match="decode kernel"):
+        D.forward_with_paged_cache(
+            model, eng.params, jnp.zeros((1, 1), jnp.int32), pool,
+            jnp.zeros((1, 2), jnp.int32), jnp.asarray([3], jnp.int32), 16,
+            kernel=True)
     eng.destroy()
 
 

@@ -425,12 +425,12 @@ def test_serving_decode_paged_within_sanitizer_budget(decode_report_paged):
 
 @pytest.fixture(scope="module")
 def decode_report_fused(devices8):
-    """tools/program_lint.py --program decode --paged --attention-backend
-    fused geometry: the PAGED decode program through the split-KV
-    flash-decode kernel (block-table walk IN-KERNEL, no dense per-slot
-    view) held to the checked-in serving-decode-fused/8/bf16 budget —
-    the fence for ROADMAP item 1's fused rewrite, enforced tier-1
-    alongside the gather gate."""
+    """tools/program_lint.py --program decode --paged --attention-interpret
+    geometry: the PAGED decode program through the flash-decode kernel
+    (block-table walk IN-KERNEL, no dense per-slot view; the engine chooses
+    it where the kernel can run) held to the checked-in
+    serving-decode-fused/8/bf16 budget, enforced tier-1 alongside the view
+    path's gate."""
     import jax.numpy as jnp
 
     import deepspeed_tpu
@@ -446,9 +446,8 @@ def decode_report_fused(devices8):
                 "serving": {"n_slots": 4, "max_len": 64,
                             "virtual_clock": True,
                             "kv_pool": {"enabled": True,
-                                        "block_size": 16,
-                                        "attention_backend": "fused"}}})
-    assert engine.serving.attn_backend == "fused"
+                                        "block_size": 16}}})
+    assert engine.serving.attn_backend == "kernel"
     report = engine.decode_program_report()
     yield report
     engine.destroy()

@@ -115,37 +115,174 @@ def test_pallas_ce_lowers():
     assert n_mosaic(text) == 1
 
 
-@pytest.mark.parametrize("nh,kvh,dh,int8", [
-    (16, 16, 128, False),      # BLOOM-1.7B: the geometry PR 15's per-head
-    (32, 32, 64, False),       # pool blocking could never lower; OPT-1.3B
-    (32, 8, 128, True),        # GQA + int8 pool
+def _paged_decode_operands(nh, kvh, dh, sharding=None, layers=24, slots=32,
+                           n_blocks=1537, cols=128, bs=16):
+    """The decode kernel's operands at the OPT-1.3B serve cell's geometry
+    unless told otherwise: 32 slots of 128 table columns over 1537 blocks
+    of 16 tokens, the pool leaves whole."""
+    sds = lambda shape, dt, sh=sharding: SDS(shape, dt, sharding=sh)
+    pool = sds((layers, n_blocks, bs, kvh * dh), jnp.bfloat16)
+    row = sds((slots, kvh, dh), jnp.bfloat16)
+    return (sds((slots, nh, dh), jnp.bfloat16), row, row, pool, pool,
+            SDS((slots, cols), jnp.int32), SDS((slots,), jnp.int32),
+            SDS((), jnp.int32))
+
+
+@pytest.mark.parametrize("nh,kvh,dh,alibi", [
+    (32, 32, 64, False),       # OPT-1.3B: the serve cell
+    (32, 32, 128, False),      # the same heads at head size 128
+    (16, 16, 128, True),       # BLOOM-1.7B: alibi
+    (32, 8, 128, False),       # GQA
+    (8, 1, 128, False),        # MQA
 ])
-def test_paged_decode_lowers(nh, kvh, dh, int8):
+def test_paged_decode_lowers(nh, kvh, dh, alibi):
+    from deepspeed_tpu.models.layers import alibi_slopes
     from deepspeed_tpu.ops.pallas.paged_attention import paged_flash_decode
 
-    s, nb, bs = 8, 32, 16
-    pool = SDS((s * nb + 1, bs, kvh, dh), jnp.int8 if int8 else jnp.bfloat16)
-    scale = SDS(pool.shape[:-1] + (1,), jnp.float32) if int8 else None
-    row = SDS((s, kvh, dh), jnp.bfloat16)
+    slopes = alibi_slopes(nh) if alibi else None
 
-    def call(q, kn, vn, kc, vc, table, pos, ks, vs):
-        return paged_flash_decode(q, kn, vn, kc, vc, table, pos, k_scale=ks,
-                                  v_scale=vs)
+    def call(q, kn, vn, kc, vc, table, pos, layer):
+        return paged_flash_decode(q, kn, vn, kc, vc, table, pos, layer=layer,
+                                  alibi_slopes=slopes)
 
-    text = lower_for_tpu(call, SDS((s, nh, dh), jnp.bfloat16), row, row, pool,
-                         pool, SDS((s, nb), jnp.int32), SDS((s,), jnp.int32),
-                         scale, scale)
-    assert n_mosaic(text) == 1
+    assert n_mosaic(lower_for_tpu(
+        call, *_paged_decode_operands(nh, kvh, dh))) == 1
 
 
-def _block_write_operands(int8, sharding=None):
-    L, n_blocks, bs, kvh, dh, max_len = 24, 1537, 16, 32, 64, 2048
-    shape = (L, n_blocks, bs, kvh, dh)
+def _abstract_params(model, dtype=jnp.bfloat16, sharding=None):
+    from deepspeed_tpu.models.layers import Param
+
+    return jax.tree_util.tree_map(
+        lambda a: SDS(a.shape, dtype, sharding=sharding),
+        jax.eval_shape(lambda r: jax.tree_util.tree_map(
+            lambda p: p.value, model.init(r),
+            is_leaf=lambda x: isinstance(x, Param)), jax.random.PRNGKey(0)))
+
+
+def _decode_step(model, bs, kernel=True):
+    from deepspeed_tpu.models import decoding as D
+
+    def decode(params, tok, pool, table, pos):
+        logits, pool = D.forward_with_paged_cache(
+            model, params, tok, pool, table, pos, bs, kernel=kernel)
+        return jnp.argmax(logits[:, 0], -1), pool
+
+    return decode
+
+
+def _decode_operands(model, sharding=None, slots=32, n_blocks=1537, cols=128,
+                     bs=16):
+    cfg = model.config
     sds = lambda shape, dt: SDS(shape, dt, sharding=sharding)
+    pool = {n: sds((cfg.n_layers, n_blocks, bs) + row, jnp.bfloat16)
+            for n, row in cfg.pool_geometry.items()}
+    return (_abstract_params(model, sharding=sharding),
+            sds((slots, 1), jnp.int32), pool, sds((slots, cols), jnp.int32),
+            sds((slots,), jnp.int32))
+
+
+def test_whole_decode_program_lowers_at_the_serve_cells_geometry():
+    """OPT-1.3B's decode step as the serve cell runs it (24 layers, 32 slots
+    of 2048 positions over 1537 blocks of 16): ONE Mosaic call in the layer
+    loop, the pool leaves [24, 1537, 16, 2048] whole, and no tensor of
+    ``n_slots x max_len`` rows anywhere in the program."""
+    model = get_model("opt", "1.3b", compute_dtype=jnp.bfloat16)
+    assert model.config.pool_geometry == {"k": (2048,), "v": (2048,)}
+    text = lower_for_tpu(_decode_step(model, 16), *_decode_operands(model))
+    assert n_mosaic(text) == 1
+    assert "24x1537x16x2048xbf16" in text
+    for view in ("32x128x16x2048", "32x2048x32x64", "32x2048x2048",
+                 "4096x16x"):
+        assert view not in text, view
+    # the view path at the same geometry is the program that holds them
+    view_text = lower_for_tpu(_decode_step(model, 16, kernel=False),
+                              *_decode_operands(model))
+    assert n_mosaic(view_text) == 0 and "32x128x16x2048" in view_text
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """One described (not attached) v5e chip to COMPILE for. Made inside a
+    fixture, after a test of this file has started, and only here: a
+    process that loads the TPU's library keeps it."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def test_compiled_kernel_decode_updates_the_pool_in_place(v5e):
+    """The kernel decode program COMPILED for a v5e at the serve cell's pool
+    and table (2 of the 24 layers: the loop body is the same): the donated
+    pool is aliased to the output, nothing the size of a pool leaf or of a
+    layer of one is copied, sliced or gathered, no ``n_slots x max_len``
+    view exists, and the temporaries are a few hundred KB where the view
+    path's were gigabytes."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    model = get_model("opt", "1.3b", n_layers=2, compute_dtype=jnp.bfloat16)
+    cached = jax.config.jax_enable_compilation_cache
+    # a compile-only executable cannot be read back from the persistent
+    # cache without a chip: keep it out
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        with lowering_target("tpu"):
+            compiled = jax.jit(_decode_step(model, 16), donate_argnums=(2,)) \
+                .trace(*_decode_operands(model, sharding=v5e)) \
+                .lower(lowering_platforms=("tpu",)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cached)
+        cc.reset_cache()
+    leaf = 2 * 1537 * 16 * 2048 * 2
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * leaf
+    assert mem.temp_size_in_bytes < 8 << 20, mem.temp_size_in_bytes
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 1
+    made = [line.split(" = ")[1] for line in text.splitlines()
+            if " = " in line]
+    for m in made:
+        head = m.split("(")[0].split()
+        if len(head) < 2:                  # not "<shape> <op>(": no array
+            continue
+        shape, op = head[0].split("{")[0], head[-1]
+        assert not shape.startswith(("bf16[32,2048,", "bf16[32,128,16,",
+                                     "bf16[4096,16,")), m
+        if shape in ("bf16[2,1537,16,2048]", "bf16[1,1537,16,2048]",
+                     "bf16[1537,16,2048]"):
+            assert op.startswith(("parameter", "get-tuple-element", "fusion",
+                                  "scatter", "bitcast", "while")) \
+                and "copy" not in op, m
+    # the pool's layout, as the device will keep it: the row minor-most
+    pool_format = compiled.input_formats[0][2]["k"]
+    assert tuple(pool_format.layout.major_to_minor) == (0, 1, 2, 3)
+
+
+def _block_write_operands(int8, sharding=None, latent=False):
+    L, n_blocks, bs, kvh, dh, max_len = 24, 1537, 16, 32, 64, 2048
+    sds = lambda shape, dt: SDS(shape, dt, sharding=sharding)
+    if latent:
+        # a latent pool with small blocks: 5-D leaves the device lays out
+        # with the blocks in the lanes (PERF.md, PR 29)
+        rows = {"k": (1, 512), "v": (1, 64)}
+        pool = {n: sds((7, 2561, bs) + r, jnp.bfloat16)
+                for n, r in rows.items()}
+        cache = {n: sds((7, 1, max_len) + r, jnp.bfloat16)
+                 for n, r in rows.items()}
+        ids = SDS((max_len // bs,), jnp.int32)
+        return pool, cache, ids, ids
+    shape = (L, n_blocks, bs, kvh * dh)
     pool = {n: sds(shape, jnp.int8 if int8 else jnp.bfloat16)
             for n in ("k", "v")}
     if int8:
-        pool.update({n + "_scale": sds(shape[:-1] + (1,), jnp.float32)
+        pool.update({n + "_scale": sds(shape[:-1] + (kvh,), jnp.float32)
                      for n in ("k", "v")})
     cache = {n: sds((L, 1, max_len, kvh, dh), jnp.bfloat16)
              for n in ("k", "v")}
@@ -153,15 +290,18 @@ def _block_write_operands(int8, sharding=None):
     return pool, cache, ids, ids
 
 
-@pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
-def test_kv_block_write_lowers(int8):
-    """The column kernel at the serve cell's pool (OPT-1.3B, 1537 blocks of
-    16): one Mosaic call a pool leaf."""
+@pytest.mark.parametrize("int8,latent", [
+    (False, False), (True, False), (False, True)],
+    ids=["bf16", "int8", "latent"])
+def test_kv_block_write_lowers(int8, latent):
+    """The column kernel, for a pool said to keep its blocks in the lanes,
+    at the serve cell's pool (OPT-1.3B, 1537 blocks of 16, rows merged) and
+    at a latent pool's 5-D leaves: one Mosaic call a pool leaf."""
     from deepspeed_tpu.models.decoding import insert_block_kv
 
     text = lower_for_tpu(
         lambda p, c, i, s: insert_block_kv(p, c, i, s, 16, lanes=True),
-        *_block_write_operands(int8))
+        *_block_write_operands(int8, latent=latent))
     assert n_mosaic(text) == (4 if int8 else 2)
 
 
@@ -181,8 +321,8 @@ def test_kv_block_write_gives_way_loudly_not_silently():
     handler.emit = lambda record: seen.append(record.getMessage())
     logger.addHandler(handler)
     rng = np.random.RandomState(0)
-    pool = {"k": jnp.asarray(rng.randn(2, 9, 8, 2, 16), jnp.float32)}
-    src = {"k": jnp.asarray(rng.randn(2, 4, 8, 2, 16), jnp.float32)}
+    pool = {"k": jnp.asarray(rng.randn(2, 9, 8, 32), jnp.float32)}
+    src = {"k": jnp.asarray(rng.randn(2, 4, 8, 32), jnp.float32)}
     ids, srcs = jnp.asarray([3, 9, 7, 10]), jnp.asarray([1, 0, 2, 0])
     try:
         got = write_pool_blocks(pool, src, ids, srcs, lanes=True)
@@ -348,20 +488,46 @@ def test_tp4_flash_prefill_lowers(devices8):
         eng.destroy()
 
 
-def test_tp4_fused_decode_lowers(devices8):
-    """kv_pool.attention_backend='fused' under TP=4: probed at the engine's
-    per-chip geometry, kept, and its decode program lowers for the TPU."""
+def test_tp4_kernel_decode_lowers(devices8):
+    """TP=4: the kernel is probed at the engine's per-chip geometry (8 of 32
+    heads, a 512-wide share of the pool's merged axis), chosen, and its
+    decode program lowers for the TPU inside a shard_map; an
+    ``attention_backend`` value changes nothing of it."""
     with lowering_target("tpu"):
-        eng = _tp4_engine(devices8, attention_backend="fused")
+        eng = _tp4_engine(devices8, attention_backend="gather")
         try:
             sv = eng.serving
-            assert sv.attn_backend == "fused"
+            assert (sv.attn_backend, sv.attn_reason) == ("kernel", "")
+            assert sv._state["k"].shape == (2, 65, 16, 2048)
+            assert sv._state["k"].sharding.spec == P(None, None, None,
+                                                     "model")
             sv._build_pool_programs()
             text = sv._decode_jit.trace(eng.params, sv._state).lower(
                 lowering_platforms=("tpu",)).as_text()
             assert n_mosaic(text) == 1
+            assert "shard_map" in text or "sdy.manual_computation" in text
         finally:
             eng.destroy()
+
+
+def test_kernel_lowers_on_a_four_device_model_mesh(devices8):
+    """The kernel alone at the serve cell's geometry with the pool's merged
+    axis (contiguous groups of kv heads) and the query heads split over
+    ``model`` = 4: per shard in a shard_map, never under GSPMD."""
+    from deepspeed_tpu.ops.pallas.paged_attention import paged_flash_decode
+
+    mesh = build_mesh(MeshConfig(model=4), devices=devices8[:4])
+    q, kn, vn, kc, vc, table, pos, layer = _paged_decode_operands(32, 32, 64)
+    on = lambda a, *spec: SDS(a.shape, a.dtype,
+                              sharding=NamedSharding(mesh, P(*spec)))
+    text = lower_for_tpu(
+        lambda *a: paged_flash_decode(*a[:7], layer=a[7], mesh=mesh),
+        on(q, None, "model"), on(kn, None, "model"), on(vn, None, "model"),
+        on(kc, None, None, None, "model"), on(vc, None, None, None, "model"),
+        table, pos, layer)
+    assert n_mosaic(text) == 1
+    assert "shard_map" in text or "sdy.manual_computation" in text
+    assert "24x1537x16x512xbf16" in text       # a shard's share of the pool
 
 
 def test_tp4_kv_block_write_lowers(devices8):
@@ -371,7 +537,7 @@ def test_tp4_kv_block_write_lowers(devices8):
     from deepspeed_tpu.models.decoding import insert_block_kv
 
     mesh = build_mesh(MeshConfig(model=4), devices=devices8[:4])
-    heads = NamedSharding(mesh, P(None, None, None, "model", None))
+    heads = NamedSharding(mesh, P(None, None, None, "model"))
     text = lower_for_tpu(
         lambda p, c, i, s: insert_block_kv(p, c, i, s, 16, lanes=True,
                                            mesh=mesh),

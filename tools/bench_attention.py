@@ -10,14 +10,17 @@ only (one process; refuses any other platform):
 Prints one line per (seq, impl): ms/iter and achieved TFLOP/s; causal
 attention flops = 2 * 0.5 * s^2 * d * 3 matmuls fwd (+~2.5x bwd).
 
-``BENCH_DECODE=1`` switches to the serving decode shape — 1 query row per
-slot against a long paged KV window — and A/Bs the three decode-attention
-paths (dense slot-pool read, paged-gather dense view, fused split-KV
-kernel) in bf16 AND int8 across slot counts x context lengths
-(``BENCH_DECODE_SLOTS``/``BENCH_DECODE_CTX``/``BENCH_DECODE_BLOCK``).
-Decode is bandwidth-bound, so the printed GB/s (ideal KV bytes touched /
-measured time) is the number that matters: the gather path pays the dense
-view's write+read on top, the fused kernel streams the pool once.
+``BENCH_DECODE=1`` switches to the serving decode shape: 1 query row per
+slot against a paged KV pool ``[L, n_blocks, block, kvh * dh]``, and A/Bs the
+two decode-attention paths of ``models/decoding.forward_with_paged_cache``
+(the ``n_slots x max_len`` gather view, the table-walking kernel) at the OPT
+serve cell's geometry by default (32 x 64 heads, 32 slots of 2048 positions,
+blocks of 16), every slot full and at the cell's ragged cursors (mean about
+510). Each impl runs ``BENCH_DECODE_LAYERS`` layers inside one program, as
+the decode step's layer loop does. Decode is bandwidth-bound, so the printed
+GB/s over the LIVE KV (bytes below the cursors / measured time) is the number
+that matters: the view moves the padded window, written and read again; the
+kernel streams the live blocks once.
 """
 
 import os
@@ -28,16 +31,11 @@ import numpy as np
 
 
 def decode_main():
-    """Decode-shape sweep (BENCH_DECODE=1): 1 query x long paged KV.
-
-    Impls per (slots, ctx, dtype):
-    - ``dense``  — the dense slot-pool read ([S, max_len] cache +
-      masked attention), the pre-paging baseline;
-    - ``gather`` — the paged gather path (``_paged_view``: dense per-slot
-      view through the block table, then the same attention);
-    - ``fused``  — the split-KV flash-decode kernel walking the table
-      in-kernel (``ops/pallas/paged_attention.py``).
-    """
+    """Decode-shape sweep (BENCH_DECODE=1): 1 query x paged KV, ``view``
+    against ``kernel``. Env: BENCH_DECODE_HEADS (``nh,kvh,dh``),
+    BENCH_DECODE_BLOCK, BENCH_DECODE_SLOTS, BENCH_DECODE_CTX,
+    BENCH_DECODE_LAYERS, BENCH_DECODE_CHUNKS (kernel ``chunk_tokens``
+    values), BENCH_DECODE_ITERS."""
     from _common import require_tpu, setup_compile_cache
 
     require_tpu("bench_attention")
@@ -48,16 +46,17 @@ def decode_main():
     sys.path.insert(0, os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
     from deepspeed_tpu.models import layers as L
-    from deepspeed_tpu.ops.pallas.paged_attention import paged_flash_decode
+    from deepspeed_tpu.ops.pallas.paged_attention import (CHUNK_TOKENS,
+                                                          paged_flash_decode)
 
-    h, kvh, dh = 16, 16, 128
-    bs = int(os.environ.get("BENCH_DECODE_BLOCK", 32))
-    slot_counts = [int(s) for s in os.environ.get(
-        "BENCH_DECODE_SLOTS", "4,16").split(",")]
-    ctxs = [int(s) for s in os.environ.get(
-        "BENCH_DECODE_CTX", "1024,4096").split(",")]
-    dtypes = os.environ.get("BENCH_DECODE_DTYPES", "bf16,int8").split(",")
-    n_iter = int(os.environ.get("BENCH_DECODE_ITERS", 16))
+    ints = lambda name, default: [int(x) for x in os.environ.get(
+        name, default).split(",")]
+    h, kvh, dh = ints("BENCH_DECODE_HEADS", "32,32,64")
+    bs = ints("BENCH_DECODE_BLOCK", "16")[0]
+    n_layers = ints("BENCH_DECODE_LAYERS", "24")[0]
+    n_iter = ints("BENCH_DECODE_ITERS", "8")[0]
+    chunks = ints("BENCH_DECODE_CHUNKS", str(CHUNK_TOKENS))
+    width, cdt = kvh * dh, jnp.bfloat16
 
     def bench(fn, *args):
         f = jax.jit(fn)
@@ -66,88 +65,77 @@ def decode_main():
         for _ in range(n_iter):
             out = f(*args)
         jax.block_until_ready(out)
-        return (time.perf_counter() - t0) / n_iter
+        return (time.perf_counter() - t0) / n_iter / n_layers
 
-    print(f"# decode shapes: h={h} dh={dh} block={bs} "
-          f"device={jax.devices()[0].device_kind}")
+    print(f"# decode shapes: {h}/{kvh} heads x {dh}, block {bs}, {n_layers} "
+          f"layers a program, device={jax.devices()[0].device_kind}")
     failed = 0
-    for s_dim in slot_counts:
-        for ctx in ctxs:
+    for s_dim in ints("BENCH_DECODE_SLOTS", "32"):
+        for ctx in ints("BENCH_DECODE_CTX", "2048"):
             nb_cols = ctx // bs
-            n_blocks = s_dim * nb_cols + 1
+            # the serve cell's pool: 3/4 of slots x window, + garbage block
+            n_blocks = s_dim * nb_cols * 3 // 8 + 1
             rng = np.random.RandomState(0)
-            table = np.arange(1, n_blocks).reshape(s_dim, nb_cols) \
-                .astype(np.int32)
-            pos = np.full((s_dim,), ctx - 1, np.int32)
-            for dt in dtypes:
-                cdt = jnp.bfloat16
-                q = jnp.asarray(rng.randn(s_dim, h, dh), cdt)
-                k_new = jnp.asarray(rng.randn(s_dim, kvh, dh), cdt)
-                v_new = jnp.asarray(rng.randn(s_dim, kvh, dh), cdt)
-                if dt == "int8":
-                    kc = jnp.asarray(rng.randint(
-                        -127, 127, (n_blocks, bs, kvh, dh)), jnp.int8)
-                    vc = jnp.asarray(rng.randint(
-                        -127, 127, (n_blocks, bs, kvh, dh)), jnp.int8)
-                    ks = jnp.asarray(
-                        np.abs(rng.randn(n_blocks, bs, kvh, 1)) * .01,
-                        jnp.float32)
-                    # distinct tensors: aliased k/v scales would let XLA
-                    # cache the duplicate reads and inflate reported GB/s
-                    vs = jnp.asarray(
-                        np.abs(rng.randn(n_blocks, bs, kvh, 1)) * .01,
-                        jnp.float32)
-                    kv_bytes = 2 * n_blocks * bs * kvh * (dh + 4)
-                else:
-                    kc = jnp.asarray(rng.randn(n_blocks, bs, kvh, dh), cdt)
-                    vc = jnp.asarray(rng.randn(n_blocks, bs, kvh, dh), cdt)
-                    ks = vs = None
-                    kv_bytes = 2 * n_blocks * bs * kvh * dh * 2
+            # distinct K and V pools: one aliased array would let XLA read
+            # the bytes once and double the reported GB/s
+            kc = jax.random.normal(jax.random.PRNGKey(1),
+                                   (n_layers, n_blocks, bs, width), cdt)
+            vc = jax.random.normal(jax.random.PRNGKey(2),
+                                   (n_layers, n_blocks, bs, width), cdt)
+            q = jnp.asarray(rng.randn(s_dim, h, dh), cdt)
+            k_new = jnp.asarray(rng.randn(s_dim, kvh, dh), cdt)
+            v_new = jnp.asarray(rng.randn(s_dim, kvh, dh), cdt)
+            cursors = {
+                "ragged": rng.randint(ctx // 32, ctx * 15 // 32, s_dim),
+                "half": np.full((s_dim,), ctx * 3 // 8 - 1)}
+            for live, pos in cursors.items():
+                pos = pos.astype(np.int32)
+                table = np.zeros((s_dim, nb_cols), np.int32)
+                free = 1 + rng.permutation(n_blocks - 1)
+                used = 0
+                for i in range(s_dim):
+                    n = pos[i] // bs + 1
+                    table[i, :n] = free[used:used + n]
+                    used += n
                 tj, pj = jnp.asarray(table), jnp.asarray(pos)
+                kv_bytes = 2 * int(pos.sum()) * width * 2
 
-                def gather(q, kc, vc, tj):
-                    g, gv = kc[tj], vc[tj]
-                    if ks is not None:
-                        g = (g.astype(jnp.float32) * ks[tj]).astype(cdt)
-                        gv = (gv.astype(jnp.float32) * vs[tj]).astype(cdt)
-                    g = g.reshape(s_dim, ctx, kvh, dh)
-                    gv = gv.reshape(s_dim, ctx, kvh, dh)
+                def layers(attend):
+                    return lambda q, kc, vc: jax.lax.fori_loop(
+                        0, n_layers,
+                        lambda i, acc: acc + attend(q, kc, vc, i)
+                        .astype(jnp.float32),
+                        jnp.zeros((s_dim, h, dh), jnp.float32))
+
+                def view(q, kc, vc, layer):
+                    g = kc[layer, tj].reshape(s_dim, ctx, kvh, dh)
+                    gv = vc[layer, tj].reshape(s_dim, ctx, kvh, dh)
                     mask = (jnp.arange(ctx)[None, None, None, :]
-                            <= pj[:, None, None, None])
+                            < pj[:, None, None, None])
+                    n_rep = h // kvh
                     return L.dot_product_attention(
-                        q[:, None], g, gv, mask=mask)
+                        q[:, None], L._repeat_kv(g, n_rep),
+                        L._repeat_kv(gv, n_rep), mask=mask)[:, 0]
 
-                def fused(q, kc, vc, tj):
-                    return paged_flash_decode(q, k_new, v_new, kc, vc, tj,
-                                              pj, k_scale=ks, v_scale=vs)
+                def kernel(chunk):
+                    return lambda q, kc, vc, layer: paged_flash_decode(
+                        q, k_new, v_new, kc, vc, tj, pj, layer=layer,
+                        chunk_tokens=chunk)
 
-                impls = [("gather", gather), ("fused", fused)]
-                if dt != "int8":
-                    # distinct K and V caches: one aliased array would let
-                    # XLA read the bytes once and double the reported GB/s
-                    dense_k = jnp.asarray(
-                        rng.randn(s_dim, ctx, kvh, dh), cdt)
-                    dense_v = jnp.asarray(
-                        rng.randn(s_dim, ctx, kvh, dh), cdt)
-
-                    def dense(q, kc, vc, tj):
-                        mask = (jnp.arange(ctx)[None, None, None, :]
-                                <= pj[:, None, None, None])
-                        return L.dot_product_attention(
-                            q[:, None], dense_k, dense_v, mask=mask)
-
-                    impls.insert(0, ("dense", dense))
+                impls = [("view", view)] + [
+                    (f"kernel/{c}", kernel(c)) for c in chunks]
                 for name, fn in impls:
+                    tag = (f"slots={s_dim:4d} ctx={ctx:6d} {live:6s} "
+                           f"live={int(pos.sum()):7d} {name:11s}")
                     try:
-                        sec = bench(fn, q, kc, vc, tj)
-                        print(f"slots={s_dim:4d} ctx={ctx:6d} {dt:5s} "
-                              f"{name:6s} {sec * 1e3:9.3f} ms "
-                              f"{kv_bytes / sec / 1e9:8.1f} GB/s")
+                        sec = bench(layers(fn), q, kc, vc)
+                        print(f"{tag} {sec * 1e3:9.4f} ms/layer "
+                              f"{kv_bytes / sec / 1e9:8.1f} GB/s live KV",
+                              flush=True)
                     except Exception as e:  # a failed row is a result
                         failed += 1
-                        print(f"slots={s_dim:4d} ctx={ctx:6d} {dt:5s} "
-                              f"{name:6s} FAILED: {type(e).__name__}: "
-                              f"{str(e)[:90]}")
+                        print(f"{tag} FAILED: {type(e).__name__}: "
+                              f"{str(e)[:90]}", flush=True)
     return 1 if failed else 0
 
 

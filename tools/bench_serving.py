@@ -188,9 +188,11 @@ def run_open_loop(args):
     mode = args.modes.split(",")[0]
     prompts = [int(p) for p in args.prompts.split(",")]
     max_tokens = ((max(prompts) + args.new_tokens + 63) // 64) * 64
-    # off a TPU the fused paged kernel only runs under the interpreter
+    # which decode attention runs is the engine's choice (on a TPU: the
+    # kernel where the compiler takes it); off a TPU the kernel only runs
+    # under the interpreter, which is all this flag still asks for
     model_kw = {"attention_interpret": True} \
-        if (args.attention_backend == "fused"
+        if (args.attention_backend in ("kernel", "fused")
             and jax.devices()[0].platform != "tpu") else {}
     engine, n_params, _ = build_engine(args.family, size, mode, max_tokens,
                                        **model_kw)
@@ -199,11 +201,10 @@ def run_open_loop(args):
         serving_kw["kv_pool"] = {
             "enabled": True, "block_size": args.kv_block_size,
             "n_blocks": args.kv_blocks, "kv_dtype": args.kv_dtype,
-            "on_demand_growth": bool(args.kv_growth),
-            "attention_backend": args.attention_backend}
-    elif args.attention_backend != "gather":
-        print("--attention-backend requires --paged (the fused kernel reads "
-              "the paged pool layout)", file=sys.stderr)
+            "on_demand_growth": bool(args.kv_growth)}
+    elif args.attention_backend in ("kernel", "fused"):
+        print("--attention-backend kernel requires --paged (the decode "
+              "kernel reads the paged pool)", file=sys.stderr)
         return 1
     if args.chunk_size:
         serving_kw["chunked_prefill"] = {"enabled": True,
@@ -535,13 +536,13 @@ def main():
     ap.add_argument("--kv-blocks", type=int, default=0,
                     help="0 = auto (dense-equivalent token capacity)")
     ap.add_argument("--kv-dtype", default="", choices=["", "int8"])
-    ap.add_argument("--attention-backend", default="gather",
-                    choices=["gather", "fused"],
-                    help="paged decode-attention backend (--paged): 'fused' "
-                         "serves through the split-KV flash-decode kernel; "
-                         "the artifact's kv_pool block records which path "
-                         "produced the numbers (unsupported shapes fall "
-                         "back to gather, also recorded)")
+    ap.add_argument("--attention-backend", default="view",
+                    choices=["view", "kernel", "gather", "fused"],
+                    help="off a TPU only: 'kernel' (alias 'fused') runs the "
+                         "paged flash-decode kernel under the interpreter "
+                         "(--paged). On a TPU the engine chooses the path; "
+                         "the artifact's kv_pool block records which one "
+                         "produced the numbers, and why where it is the view")
     ap.add_argument("--shared-prefix", type=int, default=0,
                     help="open every prompt with this many IDENTICAL "
                          "system-prompt tokens (exercises the prefix cache)")

@@ -132,7 +132,7 @@ def pallas_ce():
             run("pallas"), run("xla"), (x, emb, labels), 2e-2)
 
 
-def _paged_case(nh, kvh, dh, int8=False, alibi=False):
+def _paged_case(nh, kvh, dh, alibi=False):
     import jax
     import jax.numpy as jnp
 
@@ -140,43 +140,34 @@ def _paged_case(nh, kvh, dh, int8=False, alibi=False):
     from deepspeed_tpu.ops.pallas.paged_attention import paged_flash_decode
 
     rng = np.random.RandomState(0)
-    S, NB, bs = 8, 64, 16                      # 1024-token window per slot
+    S, NB, bs, n_layers = 8, 64, 16, 2         # 1024-token window per slot
     n_blocks = S * NB + 1
     dt = jnp.bfloat16
-    if int8:
-        kc = jnp.asarray(rng.randint(-127, 127, (n_blocks, bs, kvh, dh)),
-                         jnp.int8)
-        vc = jnp.asarray(rng.randint(-127, 127, (n_blocks, bs, kvh, dh)),
-                         jnp.int8)
-        ks = jnp.asarray(np.abs(rng.randn(n_blocks, bs, kvh, 1)) * .01,
-                         jnp.float32)
-        vs = jnp.asarray(np.abs(rng.randn(n_blocks, bs, kvh, 1)) * .01,
-                         jnp.float32)
-    else:
-        kc = jnp.asarray(rng.randn(n_blocks, bs, kvh, dh), dt)
-        vc = jnp.asarray(rng.randn(n_blocks, bs, kvh, dh), dt)
-        ks = vs = None
+    # the pool as the engine keeps it: a token's kv heads merged, leaves whole
+    kc = jnp.asarray(rng.randn(n_layers, n_blocks, bs, kvh * dh), dt)
+    vc = jnp.asarray(rng.randn(n_layers, n_blocks, bs, kvh * dh), dt)
     table = jnp.asarray(1 + rng.permutation(S * NB).reshape(S, NB), jnp.int32)
     pos = jnp.asarray([1, 15, 16, 17, 500, 777, 1000, 1023], jnp.int32)
     q = jnp.asarray(rng.randn(S, nh, dh) * 0.3, dt)
     k_new = jnp.asarray(rng.randn(S, kvh, dh), dt)
     v_new = jnp.asarray(rng.randn(S, kvh, dh), dt)
     slopes = L.alibi_slopes(nh) if alibi else None
+    layer = jnp.asarray(1, jnp.int32)
 
-    def fused(q, k_new, v_new, kc, vc, table, pos, ks, vs):
+    def kernel(q, k_new, v_new, kc, vc, table, pos, layer):
         return paged_flash_decode(q, k_new, v_new, kc, vc, table, pos,
-                                  k_scale=ks, v_scale=vs, alibi_slopes=slopes)
+                                  layer=layer, alibi_slopes=slopes)
 
-    def ref(q, k_new, v_new, kc, vc, table, pos, ks, vs):
+    def ref(q, k_new, v_new, kc, vc, table, pos, layer):
         # dense per-slot view through the table, fresh row written at the
         # cursor, exact fp32 softmax over [0, pos]
         f32 = jnp.float32
-        view = lambda c, s: (c.astype(f32) * (1.0 if s is None else s))[
-            table].reshape(S, NB * bs, kvh, dh)
+        view = lambda c: c[layer].astype(f32)[table].reshape(
+            S, NB * bs, kvh, dh)
         put = jax.vmap(lambda c, r, p: jax.lax.dynamic_update_slice(
             c, r[None], (p, 0, 0)))
-        kk = put(view(kc, ks), k_new.astype(f32), pos)
-        vv = put(view(vc, vs), v_new.astype(f32), pos)
+        kk = put(view(kc), k_new.astype(f32), pos)
+        vv = put(view(vc), v_new.astype(f32), pos)
         kv_idx = jnp.arange(NB * bs)[None, None, :]
         mask = (kv_idx <= pos[:, None, None])[:, None]
         bias = None
@@ -189,9 +180,9 @@ def _paged_case(nh, kvh, dh, int8=False, alibi=False):
             L._repeat_kv(vv, n_rep), mask=mask, alibi_bias=bias)[:, 0]
 
     geom = (f"8 slots x 1024-token window, block 16, {nh}/{kvh} heads x "
-            f"{dh}, {'int8' if int8 else 'bf16'} pool"
+            f"{dh}, bf16 pool [2, {n_blocks}, 16, {kvh * dh}], layer 1"
             + (", alibi" if alibi else ""))
-    return geom, fused, ref, (q, k_new, v_new, kc, vc, table, pos, ks, vs), \
+    return geom, kernel, ref, (q, k_new, v_new, kc, vc, table, pos, layer), \
         3e-2
 
 
@@ -231,10 +222,11 @@ def _block_write_case(int8):
 
     rng = np.random.RandomState(0)
     L, n_blocks, bs, kvh, dh, max_len = 4, 1537, 16, 32, 64, 2048
-    shape = (L, n_blocks, bs, kvh, dh)
+    shape = (L, n_blocks, bs, kvh * dh)
     if int8:
         pool = {"k": jnp.asarray(rng.randint(-127, 128, shape), jnp.int8),
-                "k_scale": jnp.asarray(rng.rand(*shape[:-1], 1), jnp.float32)}
+                "k_scale": jnp.asarray(rng.rand(*shape[:-1], kvh),
+                                       jnp.float32)}
         pool.update(v=-pool["k"], v_scale=2 * pool["k_scale"])
     else:
         pool = {"k": jnp.asarray(rng.randn(*shape), jnp.bfloat16)}
@@ -254,7 +246,7 @@ def _block_write_case(int8):
         return lambda pool, cache, ids, srcs: insert_block_kv(
             pool, cache, ids, srcs, bs, lanes=lanes)
 
-    return (f"{'int8' if int8 else 'bf16'} pool [4, 1537, 16, 32, 64], 37 of "
+    return (f"{'int8' if int8 else 'bf16'} pool [4, 1537, 16, 2048], 37 of "
             "128 entries real, exact", run(True), run(False),
             (pool, cache, jnp.asarray(ids), jnp.asarray(srcs)), 0.0)
 
@@ -302,8 +294,7 @@ CASES = {
     "paged decode (BLOOM class 16x128, alibi)":
         lambda: _paged_case(16, 16, 128, alibi=True),
     "paged decode (OPT class 32x64)": lambda: _paged_case(32, 32, 64),
-    "paged decode (GQA 32/8x128, int8 pool)":
-        lambda: _paged_case(32, 8, 128, int8=True),
+    "paged decode (GQA 32/8x128)": lambda: _paged_case(32, 8, 128),
     "quantized matmul int8": lambda: _qmm_case(8),
     "quantized matmul int4": lambda: _qmm_case(4),
     "kv block write (bf16 pool)": lambda: _block_write_case(False),

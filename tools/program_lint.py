@@ -82,16 +82,16 @@ def lint_decode(args):
         n_layers=preset["n_layers"], n_heads=preset["n_heads"],
         d_model=preset["d_model"], d_ff=preset["d_ff"],
         compute_dtype=jnp.bfloat16,
-        # this lint always runs on the CPU platform: the fused backend's
-        # kernel is audited as the interpreter lowers it
-        attention_interpret=True))
+        # this lint always runs on the CPU platform, where the engine
+        # chooses the decode kernel only under the interpreter: the kernel's
+        # program is audited as the interpreter lowers it, the view's as is
+        attention_interpret=args.attention_backend in ("kernel", "fused")))
     serving = {"n_slots": args.slots, "max_len": max_len,
                "virtual_clock": True}
     if args.paged:
         serving["kv_pool"] = {"enabled": True,
                               "block_size": args.kv_block_size,
-                              "kv_dtype": args.kv_dtype,
-                              "attention_backend": args.attention_backend}
+                              "kv_dtype": args.kv_dtype}
     engine = deepspeed_tpu.init_inference(
         model=model,
         config={"dtype": "bfloat16", "max_tokens": max_len,
@@ -304,10 +304,10 @@ def child(args):
     if args.program in ("decode", "all"):
         programs["decode"] = lint_decode(args)
     if args.program == "decode-fused":
-        # alias: the paged decode program through the fused flash-decode
-        # kernel (== --program decode --paged --attention-backend fused)
+        # alias: the paged decode program through the flash-decode kernel
+        # (== --program decode --paged --attention-backend kernel)
         args.paged = True
-        args.attention_backend = "fused"
+        args.attention_backend = "kernel"
         programs["decode-fused"] = lint_decode(args)
     if args.program in ("prefill-chunked", "all"):
         programs["prefill-chunked"] = lint_prefill_chunked(args)
@@ -347,11 +347,14 @@ def main():
                          "gate with --budget serving-decode-paged/8/bf16")
     ap.add_argument("--kv-block-size", type=int, default=16)
     ap.add_argument("--kv-dtype", default="", choices=["", "int8"])
-    ap.add_argument("--attention-backend", default="gather",
-                    choices=["gather", "fused"],
-                    help="paged decode-attention backend (--paged): 'fused' "
-                         "lints the split-KV flash-decode kernel program — "
-                         "gate with --budget serving-decode-fused/8/bf16")
+    ap.add_argument("--attention-backend", default="view",
+                    choices=["view", "kernel", "gather", "fused"],
+                    help="which paged decode program to lint (--paged): "
+                         "'kernel' (alias 'fused') the flash-decode kernel's, "
+                         "as the interpreter lowers it, gate with --budget "
+                         "serving-decode-fused/8/bf16; 'view' (alias "
+                         "'gather') the gather view's. On a chip the engine "
+                         "chooses; no configuration key selects a path")
     ap.add_argument("--chunk-size", type=int, default=16,
                     help="chunked-prefill chunk (tokens) the "
                          "prefill-chunked program is linted at")
