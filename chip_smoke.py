@@ -7,9 +7,9 @@ process (a chip belongs to one process at a time):
 
 * server  - OPT-1.3B (24 x 2048, 32 heads x 64, vocab 50272, bf16; random
   weights from a seed) through ``deepspeed_tpu.init_inference()`` and
-  ``ServingEngine`` with the paged KV pool and the default ``gather``
-  backend: requests of several prompt lengths (128-aligned buckets run the
-  flash prefill kernel) submitted while others decode, streamed to
+  ``ServingEngine`` over its paged KV pool, the decode attention the
+  engine's choice: requests of several prompt lengths (128-aligned buckets
+  run the flash prefill kernel) submitted while others decode, streamed to
   completion, greedy tokens compared with ``InferenceEngine.generate()``,
   exactly one decode compile. One device: TP=1. Several: TP=n, weights
   checked to be spread.
@@ -129,7 +129,7 @@ def serve_leg(n_dev, peaks, rehearse, cache_log):
         model, dtype="bfloat16", max_tokens=max_tokens, seed=0,
         tensor_parallel={"enabled": True, "tp_size": n_dev},
         serving={"n_slots": n_slots,
-                 "kv_pool": {"enabled": True, "block_size": block}})
+                 "kv_pool": {"block_size": block}})
     jax.block_until_ready(engine.params)
     sv = engine.serving
     init_s = time.perf_counter() - t0

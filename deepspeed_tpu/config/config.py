@@ -293,21 +293,23 @@ class CommsLoggerConfig(ConfigModel):
 
 
 class KVPoolConfig(ConfigModel):
-    """Paged KV cache (``serving/kv_pool.py``): the slot pool's KV memory is
-    a fixed-shape pool of token blocks plus a per-slot block table instead of
-    one dense ``n_slots x max_len`` region. Blocks are allocated/freed at
-    request granularity on the host; the decode program reads through the
-    (traced) block table with gathers, so it still compiles exactly once.
-    Slot count stops being capped by worst-case sequence length — requests
-    reserve ``ceil((prompt + max_new - 1) / block_size)`` blocks, their real
-    footprint."""
+    """The serving engine's KV store (``serving/kv_pool.py``): a fixed-shape
+    pool of token blocks plus a per-slot block table, never one dense
+    ``n_slots x max_len`` region. Blocks are allocated/freed at request
+    granularity on the host; the decode program reads through the (traced)
+    block table, so it still compiles exactly once. Slot count is not capped
+    by worst-case sequence length — requests reserve ``ceil((prompt +
+    max_new - 1) / block_size)`` blocks, their real footprint. With no key
+    set the pool holds ``n_slots * max_len`` tokens. Which decode attention
+    runs over it is the engine's choice from what it can observe;
+    ``snapshot()["kv_pool"]`` names the path that ran (``attention_backend``
+    ``kernel`` or ``view``, ``attention_reason``, ``decode_dispatches``)."""
 
-    enabled: bool = False
     # tokens per KV block; serving max_len must be a multiple of it
     block_size: int = 16
     # physical blocks in the pool, INCLUDING the reserved garbage block 0
-    # (freed slots' dead decode writes land there). 0 = auto: the dense
-    # pool's token capacity, n_slots * (max_len / block_size) + 1.
+    # (freed slots' dead decode writes land there). 0 = auto: room for
+    # every slot at max_len, n_slots * (max_len / block_size) + 1.
     n_blocks: int = 0
     # "" = the engine serving dtype; "int8" stores blocks as int8 payloads
     # with per-(token, head) fp32 scales (the ZeRO++ blockwise kernels from
@@ -324,19 +326,6 @@ class KVPoolConfig(ConfigModel):
     # queue (resuming bitwise-identical) instead of OOM/shed. False = the
     # PR 7 whole-footprint reservation.
     on_demand_growth: bool = False
-    # SELECTS NOTHING (kept so that configurations written before PR 30
-    # still load). Which decode attention runs is the engine's choice, from
-    # what it can observe: the flash-decode kernel
-    # (ops/pallas/paged_attention.py, walks the block table and reads the
-    # live blocks only) for one query row a slot, no banded local layers, a
-    # pool in the engine's dtype, where the compiler takes the kernel at the
-    # engine's geometry (fused_decode_supported, asked once at
-    # construction); else the n_slots x max_len gather view. A choice that
-    # depends on platform and shape is not a user's string (ROADMAP D1): a
-    # value here is accepted and logged once as having no effect, and
-    # snapshot()["kv_pool"] names the path that ran (attention_backend:
-    # "kernel" or "view", attention_reason, decode_dispatches by path).
-    attention_backend: str = ""
 
     def _validate(self):
         if self.block_size < 1:
@@ -348,10 +337,6 @@ class KVPoolConfig(ConfigModel):
         if self.kv_dtype not in ("", "int8"):
             raise ConfigError(
                 f"kv_pool.kv_dtype must be '' or 'int8', got {self.kv_dtype!r}")
-        if self.attention_backend not in ("", "gather", "fused"):
-            raise ConfigError(
-                f"kv_pool.attention_backend selects nothing; '', 'gather' "
-                f"and 'fused' are accepted, got {self.attention_backend!r}")
 
 
 class ChunkedPrefillConfig(ConfigModel):
@@ -438,8 +423,7 @@ class SpeculativeConfig(ConfigModel):
     is accepted (greedy acceptance, arXiv:2211.17192 — bitwise-checkable
     against ``generate()``). Rejected candidates roll back by cursor
     decrement; blocks left entirely past the cursor are released/scrubbed
-    at block granularity. Requires ``serving.kv_pool.enabled`` (rollback
-    rides the block machinery). Sampled (temperature > 0) requests never
+    at block granularity. Sampled (temperature > 0) requests never
     speculate — their per-slot rng streams advance exactly once per
     dispatched step either way, so enabling/disabling speculation cannot
     perturb a seeded stream."""
@@ -754,7 +738,7 @@ class TenantsConfig(ConfigModel):
     batch: TenantClassConfig = None         # default weight 1.0
     # priority preemption: when no slot is free and an arrived interactive
     # request waits, preempt the NEWEST-admitted batch-class stream
-    # (paged pools only — preemption rides the block-release machinery)
+    # (preemption rides the block-release machinery)
     preempt: bool = True
 
     def _validate(self):
@@ -839,13 +823,12 @@ class ServingConfig(ConfigModel):
     virtual_decode_step_cost: float = 1.0
     virtual_prefill_cost_per_token: float = 0.0625  # ~flash prefill vs decode
     # zero freed KV memory when a request finishes (the causal mask and
-    # whole-row/whole-block insert already prevent stale-KV leaks; hygiene/
-    # debug knob). Dense pool: zero the slot's rows; paged pool: zero each
-    # physical block as its refcount hits zero (block-granularity scrub).
+    # whole-block insert already prevent stale-KV leaks; hygiene/debug
+    # knob): each physical block is zeroed as its refcount hits zero.
     scrub_freed_slots: bool = False
     # emit Serving/* monitor events every N scheduler steps (0 disables)
     monitor_interval: int = 32
-    # paged + quantized KV cache with shared-prefix reuse (kv_pool.enabled)
+    # the KV store: block pool geometry, int8 blocks, shared-prefix reuse
     kv_pool: KVPoolConfig = None
     # chunked prefill: interleave fixed-token prefill chunks with decode
     # steps for a bounded co-batched TPOT (chunked_prefill.enabled)
@@ -911,12 +894,6 @@ class ServingConfig(ConfigModel):
             self.tenants = TenantsConfig()
         if self.degraded is None:
             self.degraded = DegradedConfig()
-        if self.pools.enabled and not self.kv_pool.enabled:
-            raise ConfigError(
-                "serving.pools.enabled requires serving.kv_pool.enabled: "
-                "the first-token handoff splices a fresh paged-pool "
-                "snapshot into the decode replica (the PR 16 zero-"
-                "recompute contract has no dense-pool form)")
         if self.pools.enabled and not self.migration.enabled:
             raise ConfigError(
                 "serving.pools.enabled requires serving.migration.enabled: "
@@ -924,11 +901,6 @@ class ServingConfig(ConfigModel):
         if self.retry_limit < 0:
             raise ConfigError(
                 f"serving.retry_limit must be >= 0, got {self.retry_limit}")
-        if self.speculative.enabled and not self.kv_pool.enabled:
-            raise ConfigError(
-                "serving.speculative.enabled requires serving.kv_pool."
-                "enabled: acceptance rollback (cursor decrement + stale-"
-                "block release/scrub) rides the paged-pool block machinery")
         if self.hol_bypass_limit < 0:
             raise ConfigError(
                 f"serving.hol_bypass_limit must be >= 0, got "

@@ -31,39 +31,6 @@ def init_cache(cfg, batch_size, max_len, dtype=None):
             for name, row in cfg.cache_geometry.items()}
 
 
-def insert_slot_kv(pool, slot_cache, slot):
-    """Write one request's freshly-prefilled cache into batch slot ``slot`` of
-    a slot-pool cache (continuous batching: a queued request joins the running
-    decode batch without draining it).
-
-    pool: {"k","v"} [L, n_slots, max_len, kvh, dh]; slot_cache: the same with
-    a batch dim of 1; ``slot`` is a TRACED scalar — one compiled insert
-    program covers every slot. The whole [max_len] row is overwritten, so
-    nothing from the slot's previous occupant survives."""
-    return {
-        "k": jax.lax.dynamic_update_slice(
-            pool["k"], slot_cache["k"].astype(pool["k"].dtype),
-            (0, slot, 0, 0, 0)),
-        "v": jax.lax.dynamic_update_slice(
-            pool["v"], slot_cache["v"].astype(pool["v"].dtype),
-            (0, slot, 0, 0, 0)),
-    }
-
-
-def reset_slot_kv(pool, slot):
-    """Zero batch slot ``slot`` of a slot-pool cache (optional hygiene when a
-    request frees its slot; the causal mask already keeps stale rows out of
-    every later request's attention window, and ``insert_slot_kv`` overwrites
-    the full row — this is for debugging / belt-and-braces serving modes)."""
-    z = jnp.zeros(pool["k"].shape[:1] + (1,) + pool["k"].shape[2:],
-                  pool["k"].dtype)
-    return {
-        "k": jax.lax.dynamic_update_slice(pool["k"], z, (0, slot, 0, 0, 0)),
-        "v": jax.lax.dynamic_update_slice(
-            pool["v"], z.astype(pool["v"].dtype), (0, slot, 0, 0, 0)),
-    }
-
-
 # ---------------------------------------------------------------------------
 # paged (block) KV cache: fixed pool of token blocks + per-slot block table
 # ---------------------------------------------------------------------------
@@ -227,8 +194,8 @@ def forward_with_paged_cache(model, params, input_ids, pool, table, pos,
       attention on it (``_block_cached``), then write each slot's new row
       back. Because the gathered view is bit-identical to the dense cache at
       every unmasked position and the math in between is the same program,
-      greedy paged decode is bitwise-equal to the dense slot pool (tier-1
-      pins it).
+      greedy decode over the view is bitwise-equal to ``generate()`` over
+      the dense ``[B, max_len]`` cache (tier-1 pins it).
 
     ``draft_len`` [S] switches the program into speculative VERIFY mode
     (see ``verify_with_paged_cache``): ``input_ids`` becomes [S, k+1]
@@ -423,9 +390,9 @@ def insert_block_kv(pool, dense_cache, block_ids, src_blocks, block_size,
 
 
 def reset_block_kv(pool, block_id):
-    """Zero physical block ``block_id`` (block-granularity hygiene scrub:
-    ``scrub_freed_slots`` generalized from the dense pool's whole-row
-    scrub; int8 scales zero too, so a dequantized read is exactly 0)."""
+    """Zero physical block ``block_id`` (the hygiene scrub of
+    ``scrub_freed_slots``; int8 scales zero too, so a dequantized read is
+    exactly 0)."""
     out = {}
     for name, a in pool.items():
         z = jnp.zeros(a.shape[:1] + (1,) + a.shape[2:], a.dtype)

@@ -274,8 +274,8 @@ def forward_with_cache(model, params, input_ids, cache, pos, kv_len,
     cfg = model.config
     if jnp.ndim(pos) != 0:
         raise ValueError(
-            "latent attention: the dense slot pool (per-row cursors over a "
-            "dense cache) is not implemented; serve through kv_pool")
+            "latent attention: per-row cursors over a dense cache are not "
+            "implemented; decode through the paged pool")
     b, q_len = input_ids.shape
     positions = jnp.broadcast_to(pos + jnp.arange(q_len)[None, :], (b, q_len))
     rope = rope_tables(cfg, positions)

@@ -2,19 +2,21 @@
 
 Orca-style iteration-level scheduling, TPU-native by construction: ONE jitted
 decode program runs over a **fixed pool of batch slots** (static shapes,
-compiled exactly once per (model, slot-pool) configuration). Each slot holds
-one request's KV rows, cursor, last token, rng key and sampling knobs — all
-as per-slot device arrays, so a finished request frees its slot mid-flight
-and a queued one is prefilled (the existing bucketed ``prefill_flash`` path)
-and spliced into the RUNNING decode batch with ``dynamic_update_slice``
-(``models/decoding.py:insert_slot_kv``). No recompilation, no waiting for the
-whole batch to drain — the serving-side half of DeepSpeed-Inference's
+compiled exactly once per (model, pool) configuration). Each slot holds one
+request's block-table row, cursor, last token, rng key and sampling knobs —
+all as per-slot device arrays; the KV rows live in ONE store, the paged
+block pool behind ``KVPoolManager`` (``serving/kv_pool.py``). A finished
+request frees its slot and blocks mid-flight and a queued one is prefilled
+(the existing bucketed ``prefill_flash`` path, over a dense b=1 scratch
+cache) and its blocks written into the RUNNING decode batch's pool
+(``models/decoding.py:insert_block_kv``). No recompilation, no waiting for
+the whole batch to drain — the serving-side half of DeepSpeed-Inference's
 latency/throughput story (arXiv:2207.00032) on top of the kernel path.
 
-Greedy streams are bitwise-identical to sequential ``generate()`` calls: the
-per-slot decode runs the same ``forward_with_cache`` math at the same
-positions over the same KV window (pinned in tier-1
-``tests/unit/test_serving.py``).
+Greedy streams are bitwise-identical to sequential ``generate()`` calls
+where the view serves (the same per-row attention math at the same
+positions over the same KV window; pinned in tier-1
+``tests/unit/test_serving.py``, ``test_kv_pool.py``).
 """
 
 import collections
@@ -31,8 +33,7 @@ from ..inference.engine import lru_compiled
 from ..models.decoding import (extract_slot_blocks, forward_with_cache,
                                forward_with_paged_cache, gather_slot_cache,
                                init_cache, init_paged_cache,
-                               insert_block_kv, insert_slot_kv,
-                               reset_block_kv, reset_slot_kv, sample_token,
+                               insert_block_kv, reset_block_kv, sample_token,
                                verify_with_paged_cache, write_pool_blocks)
 from ..ops.pallas.kv_block_write import blocks_in_lanes
 from ..utils.logging import log_dist
@@ -75,7 +76,8 @@ class _PrefillJob:
 
 
 class ServingEngine:
-    """Slot-pool continuous batching over an ``InferenceEngine``'s weights."""
+    """Continuous batching over an ``InferenceEngine``'s weights: a fixed
+    pool of batch slots whose KV lives in the paged block pool."""
 
     def __init__(self, engine, serving_config=None, clock=None, monitor=None,
                  tracer=None):
@@ -103,38 +105,23 @@ class ServingEngine:
         self._latent = bool(getattr(mcfg, "latent_attention", False))
         if self._latent:
             self._refuse_for_latent(engine)
-        # paged KV pool (kv_pool.enabled): block allocator + prefix cache on
-        # the host, block-table gathers on the device (serving/kv_pool.py)
-        self.paged = bool(self.cfg.kv_pool.enabled)
+        # the KV store: block allocator + prefix cache on the host, a block
+        # table on the device (serving/kv_pool.py)
         self.pool_mgr = KVPoolManager(self.cfg.kv_pool, self.n_slots,
-                                      self.max_len) if self.paged else None
+                                      self.max_len)
         # which decode attention runs is this engine's choice, from what it
-        # can observe: "dense" (no paging), "kernel" (the flash-decode
-        # kernel walks the block table and reads the live blocks only) or
-        # "view" (the n_slots x max_len gather view through the table, then
-        # the dense attention). The kernel is put to the compiler ONCE here,
-        # at this engine's geometry; where it is refused ``attn_reason``
-        # says why and the view serves. ``attn_backend`` (also in
-        # snapshot()["kv_pool"]) always names the path that runs; a
-        # speculative verify step takes the view whatever decode takes.
-        self.attn_backend, self.attn_reason = "dense", ""
+        # can observe: "kernel" (the flash-decode kernel walks the block
+        # table and reads the live blocks only) or "view" (the n_slots x
+        # max_len gather view through the table, then the dense attention).
+        # The kernel is put to the compiler ONCE here, at this engine's
+        # geometry; where it is refused ``attn_reason`` says why and the
+        # view serves. ``attn_backend`` (also in snapshot()["kv_pool"])
+        # always names the path that runs; a speculative verify step takes
+        # the view whatever decode takes.
         self._decode_dispatches = {"kernel": 0, "view": 0}
-        if self.paged:
-            self.attn_backend, self.attn_reason = self._choose_attention(
-                engine)
-        if self.paged and self.cfg.kv_pool.attention_backend:
-            import logging
-
-            log_dist(
-                "ServingEngine: kv_pool.attention_backend="
-                f"{self.cfg.kv_pool.attention_backend!r} has no effect: the "
-                f"engine chose {self.attn_backend!r} from the model, the "
-                "pool and the compiler's verdict"
-                + (f" ({self.attn_reason})" if self.attn_reason else ""),
-                ranks=[0], level=logging.WARNING)
-        if self.paged and self.cfg.scrub_freed_slots:
-            # block-granularity scrub: zero each physical block as its last
-            # reference drops (the dense pool's whole-row scrub generalized)
+        self.attn_backend, self.attn_reason = self._choose_attention(engine)
+        if self.cfg.scrub_freed_slots:
+            # zero each physical block as its last reference drops
             self.pool_mgr._scrub = self._scrub_block
         # chunked prefill: long prompts prefill in fixed-token chunks
         # interleaved with decode steps (bounded co-batched TPOT); each chunk
@@ -146,17 +133,17 @@ class ServingEngine:
         # (0 = the shared chunked_prefill.chunk_size)
         self.pool_role = "mixed"
         self.chunk_size_override = 0
-        # on-demand block growth (paged only): admission reserves prompt
-        # blocks, decode blocks are allocated as cursors advance, and pool
-        # exhaustion preempts the newest request back to the queue
-        self.growth = self.paged and bool(self.cfg.kv_pool.on_demand_growth)
+        # on-demand block growth: admission reserves prompt blocks, decode
+        # blocks are allocated as cursors advance, and pool exhaustion
+        # preempts the newest request back to the queue
+        self.growth = bool(self.cfg.kv_pool.on_demand_growth)
         self._prefill_jobs = collections.deque()
         self._decode_steps_since_chunk = 1 << 30  # first chunk never waits
         self._admit_seq = 0    # admission order (preemption victim = newest)
         # speculative decoding (serving/speculative.py): a drafter proposes
         # up to k tokens per greedy slot, ONE verify forward checks them,
-        # the longest agreeing prefix is accepted. Requires the paged pool
-        # (config-validated): rollback rides the block machinery.
+        # the longest agreeing prefix is accepted; rollback rides the block
+        # machinery.
         self.spec = bool(self.cfg.speculative.enabled)
         self.spec_k = int(self.cfg.speculative.k)
         self._spec_on = self.spec   # runtime toggle (set_speculation)
@@ -184,8 +171,7 @@ class ServingEngine:
         self.metrics = ServingMetrics(self.n_slots, self.clock,
                                       monitor=monitor,
                                       interval=self.cfg.monitor_interval,
-                                      kv_pool=self._kv_pool_stats
-                                      if self.paged else None,
+                                      kv_pool=self._kv_pool_stats,
                                       slo=self.cfg.slo)
         # numerics watchdog (the serving leg of telemetry/health.py): the
         # decode program ALWAYS emits the per-slot nonfinite-logit count
@@ -241,9 +227,9 @@ class ServingEngine:
         self._insert_jit = None
         self._release_jit = None
         self._sample_first_jit = None
-        self._insert_block_jit = None    # paged: copy one block into the pool
-        self._seed_cache_jit = None      # paged: block table row -> dense view
-        self._scrub_jit = None           # paged: zero one physical block
+        self._insert_block_jit = None    # copy a request's blocks into the pool
+        self._seed_cache_jit = None      # block table row -> dense view
+        self._scrub_jit = None           # zero one physical block
         self._fresh_cache_jit = None     # chunked: zeroed dense b=1 cache
         self._grow_jit = None            # growth: append one table-row block
         self._verify_jit = None          # speculative: one-forward verify
@@ -253,6 +239,7 @@ class ServingEngine:
         # replicated. Without the pin, insert and decode outputs would carry
         # different inferred shardings and each insert<->decode alternation
         # would recompile — the exact thing the slot pool exists to avoid.
+        # ``_cache_sharding`` is the dense b=1 prefill cache's.
         mesh = engine.mesh
         from ..parallel import MODEL_AXIS
 
@@ -261,52 +248,39 @@ class ServingEngine:
             else None
         self._cache_sharding = NamedSharding(
             mesh, P(None, None, None, kv_axis, None))
-        # the paged pool stores a token's kv heads merged with the head size
-        # (and an int8 pool's scales one a head): the same axis, 3, splits
-        # into contiguous groups of heads
+        # the pool stores a token's kv heads merged with the head size (and
+        # an int8 pool's scales one a head): the same axis, 3, splits into
+        # contiguous groups of heads
         self._pool_sharding = NamedSharding(
-            mesh, P(None, None, None, kv_axis)) if self.paged \
-            else self._cache_sharding
+            mesh, P(None, None, None, kv_axis))
         self._rep_sharding = NamedSharding(mesh, P())
-        kv_names = ("k", "v", "k_scale", "v_scale") \
-            if self.paged and self.cfg.kv_pool.kv_dtype == "int8" \
-            else ("k", "v")
-        extra = ("table",) if self.paged else ()
+        kv_names = self._pool_leaf_names()
         self._state_shardings = {
             name: self._pool_sharding if name in kv_names
             else self._rep_sharding
-            for name in kv_names + extra + (
-                "pos", "tok", "active", "remaining", "rng", "temp", "top_k",
-                "top_p", "eos")}
+            for name in kv_names + (
+                "table", "pos", "tok", "active", "remaining", "rng", "temp",
+                "top_k", "top_p", "eos")}
         self._state = self._init_state()
-        if self.paged:
-            # the small fix the paged pool makes necessary: the KV window is
-            # no longer n_slots x max_len — report the REAL capacity (blocks
-            # and tokens) so operators see the effective slot multiplier
-            mgr = self.pool_mgr
-            cap = mgr.allocatable * mgr.block_size
-            log_dist(
-                f"ServingEngine: {self.n_slots} slots, paged KV pool "
-                f"{mgr.allocatable} blocks x {mgr.block_size} tok = {cap} "
-                f"tokens ({cap / self.max_len:.1f} max-len-equivalent slots"
-                f", kv_dtype={self.cfg.kv_pool.kv_dtype or 'engine'}, "
-                f"attention={self.attn_backend}, "
-                f"prefix_cache={'on' if self.cfg.kv_pool.prefix_cache else 'off'}), "
-                + (f"speculative={self.cfg.speculative.drafter}/k="
-                   f"{self.spec_k}, " if self.spec else "")
-                + f"queue depth {self.cfg.max_queue_depth}, "
-                f"clock={'virtual' if isinstance(self.clock, VirtualClock) else 'wall'}",
-                ranks=[0])
-        else:
-            log_dist(
-                f"ServingEngine: {self.n_slots} slots x {self.max_len} KV window "
-                f"(attention={self.attn_backend}), "
-                f"queue depth {self.cfg.max_queue_depth}, "
-                f"clock={'virtual' if isinstance(self.clock, VirtualClock) else 'wall'}",
-                ranks=[0])
+        # the KV window is not n_slots x max_len: report the REAL capacity
+        # (blocks and tokens) so operators see the effective slot multiplier
+        mgr = self.pool_mgr
+        cap = mgr.allocatable * mgr.block_size
+        log_dist(
+            f"ServingEngine: {self.n_slots} slots, paged KV pool "
+            f"{mgr.allocatable} blocks x {mgr.block_size} tok = {cap} "
+            f"tokens ({cap / self.max_len:.1f} max-len-equivalent slots"
+            f", kv_dtype={self.cfg.kv_pool.kv_dtype or 'engine'}, "
+            f"attention={self.attn_backend}, "
+            f"prefix_cache={'on' if self.cfg.kv_pool.prefix_cache else 'off'}), "
+            + (f"speculative={self.cfg.speculative.drafter}/k="
+               f"{self.spec_k}, " if self.spec else "")
+            + f"queue depth {self.cfg.max_queue_depth}, "
+            f"clock={'virtual' if isinstance(self.clock, VirtualClock) else 'wall'}",
+            ranks=[0])
 
     def _choose_attention(self, engine):
-        """``(path, reason)`` of the paged decode program: the kernel where
+        """``(path, reason)`` of the decode program: the kernel where
         the model, the pool and the compiler allow it, else the view with
         the reason (``ops/pallas/paged_attention.fused_decode_supported``)."""
         if self._latent:
@@ -325,13 +299,11 @@ class ServingEngine:
     def _refuse_for_latent(self, engine):
         """What this engine cannot do with a latent-attention model refuses
         here, by name, instead of computing something else: the cache holds
-        one latent row a token, which only the paged pool in the engine's
-        dtype, read through its own view on one model shard, knows."""
+        one latent row a token, which only the pool in the engine's dtype,
+        read through its own view on one model shard, knows."""
         cfg = self.cfg
         why = None
-        if not cfg.kv_pool.enabled:
-            why = "the dense slot pool (serving.kv_pool.enabled=false)"
-        elif cfg.kv_pool.kv_dtype == "int8":
+        if cfg.kv_pool.kv_dtype == "int8":
             why = "an int8 pool (serving.kv_pool.kv_dtype='int8')"
         elif cfg.speculative.enabled:
             why = "speculative verify (serving.speculative.enabled)"
@@ -412,18 +384,15 @@ class ServingEngine:
     def _init_state(self):
         cfg = self.engine.module.config
         s = self.n_slots
-        if self.paged:
-            mgr = self.pool_mgr
-            cache = init_paged_cache(cfg, mgr.n_blocks, mgr.block_size,
-                                     self.engine.dtype,
-                                     self.cfg.kv_pool.kv_dtype or None)
+        mgr = self.pool_mgr
+        cache = init_paged_cache(cfg, mgr.n_blocks, mgr.block_size,
+                                 self.engine.dtype,
+                                 self.cfg.kv_pool.kv_dtype or None)
+        state = dict(cache, **{
             # every slot starts parked on the garbage block: a dead decode
             # write can never land in an allocatable block
-            cache["table"] = jnp.full((s, mgr.blocks_per_slot),
-                                      GARBAGE_BLOCK, jnp.int32)
-        else:
-            cache = init_cache(cfg, s, self.max_len, self.engine.dtype)
-        state = dict(cache, **{
+            "table": jnp.full((s, mgr.blocks_per_slot), GARBAGE_BLOCK,
+                              jnp.int32),
             "pos": jnp.zeros((s,), jnp.int32),        # next KV write cursor
             "tok": jnp.zeros((s,), jnp.int32),        # last sampled token
             "active": jnp.zeros((s,), jnp.bool_),
@@ -540,37 +509,27 @@ class ServingEngine:
 
     def _build_pool_programs(self):
         model, max_len = self.engine.module, self.max_len
-        paged = self.paged
         kernel = self.attn_backend == "kernel"
-        bs = self.pool_mgr.block_size if paged else 0
-        pool_keys = ("k", "v", "k_scale", "v_scale") \
-            if paged and self.cfg.kv_pool.kv_dtype == "int8" else ("k", "v")
+        bs = self.pool_mgr.block_size
+        pool_keys = self._pool_leaf_names()
         # how write_pool_blocks reaches the pool: by the layout the device
         # gave it, read off the live array
-        writer = dict(lanes=paged and blocks_in_lanes(self._state["k"]),
+        writer = dict(lanes=blocks_in_lanes(self._state["k"]),
                       mesh=self.engine.mesh,
                       interpret=model.config.attention_interpret)
 
         def decode(params, state):
             # one token for EVERY slot, each at its own cursor; inactive
-            # slots decode garbage into their own freed rows (dense: the
-            # slot's private rows, overwritten whole-row by the next insert;
-            # paged: the reserved garbage block their table row points at)
-            # and are masked below
+            # slots decode garbage into the reserved garbage block their
+            # table row points at and are masked below
             split = jax.vmap(jax.random.split)(state["rng"])  # [S, 2, 2]
-            routed = ()
-            if paged:
-                # a routing model also hands out what its expert layers
-                # chose, [L_moe, S, 1, 2k]
-                logits, cache, *routed = forward_with_paged_cache(
-                    model, params, state["tok"][:, None],
-                    {k: state[k] for k in pool_keys}, state["table"],
-                    state["pos"], bs, kernel=kernel,
-                    return_routing=self._routing)
-            else:
-                logits, cache = forward_with_cache(
-                    model, params, state["tok"][:, None],
-                    {"k": state["k"], "v": state["v"]}, state["pos"], max_len)
+            # a routing model also hands out what its expert layers chose,
+            # [L_moe, S, 1, 2k]
+            logits, cache, *routed = forward_with_paged_cache(
+                model, params, state["tok"][:, None],
+                {k: state[k] for k in pool_keys}, state["table"],
+                state["pos"], bs, kernel=kernel,
+                return_routing=self._routing)
             # in-graph health: per-slot nonfinite-logit count (the serving
             # leg of the numerics flight recorder — one tiny i32[S] side
             # output, no host callback; the sanitizer budget audits it)
@@ -586,6 +545,7 @@ class ServingEngine:
             hit_eos = (state["eos"] >= 0) & (nxt == state["eos"])
             done_now = active & (hit_eos | (remaining <= 0))
             new_state = dict(cache, **{
+                "table": state["table"],
                 "pos": state["pos"] + active.astype(jnp.int32),
                 "tok": nxt,
                 "active": active & jnp.logical_not(done_now),
@@ -594,8 +554,6 @@ class ServingEngine:
                 "temp": state["temp"], "top_k": state["top_k"],
                 "top_p": state["top_p"], "eos": state["eos"],
             })
-            if paged:
-                new_state["table"] = state["table"]
             # the routing is an output of its own, [L_moe, S, 2k], read back
             # with the tokens
             return (nxt, done_now, nonfinite,
@@ -665,29 +623,11 @@ class ServingEngine:
             return (out_toks, n_emit, accepted, done_now,
                     nonfinite), new_state
 
-        def insert(state, slot, k_slot, v_slot, tok, pos, remaining, rng,
-                   temp, top_k, top_p, eos):
-            # slot index is TRACED: one compiled insert covers every slot
-            kv = insert_slot_kv({"k": state["k"], "v": state["v"]},
-                                {"k": k_slot, "v": v_slot}, slot)
-            put = lambda a, v_: a.at[slot].set(v_)
-            return {
-                "k": kv["k"], "v": kv["v"],
-                "pos": put(state["pos"], pos),
-                "tok": put(state["tok"], tok),
-                "active": put(state["active"], True),
-                "remaining": put(state["remaining"], remaining),
-                "rng": state["rng"].at[slot].set(rng),
-                "temp": put(state["temp"], temp),
-                "top_k": put(state["top_k"], top_k),
-                "top_p": put(state["top_p"], top_p),
-                "eos": put(state["eos"], eos),
-            }
-
         def insert_meta(state, slot, table_row, tok, pos, remaining, rng,
                         temp, top_k, top_p, eos):
-            # paged: the KV rows were already copied block-wise
-            # (insert_block); this binds the slot's block table + scalars
+            # the KV rows were already copied block-wise (insert_blocks);
+            # this binds the slot's block table + scalars. The slot index is
+            # TRACED: one compiled insert covers every slot
             put = lambda a, v_: a.at[slot].set(v_)
             return dict(state, **{
                 "table": state["table"].at[slot].set(table_row),
@@ -735,29 +675,21 @@ class ServingEngine:
                         table=state["table"].at[slot, j].set(block_id))
 
         def release(state, slot):
-            if paged:
-                # MANDATORY on the paged pool (not hygiene): the freed
-                # slot's blocks go back to the allocator, so its table row
-                # must retreat to the garbage block before anything reuses
-                # them — a dead decode write to a reallocated block would
-                # be silent cross-request corruption
-                return dict(
-                    state,
-                    table=state["table"].at[slot].set(
-                        jnp.full((state["table"].shape[1],), GARBAGE_BLOCK,
-                                 jnp.int32)),
-                    pos=state["pos"].at[slot].set(0),
-                    active=state["active"].at[slot].set(False))
-            # hygiene scrub (config.scrub_freed_slots): zero the freed KV
-            # rows; the causal mask + whole-row insert already guarantee no
-            # stale-KV leak without it
-            kv = reset_slot_kv({"k": state["k"], "v": state["v"]}, slot)
-            return dict(state, k=kv["k"], v=kv["v"],
-                        active=state["active"].at[slot].set(False))
+            # MANDATORY (not hygiene): the freed slot's blocks go back to
+            # the allocator, so its table row must retreat to the garbage
+            # block before anything reuses them — a dead decode write to a
+            # reallocated block would be silent cross-request corruption
+            return dict(
+                state,
+                table=state["table"].at[slot].set(
+                    jnp.full((state["table"].shape[1],), GARBAGE_BLOCK,
+                             jnp.int32)),
+                pos=state["pos"].at[slot].set(0),
+                active=state["active"].at[slot].set(False))
 
         def scrub_block(state, block_id):
-            # block-granularity scrub (scrub_freed_slots under paging):
-            # zero a physical block when its last reference drops
+            # scrub_freed_slots: zero a physical block when its last
+            # reference drops
             return dict(state, **reset_block_kv(
                 {k: state[k] for k in pool_keys}, block_id))
 
@@ -789,29 +721,25 @@ class ServingEngine:
             self._decode_jit = jax.jit(decode, donate_argnums=(1,),
                                        out_shardings=(
                                            (rep,) * (3 + self._routing), st))
-            if paged:
-                self._insert_jit = jax.jit(insert_meta, donate_argnums=(0,),
-                                           out_shardings=st)
-                self._insert_block_jit = jax.jit(
-                    insert_blocks, donate_argnums=(0,), out_shardings=st)
-                self._seed_cache_jit = jax.jit(
-                    seed_cache, out_shardings={"k": self._cache_sharding,
-                                               "v": self._cache_sharding})
-                self._scrub_jit = jax.jit(scrub_block, donate_argnums=(0,),
-                                          out_shardings=st)
-                if self.growth:
-                    self._grow_jit = jax.jit(grow, donate_argnums=(0,),
-                                             out_shardings=st)
-                if self.spec:
-                    self._verify_jit = jax.jit(
-                        verify, donate_argnums=(1,),
-                        out_shardings=((rep, rep, rep, rep, rep), st))
-                if self.cfg.kv_pool.kv_dtype == "int8":
-                    self._migrate_in_jit = jax.jit(
-                        migrate_in, donate_argnums=(0,), out_shardings=st)
-            else:
-                self._insert_jit = jax.jit(insert, donate_argnums=(0,),
-                                           out_shardings=st)
+            self._insert_jit = jax.jit(insert_meta, donate_argnums=(0,),
+                                       out_shardings=st)
+            self._insert_block_jit = jax.jit(
+                insert_blocks, donate_argnums=(0,), out_shardings=st)
+            self._seed_cache_jit = jax.jit(
+                seed_cache, out_shardings={"k": self._cache_sharding,
+                                           "v": self._cache_sharding})
+            self._scrub_jit = jax.jit(scrub_block, donate_argnums=(0,),
+                                      out_shardings=st)
+            if self.growth:
+                self._grow_jit = jax.jit(grow, donate_argnums=(0,),
+                                         out_shardings=st)
+            if self.spec:
+                self._verify_jit = jax.jit(
+                    verify, donate_argnums=(1,),
+                    out_shardings=((rep, rep, rep, rep, rep), st))
+            if self.cfg.kv_pool.kv_dtype == "int8":
+                self._migrate_in_jit = jax.jit(
+                    migrate_in, donate_argnums=(0,), out_shardings=st)
             self._fresh_cache_jit = jax.jit(
                 fresh_cache, out_shardings={"k": self._cache_sharding,
                                             "v": self._cache_sharding})
@@ -867,7 +795,7 @@ class ServingEngine:
         """``(lowered, jaxpr)`` of the speculative verify program —
         the ``program_lint --program verify`` entry point, mirroring
         ``trace_decode``. Traces the SAME jitted closure a verify step
-        dispatches: k+1 positions per slot against the donated paged pool
+        dispatches: k+1 positions per slot against the donated pool
         state, with the draft matrix and per-slot draft lengths traced (one
         compiled program per k)."""
         if not self.spec:
@@ -891,14 +819,12 @@ class ServingEngine:
             "decode": size(self._decode_jit),
             "insert": size(self._insert_jit),
             "prefill_buckets": len(self._prefill_programs),
+            "suffix_buckets": len(self._suffix_programs),
+            "insert_block": size(self._insert_block_jit),
+            "seed_cache": size(self._seed_cache_jit),
         }
-        if self.paged:
-            out["insert_block"] = size(self._insert_block_jit)
-            out["seed_cache"] = size(self._seed_cache_jit)
-            if self.cfg.kv_pool.kv_dtype == "int8":
-                out["migrate_in"] = size(self._migrate_in_jit)
-        if self.paged or self.chunked or self.growth:
-            out["suffix_buckets"] = len(self._suffix_programs)
+        if self.cfg.kv_pool.kv_dtype == "int8":
+            out["migrate_in"] = size(self._migrate_in_jit)
         if self.growth:
             out["grow"] = size(self._grow_jit)
         if self.spec:
@@ -954,9 +880,8 @@ class ServingEngine:
                 if cap and req.max_new_tokens > cap:
                     req.max_new_tokens = cap
         if reason is None:
-            reason = self.queue.admit(
-                req, self.max_len,
-                kv_fits=self.pool_mgr.fits_ever if self.paged else None)
+            reason = self.queue.admit(req, self.max_len,
+                                      kv_fits=self.pool_mgr.fits_ever)
         if reason is None:
             self.metrics.record_submit(req)
             self.tracer.instant(
@@ -978,11 +903,11 @@ class ServingEngine:
     def step(self):
         """One scheduler iteration: admit queued requests into free slots,
         advance at most one pending prefill chunk (chunked prefill), grow or
-        preempt paged slots whose cursor reached the end of their blocks
+        preempt slots whose cursor reached the end of their blocks
         (on-demand growth), then run one decode step over the pool. Returns
         the list of TokenEvents produced."""
         events = []
-        can_admit = self._make_can_admit() if self.paged else None
+        can_admit = self._make_can_admit()
         admitted = self._maybe_priority_preempt(can_admit)
         if admitted is None:
             admitted = self.scheduler.next_admissions(len(self._free_slots),
@@ -1002,7 +927,7 @@ class ServingEngine:
             else:
                 self._decode_once(events)
             self._decode_steps_since_chunk += 1
-            if self.paged and self._slots and self.cfg.migration.enabled \
+            if self._slots and self.cfg.migration.enabled \
                     and self.cfg.migration.snapshot_interval_tokens > 0:
                 self._maybe_snapshot()
         elif not admitted and not self._prefill_jobs and self.queue.depth:
@@ -1033,10 +958,9 @@ class ServingEngine:
         admission order), so routing the step through ``next_admissions``
         would hand the freed slot straight back to the victim — an
         evict/re-admit livelock instead of a priority grant. Returns None
-        when no preemption applies (the normal admission path runs).
-        Paged pools only: ``_preempt`` is block-machinery-coupled."""
+        when no preemption applies (the normal admission path runs)."""
         tcfg = self.cfg.tenants
-        if not (tcfg.enabled and tcfg.preempt and self.paged) \
+        if not (tcfg.enabled and tcfg.preempt) \
                 or self._free_slots or not self.queue.depth:
             return None
         if self._pp_cooldown > 0:
@@ -1074,7 +998,7 @@ class ServingEngine:
                             n_tokens=len(victim.tokens))
         # the victim's push_front shifted the candidate one slot back
         cand = self.queue.peek_at(cand_i + 1)
-        if can_admit is not None and not can_admit(cand):
+        if not can_admit(cand):
             # the eviction freed too few blocks (large prompt vs short
             # victim): leave the candidate queued and back off — retrying
             # every step would churn evictions without ever admitting
@@ -1181,17 +1105,15 @@ class ServingEngine:
             # it first left the queue)
             req.prefill_start_time = self.clock.now()
             self.metrics.record_queue_wait(req)
-        shared_len, shared_blocks = 0, []
-        if self.paged:
-            # take refs on matched prefix blocks NOW so an eviction between
-            # here and the slot insert can't dangle them
-            shared_len, shared_blocks = self.pool_mgr.acquire_prefix(ids_full)
+        # take refs on matched prefix blocks NOW so an eviction between
+        # here and the slot insert can't dangle them
+        shared_len, shared_blocks = self.pool_mgr.acquire_prefix(ids_full)
         if shared_len and not resume:
             # positions the prefix-cache hit never dispatches: reported in
             # the goodput block (work avoided, not part of the frac)
             req.prefix_saved_tokens += shared_len
             self.metrics.prefix_saved_tokens += shared_len
-        if resume and self.paged and req.migration is not None \
+        if resume and req.migration is not None \
                 and self.cfg.migration.enabled \
                 and req.migration.compatible_with(self._pool_geometry()) \
                 and self._splice_snapshot(req, req.migration, ids_full,
@@ -1299,9 +1221,8 @@ class ServingEngine:
         if self._health_shed and nf:
             # poisoned prefill: the first token is garbage — shed BEFORE
             # streaming anything (the request never takes a slot)
-            if self.paged:
-                self.pool_mgr.release_blocks(shared_blocks)
-                self._unreserve(req)
+            self.pool_mgr.release_blocks(shared_blocks)
+            self._unreserve(req)
             if slot is not None:
                 self._free_slots.append(slot)
             self.metrics.record_shed("unhealthy_slot")
@@ -1333,10 +1254,9 @@ class ServingEngine:
                 reason = FINISH_STOP
             else:
                 reason = FINISH_LENGTH
-            if self.paged:
-                # finished at the first token: no blocks were bound
-                self.pool_mgr.release_blocks(shared_blocks)
-                self._unreserve(req)
+            # finished at the first token: no blocks were bound
+            self.pool_mgr.release_blocks(shared_blocks)
+            self._unreserve(req)
             if slot is not None:
                 self._free_slots.append(slot)
             self._finish(req, reason, now)
@@ -1351,16 +1271,8 @@ class ServingEngine:
             # RESUMED request keeps its original seniority
             req.admit_seq = self._admit_seq
             self._admit_seq += 1
-        if self.paged:
-            self._insert_paged(req, slot, cache, shared_len, shared_blocks,
-                               tok[0], keys[1], s, eos,
-                               req.max_new_tokens - 1)
-        else:
-            self._state = self._insert_jit(
-                self._state, np.int32(slot), cache["k"], cache["v"], tok[0],
-                np.int32(req.prompt_len), np.int32(req.max_new_tokens - 1),
-                keys[1], np.float32(s.temperature), np.int32(s.top_k),
-                np.float32(s.top_p), np.int32(-1 if eos is None else eos))
+        self._insert_paged(req, slot, cache, shared_len, shared_blocks,
+                           tok[0], keys[1], s, eos, req.max_new_tokens - 1)
         events.append(TokenEvent(req.request_id, t, 0, False, None, now))
 
     # ----------------------------------------------- chunked prefill driver
@@ -1468,17 +1380,8 @@ class ServingEngine:
         # insert-compiles-once pin
         tok = jax.device_put(jnp.asarray(req.tokens[-1], jnp.int32),
                              self._rep_sharding)
-        if self.paged:
-            self._insert_paged(req, slot, job.cache, job.shared_len,
-                               job.shared_blocks, tok,
-                               rng, s, eos, remaining)
-        else:
-            self._state = self._insert_jit(
-                self._state, np.int32(slot), job.cache["k"], job.cache["v"],
-                tok, np.int32(self._prefill_len(req)),
-                np.int32(remaining), rng, np.float32(s.temperature),
-                np.int32(s.top_k), np.float32(s.top_p),
-                np.int32(-1 if eos is None else eos))
+        self._insert_paged(req, slot, job.cache, job.shared_len,
+                           job.shared_blocks, tok, rng, s, eos, remaining)
         self.tracer.instant("request/resumed", cat="serving",
                             ts=self.clock.now(), request_id=req.request_id,
                             trace_id=req.trace_id,
@@ -1561,7 +1464,7 @@ class ServingEngine:
 
     def _insert_paged(self, req, slot, cache, shared_len, shared_blocks,
                       tok, chain_key, s, eos, remaining):
-        """Bind a paged slot: allocate the request's footprint in blocks,
+        """Bind a slot: allocate the request's footprint in blocks,
         copy the freshly-prefilled PRIVATE blocks from the dense cache
         (shared prefix blocks are refcounted, never copied — copy-on-write),
         set the slot's table row + scalars, and content-address the full
@@ -1621,8 +1524,7 @@ class ServingEngine:
             raise ValueError(
                 "ServingEngine: latent attention does not implement live KV "
                 "migration (a snapshot of latent blocks and its splice)")
-        if not self.paged or req.slot is None \
-                or self._slots.get(req.slot) is not req:
+        if req.slot is None or self._slots.get(req.slot) is not req:
             return None
         mgr = self.pool_mgr
         slot = req.slot
@@ -1826,15 +1728,14 @@ class ServingEngine:
         slot = req.slot
         if slot is None or self._slots.get(slot) is not req:
             return False
-        if self.paged and self.cfg.migration.enabled:
+        if self.cfg.migration.enabled:
             self.capture_snapshot(req)
         self._slots.pop(slot)
         # keep the plain resume path viable too (snapshot may not
         # splice on the target): the rng at this commit point
         req.resume_rng = np.asarray(self._state["rng"])[slot].copy()
         self._state = self._release_jit(self._state, np.int32(slot))
-        if self.paged:
-            self.pool_mgr.free_slot(slot)
+        self.pool_mgr.free_slot(slot)
         if self._drafter is not None:
             self._drafter.release(slot)
         self._free_slots.append(slot)
@@ -1865,8 +1766,7 @@ class ServingEngine:
             out.append(req)
         for job in list(self._prefill_jobs):
             req = job.req
-            if self.paged:
-                self.pool_mgr.release_blocks(job.shared_blocks)
+            self.pool_mgr.release_blocks(job.shared_blocks)
             self._unreserve(req)
             self._free_slots.append(job.slot)
             req.slot = None
@@ -2045,8 +1945,7 @@ class ServingEngine:
                 if reason is not None:
                     break
             if reason is not None:
-                self._finish(req, reason, now,
-                             deactivate=(reason == FINISH_STOP))
+                self._finish(req, reason, now)
                 continue
             if d >= n:
                 # candidate rows [pos0 + n, pos0 + d] were written but the
@@ -2091,8 +1990,7 @@ class ServingEngine:
                                                 self._state)
             self.clock.advance(self.cfg.virtual_decode_step_cost)
         self.metrics.record_decode_dispatch()
-        if self.paged:
-            self._decode_dispatches[self.attn_backend] += 1
+        self._decode_dispatches[self.attn_backend] += 1
         self._dispatch_chunk_ahead()
         # one read-back for all the step hands out (a routing model: its
         # expert choices too)
@@ -2124,7 +2022,7 @@ class ServingEngine:
                                          len(req.tokens) - 1, False, None,
                                          now))
                 continue
-            self._finish(req, reason, now, deactivate=(reason == FINISH_STOP))
+            self._finish(req, reason, now)
             events.append(TokenEvent(req.request_id, t, len(req.tokens) - 1,
                                      True, reason, now))
 
@@ -2166,14 +2064,11 @@ class ServingEngine:
         self.tracer.instant("request/unhealthy", cat="serving", ts=now,
                             request_id=req.request_id,
                             trace_id=req.trace_id, nonfinite_logits=n_bad)
-        self._finish(req, FINISH_UNHEALTHY, now, deactivate=True)
+        self._finish(req, FINISH_UNHEALTHY, now)
         events.append(TokenEvent(req.request_id, -1, len(req.tokens),
                                  True, FINISH_UNHEALTHY, now))
 
-    def _finish(self, req, reason, now, deactivate=False):
-        """``deactivate``: the device doesn't know this slot finished (host-
-        side stop policy) — clear its active flag so decode stops advancing
-        it. EOS/length finishes already cleared it inside the decode step."""
+    def _finish(self, req, reason, now):
         req.state = RequestState.FINISHED
         req.finish_reason = reason
         req.finish_time = now
@@ -2182,17 +2077,12 @@ class ServingEngine:
             self._free_slots.append(req.slot)
             if self._drafter is not None:
                 self._drafter.release(req.slot)
-            if self.paged:
-                # ALWAYS release under paging: the table row must retreat
-                # to the garbage block before the allocator reuses the
-                # blocks (the dense pool's rows are private, so it only
-                # releases for host-side stops / the hygiene scrub)
-                self._state = self._release_jit(self._state,
-                                                np.int32(req.slot))
-                self.pool_mgr.free_slot(req.slot)
-            elif deactivate or self.cfg.scrub_freed_slots:
-                self._state = self._release_jit(self._state,
-                                                np.int32(req.slot))
+            # ALWAYS release: the table row must retreat to the garbage
+            # block before the allocator reuses the blocks (and a host-side
+            # stop, which the device does not know of, clears its active
+            # flag here)
+            self._state = self._release_jit(self._state, np.int32(req.slot))
+            self.pool_mgr.free_slot(req.slot)
             req.slot = None
         self.metrics.record_finish(req)
         start = req.start_time

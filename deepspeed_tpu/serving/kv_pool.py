@@ -1,10 +1,10 @@
 """Paged KV-cache memory manager (host side of the block pool).
 
-The dense slot pool allocates one ``n_slots x max_len`` KV region, so slot
-count — i.e. concurrent users — is capped by WORST-CASE sequence length.
-This module replaces that with vLLM-style paging, TPU-native by
-construction: a fixed-shape pool of ``n_blocks`` physical token blocks plus
-a per-slot block table. Everything dynamic lives HERE, on the host
+One dense ``n_slots x max_len`` KV region would cap slot count — i.e.
+concurrent users — by WORST-CASE sequence length. The serving engine's one
+KV store is vLLM-style paging instead, TPU-native by construction: a
+fixed-shape pool of ``n_blocks`` physical token blocks plus a per-slot
+block table. Everything dynamic lives HERE, on the host
 (allocation, refcounts, the shared-prefix cache); the device only ever sees
 static shapes — the decode program reads the pool through the traced block
 table with gathers and still compiles exactly once.
@@ -80,7 +80,10 @@ class KVPoolManager:
         if max_len % self.block_size:
             raise ConfigError(
                 f"serving max_len {max_len} must be a multiple of "
-                f"kv_pool.block_size {self.block_size}")
+                f"kv_pool.block_size {self.block_size}: set "
+                f"serving.kv_pool.block_size to a divisor of {max_len} (or "
+                f"serving.max_len / max_tokens to a multiple of "
+                f"{self.block_size})")
         self.blocks_per_slot = max_len // self.block_size
         auto = n_slots * self.blocks_per_slot + 1
         self.n_blocks = int(cfg.n_blocks) or auto

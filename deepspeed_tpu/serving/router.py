@@ -81,10 +81,9 @@ class _Replica:
             / max(sv.cfg.max_queue_depth, 1)
         score += cfg.slot_weight \
             * (len(sv._slots) + len(sv._prefill_jobs)) / max(sv.n_slots, 1)
-        if sv.paged:
-            # O(1) accessor, not the full stats() dict: this runs per
-            # routed request per live replica
-            score += cfg.block_weight * sv.pool_mgr.occupancy()
+        # O(1) accessor, not the full stats() dict: this runs per routed
+        # request per live replica
+        score += cfg.block_weight * sv.pool_mgr.occupancy()
         return score
 
     def prefill_score(self, cfg):
@@ -110,8 +109,7 @@ class _Replica:
         sv = self.sv
         score = cfg.slot_weight * (len(sv._slots) + sv.queue.depth) \
             / max(sv.n_slots, 1)
-        if sv.paged:
-            score += cfg.block_weight * sv.pool_mgr.occupancy()
+        score += cfg.block_weight * sv.pool_mgr.occupancy()
         return score
 
     def pool_score(self, cfg):
@@ -284,8 +282,7 @@ class RouterMetrics:
                 "routed": sum(self.per_replica_routed[r.idx]
                               for r in members),
                 "occupancy": round(sum(
-                    r.sv.pool_mgr.occupancy() if r.sv.paged else
-                    len(r.sv._slots) / max(r.sv.n_slots, 1)
+                    r.sv.pool_mgr.occupancy()
                     for r in members) / len(members), 4),
                 "ttft_ms": {"p50": to_ms(percentile(ttft, 50)),
                             "p99": to_ms(percentile(ttft, 99))},
@@ -302,9 +299,7 @@ class RouterMetrics:
             "per_replica_queue_depth": [r.sv.queue.depth for r in reps],
             "per_replica_active_slots": [len(r.sv._slots) for r in reps],
             "per_replica_occupancy": [
-                round(r.sv.pool_mgr.occupancy(), 4) if r.sv.paged else
-                round(len(r.sv._slots) / max(r.sv.n_slots, 1), 4)
-                for r in reps],
+                round(r.sv.pool_mgr.occupancy(), 4) for r in reps],
             "draining": [i for i, r in enumerate(reps) if r.draining],
             "health": [r.health for r in reps],
             "migration": self.fleet_migration(),
@@ -715,10 +710,10 @@ class Router:
             self._prefix_index.popitem(last=False)
 
     def _chain_block_size(self):
-        """The chain-key granularity: the first paged replica's block size
-        (None when no replica pages — there are no blocks to share)."""
+        """The chain-key granularity: the block size of the first replica
+        that shares prefixes (None when none does)."""
         for r in self._replicas:
-            if r.sv.paged and r.sv.cfg.kv_pool.prefix_cache:
+            if r.sv.cfg.kv_pool.prefix_cache:
                 return r.sv.pool_mgr.block_size
         return None
 
@@ -879,9 +874,8 @@ class Router:
             target = min(self._pool_candidates(candidates, "prefill"),
                          key=lambda i: (scores[i], i))
             sv = self._replicas[target].sv
-            reason = sv.queue.admit(
-                req, sv.max_len,
-                kv_fits=sv.pool_mgr.fits_ever if sv.paged else None)
+            reason = sv.queue.admit(req, sv.max_len,
+                                    kv_fits=sv.pool_mgr.fits_ever)
             if reason is not None:
                 return self._shed_failed(req, from_idx, reason)
         self._requests[req.request_id] = (req, target)
@@ -935,9 +929,8 @@ class Router:
         target = min(self._pool_candidates(live, "prefill"),
                      key=lambda i: (scores[i], i))
         sv = self._replicas[target].sv
-        reason = sv.queue.admit(
-            req, sv.max_len,
-            kv_fits=sv.pool_mgr.fits_ever if sv.paged else None)
+        reason = sv.queue.admit(req, sv.max_len,
+                                kv_fits=sv.pool_mgr.fits_ever)
         if reason is not None:
             return self._shed_failed(req, from_idx, reason)
         self._requests[req.request_id] = (req, target)
@@ -1017,12 +1010,11 @@ class Router:
         on-demand pool growth."""
         d = self.cfg.slot_weight * (1.0 / max(hot.sv.n_slots, 1)
                                     + 1.0 / max(cold.sv.n_slots, 1))
-        if hot.sv.paged and cold.sv.paged:
-            blocks = -(-(req.prompt_len + len(req.tokens))
-                       // hot.sv.pool_mgr.block_size)
-            d += self.cfg.block_weight * blocks * (
-                1.0 / max(hot.sv.pool_mgr.n_blocks, 1)
-                + 1.0 / max(cold.sv.pool_mgr.n_blocks, 1))
+        blocks = -(-(req.prompt_len + len(req.tokens))
+                   // hot.sv.pool_mgr.block_size)
+        d += self.cfg.block_weight * blocks * (
+            1.0 / max(hot.sv.pool_mgr.n_blocks, 1)
+            + 1.0 / max(cold.sv.pool_mgr.n_blocks, 1))
         return d
 
     def _maybe_rebalance(self):

@@ -62,7 +62,7 @@ class ServingScheduler:
             return self._fair_admissions(free_slots, now, can_admit)
         return self._fcfs_admissions(free_slots, now, can_admit)
 
-    def _fcfs_admissions(self, free_slots, now, can_admit=None):
+    def _fcfs_admissions(self, free_slots, now, can_admit):
         """Requests to prefill this step: bounded by free slots AND the
         per-step prefill cap. ``now`` gates open-loop arrivals that were
         queued with a future arrival_time (virtual-clock simulations).

@@ -2,6 +2,9 @@
 per worker, the sequential-``generate()`` reference, and a virtual-clock
 replica. A file keeps only the keyword defaults that differ."""
 
+import contextlib
+import logging
+
 import numpy as np
 import jax.numpy as jnp
 import pytest
@@ -51,7 +54,7 @@ def make_replica(engine, trace_dir=None, job_name=None, **kw):
 def make_full_replica(engine, trace_dir=None, job_name=None, **kw):
     """Paged + chunked + migrating: the full recovery / handoff surface."""
     kw.setdefault("chunked_prefill", {"enabled": True, "chunk_size": 8})
-    kw.setdefault("kv_pool", {"enabled": True, "block_size": 8,
+    kw.setdefault("kv_pool", {"block_size": 8,
                               "on_demand_growth": True})
     kw.setdefault("migration", {"enabled": True,
                                 "snapshot_interval_tokens": 2})
@@ -60,8 +63,32 @@ def make_full_replica(engine, trace_dir=None, job_name=None, **kw):
 
 def make_paged(engine, kv_pool=None, **kw):
     return make_replica(
-        engine, kv_pool={"enabled": True, "block_size": 16, **(kv_pool or {})},
+        engine, kv_pool={"block_size": 16, **(kv_pool or {})},
         **kw)
+
+
+@contextlib.contextmanager
+def unknown_key_warnings():
+    """The ``ignoring unknown config key`` warnings of the package's logger
+    (it does not propagate, so ``caplog`` sees none) while the block runs."""
+    from deepspeed_tpu.utils.logging import logger
+
+    seen = []
+    handler = logging.Handler(level=logging.WARNING)
+    handler.emit = lambda record: seen.append(record.getMessage()) \
+        if "unknown config key" in record.getMessage() else None
+    logger.addHandler(handler)
+    try:
+        yield seen
+    finally:
+        logger.removeHandler(handler)
+
+
+# what a configuration written before PR 31 says of the two kv_pool keys the
+# program dropped, one warning each
+STALE_KV_KEYS = ["KVPoolConfig: ignoring unknown config key "
+                 "'attention_backend'",
+                 "KVPoolConfig: ignoring unknown config key 'enabled'"]
 
 
 def ref_tokens(engine, req):

@@ -102,17 +102,17 @@ def skewed_requests(n=10, plen=40, max_new=16, gap=0.02):
 
 def test_pools_config_validation():
     ServingConfig(pools={"enabled": True},
-                  kv_pool={"enabled": True, "block_size": 8},
+                  kv_pool={"block_size": 8},
                   migration={"enabled": True})
-    with pytest.raises(ConfigError):
-        ServingConfig(pools={"enabled": True})          # no kv pool
+    # no pool key at all: the default pool serves the handoff
+    ServingConfig(pools={"enabled": True}, migration={"enabled": True})
     with pytest.raises(ConfigError):
         ServingConfig(pools={"enabled": True},          # no migration
-                      kv_pool={"enabled": True, "block_size": 8},
+                      kv_pool={"block_size": 8},
                       migration={"enabled": False})
     with pytest.raises(ConfigError):
         ServingConfig(rebalance={"enabled": True, "min_gain": -1.0},
-                      kv_pool={"enabled": True, "block_size": 8},
+                      kv_pool={"block_size": 8},
                       migration={"enabled": True})
 
 
@@ -181,7 +181,7 @@ def test_disagg_parity_speculation_int8(engine):
     int8-quantized pool: greedy acceptance is lossless and int8 payloads
     move byte-for-byte, so handed-off streams still match a stay-put run
     with the identical serving config exactly."""
-    kw = dict(kv_pool={"enabled": True, "block_size": 8,
+    kw = dict(kv_pool={"block_size": 8,
                        "on_demand_growth": True, "kv_dtype": "int8"},
               speculative={"enabled": True, "drafter": "ngram", "k": 4})
     router = make_disagg(
@@ -224,7 +224,7 @@ def test_disagg_tp2_parity(devices8):
          "tensor_parallel": {"tp_size": 2},
          "serving": {"n_slots": 2, "virtual_clock": True,
                      "chunked_prefill": {"enabled": True, "chunk_size": 8},
-                     "kv_pool": {"enabled": True, "block_size": 8,
+                     "kv_pool": {"block_size": 8,
                                  "on_demand_growth": True},
                      "migration": {"enabled": True,
                                    "snapshot_interval_tokens": 2},

@@ -160,7 +160,7 @@ def test_migration_bitwise_int8_pool(engine):
     byte-for-byte (a dequant->requant round trip would perturb the scales'
     last ulp), so migrated int8 streams match stay-put int8 streams
     exactly — and the dedicated migrate-in program compiled once."""
-    kw = dict(kv_pool={"enabled": True, "block_size": 8,
+    kw = dict(kv_pool={"block_size": 8,
                        "on_demand_growth": True, "kv_dtype": "int8"})
     router, reqs, committed = _drain_migrate_run(engine, **kw)
     assert router.metrics.snapshot()["migration"]["migrations_in"] >= 2
@@ -194,7 +194,7 @@ def test_migration_tp_mesh_parity(devices8):
          "tensor_parallel": {"tp_size": 2},
          "serving": {"n_slots": 2, "virtual_clock": True,
                      "chunked_prefill": {"enabled": True, "chunk_size": 8},
-                     "kv_pool": {"enabled": True, "block_size": 8,
+                     "kv_pool": {"block_size": 8,
                                  "on_demand_growth": True},
                      "migration": {"enabled": True,
                                    "snapshot_interval_tokens": 2}}}),
@@ -474,7 +474,7 @@ def _poisoned_fleet(retry_limit):
     mk = lambda eng: ServingEngine(
         eng, serving_config=ServingConfig(
             n_slots=2, virtual_clock=True, retry_limit=retry_limit,
-            kv_pool={"enabled": True, "block_size": 8,
+            kv_pool={"block_size": 8,
                      "on_demand_growth": True}),
         clock=VirtualClock())
     return Router([mk(sick), mk(healthy)]), sick, healthy

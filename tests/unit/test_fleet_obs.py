@@ -230,7 +230,7 @@ def load_wide(base):
 
 PREEMPT_KW = dict(
     chunked_prefill={"enabled": True, "chunk_size": 8},
-    kv_pool={"enabled": True, "block_size": 8, "n_blocks": 6,
+    kv_pool={"block_size": 8, "n_blocks": 6,
              "prefix_cache": False, "on_demand_growth": True})
 
 

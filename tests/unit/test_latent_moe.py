@@ -314,9 +314,8 @@ def test_the_cells_comparison_catches_each_broken_variant(
 SERVING = {"n_slots": 4, "max_len": 256, "max_prefills_per_step": 1,
            "chunked_prefill": {"enabled": True, "chunk_size": 32,
                                "decode_steps_between_chunks": 1},
-           "kv_pool": {"enabled": True, "block_size": 16, "n_blocks": 49,
-                       "prefix_cache": True, "on_demand_growth": False,
-                       "attention_backend": "gather"}}
+           "kv_pool": {"block_size": 16, "n_blocks": 49,
+                       "prefix_cache": True, "on_demand_growth": False}}
 
 
 def engine(serving=None, **kw):
@@ -424,7 +423,6 @@ def refused(**changes):
 
 
 @pytest.mark.parametrize("what,serving,kw", [
-    ("dense slot pool", refused(kv_pool={"enabled": False}), {}),
     ("int8 pool", refused(kv_pool={"kv_dtype": "int8"}), {}),
     ("speculative verify", refused(speculative={"enabled": True, "k": 2}),
      {}),
@@ -440,13 +438,26 @@ def test_what_the_engine_cannot_do_refuses_by_name(what, serving, kw):
     eng.destroy()
 
 
-@pytest.mark.parametrize("value", ["gather", "fused"])
-def test_attention_backend_option_selects_nothing_for_a_latent_model(value):
-    """``kv_pool.attention_backend`` names no path any more: a latent model
-    given either value serves through its own view, as without it, and the
-    snapshot says which path that is and why. The decode kernel asked for by
-    name (the engine never does) is refused."""
-    eng = engine(refused(kv_pool={"attention_backend": value}))
+@pytest.mark.parametrize("stale", [
+    {"enabled": True, "attention_backend": "gather"},
+    {"enabled": True, "attention_backend": "fused"},
+    # what asked for the dense slot pool and was refused by name: ignored
+    # now, the engine has one KV store
+    {"enabled": False, "attention_backend": "gather"},
+], ids=["gather", "fused", "enabled-false"])
+def test_configuration_written_before_pr31_still_loads_for_a_latent_model(
+        stale):
+    """A ``kv_pool`` block with the two keys the program dropped (as
+    ``benchmark/configs/kanana-2-30b-a3b-serve.json`` carries them) loads
+    through ``init_inference``, warns once a key, and the latent model
+    serves through its own view, as without them; the snapshot says which
+    path that is and why. The decode kernel asked for by name (the engine
+    never does) is refused."""
+    from .conftest import STALE_KV_KEYS, unknown_key_warnings
+
+    with unknown_key_warnings() as seen:
+        eng = engine(refused(kv_pool=stale))
+    assert sorted(seen) == STALE_KV_KEYS
     sv = eng.serving
     assert sv.attn_backend == "view" and "latent attention" in sv.attn_reason
     kv = sv.metrics.snapshot()["kv_pool"]

@@ -14,16 +14,16 @@ The acceptance invariants of the decode-attention kernel (ROADMAP S5a):
   the kernel program MATERIALIZES NO dense per-slot view and no copy of a
   pool leaf (the compiled decode program is read);
 - which path runs is the engine's choice from what it can observe:
-  ``kv_pool.attention_backend`` selects nothing (either value is accepted,
-  logged once, and changes no token); an int8 pool, banded local layers and
-  speculative verify take the view, with the reason in the snapshot;
+  a configuration that still carries ``kv_pool.enabled`` and
+  ``kv_pool.attention_backend`` loads, warns once a key and changes no
+  token; an int8 pool, banded local layers and speculative verify take the
+  view, with the reason in the snapshot;
 - greedy serving streams are BITWISE equal kernel-vs-view-vs-sequential
   ``generate()`` under staggered arrivals (single-device and TP=2), decode
   compiles exactly once, and the snapshot counts the dispatches by path.
 """
 
 import functools
-import logging
 
 import numpy as np
 import jax
@@ -348,39 +348,34 @@ def test_serving_streams_bitwise_kernel_vs_view_vs_generate(kernel_engine,
         assert kv["decode_dispatches"][other] == 0
 
 
-@pytest.mark.parametrize("value", ["gather", "fused"])
-def test_attention_backend_option_selects_nothing(kernel_engine, engine,
-                                                  value):
-    """``kv_pool.attention_backend`` is still parsed and either value is
-    accepted, says ONCE that it has no effect, and changes neither the path
-    the engine chose nor a token."""
-    from deepspeed_tpu.utils.logging import logger
-
-    seen = []
-    handler = logging.Handler()
-    handler.emit = lambda record: seen.append(record.getMessage())
-    logger.addHandler(handler)
-    try:
-        for eng, path in ((kernel_engine, "kernel"), (engine, "view")):
-            del seen[:]
-            mk = lambda: staggered_requests(np.random.RandomState(1), 3)
-            plain, opted = mk(), mk()
+@pytest.mark.parametrize("value,enabled", [("gather", True),
+                                           ("fused", False)],
+                         ids=["gather", "fused"])
+def test_configuration_written_before_pr31_still_loads(kernel_engine, engine,
+                                                       value, enabled):
+    """A ``kv_pool`` block that still carries the two keys the program
+    dropped (``enabled``, ``attention_backend``) loads: each warns once as
+    an unknown key, and neither the path the engine chose nor a token
+    changes. The typed constructor knows neither field."""
+    stale = {"enabled": enabled, "attention_backend": value}
+    for eng, path in ((kernel_engine, "kernel"), (engine, "view")):
+        mk = lambda: staggered_requests(np.random.RandomState(1), 3)
+        plain, old = mk(), mk()
+        with conftest.unknown_key_warnings() as seen:
             list(make_serving(eng).serve(plain))
-            assert not [m for m in seen if "has no effect" in m]
-            sv = make_serving(eng, {"attention_backend": value})
-            list(sv.serve(opted))
-            assert sv.attn_backend == path
-            said = [m for m in seen if "has no effect" in m]
-            assert len(said) == 1 and repr(value) in said[0] \
-                and repr(path) in said[0], seen
-            assert [r.tokens for r in plain] == [r.tokens for r in opted]
-    finally:
-        logger.removeHandler(handler)
+        assert not seen
+        with conftest.unknown_key_warnings() as seen:
+            sv = make_serving(eng, stale)
+        assert sorted(seen) == conftest.STALE_KV_KEYS
+        list(sv.serve(old))
+        assert sv.attn_backend == path
+        assert [r.tokens for r in plain] == [r.tokens for r in old]
     from deepspeed_tpu.config import ConfigError
     from deepspeed_tpu.config.config import KVPoolConfig
 
-    with pytest.raises(ConfigError, match="selects nothing"):
-        KVPoolConfig(enabled=True, attention_backend="paged")
+    for field in stale:
+        with pytest.raises(ConfigError, match="unexpected fields"):
+            KVPoolConfig(**{field: stale[field]})
 
 
 def test_serving_seeded_sampling_unchanged_by_path(kernel_engine, engine):
@@ -553,7 +548,7 @@ def test_kernel_tp_mesh_parity(devices8):
             {"dtype": "float32", "max_tokens": 64,
              "tensor_parallel": {"tp_size": 2},
              "serving": {"n_slots": 2, "virtual_clock": True,
-                         "kv_pool": {"enabled": True, "block_size": 16}}}),
+                         "kv_pool": {"block_size": 16}}}),
             mesh=mesh)
         eng.params = jax.tree_util.tree_map(
             lambda v, s: jax.device_put(v, s), values, eng.param_shardings)

@@ -278,7 +278,7 @@ def test_priority_preemption_bitwise_tp2(devices8):
                      "policy": "weighted_fair",
                      "tenants": {"enabled": True},
                      "chunked_prefill": {"enabled": True, "chunk_size": 8},
-                     "kv_pool": {"enabled": True, "block_size": 8,
+                     "kv_pool": {"block_size": 8,
                                  "on_demand_growth": True},
                      "migration": {"enabled": True,
                                    "snapshot_interval_tokens": 2}}}),

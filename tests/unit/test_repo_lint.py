@@ -6,6 +6,8 @@ import os
 import sys
 import textwrap
 
+import pytest
+
 sys.path.insert(0, os.path.join(
     os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
     "tools"))
@@ -112,3 +114,39 @@ def test_package_is_clean():
     # the traced-set discovery is actually finding the hot programs, not
     # silently matching nothing
     assert sum(len(v) for v in traced.values()) > 50
+
+
+def _sources(*dirs):
+    for d in dirs:
+        for root, _, files in os.walk(os.path.join(repo_lint.REPO, d)):
+            for name in files:
+                if name.endswith(".py"):
+                    path = os.path.join(root, name)
+                    with open(path) as f:
+                        yield os.path.relpath(path, repo_lint.REPO), f.read()
+
+
+@pytest.mark.parametrize("what,pattern", [
+    ("an attribute .paged (an engine asking which KV store it has)",
+     r"\.paged\b"),
+    ("kv_pool.enabled", r"kv_pool\.enabled|kv_pool\[.enabled.\]"),
+    ("a --paged flag", r"--paged\b|args\.paged\b"),
+    ("attention_backend as anything but the snapshot's key",
+     r"\.attention_backend\b|--attention-backend|\battention_backend\s*="),
+])
+def test_one_kv_store(what, pattern):
+    """PR 31's fence: the serving engine has ONE KV store, the paged pool.
+    Nothing in the package or the tools asks which pool an engine has,
+    selects one, or selects a decode attention by name; ``attention_backend``
+    survives only as the key of ``snapshot()["kv_pool"]`` (and of the tools'
+    artifacts) that names the path that ran."""
+    import re
+
+    hits = [f"{rel}:{i}: {line.strip()}"
+            for rel, src in _sources("deepspeed_tpu", "tools")
+            for i, line in enumerate(src.splitlines(), 1)
+            if re.search(pattern, line)]
+    assert not hits, (what, hits)
+    from deepspeed_tpu.config.config import KVPoolConfig
+
+    assert not {"enabled", "attention_backend"} & set(KVPoolConfig().to_dict())

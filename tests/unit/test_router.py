@@ -55,7 +55,7 @@ def test_router_greedy_parity_chunked_paged_growth(engine):
     router = make_router(
         engine, n=2,
         chunked_prefill={"enabled": True, "chunk_size": 8},
-        kv_pool={"enabled": True, "block_size": 8, "on_demand_growth": True})
+        kv_pool={"block_size": 8, "on_demand_growth": True})
     reqs = [Request(
         prompt=rng.randint(0, 64, (int(rng.randint(4, 40)),)).astype(np.int32),
         max_new_tokens=int(rng.randint(3, 9)), arrival_time=i * 0.5)
@@ -90,7 +90,7 @@ def test_router_tp_mesh_parity(devices8):
          "tensor_parallel": {"tp_size": 2},
          "serving": {"n_slots": 2, "virtual_clock": True,
                      "chunked_prefill": {"enabled": True, "chunk_size": 8},
-                     "kv_pool": {"enabled": True, "block_size": 8,
+                     "kv_pool": {"block_size": 8,
                                  "on_demand_growth": True}}}), mesh=mesh)
     eng.params = jax.tree_util.tree_map(
         lambda v, s: jax.device_put(v, s), values, eng.param_shardings)
@@ -168,11 +168,11 @@ def test_prefix_affinity_beats_round_robin_hit_rate(engine):
             max_new_tokens=4, arrival_time=i * 3.0) for i in range(6)]
 
     affin = make_router(engine, n=2,
-                        kv_pool={"enabled": True, "block_size": 8})
+                        kv_pool={"block_size": 8})
     _, _, affin_snap = affin.run(requests(2))
 
     rr = make_router(engine, n=2, router={"policy": "round_robin"},
-                     kv_pool={"enabled": True, "block_size": 8})
+                     kv_pool={"block_size": 8})
     _, _, rr_snap = rr.run(requests(2))
 
     def hit_rate(snap):
@@ -194,7 +194,7 @@ def test_rebalance_overrides_overloaded_affinity_target(engine):
     rng = np.random.RandomState(3)
     router = make_router(engine, n=2, n_slots=1, max_queue_depth=64,
                          router={"rebalance_margin": 0.05},
-                         kv_pool={"enabled": True, "block_size": 8})
+                         kv_pool={"block_size": 8})
     sys_prompt = rng.randint(0, 64, (16,)).astype(np.int32)
     mk = lambda t: Request(
         prompt=np.concatenate([sys_prompt,
@@ -332,7 +332,7 @@ def test_growth_admits_more_than_whole_footprint(engine):
     mk_reqs = lambda: [Request(prompt=rng.randint(0, 64, (8,)).astype(np.int32),
                                max_new_tokens=24, arrival_time=0.0)
                        for _ in range(6)]
-    pool = {"enabled": True, "block_size": 8, "n_blocks": 9,
+    pool = {"block_size": 8, "n_blocks": 9,
             "prefix_cache": False}
 
     whole = make_replica(engine, n_slots=6, kv_pool=dict(pool))
@@ -367,7 +367,7 @@ def test_preempted_request_resumes_bitwise_identical(engine):
     greedy streams match generate() and a seeded SAMPLED stream matches its
     un-preempted self token for token."""
     rng = np.random.RandomState(8)
-    tight = {"enabled": True, "block_size": 8, "n_blocks": 8,
+    tight = {"block_size": 8, "n_blocks": 8,
              "prefix_cache": False, "on_demand_growth": True}
     sampled = lambda: Request(
         prompt=rng.randint(0, 64, (8,)).astype(np.int32), max_new_tokens=20,
@@ -394,7 +394,7 @@ def test_preempted_request_resumes_bitwise_identical(engine):
     # sampled leg: identical to the same seeded request served un-preempted
     rng = np.random.RandomState(8)
     roomy = make_replica(engine, n_slots=3,
-                         kv_pool={"enabled": True, "block_size": 8,
+                         kv_pool={"block_size": 8,
                                   "prefix_cache": False})
     s2 = sampled()
     list(roomy.serve([s2]))
@@ -410,7 +410,7 @@ def _hol_setup(engine, bypass):
     """1 running 2-block request + a 4-block head that can't fit + small
     requests behind it that could."""
     sv = make_replica(engine, n_slots=3, hol_bypass_limit=bypass,
-                      kv_pool={"enabled": True, "block_size": 8,
+                      kv_pool={"block_size": 8,
                                "n_blocks": 5, "prefix_cache": False})
     rng = np.random.RandomState(9)
     running = Request(prompt=rng.randint(0, 64, (8,)).astype(np.int32),
@@ -466,7 +466,7 @@ def test_router_monitor_events_match_snapshot(engine, tmp_path):
         csv_monitor={"enabled": True, "output_path": str(tmp_path),
                      "job_name": "router_test"})
     replicas = [make_replica(engine,
-                             kv_pool={"enabled": True, "block_size": 8})
+                             kv_pool={"block_size": 8})
                 for _ in range(2)]
     router = Router(replicas, monitor=MonitorMaster(mcfg))
     rng = np.random.RandomState(10)

@@ -9,8 +9,8 @@ Three layers, mirroring test_collective_audit.py's structure:
    pair): the defective twin must light up every rule through an actual
    lower+compile; the clean twin must produce nothing above info.
 3. The serving decode program, audited end to end and held to the
-   checked-in ``serving-decode/8/bf16`` budget — the tier-1 fence for the
-   paged-KV / flash-decode rewrites ROADMAP items 1-2 will make. (The tiny
+   checked-in ``serving-decode-paged/8/bf16`` (the view) and
+   ``serving-decode-fused/8/bf16`` (the kernel) budgets. (The tiny
    TRAINING preset's sanitizer gate lives in test_collective_audit.py,
    riding the cached tiny-test audit.)
 """
@@ -348,11 +348,15 @@ def test_clean_program_zero_findings_above_info(devices8):
 # 3. the serving decode program, held to the checked-in budget (tier-1)
 # ---------------------------------------------------------------------------
 
-@pytest.fixture(scope="module")
-def decode_report(devices8):
+@pytest.fixture(scope="module", params=[None, {"block_size": 16}],
+                ids=["no-kv_pool-key", "block16"])
+def decode_report(request, devices8):
     """Same geometry as tools/program_lint.py --program decode defaults
-    (tiny-test dims, 4 slots x 64 KV window) so the committed
-    serving-decode/8/bf16 budget's observed values are THIS program's."""
+    (tiny-test dims, 4 slots, 64-token windows in blocks of 16) so the
+    committed serving-decode-paged/8/bf16 budget's observed values are THIS
+    program's: the decode program over the gather view (block-table gathers
+    + pool writeback), once from a ``serving`` block that names no pool and
+    once from one that spells the same pool out."""
     import jax.numpy as jnp
 
     import deepspeed_tpu
@@ -361,11 +365,13 @@ def decode_report(devices8):
     model = CausalLM(TransformerConfig(
         vocab_size=512, max_seq_len=64, n_layers=4, n_heads=4,
         d_model=128, d_ff=256, compute_dtype=jnp.bfloat16))
+    serving = {"n_slots": 4, "max_len": 64, "virtual_clock": True}
+    if request.param is not None:
+        serving["kv_pool"] = request.param
     engine = deepspeed_tpu.init_inference(
         model=model,
-        config={"dtype": "bfloat16", "max_tokens": 64,
-                "serving": {"n_slots": 4, "max_len": 64,
-                            "virtual_clock": True}})
+        config={"dtype": "bfloat16", "max_tokens": 64, "serving": serving})
+    assert engine.serving.attn_backend == "view"
     report = engine.decode_program_report()
     yield report
     engine.destroy()
@@ -374,50 +380,13 @@ def decode_report(devices8):
 def test_serving_decode_within_sanitizer_budget(decode_report):
     from deepspeed_tpu.profiling.collectives import check_budgets
 
-    v = check_budgets(decode_report, BUDGETS["serving-decode/8/bf16"])
+    v = check_budgets(decode_report, BUDGETS["serving-decode-paged/8/bf16"])
     assert not v, v
     san = decode_report["sanitizer"]
     # nothing above info once the QK f32 einsum is allowlisted
     assert count_at_or_above(san["findings"], "warning") == 0
-
-
-@pytest.fixture(scope="module")
-def decode_report_paged(devices8):
-    """tools/program_lint.py --program decode --paged geometry: the PAGED
-    decode program (block-table gathers + pool writeback) held to the
-    checked-in serving-decode-paged/8/bf16 budget — the fence for ROADMAP
-    item 1's rewrite, enforced tier-1 alongside the dense gate."""
-    import jax.numpy as jnp
-
-    import deepspeed_tpu
-    from deepspeed_tpu.models import CausalLM, TransformerConfig
-
-    model = CausalLM(TransformerConfig(
-        vocab_size=512, max_seq_len=64, n_layers=4, n_heads=4,
-        d_model=128, d_ff=256, compute_dtype=jnp.bfloat16))
-    engine = deepspeed_tpu.init_inference(
-        model=model,
-        config={"dtype": "bfloat16", "max_tokens": 64,
-                "serving": {"n_slots": 4, "max_len": 64,
-                            "virtual_clock": True,
-                            "kv_pool": {"enabled": True,
-                                        "block_size": 16}}})
-    report = engine.decode_program_report()
-    yield report
-    engine.destroy()
-
-
-def test_serving_decode_paged_within_sanitizer_budget(decode_report_paged):
-    from deepspeed_tpu.profiling.collectives import check_budgets
-
-    v = check_budgets(decode_report_paged,
-                      BUDGETS["serving-decode-paged/8/bf16"])
-    assert not v, v
-    san = decode_report_paged["sanitizer"]
-    assert count_at_or_above(san["findings"], "warning") == 0
-    # full donation of the paged pool state: k/v pool + block table +
-    # per-slot cursors/rng/knobs all alias outputs, zero host transfers —
-    # the paged rewrite kept the program inside the same fence
+    # full donation of the pool state: k/v pool + block table + per-slot
+    # cursors/rng/knobs all alias outputs, zero host transfers
     assert san["summary"]["n_aliased_params"] == 12
     assert san["summary"]["undonated_candidate_bytes"] == 0
     assert san["summary"]["transfer_count"] == 0
@@ -425,8 +394,8 @@ def test_serving_decode_paged_within_sanitizer_budget(decode_report_paged):
 
 @pytest.fixture(scope="module")
 def decode_report_fused(devices8):
-    """tools/program_lint.py --program decode --paged --attention-interpret
-    geometry: the PAGED decode program through the flash-decode kernel
+    """tools/program_lint.py --program decode-fused geometry: the decode
+    program through the flash-decode kernel
     (block-table walk IN-KERNEL, no dense per-slot view; the engine chooses
     it where the kernel can run) held to the checked-in
     serving-decode-fused/8/bf16 budget, enforced tier-1 alongside the view
@@ -445,8 +414,7 @@ def decode_report_fused(devices8):
         config={"dtype": "bfloat16", "max_tokens": 64,
                 "serving": {"n_slots": 4, "max_len": 64,
                             "virtual_clock": True,
-                            "kv_pool": {"enabled": True,
-                                        "block_size": 16}}})
+                            "kv_pool": {"block_size": 16}}})
     assert engine.serving.attn_backend == "kernel"
     report = engine.decode_program_report()
     yield report
@@ -554,8 +522,7 @@ def verify_report(devices8):
         config={"dtype": "bfloat16", "max_tokens": 64,
                 "serving": {"n_slots": 4, "max_len": 64,
                             "virtual_clock": True,
-                            "kv_pool": {"enabled": True,
-                                        "block_size": 16},
+                            "kv_pool": {"block_size": 16},
                             "speculative": {"enabled": True, "k": 4}}})
     report = engine.verify_program_report()
     yield report
@@ -583,12 +550,13 @@ def test_serving_verify_within_sanitizer_budget(verify_report):
 
 def test_serving_decode_slot_state_fully_donated(decode_report):
     """The donation discipline the slot pool depends on: every state leaf
-    (KV pool, cursors, rng, sampling knobs — 11 arrays) aliases an output,
+    (KV pool, block table, cursors, rng, sampling knobs — 12 arrays)
+    aliases an output,
     so decode-in-a-loop holds ONE copy of the pool, not two. The only
     un-aliased outputs are the 2 that ran out of same-shape input buffers
     (nxt/done_now duplicates); weights are read-only by design."""
     san = decode_report["sanitizer"]
-    assert san["summary"]["n_aliased_params"] == 11
+    assert san["summary"]["n_aliased_params"] == 12
     assert san["summary"]["undonated_candidate_bytes"] == 0
     assert not [f for f in san["findings"]
                 if f["rule"] == "donation" and not f.get("allowed")]
@@ -601,4 +569,5 @@ def test_serving_decode_no_transfers_or_hazards(decode_report):
     assert san["summary"].get("python_scalar_args", 0) == 0
     p = san["peak_hbm"]
     assert 0 < p["estimate_bytes"] < \
-        BUDGETS["serving-decode/8/bf16"]["sanitizer"]["peak_hbm_gb_max"] * 1e9
+        BUDGETS["serving-decode-paged/8/bf16"]["sanitizer"][
+            "peak_hbm_gb_max"] * 1e9

@@ -88,7 +88,7 @@ def test_slot_reuse_cannot_leak_stale_kv(engine):
 
     np.testing.assert_array_equal(np.asarray(reused.tokens),
                                   np.asarray(pristine.tokens))
-    # and the same again with the hygiene scrub on (reset_slot_kv path)
+    # and the same again with the hygiene scrub on (the block scrub)
     sv2 = make_replica(engine, n_slots=1, scrub_freed_slots=True)
     list(sv2.serve([Request(prompt=long_req.prompt, max_new_tokens=20)]))
     scrubbed = Request(prompt=short_prompt, max_new_tokens=6)
@@ -361,14 +361,16 @@ def test_serving_tp_mesh_parity(devices8):
     eng.destroy()
 
 
-@pytest.mark.parametrize("paged", [True, False], ids=["paged", "dense"])
-def test_bench_serving_qps_smoke(tmp_path, paged):
+@pytest.mark.parametrize("fleet", [True, False],
+                         ids=["blocks8-shared-prefix", "blocks16"])
+def test_bench_serving_qps_smoke(tmp_path, fleet):
     """tools/bench_serving.py --qps emits the throughput–latency artifact on
     the tiny preset under JAX_PLATFORMS=cpu (tier-1 smoke, incl. overload
-    shed accounting). Both rows run THROUGH THE ROUTER (the artifact always
-    carries a router block); the paged row additionally exercises
-    --replicas 2 + --chunk-size + --session-affinity and the kv_pool block
-    the committed artifact carries."""
+    shed accounting), over two pool geometries. Both rows run THROUGH THE
+    ROUTER and carry the kv_pool block; the first (blocks of 8, prompts
+    opening with a shared prefix) additionally exercises --replicas 2 +
+    --chunk-size + --session-affinity + speculation, the second is the
+    pool no flag describes (blocks of 16, slots x max_len tokens)."""
     out = tmp_path / "serving_load.json"
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     cmd = [sys.executable, os.path.join(REPO, "tools", "bench_serving.py"),
@@ -376,8 +378,8 @@ def test_bench_serving_qps_smoke(tmp_path, paged):
            "--sizes", "tiny", "--modes", "bf16", "--prompts", "8,16",
            "--new-tokens", "6", "--slots", "2", "--queue-depth", "3",
            "--seed", "0", "--output", str(out)]
-    if paged:
-        cmd += ["--paged", "--kv-block-size", "8", "--shared-prefix", "8",
+    if fleet:
+        cmd += ["--kv-block-size", "8", "--shared-prefix", "8",
                 "--replicas", "2", "--chunk-size", "8",
                 "--session-affinity", "--spec-draft", "ngram",
                 "--spec-k", "4"]
@@ -396,7 +398,7 @@ def test_bench_serving_qps_smoke(tmp_path, paged):
     # the router block is always present: per-replica routing/occupancy,
     # affinity hit rates, rebalances + drain counts
     router = art["router"]
-    assert router["replicas"] == (2 if paged else 1)
+    assert router["replicas"] == (2 if fleet else 1)
     assert sum(router["per_replica_routed"]) == router["routed"]
     assert router["routed"] == art["completed"]
     assert "affinity_hit_rate" in router and "rebalances" in router
@@ -406,7 +408,13 @@ def test_bench_serving_qps_smoke(tmp_path, paged):
     assert art["slo"]["configured"] is False and art["slo"]["pass"] is True
     assert 0.0 < art["goodput"]["goodput_frac"] <= 1.0
     assert art["goodput"]["replay_tokens"] == 0
-    if paged:
+    kv = art["kv_pool"]
+    assert kv["n_blocks"] > 1 and kv["block_size"] == (8 if fleet else 16)
+    assert 0.0 <= kv["occupancy"] <= 1.0
+    assert 0.0 <= kv["fragmentation"] <= 1.0
+    assert "prefix_hit_rate" in kv and "shed_reasons" in kv
+    assert sum(kv["shed_reasons"].values()) == art["shed"]
+    if fleet:
         assert art["replicas"] == 2
         assert router["session_hits"] > 0  # sticky sessions engaged
         assert len(art["compile_counts_per_replica"]) == 2
@@ -418,13 +426,8 @@ def test_bench_serving_qps_smoke(tmp_path, paged):
         assert spec["drafts"] == spec["accepted"] + spec["rollbacks"]
         assert 0.0 <= spec["accept_rate"] <= 1.0
         assert art["compile_counts"].get("verify", 0) <= 1
-        kv = art["kv_pool"]
-        assert kv["n_blocks"] > 1 and kv["block_size"] == 8
-        assert 0.0 <= kv["occupancy"] <= 1.0
-        assert 0.0 <= kv["fragmentation"] <= 1.0
-        assert "prefix_hit_rate" in kv and "shed_reasons" in kv
-        assert sum(kv["shed_reasons"].values()) == art["shed"]
     else:
-        assert "kv_pool" not in art  # dense path unchanged
+        # no flag: slots x max_len tokens of blocks + the garbage block
+        assert kv["capacity_tokens"] == 2 * 64
         assert art["speculative"]["drafter"] == "off"
         assert art["speculative"]["drafts"] == 0

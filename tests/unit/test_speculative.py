@@ -75,15 +75,13 @@ class WrongDrafter:
 # ---------------------------------------------------------------------------
 
 def test_config_validation():
+    # speculation needs no pool key: the one KV store has the blocks
+    # rollback rides on
+    assert ServingConfig(speculative={"enabled": True}).speculative.enabled
     with pytest.raises(ConfigError):
-        # speculation without the paged pool: rollback needs blocks
-        ServingConfig(speculative={"enabled": True})
+        ServingConfig(speculative={"enabled": True, "drafter": "oracle"})
     with pytest.raises(ConfigError):
-        ServingConfig(kv_pool={"enabled": True},
-                      speculative={"enabled": True, "drafter": "oracle"})
-    with pytest.raises(ConfigError):
-        ServingConfig(kv_pool={"enabled": True},
-                      speculative={"enabled": True, "k": 0})
+        ServingConfig(speculative={"enabled": True, "k": 0})
 
 
 def test_ngram_drafter_prompt_lookup():
@@ -429,7 +427,7 @@ def test_spec_monitor_events_coherent_with_snapshot(engine, tmp_path):
         engine,
         serving_config=ServingConfig(
             n_slots=2, virtual_clock=True, monitor_interval=1,
-            kv_pool={"enabled": True, "block_size": 16},
+            kv_pool={"block_size": 16},
             speculative={"enabled": True, "drafter": "ngram", "k": 4}),
         clock=VirtualClock(), monitor=MonitorMaster(mcfg))
     req = Request(prompt=repetitive_prompt(), max_new_tokens=20)
@@ -465,7 +463,7 @@ def test_spec_wide_event_counts_reconcile(engine):
         engine,
         serving_config=ServingConfig(
             n_slots=2, virtual_clock=True,
-            kv_pool={"enabled": True, "block_size": 16},
+            kv_pool={"block_size": 16},
             speculative={"enabled": True, "drafter": "ngram", "k": 4}),
         clock=clock, tracer=SpanTracer(enabled=True, clock=clock.now))
     list(sv.serve(reqs))
@@ -510,7 +508,7 @@ def test_spec_tp_mesh_parity(devices8):
          "tensor_parallel": {"tp_size": 2},
          "serving": {"n_slots": 2, "virtual_clock": True,
                      "max_prefills_per_step": 2,
-                     "kv_pool": {"enabled": True, "block_size": 16,
+                     "kv_pool": {"block_size": 16,
                                  "n_blocks": 6, "prefix_cache": False,
                                  "on_demand_growth": True},
                      "speculative": {"enabled": True, "drafter": "ngram",

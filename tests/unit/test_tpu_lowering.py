@@ -468,8 +468,8 @@ def _tp4_engine(devices, **serving):
     return InferenceEngine(model, DeepSpeedInferenceConfig.from_dict(
         {"dtype": "bfloat16", "max_tokens": 256,
          "tensor_parallel": {"tp_size": 4},
-         "serving": {"n_slots": 4, "kv_pool": {"enabled": True,
-                                               "block_size": 16, **serving}}}),
+         "serving": {"n_slots": 4, "kv_pool": {"block_size": 16,
+                                               **serving}}}),
         mesh=mesh)
 
 
@@ -491,10 +491,16 @@ def test_tp4_flash_prefill_lowers(devices8):
 def test_tp4_kernel_decode_lowers(devices8):
     """TP=4: the kernel is probed at the engine's per-chip geometry (8 of 32
     heads, a 512-wide share of the pool's merged axis), chosen, and its
-    decode program lowers for the TPU inside a shard_map; an
-    ``attention_backend`` value changes nothing of it."""
+    decode program lowers for the TPU inside a shard_map; a configuration
+    written before PR 31 (``enabled``, ``attention_backend``) still loads,
+    warns once a key, and changes nothing of it."""
+    from .conftest import STALE_KV_KEYS, unknown_key_warnings
+
     with lowering_target("tpu"):
-        eng = _tp4_engine(devices8, attention_backend="gather")
+        with unknown_key_warnings() as seen:
+            eng = _tp4_engine(devices8, enabled=True,
+                              attention_backend="gather")
+        assert sorted(seen) == STALE_KV_KEYS
         try:
             sv = eng.serving
             assert (sv.attn_backend, sv.attn_reason) == ("kernel", "")

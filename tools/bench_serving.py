@@ -190,30 +190,21 @@ def run_open_loop(args):
     max_tokens = ((max(prompts) + args.new_tokens + 63) // 64) * 64
     # which decode attention runs is the engine's choice (on a TPU: the
     # kernel where the compiler takes it); off a TPU the kernel only runs
-    # under the interpreter, which is all this flag still asks for
+    # under the interpreter, which is all this flag asks for
     model_kw = {"attention_interpret": True} \
-        if (args.attention_backend in ("kernel", "fused")
+        if (args.attention_interpret
             and jax.devices()[0].platform != "tpu") else {}
     engine, n_params, _ = build_engine(args.family, size, mode, max_tokens,
                                        **model_kw)
-    serving_kw = dict(n_slots=args.slots, max_queue_depth=args.queue_depth)
-    if args.paged:
-        serving_kw["kv_pool"] = {
-            "enabled": True, "block_size": args.kv_block_size,
-            "n_blocks": args.kv_blocks, "kv_dtype": args.kv_dtype,
-            "on_demand_growth": bool(args.kv_growth)}
-    elif args.attention_backend in ("kernel", "fused"):
-        print("--attention-backend kernel requires --paged (the decode "
-              "kernel reads the paged pool)", file=sys.stderr)
-        return 1
+    serving_kw = dict(
+        n_slots=args.slots, max_queue_depth=args.queue_depth,
+        kv_pool={"block_size": args.kv_block_size,
+                 "n_blocks": args.kv_blocks, "kv_dtype": args.kv_dtype,
+                 "on_demand_growth": bool(args.kv_growth)})
     if args.chunk_size:
         serving_kw["chunked_prefill"] = {"enabled": True,
                                          "chunk_size": args.chunk_size}
     if args.spec_draft:
-        if not args.paged:
-            print("--spec-draft requires --paged (speculative rollback "
-                  "rides the block machinery)", file=sys.stderr)
-            return 1
         serving_kw["speculative"] = {"enabled": True,
                                      "drafter": args.spec_draft,
                                      "k": args.spec_k}
@@ -238,11 +229,6 @@ def run_open_loop(args):
             "scale_up_queue_depth": max(2.0, args.queue_depth / 2.0)}
     pools_on = bool(args.prefill_replicas or args.decode_replicas)
     if pools_on:
-        if not args.paged:
-            print("--prefill-replicas/--decode-replicas require --paged "
-                  "(the first-token KV handoff splices pool blocks)",
-                  file=sys.stderr)
-            return 1
         # disaggregated topology: the pool split IS the replica count
         args.replicas = max(args.prefill_replicas, 1) \
             + max(args.decode_replicas, 1)
@@ -261,10 +247,6 @@ def run_open_loop(args):
             print(f"--chaos-kills {args.chaos_kills} must leave at least one "
                   f"survivor of --replicas {args.replicas}", file=sys.stderr)
             return 1
-        if not args.paged:
-            print("--chaos-kills requires --paged (live KV migration "
-                  "snapshots ride the block pool)", file=sys.stderr)
-            return 1
         # arm live migration so failover re-dispatches splice from the last
         # snapshot instead of replaying the whole committed stream
         serving_kw["migration"] = {
@@ -276,7 +258,7 @@ def run_open_loop(args):
     arrivals = np.cumsum(rng.exponential(1.0 / args.qps, args.num_requests))
     vocab = engine.module.config.vocab_size
     # --shared-prefix: every prompt opens with the SAME system-prompt tokens
-    # (the paged pool's prefix cache turns the repeats into block hits)
+    # (the pool's prefix cache turns the repeats into block hits)
     shared = rng.randint(0, vocab, (max(args.shared_prefix, 0),)) \
         .astype(np.int32)
     requests = []
@@ -467,15 +449,14 @@ def run_open_loop(args):
     }
     if len(replicas) > 1:
         artifact["compile_counts_per_replica"] = router.compile_counts()
-    if "kv_pool" in metrics_snap:
-        # paged-pool accounting next to the run stamp / numerics blocks: a
-        # tokens/s number means something different at 30% vs 95% block
-        # occupancy, and the shed histogram says WHY work was turned away
-        # (replica 0's pool; per-replica occupancy lives in the router block)
-        artifact["kv_pool"] = dict(
-            metrics_snap["kv_pool"],
-            kv_dtype=args.kv_dtype or "engine",
-            shed_reasons=agg_shed)
+    # pool accounting next to the run stamp / numerics blocks: a tokens/s
+    # number means something different at 30% vs 95% block occupancy, and
+    # the shed histogram says WHY work was turned away (replica 0's pool;
+    # per-replica occupancy lives in the router block)
+    artifact["kv_pool"] = dict(
+        metrics_snap["kv_pool"],
+        kv_dtype=args.kv_dtype or "engine",
+        shed_reasons=agg_shed)
     from _common import stamp_record
 
     stamp_record(artifact, config={
@@ -483,10 +464,10 @@ def run_open_loop(args):
         "num_requests": args.num_requests, "slots": args.slots,
         "queue_depth": args.queue_depth, "prompts": prompts,
         "new_tokens": args.new_tokens, "seed": args.seed,
-        "paged": bool(args.paged), "kv_block_size": args.kv_block_size,
+        "kv_block_size": args.kv_block_size,
         "kv_blocks": args.kv_blocks, "kv_dtype": args.kv_dtype,
-        # the backend that ACTUALLY ran (the probe may have fallen back to
-        # gather) — must agree with the kv_pool block's field
+        # the decode attention that ACTUALLY ran — must agree with the
+        # kv_pool block's field
         "attention_backend": replicas[0].attn_backend,
         "shared_prefix": args.shared_prefix, "replicas": len(replicas),
         "chunk_size": args.chunk_size,
@@ -527,22 +508,20 @@ def main():
     ap.add_argument("--num-requests", type=int, default=64)
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--queue-depth", type=int, default=8)
-    ap.add_argument("--paged", action="store_true",
-                    help="open-loop mode over the PAGED KV pool "
-                         "(serving.kv_pool): the artifact gains a kv_pool "
-                         "block (occupancy, fragmentation, prefix_hit_rate, "
-                         "shed histogram)")
-    ap.add_argument("--kv-block-size", type=int, default=16)
+    ap.add_argument("--kv-block-size", type=int, default=16,
+                    help="open-loop mode: tokens per block of the KV pool "
+                         "(serving.kv_pool); the artifact's kv_pool block "
+                         "carries occupancy, fragmentation, "
+                         "prefix_hit_rate and the shed histogram")
     ap.add_argument("--kv-blocks", type=int, default=0,
-                    help="0 = auto (dense-equivalent token capacity)")
+                    help="0 = auto (slots x max_len tokens)")
     ap.add_argument("--kv-dtype", default="", choices=["", "int8"])
-    ap.add_argument("--attention-backend", default="view",
-                    choices=["view", "kernel", "gather", "fused"],
-                    help="off a TPU only: 'kernel' (alias 'fused') runs the "
-                         "paged flash-decode kernel under the interpreter "
-                         "(--paged). On a TPU the engine chooses the path; "
-                         "the artifact's kv_pool block records which one "
-                         "produced the numbers, and why where it is the view")
+    ap.add_argument("--attention-interpret", action="store_true",
+                    help="off a TPU only: run the flash-decode kernel under "
+                         "the Pallas interpreter. On a TPU the engine "
+                         "chooses the path; the artifact's kv_pool block "
+                         "records which one produced the numbers, and why "
+                         "where it is the view")
     ap.add_argument("--shared-prefix", type=int, default=0,
                     help="open every prompt with this many IDENTICAL "
                          "system-prompt tokens (exercises the prefix cache)")
@@ -560,11 +539,11 @@ def main():
                     help="tag requests with a small pool of session ids so "
                          "the router's sticky-session map is exercised")
     ap.add_argument("--kv-growth", action="store_true",
-                    help="paged pool reserves prompt blocks only and grows "
+                    help="the pool reserves prompt blocks only and grows "
                          "decode blocks on demand (preempt-to-queue on "
                          "exhaustion)")
     ap.add_argument("--spec-draft", default="", choices=["", "ngram", "model"],
-                    help="speculative decoding (requires --paged): drafter "
+                    help="speculative decoding: drafter "
                          "proposing up to --spec-k tokens per greedy slot, "
                          "verified in ONE target forward; the artifact "
                          "gains a speculative block (accept_rate, "
@@ -572,7 +551,7 @@ def main():
     ap.add_argument("--spec-k", type=int, default=4,
                     help="max draft tokens per verify step")
     ap.add_argument("--prefill-replicas", type=int, default=0,
-                    help="disaggregated fleet (requires --paged): dedicate "
+                    help="disaggregated fleet: dedicate "
                          "this many replicas to PREFILL; at first token the "
                          "stream's KV hands off to the decode pool via a "
                          "fresh snapshot splice (zero recompute). Overrides "
@@ -608,7 +587,7 @@ def main():
                          "idle; the artifact's autoscaler block records the "
                          "scale-event timeline and replica-step economics")
     ap.add_argument("--chaos-kills", type=int, default=0,
-                    help="open-loop mode (requires --paged): kill this many "
+                    help="open-loop mode: kill this many "
                          "replicas at seeded instants during the offered-"
                          "load window (testing.ReplicaChaosSchedule); arms "
                          "live KV migration so failovers splice snapshots "

@@ -85,7 +85,7 @@ def make_replica(engine, args):
         n_slots=args.slots,
         retry_limit=args.retry_limit,
         chunked_prefill={"enabled": True, "chunk_size": 8},
-        kv_pool={"enabled": True, "block_size": 8, "on_demand_growth": True},
+        kv_pool={"block_size": 8, "on_demand_growth": True},
         migration={"enabled": True,
                    "snapshot_interval_tokens": args.snapshot_interval})
     if args.prefill_replicas or args.decode_replicas:

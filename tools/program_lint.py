@@ -11,8 +11,7 @@ recompile hazards, and a liveness-walk peak-HBM estimate.
     # the tier-1-shaped gates (also run in tests/unit/test_sanitizer.py):
     python tools/program_lint.py --program train --preset tiny-test \
         --devices 8 --budget tiny-test/8/bf16 --fail-on error
-    python tools/program_lint.py --program decode --budget serving-decode/8/bf16
-    python tools/program_lint.py --program decode --paged \
+    python tools/program_lint.py --program decode \
         --budget serving-decode-paged/8/bf16 --fail-on warning
     python tools/program_lint.py --program decode-fused \
         --budget serving-decode-fused/8/bf16 --fail-on warning
@@ -60,11 +59,12 @@ def lint_train(args):
                            gather_impl=args.gather_impl)
 
 
-def lint_decode(args):
+def lint_decode(args, kernel=False):
     """The serving decode program over a live slot pool. Builds a REAL
     engine (params materialize), so this path is for test-sized presets —
     the decode program's geometry (slot pool, KV layout, donation pattern)
-    is preset-independent."""
+    is preset-independent. ``kernel``: the flash-decode kernel's program
+    instead of the gather view's."""
     import jax.numpy as jnp
 
     if REPO not in sys.path:
@@ -85,13 +85,11 @@ def lint_decode(args):
         # this lint always runs on the CPU platform, where the engine
         # chooses the decode kernel only under the interpreter: the kernel's
         # program is audited as the interpreter lowers it, the view's as is
-        attention_interpret=args.attention_backend in ("kernel", "fused")))
+        attention_interpret=kernel))
     serving = {"n_slots": args.slots, "max_len": max_len,
-               "virtual_clock": True}
-    if args.paged:
-        serving["kv_pool"] = {"enabled": True,
-                              "block_size": args.kv_block_size,
-                              "kv_dtype": args.kv_dtype}
+               "virtual_clock": True,
+               "kv_pool": {"block_size": args.kv_block_size,
+                           "kv_dtype": args.kv_dtype}}
     engine = deepspeed_tpu.init_inference(
         model=model,
         config={"dtype": "bfloat16", "max_tokens": max_len,
@@ -99,9 +97,7 @@ def lint_decode(args):
     report = engine.decode_program_report()
     report.update({"preset": args.preset, "devices": args.devices,
                    "n_slots": args.slots, "serving_max_len": max_len,
-                   "paged": bool(args.paged),
-                   "attention_backend": engine.serving.attn_backend
-                   if args.paged else "dense",
+                   "attention_backend": engine.serving.attn_backend,
                    "n_params": engine.module.num_parameters
                    if hasattr(engine.module, "num_parameters") else None})
     engine.destroy()
@@ -172,8 +168,7 @@ def lint_verify(args):
         compute_dtype=jnp.bfloat16))
     serving = {"n_slots": args.slots, "max_len": max_len,
                "virtual_clock": True,
-               "kv_pool": {"enabled": True,
-                           "block_size": args.kv_block_size,
+               "kv_pool": {"block_size": args.kv_block_size,
                            "kv_dtype": args.kv_dtype},
                "speculative": {"enabled": True, "k": args.spec_k}}
     engine = deepspeed_tpu.init_inference(
@@ -304,11 +299,8 @@ def child(args):
     if args.program in ("decode", "all"):
         programs["decode"] = lint_decode(args)
     if args.program == "decode-fused":
-        # alias: the paged decode program through the flash-decode kernel
-        # (== --program decode --paged --attention-backend kernel)
-        args.paged = True
-        args.attention_backend = "kernel"
-        programs["decode-fused"] = lint_decode(args)
+        # the decode program through the flash-decode kernel
+        programs["decode-fused"] = lint_decode(args, kernel=True)
     if args.program in ("prefill-chunked", "all"):
         programs["prefill-chunked"] = lint_prefill_chunked(args)
     if args.program in ("verify", "all"):
@@ -329,7 +321,13 @@ def main():
     ap.add_argument("--program", default="all",
                     choices=["train", "decode", "decode-fused",
                              "prefill-chunked", "verify", "all", "planted",
-                             "clean"])
+                             "clean"],
+                    help="'decode' lints the serving decode program over "
+                         "the gather view (--budget serving-decode-paged/8/"
+                         "bf16), 'decode-fused' the same program through "
+                         "the flash-decode kernel as the interpreter lowers "
+                         "it (--budget serving-decode-fused/8/bf16). On a "
+                         "chip the engine chooses between the two")
     ap.add_argument("--preset", default="tiny-test")
     ap.add_argument("--devices", type=int, default=8)
     ap.add_argument("--micro", type=int, default=1)
@@ -341,20 +339,8 @@ def main():
                     choices=["fp32", "bf16"])
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--serving-max-len", type=int, default=None)
-    ap.add_argument("--paged", action="store_true",
-                    help="decode program over the PAGED KV pool "
-                         "(serving.kv_pool) instead of the dense slot pool; "
-                         "gate with --budget serving-decode-paged/8/bf16")
     ap.add_argument("--kv-block-size", type=int, default=16)
     ap.add_argument("--kv-dtype", default="", choices=["", "int8"])
-    ap.add_argument("--attention-backend", default="view",
-                    choices=["view", "kernel", "gather", "fused"],
-                    help="which paged decode program to lint (--paged): "
-                         "'kernel' (alias 'fused') the flash-decode kernel's, "
-                         "as the interpreter lowers it, gate with --budget "
-                         "serving-decode-fused/8/bf16; 'view' (alias "
-                         "'gather') the gather view's. On a chip the engine "
-                         "chooses; no configuration key selects a path")
     ap.add_argument("--chunk-size", type=int, default=16,
                     help="chunked-prefill chunk (tokens) the "
                          "prefill-chunked program is linted at")
@@ -396,11 +382,8 @@ def main():
            "--grad-reduce-dtype", args.grad_reduce_dtype,
            "--slots", str(args.slots),
            "--kv-block-size", str(args.kv_block_size),
-           "--attention-backend", args.attention_backend,
            "--chunk-size", str(args.chunk_size),
            "--spec-k", str(args.spec_k)]
-    if args.paged:
-        cmd += ["--paged"]
     if args.kv_dtype:
         cmd += ["--kv-dtype", args.kv_dtype]
     if args.serving_max_len:
