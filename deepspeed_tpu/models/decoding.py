@@ -701,16 +701,20 @@ def sample_token(logits, rng, *, temperature=1.0, top_k=0, top_p=1.0,
     ``greedy``, ``top_k`` and ``top_p`` are static (shape the program);
     ``temperature`` may be a TRACED scalar so serving/rollout loops can change
     it without recompiling (the reference recompiles nothing — CUDA kernels
-    take it as a runtime arg; so do we).
+    take it as a runtime arg; so do we). What runs is decided at trace time:
+    ``greedy`` (or a Python ``temperature`` of 0) is the argmax alone; the
+    sort runs only for a static ``top_k > 0`` or ``0 < top_p < 1``.
 
     PER-REQUEST mode: pass ``rng`` as a [b, 2] stack of PRNG keys and
     temperature/top_k/top_p as [b] arrays — every co-batched row then samples
     from its OWN rng stream with its own knobs (continuous-batching slot
     pools), all traced so one compiled program covers every mix. Rows with
-    temperature <= 0 are greedy."""
+    temperature <= 0 are greedy, and what runs is decided ON THE DEVICE from
+    the rows' knobs (``sample_token_per_request``, which a slot pool calls
+    itself to say which rows are live)."""
     if jnp.ndim(rng) == 2:
         return sample_token_per_request(logits, rng, temperature=temperature,
-                                        top_k=top_k, top_p=top_p)
+                                        top_k=top_k, top_p=top_p)[0]
     logits = logits.astype(jnp.float32)
     if greedy:
         return jnp.argmax(logits, axis=-1).astype(jnp.int32)
@@ -748,38 +752,59 @@ def _apply_top_p(logits, top_p, sorted_desc=None):
     return jnp.where(top_p[:, None] >= 1.0, logits, filtered)
 
 
-def sample_token_per_request(logits, rngs, *, temperature, top_k, top_p):
+def sample_token_per_request(logits, rngs, *, temperature, top_k, top_p,
+                             live=None):
     """Per-request sampling for a slot pool: logits [b, vocab], rngs [b, 2]
     (one PRNG key per row — co-batched requests NEVER share an rng stream),
-    temperature/top_k/top_p [b] traced arrays. Rows with temperature <= 0
-    take the exact argmax (same tie-breaking as the scalar greedy path).
-    Returns [b] int32. Everything is traced: requests with any knob mix
-    join/leave the batch without recompiling."""
+    temperature/top_k/top_p [b] traced arrays, ``live`` [b] bool the rows
+    whose token is used (None: all; a freed slot keeps the knobs of the
+    request that left it, so it must not count). Returns ``(tokens,
+    sampled)``: [b] int32, and the scalar bool that chose the arm.
+    Everything is traced: requests with any knob mix join/leave the batch
+    without recompiling, and the ONE program does only the work its live
+    rows ask for (``lax.cond`` on what it observes):
+
+    - ``sampled`` false, no live row with ``temperature > 0``: the argmax
+      (float32, first index on a tie: the scalar greedy path's) and nothing
+      else;
+    - true: scale by the temperature, the whole-vocabulary sort with the
+      top-k threshold and the nucleus filter (a row that asks for neither
+      comes back unchanged), and one categorical per row from its own key.
+
+    A row's token never depends on which arm its neighbours chose: a greedy
+    row is the exact argmax in both, and a dead row's token is for the
+    caller to mask."""
     logits = logits.astype(jnp.float32)
-    b, vocab = logits.shape
     temperature = jnp.asarray(temperature, jnp.float32)
     top_k = jnp.asarray(top_k, jnp.int32)
     top_p = jnp.asarray(top_p, jnp.float32)
 
     greedy_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    sampling = temperature > 0.0
+    if live is not None:
+        sampling = sampling & live
+    sampled = jnp.any(sampling)
 
-    scaled = logits / jnp.maximum(temperature, 1e-6)[:, None]
-    # per-row top-k: threshold at the k-th largest (k <= 0 disables)
-    sorted_desc = jnp.sort(scaled, axis=-1)[:, ::-1]
-    k = jnp.clip(top_k, 0, vocab)
-    kth = jnp.take_along_axis(
-        sorted_desc, jnp.clip(k - 1, 0, vocab - 1)[:, None], axis=-1)
-    below_kth = lambda a: (k[:, None] > 0) & (a < kth)
-    scaled = jnp.where(below_kth(scaled), -1e30, scaled)
-    # masking the same tail in the already-sorted array keeps it sorted —
-    # one O(b * V log V) sort per decode step, not two
-    sorted_masked = jnp.where(below_kth(sorted_desc), -1e30, sorted_desc)
-    scaled = _apply_top_p(scaled, top_p, sorted_desc=sorted_masked)
+    def sample():
+        vocab = logits.shape[-1]
+        scaled = logits / jnp.maximum(temperature, 1e-6)[:, None]
+        # per-row top-k: threshold at the k-th largest (k <= 0 disables)
+        sorted_desc = jnp.sort(scaled, axis=-1)[:, ::-1]
+        k = jnp.clip(top_k, 0, vocab)
+        kth = jnp.take_along_axis(
+            sorted_desc, jnp.clip(k - 1, 0, vocab - 1)[:, None], axis=-1)
+        below_kth = lambda a: (k[:, None] > 0) & (a < kth)
+        scaled = jnp.where(below_kth(scaled), -1e30, scaled)
+        # masking the same tail in the already-sorted array keeps it sorted
+        # — one O(b * V log V) sort per sampled step, not two
+        sorted_masked = jnp.where(below_kth(sorted_desc), -1e30, sorted_desc)
+        scaled = _apply_top_p(scaled, top_p, sorted_desc=sorted_masked)
+        tok = jax.vmap(
+            lambda key, row: jax.random.categorical(key, row))(rngs, scaled)
+        return jnp.where(temperature <= 0.0, greedy_tok,
+                         tok.astype(jnp.int32))
 
-    sampled = jax.vmap(
-        lambda key, row: jax.random.categorical(key, row))(rngs, scaled)
-    return jnp.where(temperature <= 0.0, greedy_tok,
-                     sampled.astype(jnp.int32))
+    return jax.lax.cond(sampled, sample, lambda: greedy_tok), sampled
 
 
 def prefill_and_first_token(model, params, ids, rng, temperature, *, max_len,

@@ -33,7 +33,8 @@ from ..inference.engine import lru_compiled
 from ..models.decoding import (extract_slot_blocks, forward_with_cache,
                                forward_with_paged_cache, gather_slot_cache,
                                init_cache, init_paged_cache,
-                               insert_block_kv, reset_block_kv, sample_token,
+                               insert_block_kv, reset_block_kv,
+                               sample_token_per_request,
                                verify_with_paged_cache, write_pool_blocks)
 from ..ops.pallas.kv_block_write import blocks_in_lanes
 from ..utils.logging import log_dist
@@ -536,10 +537,13 @@ class ServingEngine:
             nonfinite = jnp.sum(
                 jnp.logical_not(jnp.isfinite(logits[:, 0])),
                 axis=-1).astype(jnp.int32)
-            nxt = sample_token(logits[:, 0], split[:, 0],
-                               temperature=state["temp"],
-                               top_k=state["top_k"], top_p=state["top_p"])
+            # the sampler does the work the LIVE rows ask for (a freed slot
+            # keeps the knobs of the request that left it) and says which
+            # arm that was; the rng split above stays outside its branches
             active = state["active"]
+            nxt, sampled = sample_token_per_request(
+                logits[:, 0], split[:, 0], temperature=state["temp"],
+                top_k=state["top_k"], top_p=state["top_p"], live=active)
             nxt = jnp.where(active, nxt, state["tok"])
             remaining = state["remaining"] - active.astype(jnp.int32)
             hit_eos = (state["eos"] >= 0) & (nxt == state["eos"])
@@ -556,7 +560,7 @@ class ServingEngine:
             })
             # the routing is an output of its own, [L_moe, S, 2k], read back
             # with the tokens
-            return (nxt, done_now, nonfinite,
+            return (nxt, done_now, nonfinite, sampled,
                     *(r[:, :, 0] for r in routed)), new_state
 
         def verify(params, state, drafts, draft_len):
@@ -575,11 +579,11 @@ class ServingEngine:
             active = state["active"]
             kk = drafts.shape[1]
             # column 0 samples with the slot's key (greedy rows are exact
-            # argmax inside sample_token); columns 1..k are greedy targets
+            # argmax inside the sampler); columns 1..k are greedy targets
             # — only greedy rows ever carry drafts (engine eligibility)
-            first = sample_token(logits[:, 0], split[:, 0],
-                                 temperature=state["temp"],
-                                 top_k=state["top_k"], top_p=state["top_p"])
+            first, sampled = sample_token_per_request(
+                logits[:, 0], split[:, 0], temperature=state["temp"],
+                top_k=state["top_k"], top_p=state["top_p"], live=active)
             tgt = jnp.argmax(logits.astype(jnp.float32),
                              axis=-1).astype(jnp.int32)
             out_toks = jnp.concatenate([first[:, None], tgt[:, 1:]], axis=1)
@@ -620,8 +624,8 @@ class ServingEngine:
                 "temp": state["temp"], "top_k": state["top_k"],
                 "top_p": state["top_p"], "eos": state["eos"],
             })
-            return (out_toks, n_emit, accepted, done_now,
-                    nonfinite), new_state
+            return (out_toks, n_emit, accepted, done_now, nonfinite,
+                    sampled), new_state
 
         def insert_meta(state, slot, table_row, tok, pos, remaining, rng,
                         temp, top_k, top_p, eos):
@@ -710,17 +714,17 @@ class ServingEngine:
             # prefill logits, which must never stream unchecked
             nonfinite = jnp.sum(
                 jnp.logical_not(jnp.isfinite(logits))).astype(jnp.int32)
-            tok = sample_token(logits, key[None, :],
-                               temperature=jnp.reshape(temp, (1,)),
-                               top_k=jnp.reshape(top_k, (1,)),
-                               top_p=jnp.reshape(top_p, (1,)))
+            tok, _ = sample_token_per_request(
+                logits, key[None, :], temperature=jnp.reshape(temp, (1,)),
+                top_k=jnp.reshape(top_k, (1,)),
+                top_p=jnp.reshape(top_p, (1,)))
             return tok, nonfinite
 
         rep, st = self._rep_sharding, self._state_shardings
         with self.engine.mesh:
             self._decode_jit = jax.jit(decode, donate_argnums=(1,),
                                        out_shardings=(
-                                           (rep,) * (3 + self._routing), st))
+                                           (rep,) * (4 + self._routing), st))
             self._insert_jit = jax.jit(insert_meta, donate_argnums=(0,),
                                        out_shardings=st)
             self._insert_block_jit = jax.jit(
@@ -736,7 +740,7 @@ class ServingEngine:
             if self.spec:
                 self._verify_jit = jax.jit(
                     verify, donate_argnums=(1,),
-                    out_shardings=((rep, rep, rep, rep, rep), st))
+                    out_shardings=((rep,) * 6, st))
             if self.cfg.kv_pool.kv_dtype == "int8":
                 self._migrate_in_jit = jax.jit(
                     migrate_in, donate_argnums=(0,), out_shardings=st)
@@ -1895,7 +1899,7 @@ class ServingEngine:
         with self.tracer.span("decode_step", cat="serving",
                               active=len(self._slots), verify=True,
                               drafted=int(dlen.sum())):
-            ((toks, n_emit, accepted, done_now, nonfinite),
+            ((toks, n_emit, accepted, done_now, nonfinite, sampled),
              self._state) = self._verify_jit(
                 self.engine.params, self._state, jnp.asarray(dmat),
                 jnp.asarray(dlen))
@@ -1911,6 +1915,7 @@ class ServingEngine:
         self.metrics.record_verify_step()
         self.metrics.record_decode_dispatch()
         self._decode_dispatches["view"] += 1
+        self.metrics.record_sampler_step(bool(sampled))
         for slot in sorted(self._slots):
             req = self._slots[slot]
             pos0 = req.prompt_len + len(req.tokens) - 1  # this step's cursor
@@ -1994,7 +1999,8 @@ class ServingEngine:
         self._dispatch_chunk_ahead()
         # one read-back for all the step hands out (a routing model: its
         # expert choices too)
-        toks, done_now, nonfinite, *routed = jax.device_get(out)
+        toks, done_now, nonfinite, sampled, *routed = jax.device_get(out)
+        self.metrics.record_sampler_step(bool(sampled))
         if routed:
             self._book_decode_routing(routed[0])
         now = self.clock.now()

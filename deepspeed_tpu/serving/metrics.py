@@ -107,6 +107,9 @@ class ServingMetrics:
         self.slo = slo
         self.tracer = tracer
         self.slo_violations = 0   # emit intervals with >=1 violated target
+        # decode dispatches by the sampler arm their live rows selected
+        # (lifetime counters, like the kv_pool block's decode_dispatches)
+        self.sampler_steps = {"greedy_steps": 0, "sampled_steps": 0}
         self.window_resets = 0    # reset_window() calls (warmup exclusion)
         # multi-tenant QoS: per-tenant counters + latency digests, keyed by
         # tenant_id (populated lazily — a single-tenant engine pays one
@@ -321,6 +324,12 @@ class ServingMetrics:
         """One decode-program dispatch (plain decode OR speculative
         verify): the denominator of ``accepted_tokens_per_step``."""
         self.decode_dispatches += 1
+
+    def record_sampler_step(self, sampled):
+        """One decode or verify dispatch, by the sampler arm that ran (the
+        program's own predicate, read back with its tokens): the argmax
+        alone (``greedy_steps``) or the full sampler (``sampled_steps``)."""
+        self.sampler_steps["sampled_steps" if sampled else "greedy_steps"] += 1
 
     def record_draft(self, n):
         self.drafted_tokens += int(n)
@@ -565,6 +574,7 @@ class ServingMetrics:
             "preempted": self.preempted,
             "kv_insert_dispatches": self.kv_insert_dispatches,
             "kv_insert_blocks": self.kv_insert_blocks,
+            "sampler": dict(self.sampler_steps),
             "health": {
                 "nonfinite_logit_steps": self.nonfinite_logit_steps,
                 "unhealthy_slots": self.unhealthy_slots,

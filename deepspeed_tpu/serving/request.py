@@ -51,9 +51,10 @@ FINISH_UNHEALTHY = "unhealthy_slot"
 
 @dataclasses.dataclass
 class SamplingParams:
-    """Per-request sampling knobs, threaded through ``sample_token`` as traced
-    per-slot arrays — co-batched requests never share an rng stream or a
-    temperature. ``temperature <= 0`` means greedy."""
+    """Per-request sampling knobs, threaded through
+    ``sample_token_per_request`` as traced per-slot arrays — co-batched
+    requests never share an rng stream or a temperature.
+    ``temperature <= 0`` means greedy."""
 
     temperature: float = 0.0
     top_k: int = 0

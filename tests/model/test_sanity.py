@@ -99,7 +99,8 @@ def test_real_text_byte_lm(devices8):
     root = os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
     text = b""
-    for fn in ("README.md", "SURVEY.md", "PERF.md"):
+    # documents no PR rewrites: the loss after 40 steps hangs on the text
+    for fn in ("README.md", "SURVEY.md"):
         p = os.path.join(root, fn)
         if os.path.isfile(p):
             with open(p, "rb") as f:

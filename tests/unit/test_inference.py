@@ -189,6 +189,157 @@ def test_sampling_shapes():
         assert int(sampled[i]) in top5[i]
 
 
+def _reference_per_request_sampler(logits, rngs, temperature, top_k, top_p):
+    """The per-request sampler as it was before it chose its work from its
+    rows (every step: argmax, whole-vocabulary sort, top-k threshold,
+    softmax and prefix sum, one categorical a row, THEN the greedy rows'
+    argmax selected). Kept here, plain, as what the tokens must equal."""
+    logits = logits.astype(jnp.float32)
+    vocab = logits.shape[-1]
+    temperature = jnp.asarray(temperature, jnp.float32)
+    top_k = jnp.asarray(top_k, jnp.int32)
+    top_p = jnp.asarray(top_p, jnp.float32)
+    greedy_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    scaled = logits / jnp.maximum(temperature, 1e-6)[:, None]
+    sorted_desc = jnp.sort(scaled, axis=-1)[:, ::-1]
+    k = jnp.clip(top_k, 0, vocab)
+    kth = jnp.take_along_axis(
+        sorted_desc, jnp.clip(k - 1, 0, vocab - 1)[:, None], axis=-1)
+    scaled = jnp.where((k[:, None] > 0) & (scaled < kth), -1e30, scaled)
+    sorted_desc = jnp.where((k[:, None] > 0) & (sorted_desc < kth), -1e30,
+                            sorted_desc)
+    probs = jax.nn.softmax(sorted_desc, axis=-1)
+    keep = (jnp.cumsum(probs, axis=-1) - probs) < top_p[:, None]
+    keep = keep.at[:, 0].set(True)
+    cutoff = jnp.min(jnp.where(keep, sorted_desc, jnp.inf), axis=-1,
+                     keepdims=True)
+    filtered = jnp.where(scaled < cutoff, -1e30, scaled)
+    scaled = jnp.where(top_p[:, None] >= 1.0, scaled, filtered)
+    sampled = jax.vmap(
+        lambda key, row: jax.random.categorical(key, row))(rngs, scaled)
+    return jnp.where(temperature <= 0.0, greedy_tok,
+                     sampled.astype(jnp.int32))
+
+
+# name -> per-row (temperature, top_k, top_p, live)
+SAMPLER_CASES = {
+    "all_greedy": [(0.0, 0, 1.0, True)] * 4,
+    "all_greedy_stale_filters": [(0.0, 5, 0.5, True), (0.0, 0, 0.9, True),
+                                 (-1.0, 3, 1.0, True), (0.0, 0, 1.0, True)],
+    "all_temperature_only": [(0.7, 0, 1.0, True), (1.0, 0, 1.0, True),
+                             (1.3, 0, 1.0, True), (0.2, 0, 1.0, True)],
+    "temperature_beside_greedy": [(0.0, 0, 1.0, True), (0.9, 0, 1.0, True),
+                                  (0.0, 0, 1.0, True), (1.1, 0, 1.0, True)],
+    "mixed_greedy_topk_topp_both": [(0.0, 0, 1.0, True), (0.8, 5, 1.0, True),
+                                    (1.0, 0, 0.7, True), (0.9, 7, 0.8, True),
+                                    (1.2, 0, 1.0, True)],
+    "one_topp_row_among_plain_sampled": [(0.8, 0, 1.0, True),
+                                         (0.8, 0, 0.5, True),
+                                         (1.0, 0, 1.0, True)],
+    "dead_sampled_row_beside_greedy": [(0.0, 0, 1.0, True),
+                                       (0.8, 5, 1.0, False),
+                                       (0.0, 0, 1.0, True)],
+    "dead_filtered_row_beside_plain_sampled": [(0.9, 0, 1.0, True),
+                                               (0.8, 5, 0.6, False),
+                                               (0.0, 0, 1.0, True)],
+    "dead_greedy_row_beside_filtered": [(0.0, 0, 1.0, False),
+                                        (0.8, 4, 0.9, True)],
+    "single_row_sampled": [(0.8, 3, 1.0, True)],
+    "single_row_greedy": [(0.0, 0, 1.0, True)],
+}
+
+
+def _sampler_inputs(rows, vocab=97, seed=0):
+    rs = np.random.RandomState(seed)
+    logits = rs.randn(len(rows), vocab).astype(np.float32) * 3.0
+    # an exact tie at the top of row 0: the first index must win
+    logits[0, 11] = logits[0, 60] = logits[0].max() + 1.0
+    keys = jax.vmap(jax.random.PRNGKey)(jnp.arange(len(rows)) + 17 * seed)
+    temp, top_k, top_p, live = (np.asarray(c) for c in zip(*rows))
+    return (jnp.asarray(logits), keys, jnp.asarray(temp, jnp.float32),
+            jnp.asarray(top_k, jnp.int32), jnp.asarray(top_p, jnp.float32),
+            jnp.asarray(live, jnp.bool_))
+
+
+@pytest.mark.parametrize("case", sorted(SAMPLER_CASES))
+def test_per_request_sampler_matches_reference(case):
+    """Whatever arm the live rows select, every LIVE row's token is what the
+    always-sort-everything sampler returns for the same key, logits and
+    knobs: a greedy row the argmax (first index on a tie), a sampled row
+    its own draw, whoever sits beside it."""
+    from deepspeed_tpu.models.decoding import (sample_token,
+                                               sample_token_per_request)
+
+    rows = SAMPLER_CASES[case]
+    fn = jax.jit(lambda *a: sample_token_per_request(
+        a[0], a[1], temperature=a[2], top_k=a[3], top_p=a[4], live=a[5]))
+    ref = jax.jit(_reference_per_request_sampler)
+    for seed in range(3):
+        logits, keys, temp, top_k, top_p, live = _sampler_inputs(rows,
+                                                                 seed=seed)
+        got, sampled = map(np.asarray,
+                           fn(logits, keys, temp, top_k, top_p, live))
+        want = np.asarray(ref(logits, keys, temp, top_k, top_p))
+        alive = np.asarray(live)
+        np.testing.assert_array_equal(got[alive], want[alive])
+        # the arm it says it took: the sampled one iff a LIVE row samples
+        assert bool(sampled) == bool((alive & (np.asarray(temp) > 0)).any())
+        if float(temp[0]) <= 0 and alive[0]:
+            assert got[0] == 11       # the tie: the first index
+        if not sampled:
+            # the arm taken shows in the rows nobody reads: a dead row's
+            # knobs (temperature 0.8, top_k 5) pin nothing, the argmax
+            # alone ran
+            np.testing.assert_array_equal(
+                got, np.argmax(np.asarray(logits), axis=-1))
+        if alive.all():
+            # no mask given = every row live; the [b, 2]-key form of
+            # sample_token is the same function
+            for out in (sample_token_per_request(
+                            logits, keys, temperature=temp, top_k=top_k,
+                            top_p=top_p)[0],
+                        sample_token(logits, keys, temperature=temp,
+                                     top_k=top_k, top_p=top_p)):
+                np.testing.assert_array_equal(np.asarray(out), want)
+
+
+def _equations(jaxpr, inside_cond=False):
+    """(equation, inside a cond branch?) of a jaxpr, nested ones included."""
+    for eqn in jaxpr.eqns:
+        yield eqn, inside_cond
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _equations(
+                sub, inside_cond or eqn.primitive.name == "cond")
+
+
+def test_per_request_sampler_work_sits_in_cond_branches():
+    """The structure that makes an all-greedy step cheap: in the jitted
+    sampler no sort, prefix sum or random-bits primitive sits outside a
+    ``cond`` branch, and the arm an all-greedy step takes holds none (no
+    arithmetic at all: it hands the argmax on)."""
+    from deepspeed_tpu.models.decoding import sample_token_per_request
+
+    args = _sampler_inputs(SAMPLER_CASES["mixed_greedy_topk_topp_both"])
+    jaxpr = jax.make_jaxpr(jax.jit(lambda *a: sample_token_per_request(
+        a[0], a[1], temperature=a[2], top_k=a[3], top_p=a[4],
+        live=a[5])))(*args).jaxpr
+    heavy = {"sort", "cumsum", "cumlogsumexp", "random_bits", "threefry2x32",
+             "random_wrap", "exp", "reduce_precision"}
+    noise = {"random_bits", "threefry2x32"}
+    names = lambda eqns: {(e.primitive.name, inside) for e, inside in eqns}
+    seen = names(_equations(jaxpr))
+    assert {"sort", "cumsum", "cond"} <= {n for n, _ in seen}, seen
+    assert noise & {n for n, _ in seen}, seen
+    outside = sorted(n for n, inside in seen if not inside and n in heavy)
+    assert not outside, outside
+
+    # the one cond: index 0 is its predicate-false arm, the all-greedy
+    # step's
+    (cond,) = [e for e, _ in _equations(jaxpr) if e.primitive.name == "cond"]
+    greedy_arm, _ = cond.params["branches"]
+    assert not greedy_arm.jaxpr.eqns, greedy_arm
+
+
 def test_generate_temperature_change_does_not_recompile(devices8):
     """VERDICT weak item: sampling-knob changes must reuse the compiled
     prefill/decode programs."""

@@ -196,6 +196,67 @@ def test_per_request_rng_and_sampling_isolation(engine):
                                   ref[0, len(prompt):])
 
 
+@pytest.mark.parametrize("case", ["greedy_only", "temperature_only",
+                                  "top_k", "top_p", "verify_steps"])
+def test_sampler_step_counters(engine, case):
+    """``snapshot()["sampler"]`` books every decode (or verify) dispatch by
+    the sampler arm its LIVE rows selected: a sampled request pins the sampled
+    arm for the steps it is live and no longer; its freed slot still carries
+    its knobs on the device, and the steps are greedy again."""
+    rng = np.random.RandomState(11)
+    prompt = lambda n: rng.randint(0, 64, (n,)).astype(np.int32)
+    kw = {}
+    if case == "verify_steps":
+        kw = dict(kv_pool={"block_size": 16},
+                  speculative={"enabled": True, "drafter": "ngram", "k": 4})
+    sv = make_replica(engine, n_slots=2, **kw)
+    sampler = lambda: sv.metrics.snapshot()["sampler"]
+    dispatches = lambda: sum(
+        sv.metrics.snapshot()["kv_pool"]["decode_dispatches"].values())
+    assert sampler() == {"greedy_steps": 0, "sampled_steps": 0}
+
+    if case in ("greedy_only", "verify_steps"):
+        # (a periodic prompt, so that the n-gram drafter drafts)
+        reqs = [Request(prompt=np.tile(prompt(4), 4), max_new_tokens=12),
+                Request(prompt=prompt(9), max_new_tokens=5),
+                Request(prompt=prompt(5), max_new_tokens=7)]
+        list(sv.serve(reqs))
+        if case == "verify_steps":
+            assert sv.metrics.snapshot()["speculative"]["verify_steps"] > 0
+        assert sampler() == {"greedy_steps": dispatches(),
+                             "sampled_steps": 0}
+        return
+
+    knobs = {"temperature_only": dict(temperature=0.8),
+             "top_k": dict(temperature=0.8, top_k=8),
+             "top_p": dict(temperature=0.8, top_p=0.7)}[case]
+    sampled = Request(prompt=prompt(6), max_new_tokens=4,
+                      sampling=SamplingParams(seed=5, **knobs))
+    greedy_req = Request(prompt=prompt(7), max_new_tokens=14)
+    list(sv.serve([sampled, greedy_req]))
+    # its first token left the prefill; every later one a decode step
+    live_steps = len(sampled.tokens) - 1
+    assert live_steps == 3
+    after_first = sampler()
+    assert after_first["sampled_steps"] == live_steps
+    # the greedy request outlived it beside its freed slot: greedy steps
+    assert after_first["greedy_steps"] == dispatches() - live_steps
+    assert after_first["greedy_steps"] >= 8
+    ref = np.asarray(engine.generate(greedy_req.prompt[None, :],
+                                     max_new_tokens=14, greedy=True))
+    np.testing.assert_array_equal(np.asarray(greedy_req.tokens),
+                                  ref[0, greedy_req.prompt_len:])
+
+    # the slot reused by a greedy request: still greedy steps only
+    list(sv.serve([Request(prompt=prompt(5), max_new_tokens=6),
+                   Request(prompt=prompt(8), max_new_tokens=6)]))
+    after_reuse = sampler()
+    assert after_reuse["sampled_steps"] == live_steps
+    assert after_reuse["greedy_steps"] == dispatches() - live_steps
+    assert after_reuse["greedy_steps"] > after_first["greedy_steps"]
+    assert sv.compile_counts()["decode"] == 1
+
+
 def test_eos_stops_slot_early(engine):
     """Per-request EOS frees the slot mid-flight; the stream ends with the
     eos token and finish_reason 'eos', matching generate()'s truncation."""
