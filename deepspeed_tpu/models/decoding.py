@@ -35,7 +35,8 @@ def init_cache(cfg, batch_size, max_len, dtype=None):
 # paged (block) KV cache: fixed pool of token blocks + per-slot block table
 # ---------------------------------------------------------------------------
 
-def init_paged_cache(cfg, n_blocks, block_size, dtype=None, kv_dtype=None):
+def init_paged_cache(cfg, n_blocks, block_size, dtype=None, kv_dtype=None,
+                     n_layers=None):
     """Allocate the paged KV pool: ``n_blocks`` physical blocks of
     ``block_size`` tokens each, stacked over layers (a physical block id
     addresses the same block row in EVERY layer, so host allocation is one
@@ -55,9 +56,13 @@ def init_paged_cache(cfg, n_blocks, block_size, dtype=None, kv_dtype=None):
     ``kv_dtype="int8"`` stores blocks as int8 payloads with per-(token, head)
     fp32 scales (``comm/collectives.py`` blockwise kernels, ZeRO++ idiom):
     k/v ``[.., kv_heads * head_dim]`` int8, k_scale/v_scale ``[..,
-    kv_heads]`` f32."""
+    kv_heads]`` f32.
+
+    ``n_layers``: the layers of ONE block group, where a model keeps
+    several (``models/window_moe.py``: the full layers' group and the window
+    layers' ring, each with its own ``n_blocks``)."""
     dtype = dtype or cfg.compute_dtype
-    shapes = {name: (cfg.n_layers, n_blocks, block_size) + row
+    shapes = {name: (n_layers or cfg.n_layers, n_blocks, block_size) + row
               for name, row in cfg.pool_geometry.items()}
     if kv_dtype == "int8":
         pool = {name: jnp.zeros(s, jnp.int8) for name, s in shapes.items()}
@@ -93,7 +98,8 @@ def _paged_view(pool, name, layer, table, heads, view_dtype):
     return g.reshape(s_dim, per_slot * bs, heads, width // heads)
 
 
-def _paged_write_rows(pool, layer, rows, table, pos, block_size, valid=None):
+def _paged_write_rows(pool, layer, rows, table, pos, block_size, valid=None,
+                      ring=False):
     """Write each slot's fresh rows ``rows`` (``{"k": [S, kvh, dh], "v":
     ..}``) into layer ``layer`` of the pool at (table[s, pos // bs], pos %
     bs): row-sized updates of the leaves, in place in the layer loop's
@@ -104,8 +110,11 @@ def _paged_write_rows(pool, layer, rows, table, pos, block_size, valid=None):
     ``valid`` ([S] bool, optional): rows whose write must instead be
     redirected to the reserved garbage block 0 (speculative verify's padded
     draft rows: they can lie past the slot's bound blocks or the KV window,
-    and a clamped block index would silently corrupt a REAL block)."""
-    j = jnp.clip(pos // block_size, 0, table.shape[1] - 1)
+    and a clamped block index would silently corrupt a REAL block).
+    ``ring``: the table is a ring as wide as a window layer's band, block
+    ``j`` at column ``j % n_cols`` (``models/window_moe.py``)."""
+    j = (pos // block_size) % table.shape[1] if ring \
+        else jnp.clip(pos // block_size, 0, table.shape[1] - 1)
     bi = jnp.take_along_axis(table, j[:, None], axis=1)[:, 0]
     if valid is not None:
         bi = jnp.where(valid, bi, 0)  # block 0 = the reserved garbage block
@@ -209,8 +218,24 @@ def forward_with_paged_cache(model, params, input_ids, pool, table, pos,
 
     ``return_routing`` (expert models with ``moe_routing="dropfree"``): also
     return what the step's expert layers chose, [L_moe, S, 1, 2k] int32 (ids
-    and the bits of their weights: ``moe/dropfree.py``)."""
+    and the bits of their weights: ``moe/dropfree.py``).
+
+    A model of window and full attention layers (``models/window_moe.py``)
+    keeps two pool groups: ``pool`` then also holds ``wk`` / ``wv`` and
+    ``table`` is the pair (full group's table, window group's ring)."""
     cfg = model.config
+    if cfg.window_layers:
+        from . import window_moe
+
+        if draft_len is not None or "k_scale" in pool:
+            raise ValueError(
+                "window and full attention layers decode one row a slot "
+                "over a pool in the engine's dtype: speculative verify and "
+                "an int8 pool are not implemented")
+        logits, pool, ids = window_moe.forward_with_paged_cache(
+            model, params, input_ids, pool, table, pos, block_size,
+            kernel=kernel)
+        return (logits, pool, ids) if return_routing else (logits, pool)
     if cfg.latent_attention:
         from . import latent
 
@@ -632,6 +657,13 @@ def forward_with_cache(model, params, input_ids, cache, pos, kv_len,
     also return what the expert layers chose, [L_moe, b, q, 2k] int32.
     """
     cfg = model.config
+    if cfg.window_layers:
+        from . import window_moe
+
+        logits, cache, ids = window_moe.forward_with_cache(
+            model, params, input_ids, cache, pos, kv_len,
+            last_index=last_index)
+        return (logits, cache, ids) if return_routing else (logits, cache)
     if cfg.latent_attention:
         from . import latent
 
@@ -640,8 +672,8 @@ def forward_with_cache(model, params, input_ids, cache, pos, kv_len,
             last_index=last_index)
         return (logits, cache, ids) if return_routing else (logits, cache)
     if last_index is not None or return_routing:
-        raise ValueError("last_index and return_routing are the latent "
-                         "expert model's (models/latent.py)")
+        raise ValueError("last_index and return_routing are the expert "
+                         "models' (models/latent.py, models/window_moe.py)")
     b, q_len = input_ids.shape
     if jnp.ndim(pos) == 1:
         positions = pos[:, None] + jnp.arange(q_len)[None, :]  # [b, q]

@@ -252,6 +252,43 @@ def kanana2_config(size="30b-a3b", **overrides):
     return TransformerConfig(**base)
 
 
+def trinity_config(size="mini", **overrides):
+    """arcee-ai/Trinity-Mini (``model_type`` afmoe; huggingface.co/arcee-ai/
+    Trinity-Mini config.json): grouped-query attention (32 heads over 4 K/V
+    heads of 128) in two kinds of layer, three that see the last 2048
+    positions and rotate q and k to one that sees all and rotates nothing;
+    RMS norms on q and k per head, a sigmoid output gate, four norms a
+    layer; two leading dense SwiGLU layers, then layers of 128
+    sigmoid-routed experts (top-8 of ``s + b``, weights from ``s``,
+    normalised, x 2.826) beside one shared expert; the embedding scaled by
+    sqrt(hidden) (``mup_enabled``); untied head; RMSNorm eps 1e-5; no
+    biases. ``layer_types`` follows ``n_layers`` in the published period
+    (sliding, sliding, sliding, full) unless given."""
+    presets = {
+        "tiny": dict(n_layers=6, d_model=64, n_heads=4, n_kv_heads=2,
+                     head_dim_override=16, d_ff=128, moe_d_ff=32,
+                     n_experts=8, moe_top_k=2, sliding_window=24,
+                     max_seq_len=256, vocab_size=512),
+        "mini": dict(n_layers=32, d_model=2048, n_heads=32, n_kv_heads=4,
+                     head_dim_override=128, d_ff=6144, moe_d_ff=1024,
+                     n_experts=128, moe_top_k=8, sliding_window=2048),
+    }
+    base = dict(
+        vocab_size=200192, max_seq_len=131072, activation="swiglu",
+        norm="rmsnorm", position_embedding="rope", rope_base=10000.0,
+        tie_embeddings=False, use_bias=False, prenorm=True,
+        layernorm_eps=1e-5, first_k_dense=2, n_shared_experts=1,
+        moe_routing="dropfree", moe_routed_scale=2.826,
+    )
+    base.update(presets[size])
+    base.update(overrides)
+    period = ("sliding_attention",) * 3 + ("full_attention",)
+    base.setdefault("layer_types", tuple(
+        period[i % 4] for i in range(base["n_layers"])))
+    base.setdefault("embed_scale", float(base["d_model"]) ** 0.5)
+    return TransformerConfig(**base)
+
+
 def bert_config(size="base", **overrides):
     """Encoder presets (BERT paper table 1 geometry): post-norm, bidirectional,
     learned positions + segment embeddings, gelu, embed LN."""
@@ -286,6 +323,7 @@ MODEL_CONFIGS = {
     "bert": bert_config,
     "gpt2_moe": gpt2_moe_config,
     "kanana2": kanana2_config,
+    "trinity": trinity_config,
 }
 
 

@@ -200,6 +200,16 @@ class TransformerConfig:
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
+    # Window and full attention layers mixed (AFMoE, Arcee Trinity;
+    # models/window_moe.py): the published ``layer_types``, one a layer
+    # ("sliding_attention" sees the last ``sliding_window`` positions and
+    # rotates q and k; "full_attention" sees all and rotates nothing). A
+    # model that sets them gets that family's block: four norms a layer, RMS
+    # norms on q and k per head, a sigmoid output gate on attention, the
+    # embedding scaled by ``embed_scale``.
+    layer_types: tuple = ()
+    sliding_window: int = 0
+    embed_scale: float = 1.0
 
     def __post_init__(self):
         # a typo here would silently run the exact fp32 path and let a
@@ -225,12 +235,29 @@ class TransformerConfig:
             raise ValueError(
                 f"moe_routing must be 'capacity' or 'dropfree', got "
                 f"{self.moe_routing!r}")
+        self.layer_types = tuple(self.layer_types)
+        if self.layer_types:
+            kinds = {"sliding_attention", "full_attention"}
+            if len(self.layer_types) != self.n_layers \
+                    or not set(self.layer_types) <= kinds:
+                raise ValueError(
+                    f"layer_types must name one of {sorted(kinds)} for each "
+                    f"of the {self.n_layers} layers, got "
+                    f"{len(self.layer_types)}: {sorted(set(self.layer_types))}")
+            if set(self.layer_types) != kinds or self.sliding_window < 1 \
+                    or self.kv_lora_rank:
+                raise ValueError(
+                    "layer_types (models/window_moe.py) needs layers of both "
+                    "kinds, sliding_window > 0 and per-head K and V (no "
+                    "latent attention)")
         if self.n_experts > 0 and self.moe_routing == "dropfree" \
-                and not self.kv_lora_rank:
+                and not (self.kv_lora_rank or self.layer_types):
             raise ValueError(
                 "moe_routing='dropfree' is implemented beside latent "
-                "attention only (kv_lora_rank > 0): the cache paths that "
-                "carry its routing are models/latent.py's")
+                "attention (kv_lora_rank > 0, models/latent.py) and beside "
+                "the window and full attention layers of layer_types "
+                "(models/window_moe.py): the cache paths that carry its "
+                "routing are theirs")
         if self.first_k_dense and not 0 < self.first_k_dense < self.n_layers:
             raise ValueError(
                 f"first_k_dense {self.first_k_dense} must leave at least one "
@@ -256,6 +283,11 @@ class TransformerConfig:
     @property
     def latent_attention(self):
         return self.kv_lora_rank > 0
+
+    @property
+    def window_layers(self):
+        """Window and full attention layers mixed (``layer_types``)."""
+        return bool(self.layer_types)
 
     @property
     def expert_d_ff(self):
@@ -303,6 +335,9 @@ class TransformerConfig:
                           self.v_head_dim)
             per_block = (d * H * (dn + dr) + d * (r + dr) + r
                          + r * H * (dn + dv) + H * dv * d)
+        if self.window_layers:
+            # the output gate, the q and k norms, the two post-branch norms
+            per_block += d * q_dim + 2 * self.head_dim + 2 * d
         ffn = (3 if self.activation == "swiglu" else 2) * d * f
         per_block += 4 * d if self.use_bias else 0
         per_block += 2 * d  # two norms (scale+bias counted roughly)
@@ -371,6 +406,10 @@ def _mlp_apply(cfg, p, x, tp_manual=False):
 
 
 def block_init(rng, cfg):
+    if cfg.window_layers:
+        from .window_moe import block_init as window_block_init
+
+        return window_block_init(rng, cfg)
     k_attn, k_mlp = jax.random.split(rng)
     out_std = cfg.initializer_range / (2.0 * cfg.n_layers) ** 0.5
     if cfg.n_experts > 0 and cfg.moe_routing == "dropfree":
@@ -1044,6 +1083,17 @@ class CausalLM:
                  pld_theta=None):
         """Embedding + blocks + final norm -> ([batch, seq, d_model], aux)."""
         cfg = self.config
+        if cfg.window_layers:
+            if attention_mask is not None or token_type_ids is not None \
+                    or pld_theta is not None or not deterministic:
+                raise NotImplementedError(
+                    "window and full attention layers (layer_types) run the "
+                    "plain causal forward: no padding mask, token types, "
+                    "dropout or progressive layer drop")
+            from .window_moe import backbone
+
+            return backbone(self, params, input_ids, positions), \
+                jnp.zeros((), jnp.float32)
         params = self._gather_toplevel(params)
         b, s = input_ids.shape
         if positions is None:

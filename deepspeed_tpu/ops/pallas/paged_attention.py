@@ -44,8 +44,17 @@ Structure:
   a probability of exactly 0 never meets bytes that were not KV. Unbound
   table columns hold the reserved GARBAGE block and are never live.
 
-An int8 pool, several query rows a slot (speculative verify) and banded
-local layers take the view path (``fused_decode_supported`` says why).
+A WINDOW layer (``window`` > 0: a query sees the last ``window`` positions,
+its own included) walks a band: the walk starts at the chunk that holds
+position ``pos - window + 1`` and masks inside it, so the layer reads at most
+``window`` rows and a chunk's edge, whatever the cursor. Its blocks may sit in
+a RING (``ring``): block ``j`` of a slot at table column ``j % n_cols``, the
+window group of ``serving/kv_pool.py``, whose table is as wide as the band.
+With ``window`` 0 the program is the one it was before the band existed.
+
+An int8 pool, several query rows a slot (speculative verify) and GPT-Neo's
+per-layer traced local flags take the view path (``fused_decode_supported``
+says why).
 
 Tier-1 runs the kernel under ``interpret=True`` on the CPU (the models'
 ``attention_interpret``) and lowers it for the TPU at OPT-1.3B's geometry.
@@ -65,11 +74,14 @@ CHUNK_TOKENS = 256
 
 
 def fused_decode_supported(cfg, block_size, *, n_slots=8, blocks_per_slot=8,
-                           tp=1, kv_dtype=""):
+                           tp=1, kv_dtype="", window=0, ring=False):
     """May the decode program attend through the kernel? ``(ok, reason)``.
 
-    Structural refusals first (what the kernel does not implement: banded
-    local-attention layers, ragged GQA groups, an int8 pool), then the
+    ``window`` / ``ring``: the band of a window layer whose kind is static
+    (``models/window_moe.py``), probed at that group's table width.
+
+    Structural refusals first (what the kernel does not implement: GPT-Neo's
+    traced per-layer local flags, ragged GQA groups, an int8 pool), then the
     question goes to the compiler: the kernel is lowered for the target
     platform at the engine's per-device geometry (``cfg`` heads / ``tp``,
     ``block_size``, table width) and compiled, when the backend is a TPU;
@@ -82,8 +94,9 @@ def fused_decode_supported(cfg, block_size, *, n_slots=8, blocks_per_slot=8,
     from . import compiler_verdict, unavailable_reason
 
     if cfg.local_attention_window > 0:
-        return False, ("local_attention_window > 0: banded layer masks are "
-                       "not implemented in the decode kernel")
+        return False, ("local_attention_window > 0: a layer's kind is a "
+                       "traced flag there, and the decode kernel's band is "
+                       "static")
     if cfg.n_heads % cfg.kv_heads:
         return False, (f"n_heads {cfg.n_heads} not a multiple of kv_heads "
                        f"{cfg.kv_heads}")
@@ -107,7 +120,8 @@ def fused_decode_supported(cfg, block_size, *, n_slots=8, blocks_per_slot=8,
     def call(q, k_new, v_new, kc, vc, table, pos, layer):
         return paged_flash_decode(q, k_new, v_new, kc, vc, table, pos,
                                   layer=layer, scale=cfg.attn_scale,
-                                  alibi_slopes=slopes)
+                                  alibi_slopes=slopes, window=window,
+                                  ring=ring)
 
     ok, reason = compiler_verdict(
         call, sds((n_slots, nh, dh), cfg.compute_dtype), row, row, pool, pool,
@@ -117,7 +131,8 @@ def fused_decode_supported(cfg, block_size, *, n_slots=8, blocks_per_slot=8,
 
 
 def _decode_kernel(layer_ref, table_ref, pos_ref, q_ref, kn_ref, vn_ref,
-                   *rest, scale, block_size, chunk_blocks, head_dim, alibi):
+                   *rest, scale, block_size, chunk_blocks, head_dim, alibi,
+                   window, ring):
     """One slot: walk its live blocks chunk by chunk, fold each chunk into
     the running (m, l, acc), emit the slot's normalized output rows.
 
@@ -128,7 +143,9 @@ def _decode_kernel(layer_ref, table_ref, pos_ref, q_ref, kn_ref, vn_ref,
     head ``g * hq + j``. ``kbuf``/``vbuf`` [2, chunk, W] are the two chunk
     buffers, ``cur_ref`` the buffer the next chunk to consume lands in (it
     outlives a grid step: the next live slot's first chunk is already in
-    flight when its step begins)."""
+    flight when its step begins). ``window`` > 0: the valid pool window is
+    ``[max(pos - window + 1, 0), pos)`` and the walk starts at its chunk;
+    ``ring``: block ``j`` sits at table column ``j % n_cols``."""
     if alibi:
         slopes_ref, rest = rest[0], rest[1:]
     k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, acc_scr, cur_ref = rest
@@ -142,16 +159,27 @@ def _decode_kernel(layer_ref, table_ref, pos_ref, q_ref, kn_ref, vn_ref,
     pos = pos_ref[s]                       # valid pool window = [0, pos)
     n_chunks = (pos + chunk - 1) // chunk
 
+    def band_start(slot):
+        """First position of ``slot``'s valid pool window."""
+        return jnp.maximum(pos_ref[slot] - (window - 1), 0) if window else 0
+
+    def first_chunk(slot):
+        return band_start(slot) // chunk if window else 0
+
     def for_live_blocks(slot, c, buf, act):
         """``act`` on the K and the V copy of every live block of chunk
         ``c`` of ``slot``, aimed at buffer ``buf``: the same descriptors
         start a chunk and wait for it."""
         for j in range(chunk_blocks):
             col = c * chunk_blocks + j
+            live = col * block_size < pos_ref[slot]
+            if window:
+                live &= (col + 1) * block_size > band_start(slot)
 
-            @pl.when(col * block_size < pos_ref[slot])
+            @pl.when(live)
             def _():
-                blk = table_ref[slot, jnp.minimum(col, n_cols - 1)]
+                blk = table_ref[slot, col % n_cols if ring
+                                else jnp.minimum(col, n_cols - 1)]
                 rows = pl.ds(j * block_size, block_size)
                 act(pltpu.make_async_copy(
                     k_hbm.at[layer, blk], kbuf.at[buf, rows], sem.at[0, buf]))
@@ -175,7 +203,7 @@ def _decode_kernel(layer_ref, table_ref, pos_ref, q_ref, kn_ref, vn_ref,
 
         @pl.when(nxt < n_slots)
         def _():
-            start(nxt, 0, buf)
+            start(nxt, first_chunk(nxt), buf)
 
     @pl.when(s == 0)
     def _first():
@@ -187,6 +215,7 @@ def _decode_kernel(layer_ref, table_ref, pos_ref, q_ref, kn_ref, vn_ref,
         start_next_live(-1, 0)
 
     buf0 = cur_ref[0]
+    c0 = first_chunk(s)
     # the allowlisted attention-f32 island (sanitizer ATTENTION_F32_ALLOW):
     # logits, softmax and the PV accumulator run fp32 on purpose
     with jax.named_scope("paged_flash_decode"):
@@ -199,7 +228,7 @@ def _decode_kernel(layer_ref, table_ref, pos_ref, q_ref, kn_ref, vn_ref,
 
         def fold(c, carry):
             m_prev, l_prev = carry
-            buf = (buf0 + c) % 2
+            buf = (buf0 + c - c0) % 2 if window else (buf0 + c) % 2
 
             @pl.when(c + 1 < n_chunks)
             def _():
@@ -218,7 +247,10 @@ def _decode_kernel(layer_ref, table_ref, pos_ref, q_ref, kn_ref, vn_ref,
                 # slopes * (kv_pos - cursor): the same int difference, then
                 # fp32 multiply, as the view path's per-row alibi
                 sc = sc + slopes_ref[...] * (t - pos).astype(jnp.float32)
-            sc = jnp.where(t < pos, sc, NEG_INF)
+            valid = t < pos
+            if window:
+                valid &= t >= band_start(s)
+            sc = jnp.where(valid, sc, NEG_INF)
             m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
             p = jnp.exp(sc - m_new)
             corr = jnp.exp(m_prev - m_new)
@@ -234,9 +266,10 @@ def _decode_kernel(layer_ref, table_ref, pos_ref, q_ref, kn_ref, vn_ref,
             acc_scr[...] = acc_scr[...] * corr + pv
             return m_new, l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
 
-        _, l_fin = jax.lax.fori_loop(0, n_chunks, fold,
+        _, l_fin = jax.lax.fori_loop(c0, n_chunks, fold,
                                      (m0, jnp.ones_like(m0)))
-        cur_ref[0] = (buf0 + n_chunks) % 2
+        cur_ref[0] = (buf0 + n_chunks - c0) % 2 if window \
+            else (buf0 + n_chunks) % 2
 
         # head h keeps its own kv group's lanes: row j of the output holds
         # head g * hq + j in the lanes of group g
@@ -251,7 +284,7 @@ def _decode_kernel(layer_ref, table_ref, pos_ref, q_ref, kn_ref, vn_ref,
 
 
 def paged_flash_decode(q, k_new, v_new, kc, vc, table, pos, *, layer=None,
-                       scale=None, alibi_slopes=None,
+                       scale=None, alibi_slopes=None, window=0, ring=False,
                        chunk_tokens=CHUNK_TOKENS, interpret=False, mesh=None):
     """Paged decode attention: softmax(q·K/√d)·V for ONE query row per slot,
     where K/V live in the paged pool and the kernel walks the block table
@@ -266,6 +299,11 @@ def paged_flash_decode(q, k_new, v_new, kc, vc, table, pos, *, layer=None,
     - ``table``: [S, NB] int32 physical block ids; ``pos``: [S] int32
       cursors. Pool positions [0, pos) are attended; everything past the
       cursor (a ragged tail, unbound garbage-block columns) is never read;
+    - ``window`` (static; 0 = none): a query sees the last ``window``
+      positions, its own included: pool positions ``[max(pos - window + 1,
+      0), pos)`` are attended, and nothing before them is read. ``ring``
+      (static): block ``j`` of a slot sits at table column ``j % NB`` (a
+      table as wide as the band; ``NB * block_size >= window + block_size``);
     - ``chunk_tokens``: tokens consumed a step of the kernel's inner loop
       (rounded down to whole blocks);
     - ``interpret``: run under the Pallas interpreter (the models'
@@ -295,13 +333,13 @@ def paged_flash_decode(q, k_new, v_new, kc, vc, table, pos, *, layer=None,
     def per_shard(q, k_new, v_new, kc, vc, table, pos, layer, slopes=None):
         return _paged_flash_decode(q, k_new, v_new, kc, vc, table, pos,
                                    layer, slopes, scale, chunk_tokens,
-                                   interpret)
+                                   interpret, window, ring)
 
     return shard_kernel(per_shard, mesh, operands, dim_axes, [at(1)])
 
 
 def _paged_flash_decode(q, k_new, v_new, kc, vc, table, pos, layer, slopes,
-                        scale, chunk_tokens, interpret):
+                        scale, chunk_tokens, interpret, window=0, ring=False):
     """One device's share of ``paged_flash_decode`` (local head counts)."""
     s_dim, n_heads, dh = q.shape
     kvh = k_new.shape[1]
@@ -332,7 +370,8 @@ def _paged_flash_decode(q, k_new, v_new, kc, vc, table, pos, layer, slopes,
     chunk = chunk_blocks * block_size
     kernel = functools.partial(
         _decode_kernel, scale=scale, block_size=block_size,
-        chunk_blocks=chunk_blocks, head_dim=dh, alibi=alibi)
+        chunk_blocks=chunk_blocks, head_dim=dh, alibi=alibi,
+        window=int(window), ring=bool(ring))
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(

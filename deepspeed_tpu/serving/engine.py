@@ -39,7 +39,8 @@ from ..models.decoding import (extract_slot_blocks, forward_with_cache,
 from ..ops.pallas.kv_block_write import blocks_in_lanes
 from ..utils.logging import log_dist
 from .clock import VirtualClock, WallClock
-from .kv_pool import GARBAGE_BLOCK, KVPoolManager, prefix_chain_keys
+from .kv_pool import (GARBAGE_BLOCK, KVPoolManager, WindowGroupManager,
+                      prefix_chain_keys)
 from .migration import RequestSnapshot, advance_rng
 from .metrics import ServingMetrics
 from .queue import RequestQueue
@@ -104,12 +105,18 @@ class ServingEngine:
         self._routing = getattr(mcfg, "n_experts", 0) > 0 \
             and getattr(mcfg, "moe_routing", "") == "dropfree"
         self._latent = bool(getattr(mcfg, "latent_attention", False))
-        if self._latent:
-            self._refuse_for_latent(engine)
+        # window and full attention layers mixed (models/window_moe.py):
+        # two block groups, the window layers' a ring a slot
+        self._window = bool(getattr(mcfg, "window_layers", False))
+        if self._latent or self._window:
+            self._refuse_for_cache_family(engine)
         # the KV store: block allocator + prefix cache on the host, a block
         # table on the device (serving/kv_pool.py)
         self.pool_mgr = KVPoolManager(self.cfg.kv_pool, self.n_slots,
                                       self.max_len)
+        self.window_mgr = WindowGroupManager(
+            self.cfg.kv_pool, self.n_slots, mcfg.sliding_window) \
+            if self._window else None
         # which decode attention runs is this engine's choice, from what it
         # can observe: "kernel" (the flash-decode kernel walks the block
         # table and reads the live blocks only) or "view" (the n_slots x
@@ -261,7 +268,13 @@ class ServingEngine:
             else self._rep_sharding
             for name in kv_names + (
                 "table", "pos", "tok", "active", "remaining", "rng", "temp",
-                "top_k", "top_p", "eos")}
+                "top_k", "top_p", "eos")
+            + (("wtable",) if self._window else ())}
+        if self._window:
+            from ..models.window_moe import layer_groups
+
+            # (window, full): the model's layers of each kind
+            self._layer_groups = layer_groups(mcfg)
         self._state = self._init_state()
         # the KV window is not n_slots x max_len: report the REAL capacity
         # (blocks and tokens) so operators see the effective slot multiplier
@@ -289,22 +302,44 @@ class ServingEngine:
                             "over its own view (models/latent.py)")
         from ..ops.pallas.paged_attention import fused_decode_supported
 
+        mcfg = engine.module.config
+        probe = dict(n_slots=self.n_slots, tp=max(engine.mp_world_size, 1),
+                     kv_dtype=self.cfg.kv_pool.kv_dtype)
         ok, reason = fused_decode_supported(
-            engine.module.config, self.pool_mgr.block_size,
-            n_slots=self.n_slots,
-            blocks_per_slot=self.pool_mgr.blocks_per_slot,
-            tp=max(engine.mp_world_size, 1),
-            kv_dtype=self.cfg.kv_pool.kv_dtype)
+            mcfg, self.pool_mgr.block_size,
+            blocks_per_slot=self.pool_mgr.blocks_per_slot, **probe)
+        if ok and self._window:
+            # the window layers' calls have their own program: the band,
+            # over a table as wide as the ring
+            ok, reason = fused_decode_supported(
+                mcfg, self.pool_mgr.block_size,
+                blocks_per_slot=self.window_mgr.ring,
+                window=mcfg.sliding_window, ring=True, **probe)
         return ("kernel", "") if ok else ("view", reason)
 
-    def _refuse_for_latent(self, engine):
-        """What this engine cannot do with a latent-attention model refuses
-        here, by name, instead of computing something else: the cache holds
-        one latent row a token, which only the pool in the engine's dtype,
-        read through its own view on one model shard, knows."""
+    def _refuse_for_cache_family(self, engine):
+        """What this engine cannot do with a model whose cache is not one
+        group of per-head K and V refuses here, by name, instead of
+        computing something else. Latent attention: the cache holds one
+        latent row a token, which only the pool in the engine's dtype, read
+        through its own view on one model shard, knows. Window and full
+        attention layers: two block groups and two tables, which the prefix
+        cache (it would share a ring's blocks, overwritten in place), the
+        verify step, the int8 pool, growth, the scrub and the snapshot do
+        not know."""
         cfg = self.cfg
+        family = "latent attention" if self._latent \
+            else "window and full attention layers"
         why = None
-        if cfg.kv_pool.kv_dtype == "int8":
+        if self._window and cfg.kv_pool.prefix_cache:
+            why = ("the prefix cache over window blocks "
+                   "(serving.kv_pool.prefix_cache)")
+        elif self._window and cfg.kv_pool.on_demand_growth:
+            why = ("on-demand block growth "
+                   "(serving.kv_pool.on_demand_growth)")
+        elif self._window and cfg.scrub_freed_slots:
+            why = "the freed-block scrub (serving.scrub_freed_slots)"
+        elif cfg.kv_pool.kv_dtype == "int8":
             why = "an int8 pool (serving.kv_pool.kv_dtype='int8')"
         elif cfg.speculative.enabled:
             why = "speculative verify (serving.speculative.enabled)"
@@ -315,7 +350,7 @@ class ServingEngine:
                    "snapshot_interval_tokens > 0)")
         if why is not None:
             raise ValueError(
-                f"ServingEngine: latent attention does not implement {why}")
+                f"ServingEngine: {family} does not implement {why}")
 
     @property
     def chunk_size(self):
@@ -334,9 +369,10 @@ class ServingEngine:
         cannot change any committed token."""
         if role not in ("mixed", "prefill", "decode"):
             raise ValueError(f"unknown pool role {role!r}")
-        if role != "mixed" and self._latent:
+        if role != "mixed" and (self._latent or self._window):
             raise ValueError(
-                "ServingEngine: latent attention does not implement the "
+                "ServingEngine: latent attention and window and full "
+                "attention layers do not implement the "
                 f"disaggregated hand-off (pool role {role!r})")
         self.pool_role = role
         self.chunk_size_override = int(chunk_size)
@@ -361,7 +397,7 @@ class ServingEngine:
         keeps them: what decides which block writer runs and what a write
         costs (PR 27); None where the backend tells no layout."""
         out = {}
-        for name in ("k", "v"):
+        for name in self._pool_leaf_names():
             layout = getattr(getattr(self._state[name], "format", None),
                              "layout", None)
             out[name] = None if layout is None \
@@ -379,6 +415,19 @@ class ServingEngine:
         st["attention_backend"] = self.attn_backend
         st["attention_reason"] = self.attn_reason
         st["decode_dispatches"] = dict(self._decode_dispatches)
+        if self._window:
+            # by group: the blocks each holds, those of the live requests
+            # (the slots' bindings), and the K/V rows the decode steps read
+            # in a layer of each kind, booked from the cursors
+            st["groups"] = {
+                "full": {"layers": len(self._layer_groups[1]),
+                         "allocated_blocks": st["allocated_blocks"],
+                         "rows_read_per_layer":
+                         self.metrics.latent_kv_tokens_read},
+                "window": dict(self.window_mgr.stats(),
+                               layers=len(self._layer_groups[0]),
+                               rows_read_per_layer=self.metrics
+                               .kv_window_rows_read)}
         return st
 
     # ------------------------------------------------------------------ state
@@ -386,9 +435,23 @@ class ServingEngine:
         cfg = self.engine.module.config
         s = self.n_slots
         mgr = self.pool_mgr
-        cache = init_paged_cache(cfg, mgr.n_blocks, mgr.block_size,
-                                 self.engine.dtype,
-                                 self.cfg.kv_pool.kv_dtype or None)
+        if self._window:
+            # two groups: every token of a request in the full layers', a
+            # ring a slot in the window layers'
+            wmgr = self.window_mgr
+            cache = init_paged_cache(cfg, mgr.n_blocks, mgr.block_size,
+                                     self.engine.dtype,
+                                     n_layers=len(self._layer_groups[1]))
+            ring = init_paged_cache(cfg, wmgr.n_blocks, mgr.block_size,
+                                    self.engine.dtype,
+                                    n_layers=len(self._layer_groups[0]))
+            cache.update(wk=ring["k"], wv=ring["v"],
+                         wtable=jnp.full((s, wmgr.ring), GARBAGE_BLOCK,
+                                         jnp.int32))
+        else:
+            cache = init_paged_cache(cfg, mgr.n_blocks, mgr.block_size,
+                                     self.engine.dtype,
+                                     self.cfg.kv_pool.kv_dtype or None)
         state = dict(cache, **{
             # every slot starts parked on the garbage block: a dead decode
             # write can never land in an allocatable block
@@ -513,6 +576,11 @@ class ServingEngine:
         kernel = self.attn_backend == "kernel"
         bs = self.pool_mgr.block_size
         pool_keys = self._pool_leaf_names()
+        window = self._window
+        # a model with window layers reads through two tables
+        tables = lambda state: (state["table"], state["wtable"]) \
+            if window else state["table"]
+        carried = ("wtable",) if window else ()
         # how write_pool_blocks reaches the pool: by the layout the device
         # gave it, read off the live array
         writer = dict(lanes=blocks_in_lanes(self._state["k"]),
@@ -528,7 +596,7 @@ class ServingEngine:
             # [L_moe, S, 1, 2k]
             logits, cache, *routed = forward_with_paged_cache(
                 model, params, state["tok"][:, None],
-                {k: state[k] for k in pool_keys}, state["table"],
+                {k: state[k] for k in pool_keys}, tables(state),
                 state["pos"], bs, kernel=kernel,
                 return_routing=self._routing)
             # in-graph health: per-slot nonfinite-logit count (the serving
@@ -557,6 +625,7 @@ class ServingEngine:
                 "rng": split[:, 1],
                 "temp": state["temp"], "top_k": state["top_k"],
                 "top_p": state["top_p"], "eos": state["eos"],
+                **{k: state[k] for k in carried},
             })
             # the routing is an output of its own, [L_moe, S, 2k], read back
             # with the tokens
@@ -628,11 +697,14 @@ class ServingEngine:
                     sampled), new_state
 
         def insert_meta(state, slot, table_row, tok, pos, remaining, rng,
-                        temp, top_k, top_p, eos):
+                        temp, top_k, top_p, eos, *ring_row):
             # the KV rows were already copied block-wise (insert_blocks);
-            # this binds the slot's block table + scalars. The slot index is
-            # TRACED: one compiled insert covers every slot
+            # this binds the slot's block table (a window model: its ring
+            # too) + scalars. The slot index is TRACED: one compiled insert
+            # covers every slot
             put = lambda a, v_: a.at[slot].set(v_)
+            if window:
+                state = dict(state, wtable=put(state["wtable"], ring_row[0]))
             return dict(state, **{
                 "table": state["table"].at[slot].set(table_row),
                 "pos": put(state["pos"], pos),
@@ -656,6 +728,23 @@ class ServingEngine:
             return dict(state, **insert_block_kv(
                 pool, {"k": dense_k, "v": dense_v}, block_ids, src_blocks,
                 bs, **writer))
+
+        def insert_blocks_by_group(state, dense_k, dense_v, block_ids,
+                                   src_blocks, ring_ids, ring_srcs):
+            # a model with window layers: the full layers' rows of the dense
+            # cache go to the full group as above, and of the window
+            # layers' rows the blocks that hold the band go to the slot's
+            # ring (the blocks before them are never read again)
+            win, full = (jnp.asarray(g) for g in self._layer_groups)
+            out = insert_block_kv(
+                {"k": state["k"], "v": state["v"]},
+                {"k": dense_k[full], "v": dense_v[full]}, block_ids,
+                src_blocks, bs, **writer)
+            ring = insert_block_kv(
+                {"k": state["wk"], "v": state["wv"]},
+                {"k": dense_k[win], "v": dense_v[win]}, ring_ids, ring_srcs,
+                bs, **writer)
+            return dict(state, **out, wk=ring["k"], wv=ring["v"])
 
         def seed_cache(state, table_row):
             # shared-prefix hit: materialize the slot's dense cache view
@@ -683,13 +772,13 @@ class ServingEngine:
             # the allocator, so its table row must retreat to the garbage
             # block before anything reuses them — a dead decode write to a
             # reallocated block would be silent cross-request corruption
+            parked = lambda t: t.at[slot].set(
+                jnp.full((t.shape[1],), GARBAGE_BLOCK, jnp.int32))
             return dict(
-                state,
-                table=state["table"].at[slot].set(
-                    jnp.full((state["table"].shape[1],), GARBAGE_BLOCK,
-                             jnp.int32)),
+                state, table=parked(state["table"]),
                 pos=state["pos"].at[slot].set(0),
-                active=state["active"].at[slot].set(False))
+                active=state["active"].at[slot].set(False),
+                **{k: parked(state[k]) for k in carried})
 
         def scrub_block(state, block_id):
             # scrub_freed_slots: zero a physical block when its last
@@ -728,7 +817,8 @@ class ServingEngine:
             self._insert_jit = jax.jit(insert_meta, donate_argnums=(0,),
                                        out_shardings=st)
             self._insert_block_jit = jax.jit(
-                insert_blocks, donate_argnums=(0,), out_shardings=st)
+                insert_blocks_by_group if window else insert_blocks,
+                donate_argnums=(0,), out_shardings=st)
             self._seed_cache_jit = jax.jit(
                 seed_cache, out_shardings={"k": self._cache_sharding,
                                            "v": self._cache_sharding})
@@ -1441,7 +1531,7 @@ class ServingEngine:
         self.pool_mgr.preempted_requests += 1
         self.metrics.record_preempt()
         self._state = self._release_jit(self._state, np.int32(slot))
-        self.pool_mgr.free_slot(slot)
+        self._free_slot_blocks(slot)
         self._free_slots.append(slot)
         if self._drafter is not None:
             self._drafter.release(slot)
@@ -1452,13 +1542,13 @@ class ServingEngine:
                             trace_id=req.trace_id,
                             n_tokens=len(req.tokens))
 
-    def _writer_ids(self, targets, sources):
+    def _writer_ids(self, targets, sources, mgr=None):
         """The block writer's two ``[blocks_per_slot]`` id arrays for one
         dispatch: source block ``sources[i]`` lands on pool block
         ``targets[i]``. Every entry past them is padding: a distinct id past
         the pool, which writes nothing (``write_pool_blocks``). Counted
         here, so ``kv_insert_blocks`` is the blocks really written."""
-        mgr = self.pool_mgr
+        mgr = mgr or self.pool_mgr
         ids = mgr.n_blocks + np.arange(mgr.blocks_per_slot, dtype=np.int32)
         srcs = np.zeros((mgr.blocks_per_slot,), np.int32)
         ids[:len(targets)] = targets
@@ -1486,15 +1576,32 @@ class ServingEngine:
         blocks = list(shared_blocks) + private
         ids, srcs = self._writer_ids(
             private, range(len(shared_blocks), len(blocks)))
+        ring_args = ring_row = ()
+        if self._window:
+            # the slot's ring in the window group: its footprint, capped at
+            # the ring (a free slot always finds it: kv_pool.py); of the
+            # prefilled blocks those that hold the band are copied
+            wmgr = self.window_mgr
+            ring = wmgr.alloc(wmgr.blocks_for(req.prompt_len,
+                                              req.max_new_tokens))
+            wmgr.bind_slot(slot, ring,
+                           req.prompt_len + req.max_new_tokens - 1)
+            held = wmgr.ring_columns(prefill_len)
+            ring_args = self._writer_ids(
+                [ring[c] for _, c in held], [j for j, _ in held], wmgr)
+            row_w = np.full((wmgr.ring,), GARBAGE_BLOCK, np.int32)
+            row_w[:len(ring)] = ring
+            ring_row = (jnp.asarray(row_w),)
         self._state = self._insert_block_jit(
-            self._state, cache["k"], cache["v"], ids, srcs)
+            self._state, cache["k"], cache["v"], ids, srcs, *ring_args)
         row = np.full((mgr.blocks_per_slot,), GARBAGE_BLOCK, np.int32)
         row[:len(blocks)] = blocks
         self._state = self._insert_jit(
             self._state, np.int32(slot), jnp.asarray(row), tok,
             np.int32(prefill_len), np.int32(remaining),
             chain_key, np.float32(s.temperature), np.int32(s.top_k),
-            np.float32(s.top_p), np.int32(-1 if eos is None else eos))
+            np.float32(s.top_p), np.int32(-1 if eos is None else eos),
+            *ring_row)
         mgr.bind_slot(slot, blocks,
                       self._growth_admission_len(req) if self.growth
                       else req.prompt_len + req.max_new_tokens - 1)
@@ -1503,8 +1610,16 @@ class ServingEngine:
 
     # ------------------------------------------------- live KV migration
     def _pool_leaf_names(self):
+        if self._window:
+            return ("k", "v", "wk", "wv")
         return ("k", "v", "k_scale", "v_scale") \
             if self.cfg.kv_pool.kv_dtype == "int8" else ("k", "v")
+
+    def _free_slot_blocks(self, slot):
+        """The slot's blocks go back to their allocators, group by group."""
+        self.pool_mgr.free_slot(slot)
+        if self._window:
+            self.window_mgr.free_slot(slot)
 
     def _pool_geometry(self):
         """The splice-compatibility fingerprint a ``RequestSnapshot``
@@ -1524,10 +1639,11 @@ class ServingEngine:
         gathers only — no new compiled program, no device mutation — so a
         capture can run on any step boundary without perturbing the
         stay-put stream."""
-        if self._latent:
+        if self._latent or self._window:
             raise ValueError(
-                "ServingEngine: latent attention does not implement live KV "
-                "migration (a snapshot of latent blocks and its splice)")
+                "ServingEngine: latent attention and window and full "
+                "attention layers do not implement live KV "
+                "migration (a snapshot of their blocks and its splice)")
         if req.slot is None or self._slots.get(req.slot) is not req:
             return None
         mgr = self.pool_mgr
@@ -1739,7 +1855,7 @@ class ServingEngine:
         # splice on the target): the rng at this commit point
         req.resume_rng = np.asarray(self._state["rng"])[slot].copy()
         self._state = self._release_jit(self._state, np.int32(slot))
-        self.pool_mgr.free_slot(slot)
+        self._free_slot_blocks(slot)
         if self._drafter is not None:
             self._drafter.release(slot)
         self._free_slots.append(slot)
@@ -2048,6 +2164,11 @@ class ServingEngine:
             # the one the new token takes
             live = req.prompt_len + len(req.tokens)
             self.metrics.latent_kv_tokens_read += live
+            if self._window:
+                # a window layer attended the band of them
+                self.metrics.kv_window_rows_read += min(
+                    live, self.window_mgr.window)
+                self.window_mgr.book_cursor(live - 1)
             if req.record_routing:
                 req.routing.append((live - 1, 1, routed[:, slot, None]))
 
@@ -2088,7 +2209,7 @@ class ServingEngine:
             # stop, which the device does not know of, clears its active
             # flag here)
             self._state = self._release_jit(self._state, np.int32(req.slot))
-            self.pool_mgr.free_slot(req.slot)
+            self._free_slot_blocks(req.slot)
             req.slot = None
         self.metrics.record_finish(req)
         start = req.start_time

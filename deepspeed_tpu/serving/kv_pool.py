@@ -29,6 +29,12 @@ Three mechanisms, one invariant set:
   could EVER provide sheds ``no_free_blocks`` at admission; one that merely
   has to wait for running requests to free blocks stays queued (FCFS).
 
+A model whose layers are of two kinds (``models/window_moe.py``: window and
+full attention layers) has TWO block groups and two tables: this manager
+over the full layers' group (``kv_pool.block_size`` / ``n_blocks`` size it,
+every token of a request is held), and ``WindowGroupManager`` over the window
+layers' group, whose size follows from slots, window and block.
+
 ``stats()`` feeds ``ServingMetrics``' kv_pool block: occupancy (allocated /
 allocatable blocks), internal fragmentation (1 - live tokens / allocated
 token capacity), and the prefix hit rate (matched / candidate full blocks).
@@ -344,3 +350,66 @@ class KVPoolManager:
             "rolled_back_blocks": self.rolled_back_blocks,
             "reserved_blocks": self._pending,
         }
+
+
+def window_ring_blocks(window, block_size):
+    """Blocks in a slot's ring of the window group: the band (the last
+    ``window`` positions) touches at most ``ceil(window / block) + 1``
+    blocks, wherever in a block the cursor stands."""
+    return -(-int(window) // int(block_size)) + 1
+
+
+class WindowGroupManager(KVPoolManager):
+    """The window layers' block group: a RING of blocks a slot.
+
+    A window layer reads the last ``window`` positions and nothing before
+    them, so its group keeps, per slot, ``window_ring_blocks`` blocks and no
+    more, whatever the request's length: block ``j`` of the request sits at
+    table column ``j % ring``, and when the cursor enters block ``j`` its
+    rows overwrite block ``j - ring``, every row of which has left the band
+    (``recycled_blocks`` counts them, booked from the cursors). The ring, and
+    not a release of the blocks that leave the band with a fresh block for
+    each the cursor enters: the slot's table never changes while it runs, so
+    a step dispatches nothing for it, and a slot can never wait for a block
+    in the middle of a request. What a shorter request does not need it does
+    not take: ``blocks_for`` is its footprint, capped at the ring.
+
+    Same allocator, reservations and counters as the full group's manager
+    (no prefix cache: a ring's blocks are overwritten in place). The group
+    holds ``n_slots * ring`` blocks and the garbage block, so a free slot
+    always finds its ring."""
+
+    def __init__(self, cfg, n_slots, window):
+        self.window = int(window)
+        self.ring = window_ring_blocks(window, cfg.block_size)
+        super().__init__(
+            cfg.replace(n_blocks=n_slots * self.ring + 1, prefix_cache=False,
+                        on_demand_growth=False),
+            n_slots, self.ring * int(cfg.block_size))
+        self.recycled_blocks = 0
+
+    def blocks_for(self, prompt_len, max_new_tokens):
+        return min(super().blocks_for(prompt_len, max_new_tokens), self.ring)
+
+    def ring_columns(self, prefill_len):
+        """``[(request block j, ring column)]`` of the blocks that hold the
+        band behind ``prefill_len`` prefilled positions: what the insert
+        copies (a block before them is never read again)."""
+        last = max(prefill_len - 1, 0) // self.block_size
+        return [(j, j % self.ring)
+                for j in range(max(last - self.ring + 1, 0), last + 1)]
+
+    def book_cursor(self, pos):
+        """A decode step writes position ``pos``: entering a block past the
+        ring's first lap overwrites one that left the band."""
+        if pos % self.block_size == 0 and pos // self.block_size >= self.ring:
+            self.recycled_blocks += 1
+
+    def stats(self):
+        st = super().stats()
+        return {"n_blocks": st["n_blocks"], "ring_blocks": self.ring,
+                "window": self.window,
+                "allocated_blocks": st["allocated_blocks"],
+                "free_blocks": st["free_blocks"],
+                "reserved_blocks": st["reserved_blocks"],
+                "recycled_blocks": self.recycled_blocks}

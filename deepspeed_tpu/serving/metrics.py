@@ -182,6 +182,11 @@ class ServingMetrics:
         self.moe_decode_experts_hit = 0
         self.moe_max_expert_load = 0  # summed over dispatches: / dispatches
         self.latent_kv_tokens_read = 0  # live cache rows the decode steps read
+        # a model of window and full attention layers: the K/V rows the
+        # decode steps read in ONE window layer (of each slot the rows in
+        # its band), booked from the cursors; a full layer reads the live
+        # rows above (snapshot()["kv_pool"]["groups"])
+        self.kv_window_rows_read = 0
         self.prefill_chunks = 0
         self.prefill_chunk_tokens = 0
         # (first position, positions) of the newest chunks, newest last

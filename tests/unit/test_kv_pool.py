@@ -38,6 +38,48 @@ from .conftest import (make_paged, make_replica, staggered_requests,
 # host-side allocator + prefix cache (no device work)
 # ---------------------------------------------------------------------------
 
+@pytest.mark.parametrize("window,block,ring", [(2048, 128, 17), (24, 8, 4),
+                                               (100, 16, 8), (16, 16, 2)])
+def test_window_group_is_a_ring_a_slot(window, block, ring):
+    """The window layers' group (``models/window_moe.py``): its size follows
+    from slots, window and block; a request takes its footprint capped at
+    the ring, the band behind a prefill is what the insert copies, a cursor
+    that enters a block past the first lap writes over one that left the
+    band, and a finished slot gives everything back."""
+    from deepspeed_tpu.config import KVPoolConfig
+    from deepspeed_tpu.serving.kv_pool import (WindowGroupManager,
+                                               window_ring_blocks)
+
+    assert window_ring_blocks(window, block) == ring
+    mgr = WindowGroupManager(
+        KVPoolConfig(block_size=block, n_blocks=7, prefix_cache=True),
+        n_slots=3, window=window)
+    assert mgr.ring == ring and mgr.blocks_per_slot == ring
+    assert mgr.n_blocks == 3 * ring + 1 and not mgr.cfg.prefix_cache
+    assert mgr.blocks_for(block, 1) == 1
+    assert mgr.blocks_for(50 * window, 100) == ring
+    # the band behind any cursor lies inside the blocks the ring keeps
+    for prefill in (1, block, window, window + 1, 7 * window + 3):
+        held = mgr.ring_columns(prefill)
+        assert len(held) <= ring
+        assert len({c for _, c in held}) == len(held)
+        assert held[-1][0] == (prefill - 1) // block
+        assert held[0][0] * block <= max(prefill - window, 0)
+        assert all(c == j % ring for j, c in held)
+    for slot in range(3):
+        mgr.bind_slot(slot, mgr.alloc(ring), 10 * window)
+        assert mgr.slot_block_count(slot) == ring
+    assert not mgr.can_allocate(1)
+    for pos in range(2 * ring * block):
+        mgr.book_cursor(pos)
+    assert mgr.recycled_blocks == ring           # the second lap's blocks
+    mgr.free_slot(1)
+    assert mgr.can_allocate(ring) and not mgr.can_allocate(ring + 1)
+    st = mgr.stats()
+    assert st["ring_blocks"] == ring and st["window"] == window
+    assert st["allocated_blocks"] == 2 * ring and st["free_blocks"] == ring
+
+
 def test_allocator_refcount_and_eviction():
     from deepspeed_tpu.config import KVPoolConfig
 

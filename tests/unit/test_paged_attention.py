@@ -154,6 +154,98 @@ def test_kernel_gqa_and_alibi(kvh, hq, dh, cursors):
     np.testing.assert_allclose(np.asarray(out), ref, atol=2e-6)
 
 
+def _band_reference(q, k_new, v_new, kc, vc, table, pos, window, ring):
+    """The view with the mask: each slot's blocks gathered through the
+    table (a ring: column ``c`` holds the newest block ``j <= pos // bs``
+    with ``j % n_cols == c``), exact softmax over the positions ``[pos -
+    window + 1, pos)`` and the fresh row."""
+    S, nh, dh = q.shape
+    kvh = k_new.shape[1]
+    nb, bs, _ = kc.shape
+    NB = table.shape[1]
+    kc = np.asarray(kc, np.float32).reshape(nb, bs, kvh, dh)
+    vc = np.asarray(vc, np.float32).reshape(nb, bs, kvh, dh)
+    out = np.zeros((S, nh, dh), np.float32)
+    for s in range(S):
+        p_ = int(pos[s])
+        seen = range(max(p_ - window + 1, 0), p_)
+        cols = [(t // bs) % NB if ring else t // bs for t in seen]
+        rows = [(table[s, c], t % bs) for c, t in zip(cols, seen)]
+        for h in range(nh):
+            g = h // (nh // kvh)
+            keys = np.stack([kc[b, o, g] for b, o in rows]
+                            + [np.asarray(k_new)[s, g]])
+            vals = np.stack([vc[b, o, g] for b, o in rows]
+                            + [np.asarray(v_new)[s, g]])
+            sc = (np.asarray(q)[s, h] @ keys.T) / np.sqrt(dh)
+            e = np.exp(sc - sc.max())
+            out[s, h] = (e / e.sum()) @ vals
+    return out
+
+
+# window 20 over blocks of 8: before the band fills (5), at its edge (19,
+# 20, 21), a block boundary inside a lap (24), several laps of a 4-block
+# ring on (67, 96, 127), and dead slots between
+BAND_CURSORS = {"filling": [5, 19, 0, 20], "edge": [21, 24, 23, 1],
+                "laps": [67, 0, 96, 127]}
+
+
+@pytest.mark.parametrize("chunk_tokens", [8, 16, 256])
+@pytest.mark.parametrize("kvh,hq", [(2, 2), (1, 4), (2, 4)])
+@pytest.mark.parametrize("cursors", sorted(BAND_CURSORS))
+def test_kernel_band_over_a_ring_matches_the_masked_view(cursors, kvh, hq,
+                                                         chunk_tokens):
+    """A window layer's calls: the walk starts at the chunk that holds
+    ``pos - window + 1``, masks inside it, and reads a slot's blocks through
+    a ring as wide as the band; every cursor x chunking x GQA grouping
+    against the view with the mask. Blocks the band has left are POISONED:
+    any read of them explodes the output."""
+    rng = np.random.RandomState(1)
+    S, bs, window, dh = 4, 8, 20, 16
+    NB = -(-window // bs) + 1                    # the ring: 4 blocks
+    nh = kvh * hq
+    n_blocks = S * NB + 1
+    kc = rng.randn(n_blocks, bs, kvh * dh).astype(np.float32)
+    vc = rng.randn(n_blocks, bs, kvh * dh).astype(np.float32)
+    pos = np.asarray(BAND_CURSORS[cursors], np.int32)
+    table = (1 + rng.permutation(S * NB).reshape(S, NB)).astype(np.int32)
+    for s in range(S):
+        # rows of the ring that hold nothing the band sees: poison
+        first = max(pos[s] - window + 1, 0)
+        live = {((t // bs) % NB, t % bs) for t in range(first, pos[s])}
+        for c in range(NB):
+            for o in range(bs):
+                if (c, o) not in live:
+                    kc[table[s, c], o] = 1e4
+                    vc[table[s, c], o] = 1e4
+    q = rng.randn(S, nh, dh).astype(np.float32)
+    k_new = rng.randn(S, kvh, dh).astype(np.float32)
+    v_new = rng.randn(S, kvh, dh).astype(np.float32)
+    out = _run_kernel(q, k_new, v_new, kc, vc, table, pos, window=window,
+                      ring=True, chunk_tokens=chunk_tokens)
+    want = _band_reference(q, k_new, v_new, kc, vc, table, pos, window, True)
+    np.testing.assert_allclose(np.asarray(out), want, atol=3e-6)
+
+
+def test_kernel_band_over_an_ordinary_table_and_no_band_is_the_old_program():
+    """The band without the ring (every block kept, the walk still starts
+    at the band); and ``window=0`` traces the program the kernel had before
+    the band existed, whatever ``ring`` says about a table it never wraps."""
+    q, k_new, v_new, kc, vc, table, pos = _kernel_fixture()
+    out = _run_kernel(q, k_new, v_new, kc, vc, table, pos, window=6,
+                      chunk_tokens=8)
+    want = _band_reference(q, k_new, v_new, kc, vc, table, pos, 6, False)
+    np.testing.assert_allclose(np.asarray(out), want, atol=3e-6)
+    args = [jnp.asarray(a) for a in (q, k_new, v_new, kc, vc, table, pos)]
+    plain = jax.make_jaxpr(lambda *a: paged_flash_decode(
+        *a, interpret=True))(*args)
+    no_band = jax.make_jaxpr(lambda *a: paged_flash_decode(
+        *a, window=0, interpret=True))(*args)
+    banded = jax.make_jaxpr(lambda *a: paged_flash_decode(
+        *a, window=6, interpret=True))(*args)
+    assert str(plain) == str(no_band) != str(banded)
+
+
 def test_kernel_reads_one_layer_of_the_whole_pool():
     """The decode program hands the kernel the pool leaves WHOLE and a
     traced layer index (no slice of a leaf is made): every layer reads its

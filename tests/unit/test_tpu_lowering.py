@@ -390,6 +390,118 @@ def test_latent_expert_serving_programs_lower_without_a_kernel():
     assert n_mosaic(text) == 0 and "ragged_dot" in text
 
 
+def _trinity_cell_programs(sharding=None):
+    """trinity's decode step and 1024-token chunk at the published widths
+    and the serve cell's geometry (5 of 32 layers: one dense, one period; 32
+    slots of 32,768 positions, blocks of 128, a full group of 5121 blocks
+    and a window group of 32 rings of 17)."""
+    from deepspeed_tpu.models import decoding as D
+    from deepspeed_tpu.serving.kv_pool import window_ring_blocks
+
+    kinds = ("sliding_attention",) * 4 + ("full_attention",)
+    model = get_model("trinity", "mini", n_layers=5, first_k_dense=1,
+                      layer_types=kinds, compute_dtype=jnp.bfloat16)
+    cfg = model.config
+    slots, bs, max_len = 32, 128, 32768
+    ring = window_ring_blocks(cfg.sliding_window, bs)
+    assert ring == 17 and cfg.pool_geometry == {"k": (512,), "v": (512,)}
+    sds = lambda shape, dt: SDS(shape, dt, sharding=sharding)
+    params = _abstract_params(model, sharding=sharding)
+    n_params = sum(int(np.prod(a.shape))
+                   for a in jax.tree_util.tree_leaves(params))
+    assert n_params == 4_241_534_720
+    pool = {"k": sds((1, 5121, bs, 512), jnp.bfloat16),
+            "v": sds((1, 5121, bs, 512), jnp.bfloat16),
+            "wk": sds((4, slots * ring + 1, bs, 512), jnp.bfloat16),
+            "wv": sds((4, slots * ring + 1, bs, 512), jnp.bfloat16)}
+    cache = {n: sds((5, 1, max_len, 4, 128), jnp.bfloat16) for n in "kv"}
+
+    def decode(params, tok, pool, table, wtable, pos):
+        return D.forward_with_paged_cache(
+            model, params, tok, pool, (table, wtable), pos, bs, kernel=True,
+            return_routing=True)
+
+    def chunk(params, ids, cache, start, last):
+        return D.forward_with_cache(model, params, ids, cache, start, max_len,
+                                    last_index=last, return_routing=True)
+
+    return (decode, (params, sds((slots, 1), jnp.int32), pool,
+                     sds((slots, max_len // bs), jnp.int32),
+                     sds((slots, ring), jnp.int32), sds((slots,), jnp.int32)),
+            chunk, (params, sds((1, 1024), jnp.int32), cache,
+                    sds((), jnp.int32), sds((), jnp.int32)))
+
+
+def test_window_expert_serving_programs_lower_with_the_kernel_and_no_view():
+    """trinity's decode step and prefill chunk at the PUBLISHED widths lower
+    for the TPU: one Mosaic call a layer in decode (the band in the four
+    window layers), ``ragged_dot`` for the experts in both, no ``n_slots x
+    max_len`` tensor in decode (the 32 x 256 x 128 gather of the full group's
+    view, a 32 x 32768 view or score row), and in the chunk no score tensor
+    against the whole ``max_len`` (``[32 heads, 1024, 32768]`` float32 is 4.3
+    GB): the context is visited in blocks of 1024."""
+    decode, d_args, chunk, c_args = _trinity_cell_programs()
+    text = lower_for_tpu(decode, *d_args)
+    assert n_mosaic(text) == 5 and "ragged_dot" in text
+    assert "1x5121x128x512xbf16" in text and "4x545x128x512xbf16" in text
+    for view in ("32x256x128x512", "32x32768x", "32x2176x", "x32768xf32",
+                 "x32768xbf16"):
+        assert view not in text, view
+    text = lower_for_tpu(chunk, *c_args)
+    assert n_mosaic(text) == 0 and "ragged_dot" in text
+    assert "5x1x32768x4x128xbf16" in text
+    assert "4x8x1024x1024xf32" in text           # a block of scores
+    for scores in ("1024x32768xf32", "32x1024x32768", "4x8x1024x32768"):
+        assert scores not in text, scores
+
+
+def test_compiled_window_decode_copies_no_experts_and_keeps_its_pools(v5e):
+    """The same decode program COMPILED for a v5e: both groups' pools are
+    aliased to the output, the temporaries are tens of MB (a layer's 128
+    experts are 1.6 GB, the full group's view would be 4.3 GB), no
+    instruction copies, slices or gathers an expert stack or a pool leaf,
+    and the device keeps a token's row in the lanes in both groups."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    decode, d_args, _, _ = _trinity_cell_programs(sharding=v5e)
+    cached = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        with lowering_target("tpu"):
+            compiled = jax.jit(decode, donate_argnums=(2,)).trace(*d_args) \
+                .lower(lowering_platforms=("tpu",)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cached)
+        cc.reset_cache()
+    mem = compiled.memory_analysis()
+    pools = 2 * (5121 + 4 * 545) * 128 * 512 * 2
+    assert mem.alias_size_in_bytes >= pools
+    assert mem.temp_size_in_bytes < 256 << 20, mem.temp_size_in_bytes
+    text = compiled.as_text()
+    assert text.count("paged_flash_decode") >= 5
+    big = ("bf16[512,2048,2048]", "bf16[512,1024,2048]",
+           "bf16[128,2048,2048]", "bf16[128,1024,2048]",
+           "bf16[4,128,2048,2048]", "bf16[4,128,1024,2048]",
+           "bf16[1,5121,128,512]", "bf16[4,545,128,512]",
+           "bf16[5121,128,512]", "bf16[545,128,512]")
+    for line in text.splitlines():
+        if " = " not in line:
+            continue
+        made = line.split(" = ")[1]
+        head = made.split("(")[0].split()
+        if len(head) < 2:
+            continue
+        shape, op = head[0].split("{")[0], head[-1]
+        if shape in big:
+            assert op.startswith(("parameter", "get-tuple-element", "fusion",
+                                  "scatter", "bitcast", "while", "tuple")) \
+                and "copy" not in op, line[:200]
+    for name in ("k", "v", "wk", "wv"):
+        fmt = compiled.input_formats[0][2][name]
+        assert tuple(fmt.layout.major_to_minor) == (0, 1, 2, 3)
+
+
 def test_compiler_verdict_carries_the_compilers_words():
     """The blocking the paged kernel shipped with — one kv head of many per
     block — is what Mosaic refuses; the verdict hands back its sentence."""

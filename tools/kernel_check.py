@@ -186,6 +186,67 @@ def _paged_case(nh, kvh, dh, alibi=False):
         3e-2
 
 
+def _paged_band_case(nh, kvh, dh, window, bs):
+    """A window layer's calls (``models/window_moe.py``): the band of the
+    last ``window`` positions over a RING of blocks a slot, block ``j`` at
+    table column ``j % ring``; cursors before the band fills, at its edge
+    and several laps of the ring on."""
+    import jax
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.ops.pallas.paged_attention import paged_flash_decode
+
+    rng = np.random.RandomState(0)
+    S, n_layers = 8, 2
+    ring = -(-window // bs) + 1
+    n_blocks = S * ring + 1
+    dt = jnp.bfloat16
+    kc = jnp.asarray(rng.randn(n_layers, n_blocks, bs, kvh * dh), dt)
+    vc = jnp.asarray(rng.randn(n_layers, n_blocks, bs, kvh * dh), dt)
+    table = jnp.asarray(1 + rng.permutation(S * ring).reshape(S, ring),
+                        jnp.int32)
+    pos = jnp.asarray([1, bs - 1, window - 1, window, window + 1,
+                       2 * window + bs + 5, 9 * window + 77,
+                       15 * window - 1], jnp.int32)
+    q = jnp.asarray(rng.randn(S, nh, dh) * 0.3, dt)
+    k_new = jnp.asarray(rng.randn(S, kvh, dh), dt)
+    v_new = jnp.asarray(rng.randn(S, kvh, dh), dt)
+    layer = jnp.asarray(1, jnp.int32)
+
+    def kernel(q, k_new, v_new, kc, vc, table, pos, layer):
+        return paged_flash_decode(q, k_new, v_new, kc, vc, table, pos,
+                                  layer=layer, window=window, ring=True)
+
+    def ref(q, k_new, v_new, kc, vc, table, pos, layer):
+        # the slot's ring through the table; row (c, o) holds position
+        # b * bs + o of the newest block b <= cursor's with b % ring == c;
+        # the fresh row beside it; exact fp32 softmax over the band
+        f32 = jnp.float32
+        view = lambda c: c[layer].astype(f32)[table].reshape(
+            S, ring * bs, kvh, dh)
+        cur = pos // bs
+        block = cur[:, None] - (cur[:, None] - jnp.arange(ring)[None]) % ring
+        k_pos = (block[:, :, None] * bs + jnp.arange(bs)).reshape(S, -1)
+        seen = (k_pos >= 0) & (k_pos < pos[:, None]) \
+            & (pos[:, None] - k_pos < window)
+        g = nh // kvh
+        qg = q.astype(f32).reshape(S, kvh, g, dh)
+        sc = jnp.einsum("sgrd,stgd->sgrt", qg, view(kc)) / np.sqrt(dh)
+        sc = jnp.where(seen[:, None, None], sc, -jnp.inf)
+        own = jnp.einsum("sgrd,sgd->sgr", qg, k_new.astype(f32)) \
+            / np.sqrt(dh)
+        p = jax.nn.softmax(jnp.concatenate([sc, own[..., None]], -1), -1)
+        out = jnp.einsum("sgrt,stgd->sgrd", p[..., :-1], view(vc)) \
+            + p[..., -1:] * v_new.astype(f32)[:, :, None]
+        return out.reshape(S, nh, dh)
+
+    geom = (f"8 slots, band {window} over a ring of {ring} blocks of {bs}, "
+            f"{nh}/{kvh} heads x {dh}, bf16 pool [2, {n_blocks}, {bs}, "
+            f"{kvh * dh}], layer 1, cursors to {15 * window - 1}")
+    return geom, kernel, ref, (q, k_new, v_new, kc, vc, table, pos, layer), \
+        3e-2
+
+
 def _qmm_case(bits):
     import jax
     import jax.numpy as jnp
@@ -295,6 +356,8 @@ CASES = {
         lambda: _paged_case(16, 16, 128, alibi=True),
     "paged decode (OPT class 32x64)": lambda: _paged_case(32, 32, 64),
     "paged decode (GQA 32/8x128)": lambda: _paged_case(32, 8, 128),
+    "paged decode (GQA 32/4x128, band 2048 over a ring)":
+        lambda: _paged_band_case(32, 4, 128, 2048, 128),
     "quantized matmul int8": lambda: _qmm_case(8),
     "quantized matmul int4": lambda: _qmm_case(4),
     "kv block write (bf16 pool)": lambda: _block_write_case(False),
