@@ -19,6 +19,8 @@ means ``C = s`` and E / top_k times the products the tokens need. Here the
 work is the pairs', and in decode the bytes are those of the experts hit.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -78,22 +80,81 @@ def pair_weights(cfg, scores, ids):
         * cfg.moe_routed_scale
 
 
-def grouped_product(rows, w, group_sizes):
+def product_path(m, interpret=False, mesh=None):
+    """Which implementation ``grouped_product`` takes for ``m`` rows:
+    ``"kernel"`` (``ops/pallas/grouped_matmul.py``) or ``"ragged_dot"``.
+    The choice is static, from what a program can see when it is traced:
+    the platform it is lowered for (a kernel needs a TPU, or ``interpret``:
+    the models' ``attention_interpret``), the row count, which must cut
+    into the kernel's row tiles, and the mesh (GSPMD cannot partition a
+    Mosaic call, and the expert layer has no ``shard_map`` of its own: a
+    TPU program over several devices keeps ``ragged_dot``). The serving engine
+    books it by dispatch (``snapshot()["moe"]["product_dispatches"]``)."""
+    from ..ops.pallas import grouped_matmul, unavailable_reason
+
+    ok = grouped_matmul.row_tile(m) is not None \
+        and unavailable_reason(interpret) is None \
+        and (interpret or mesh is None or mesh.size == 1)
+    return "kernel" if ok else "ragged_dot"
+
+
+def _ragged_product(rows, w, group_sizes):
+    return jax.lax.ragged_dot(
+        rows, w, group_sizes, precision=_precision(rows.dtype),
+        preferred_element_type=F32).astype(rows.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _kernel_product(rows, w, group_sizes, interpret):
+    from ..ops.pallas.grouped_matmul import grouped_matmul
+
+    return grouped_matmul(rows, w, group_sizes, interpret=interpret,
+                          precision=_precision(rows.dtype))
+
+
+def _kernel_product_fwd(rows, w, group_sizes, interpret):
+    return _kernel_product(rows, w, group_sizes, interpret), \
+        (rows, w, group_sizes)
+
+
+def _kernel_product_bwd(interpret, saved, g):
+    # no backward kernel: the same product's own derivative through
+    # ``ragged_dot`` (the generic training path, models/transformer.py)
+    rows, w, group_sizes = saved
+    _, vjp = jax.vjp(lambda r, w_: _ragged_product(r, w_, group_sizes),
+                     rows, w)
+    return (*vjp(g), None)
+
+
+_kernel_product.defvjp(_kernel_product_fwd, _kernel_product_bwd)
+
+
+def grouped_product(rows, w, group_sizes, interpret=False, mesh=None):
     """``rows`` [M, K], sorted by group, times ``w[g]`` [K, N] for the rows
     of group g (``group_sizes`` [E] int32, summing to M; zeros allowed) ->
-    [M, N] in ``rows.dtype``, accumulated in float32.
+    [M, N] in ``rows.dtype``, accumulated in float32 over the whole of K.
 
-    ``jax.lax.ragged_dot``, settled on the chip (v5e, PR 29): for one layer's
-    192 decode pairs over 128 experts it takes 1.8 ms where reading the 106
-    experts hit takes 1.2 ms at the published bandwidth, and 4.7 ms for a
-    1024-token chunk's 6,144 pairs where reading all 128 takes 1.5 ms; no
-    kernel of this repo's own is needed beside it. The scope names it in the
-    device trace."""
+    Two implementations of the one product, chosen by ``product_path``.
+    Settled on the chip (v5e, PR 36; one layer's two products at the serve
+    cells' widths, group sizes as skewed as the cells' routing makes them):
+    ``jax.lax.ragged_dot`` takes nearly as long for 512 rows as for 8,192
+    (trinity 5.2 and 6.1 ms, kanana 2.3 ms for 384 and 4.7 for 6,144): its
+    time follows the groups with rows, not the rows, and is 31-32% of what
+    the experts' bytes take at 819 GB/s. The kernel of ``ops/pallas/
+    grouped_matmul.py``, row tiles of 128 and the whole of K a step, takes
+    3.1 and 2.3 ms for a 1024-token chunk's 8,192 / 6,144 pairs (64-65% of
+    that floor), is ahead at every row count the engines' programs have,
+    decode's 256 / 192 rows included (3.2 -> 2.0 and 1.8 -> 1.4 ms), and
+    still at 512 rows a group (65,536 rows: 4.6 against 9.2 ms), to the
+    same bits. So a TPU program takes the kernel wherever ``product_path``
+    allows it, and ``ragged_dot`` stays for the CPU, for a program over
+    several devices and as the kernel's derivative. The scope names the
+    region in the device trace whichever runs."""
     with jax.named_scope("moe_grouped_matmul"):
-        return jax.lax.ragged_dot(
-            rows, w.astype(rows.dtype), group_sizes,
-            precision=_precision(rows.dtype),
-            preferred_element_type=F32).astype(rows.dtype)
+        w = w.astype(rows.dtype)
+        if product_path(rows.shape[0], interpret, mesh) == "kernel":
+            return _kernel_product(rows, w, group_sizes, interpret)
+        return _ragged_product(rows, w, group_sizes)
 
 
 def _precision(dtype):
@@ -142,9 +203,10 @@ def dropfree_moe_apply(cfg, p, x, ids=None, stacked=None):
     group_sizes = jnp.zeros((gate_up.shape[0],), jnp.int32).at[
         first + pair_expert].add(1)
     rows = flat[pair_token]                                   # [T*k, d]
-    h = grouped_product(rows, gate_up, group_sizes)           # [T*k, 2f]
+    how = (cfg.attention_interpret, cfg.mesh)
+    h = grouped_product(rows, gate_up, group_sizes, *how)     # [T*k, 2f]
     h = jax.nn.silu(h[:, :f]) * h[:, f:]
-    out = grouped_product(h, down, group_sizes)               # [T*k, d]
+    out = grouped_product(h, down, group_sizes, *how)         # [T*k, d]
     w_sorted = weights.reshape(-1)[order]
     out = out.astype(F32) * w_sorted[:, None]
     # back to token order: pair i of token t sits at row inverse[t * k + i]

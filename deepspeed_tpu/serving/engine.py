@@ -567,9 +567,22 @@ class ServingEngine:
             return out
         logits, cache, (routed, counts) = out
         self._pending_loads.append(counts)
+        self._book_product_path(routed)
         if req.record_routing:
             req.routing.append((start, n, routed))
         return logits, cache
+
+    def _book_product_path(self, routed):
+        """``routed`` [L_moe, rows, 2k] of a program just dispatched (its
+        shape alone is read): book its expert layers under the grouped
+        product its trace chose (``moe/dropfree.py:product_path``)."""
+        from ..moe.dropfree import product_path
+
+        cfg = self.engine.module.config
+        n_layers, rows = routed.shape[0], routed.shape[1]
+        self.metrics.record_moe_product(
+            product_path(rows * cfg.moe_top_k, cfg.attention_interpret,
+                         cfg.mesh), n_layers)
 
     def _build_pool_programs(self):
         model, max_len = self.engine.module, self.max_len
@@ -2159,6 +2172,7 @@ class ServingEngine:
                                        minlength=cfg.n_experts)
                            for layer in routed])
         self.metrics.record_moe_loads(counts, decode=True)
+        self._book_product_path(routed)
         for slot, req in self._slots.items():
             # this step fed the slot's last token, at the position before
             # the one the new token takes

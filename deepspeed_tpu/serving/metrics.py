@@ -181,6 +181,9 @@ class ServingMetrics:
         self.moe_decode_pairs = 0
         self.moe_decode_experts_hit = 0
         self.moe_max_expert_load = 0  # summed over dispatches: / dispatches
+        # dispatches by the implementation of the grouped product their
+        # program was traced with (moe/dropfree.py:product_path)
+        self.moe_product_dispatches = {"kernel": 0, "ragged_dot": 0}
         self.latent_kv_tokens_read = 0  # live cache rows the decode steps read
         # a model of window and full attention layers: the K/V rows the
         # decode steps read in ONE window layer (of each slot the rows in
@@ -390,6 +393,12 @@ class ServingMetrics:
             self.moe_decode_pairs += int(counts.sum())
             self.moe_decode_experts_hit += int(hit.sum())
 
+    def record_moe_product(self, path, n_layers):
+        """``n_layers`` expert layers dispatched in one program whose
+        grouped products take ``path``: booked on the host from the
+        program's static choice, at dispatch."""
+        self.moe_product_dispatches[path] += int(n_layers)
+
     def record_prefill_chunk(self, start, n):
         """One chunk of ``n`` prompt positions written at ``start``,
         counted where its program is dispatched."""
@@ -406,6 +415,7 @@ class ServingMetrics:
             "decode_dispatches": self.moe_decode_dispatches,
             "decode_pairs": self.moe_decode_pairs,
             "decode_experts_hit": self.moe_decode_experts_hit,
+            "product_dispatches": dict(self.moe_product_dispatches),
             "max_expert_load_sum": self.moe_max_expert_load,
             "moe_max_expert_load": self.moe_max_expert_load / d,
             "moe_mean_expert_load": self.moe_pairs

@@ -347,47 +347,50 @@ def test_quantized_matmul_lowers(bits):
     assert not ok and "no legal tiling" in reason
 
 
-def test_latent_expert_serving_programs_lower_without_a_kernel():
-    """kanana2's decode step and prefill chunk at the published widths lower
-    for the TPU with no Mosaic call: the grouped expert product is
-    ``jax.lax.ragged_dot`` (settled on the chip, PR 29: ``moe/dropfree.py``)
-    and both attention forms are XLA's, so there is no Pallas twin to keep
-    in step and nothing that gives way off the TPU."""
+def _kanana_programs(n_layers, n_blocks, sharding=None):
+    """kanana2's decode step and 1024-token chunk at the published widths:
+    32 slots of 16,384 positions over a pool of ``n_blocks`` blocks of 128
+    latent rows, ``n_layers`` of them (layer 0 dense)."""
     from deepspeed_tpu.models import decoding as D
-    from deepspeed_tpu.models.layers import Param
 
-    model = get_model("kanana2", "30b-a3b", n_layers=3,
+    model = get_model("kanana2", "30b-a3b", n_layers=n_layers,
                       compute_dtype=jnp.bfloat16)
     cfg = model.config
-    params = jax.tree_util.tree_map(
-        lambda a: SDS(a.shape, jnp.bfloat16),
-        jax.eval_shape(lambda r: jax.tree_util.tree_map(
-            lambda p: p.value, model.init(r),
-            is_leaf=lambda x: isinstance(x, Param)), jax.random.PRNGKey(0)))
+    sds = lambda shape, dt: SDS(shape, dt, sharding=sharding)
+    params = _abstract_params(model, sharding=sharding)
     slots, bs, max_len = 32, 128, 16384
-    pool = {n: SDS((3, 257, bs) + row, jnp.bfloat16)
+    pool = {n: sds((n_layers, n_blocks, bs) + row, jnp.bfloat16)
             for n, row in cfg.cache_geometry.items()}
     assert pool["k"].shape[-2:] == (1, 512) and pool["v"].shape[-2:] == (1, 64)
+    cache = {n: sds((n_layers, 1, max_len) + row, jnp.bfloat16)
+             for n, row in cfg.cache_geometry.items()}
 
     def decode(params, tok, pool, table, pos):
         return D.forward_with_paged_cache(model, params, tok, pool, table,
                                           pos, bs, return_routing=True)
 
-    text = lower_for_tpu(decode, params, SDS((slots, 1), jnp.int32), pool,
-                         SDS((slots, max_len // bs), jnp.int32),
-                         SDS((slots,), jnp.int32))
-    assert n_mosaic(text) == 0 and "ragged_dot" in text
-
-    cache = {n: SDS((3, 1, max_len) + row, jnp.bfloat16)
-             for n, row in cfg.cache_geometry.items()}
-
     def chunk(params, ids, cache, start, last):
         return D.forward_with_cache(model, params, ids, cache, start, max_len,
                                     last_index=last, return_routing=True)
 
-    text = lower_for_tpu(chunk, params, SDS((1, 1024), jnp.int32), cache,
-                         SDS((), jnp.int32), SDS((), jnp.int32))
-    assert n_mosaic(text) == 0 and "ragged_dot" in text
+    return (decode, (params, sds((slots, 1), jnp.int32), pool,
+                     sds((slots, max_len // bs), jnp.int32),
+                     sds((slots,), jnp.int32)),
+            chunk, (params, sds((1, 1024), jnp.int32), cache,
+                    sds((), jnp.int32), sds((), jnp.int32)))
+
+
+def test_latent_expert_serving_programs_lower_with_the_grouped_kernel_alone():
+    """kanana2's decode step and prefill chunk at the published widths lower
+    for the TPU with two Mosaic calls in the scanned expert layer's body and
+    no other: the grouped expert products are ``ops/pallas/grouped_matmul.py``
+    in both programs (settled on the chip, PR 36: ``moe/dropfree.py``), no
+    ``ragged_dot`` is left, and both attention forms are XLA's."""
+    decode, d_args, chunk, c_args = _kanana_programs(3, 257)
+    for fn, args in ((decode, d_args), (chunk, c_args)):
+        text = lower_for_tpu(fn, *args)
+        assert n_mosaic(text) == 2 and "ragged_dot" not in text
+        assert text.count("grouped_matmul") == 2
 
 
 def _trinity_cell_programs(sharding=None):
@@ -434,57 +437,49 @@ def _trinity_cell_programs(sharding=None):
 
 def test_window_expert_serving_programs_lower_with_the_kernel_and_no_view():
     """trinity's decode step and prefill chunk at the PUBLISHED widths lower
-    for the TPU: one Mosaic call a layer in decode (the band in the four
-    window layers), ``ragged_dot`` for the experts in both, no ``n_slots x
+    for the TPU: in decode one Mosaic call a layer for attention (the band in
+    the four window layers) and two an expert layer for the grouped products
+    (``ops/pallas/grouped_matmul.py``; a period's four layers are written
+    out), those eight alone in the chunk, no ``ragged_dot`` left, no ``n_slots x
     max_len`` tensor in decode (the 32 x 256 x 128 gather of the full group's
     view, a 32 x 32768 view or score row), and in the chunk no score tensor
     against the whole ``max_len`` (``[32 heads, 1024, 32768]`` float32 is 4.3
     GB): the context is visited in blocks of 1024."""
     decode, d_args, chunk, c_args = _trinity_cell_programs()
     text = lower_for_tpu(decode, *d_args)
-    assert n_mosaic(text) == 5 and "ragged_dot" in text
+    assert n_mosaic(text) == 5 + 8 and "ragged_dot" not in text
     assert "1x5121x128x512xbf16" in text and "4x545x128x512xbf16" in text
     for view in ("32x256x128x512", "32x32768x", "32x2176x", "x32768xf32",
                  "x32768xbf16"):
         assert view not in text, view
     text = lower_for_tpu(chunk, *c_args)
-    assert n_mosaic(text) == 0 and "ragged_dot" in text
+    assert n_mosaic(text) == 8 and "ragged_dot" not in text
     assert "5x1x32768x4x128xbf16" in text
     assert "4x8x1024x1024xf32" in text           # a block of scores
     for scores in ("1024x32768xf32", "32x1024x32768", "4x8x1024x32768"):
         assert scores not in text, scores
 
 
-def test_compiled_window_decode_copies_no_experts_and_keeps_its_pools(v5e):
-    """The same decode program COMPILED for a v5e: both groups' pools are
-    aliased to the output, the temporaries are tens of MB (a layer's 128
-    experts are 1.6 GB, the full group's view would be 4.3 GB), no
-    instruction copies, slices or gathers an expert stack or a pool leaf,
-    and the device keeps a token's row in the lanes in both groups."""
+def _compile_for(fn, args):
+    """``fn`` compiled for the described chip, its third argument donated,
+    with the persistent cache off (such a compile cannot be read back)."""
     from jax.experimental.compilation_cache import compilation_cache as cc
 
-    decode, d_args, _, _ = _trinity_cell_programs(sharding=v5e)
     cached = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     cc.reset_cache()
     try:
         with lowering_target("tpu"):
-            compiled = jax.jit(decode, donate_argnums=(2,)).trace(*d_args) \
+            return jax.jit(fn, donate_argnums=(2,)).trace(*args) \
                 .lower(lowering_platforms=("tpu",)).compile()
     finally:
         jax.config.update("jax_enable_compilation_cache", cached)
         cc.reset_cache()
-    mem = compiled.memory_analysis()
-    pools = 2 * (5121 + 4 * 545) * 128 * 512 * 2
-    assert mem.alias_size_in_bytes >= pools
-    assert mem.temp_size_in_bytes < 256 << 20, mem.temp_size_in_bytes
-    text = compiled.as_text()
-    assert text.count("paged_flash_decode") >= 5
-    big = ("bf16[512,2048,2048]", "bf16[512,1024,2048]",
-           "bf16[128,2048,2048]", "bf16[128,1024,2048]",
-           "bf16[4,128,2048,2048]", "bf16[4,128,1024,2048]",
-           "bf16[1,5121,128,512]", "bf16[4,545,128,512]",
-           "bf16[5121,128,512]", "bf16[545,128,512]")
+
+
+def _assert_only_passed_along(text, big):
+    """No instruction of a compiled program's ``text`` that makes one of
+    the shapes ``big`` copies, slices or gathers it."""
     for line in text.splitlines():
         if " = " not in line:
             continue
@@ -497,9 +492,66 @@ def test_compiled_window_decode_copies_no_experts_and_keeps_its_pools(v5e):
             assert op.startswith(("parameter", "get-tuple-element", "fusion",
                                   "scatter", "bitcast", "while", "tuple")) \
                 and "copy" not in op, line[:200]
+
+
+TRINITY_EXPERTS = ("bf16[512,2048,2048]", "bf16[512,1024,2048]",
+                   "bf16[128,2048,2048]", "bf16[128,1024,2048]",
+                   "bf16[4,128,2048,2048]", "bf16[4,128,1024,2048]")
+KANANA_EXPERTS = ("bf16[768,2048,1536]", "bf16[768,768,2048]",
+                  "bf16[128,2048,1536]", "bf16[128,768,2048]",
+                  "bf16[6,128,2048,1536]", "bf16[6,128,768,2048]")
+
+
+def test_compiled_window_decode_copies_no_experts_and_keeps_its_pools(v5e):
+    """The same decode program COMPILED for a v5e: both groups' pools are
+    aliased to the output, the temporaries are tens of MB (a layer's 128
+    experts are 1.6 GB, the full group's view would be 4.3 GB), no
+    instruction copies, slices or gathers an expert stack or a pool leaf,
+    and the device keeps a token's row in the lanes in both groups."""
+    decode, d_args, _, _ = _trinity_cell_programs(sharding=v5e)
+    compiled = _compile_for(decode, d_args)
+    mem = compiled.memory_analysis()
+    pools = 2 * (5121 + 4 * 545) * 128 * 512 * 2
+    assert mem.alias_size_in_bytes >= pools
+    assert mem.temp_size_in_bytes < 256 << 20, mem.temp_size_in_bytes
+    text = compiled.as_text()
+    assert text.count("paged_flash_decode") >= 5
+    _assert_only_passed_along(text, TRINITY_EXPERTS + (
+        "bf16[1,5121,128,512]", "bf16[4,545,128,512]",
+        "bf16[5121,128,512]", "bf16[545,128,512]"))
     for name in ("k", "v", "wk", "wv"):
         fmt = compiled.input_formats[0][2][name]
         assert tuple(fmt.layout.major_to_minor) == (0, 1, 2, 3)
+
+
+@pytest.mark.parametrize("cell", ["trinity", "kanana"])
+def test_compiled_chunk_reads_its_experts_in_place(v5e, cell):
+    """The 1024-token chunk program (``jit_suffix_routed``) COMPILED for a
+    v5e at a cell's widths and depth: the grouped products are the kernel's
+    calls, two an expert layer as the program holds it (trinity writes a
+    period's four out, kanana scans one body over its six), handed the stack
+    as L x E groups (a bitcast of the parameter); no instruction copies, slices or gathers a layer's
+    experts (1.6 GB in trinity, 1.2 GB in kanana) or the stack, and the
+    temporaries stay what they were with ``ragged_dot`` (249 MB in trinity,
+    343 MB in kanana: the parent's, compiled the same way)."""
+    if cell == "trinity":
+        _, _, chunk, c_args = _trinity_cell_programs(sharding=v5e)
+        experts, n_calls, stack = TRINITY_EXPERTS, 8, "bf16[512,2048,2048]"
+        temp_limit = 256 << 20
+    else:
+        _, _, chunk, c_args = _kanana_programs(7, 2561, sharding=v5e)
+        experts, n_calls, stack = KANANA_EXPERTS, 2, "bf16[768,2048,1536]"
+        temp_limit = 336 << 20
+    compiled = _compile_for(chunk, c_args)
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < temp_limit, mem.temp_size_in_bytes
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == n_calls and "ragged-dot" not in text
+    assert all("moe_grouped_matmul/grouped_matmul" in c for c in calls)
+    assert any(stack in c for c in calls)       # the whole stack goes in
+    _assert_only_passed_along(text, experts)
 
 
 def test_compiler_verdict_carries_the_compilers_words():

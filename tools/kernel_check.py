@@ -4,10 +4,12 @@ reference on the same device.
 
     python tools/kernel_check.py          # on the chip machine (one process)
 
-One JSON line per kernel: ``{"kernel", "geometry", "max_err", "tol", "ok"}``,
-then a summary; exit code 1 if any kernel failed to compile or to match. A
-TPU is required: off-chip these kernels only run under the interpreter,
-which tier-1 already covers (``tests/unit/test_*attention*.py`` etc.), and
+One JSON line per kernel: ``{"kernel", "geometry", "max_err", "tol", "ok"}``
+(the grouped expert products also ``"ms"``: the kernel and ``ragged_dot``
+timed on the same operands), then a summary; exit code 1 if any kernel
+failed to compile or to match. A TPU is required: off-chip these kernels only
+run under the interpreter, which tier-1 already covers
+(``tests/unit/test_*attention*.py`` etc.), and
 ``tests/unit/test_tpu_lowering.py`` covers lowering. Tolerances are bf16
 ones: both sides round to bf16 somewhere, in a different order.
 """
@@ -35,6 +37,22 @@ def _err(got, want):
         worst = max(worst, float(np.max(np.abs(g - w))
                                  / max(np.max(np.abs(w)), 1e-6)))
     return worst
+
+
+def _ms(fn, operands, reps=10):
+    """Milliseconds a call of the jitted ``fn``: ``reps`` dispatched back to
+    back behind a warm one, waited for once."""
+    import time
+
+    import jax
+
+    fn = jax.jit(fn)
+    jax.block_until_ready(fn(*operands))
+    t0 = time.perf_counter()
+    for _ in range(reps):
+        out = fn(*operands)
+    jax.block_until_ready(out)
+    return round((time.perf_counter() - t0) / reps * 1e3, 4)
 
 
 def _qkv(b, s, h, d, seed=0):
@@ -312,41 +330,79 @@ def _block_write_case(int8):
             (pool, cache, jnp.asarray(ids), jnp.asarray(srcs)), 0.0)
 
 
-def _grouped_product_case():
-    """Not a kernel of this repo's: ``jax.lax.ragged_dot`` as the drop-free
-    expert layer calls it (``moe/dropfree.py``), against every expert
-    computed for every row and masked. One layer's decode pairs at the
-    published widths: 192 rows over 128 experts, some groups empty."""
+def _skewed_sizes(rng, n_groups, m, empty=3, sigma=0.6):
+    """``m`` rows dealt over ``n_groups`` as a serve cell's routing deals a
+    chunk's pairs (random weights, a drawn selection bias): a few experts
+    several times the mean (about 230 of 8,192 where the mean is 64), a few
+    with none."""
+    p = np.exp(sigma * rng.randn(n_groups))
+    p[rng.choice(n_groups, empty, replace=False)] = 0
+    return rng.multinomial(m, p / p.sum()).astype(np.int32)
+
+
+def _grouped_product_case(n_layers, K, N, M, dtype="bfloat16", E=128):
+    """The drop-free expert layer's grouped product (``moe/dropfree.py``:
+    ``ops/pallas/grouped_matmul.py`` on a TPU) as the served programs call
+    it: ``M`` rows sorted by expert, the weights a stack of ``n_layers`` x
+    ``E`` groups of which one layer's have rows, against every expert of
+    that layer computed for every row and masked. TIMED beside
+    ``jax.lax.ragged_dot`` on the same operands (the row's ``ms``), so the
+    choice ``product_path`` makes can be checked again in one call."""
     import jax
     import jax.numpy as jnp
 
-    from deepspeed_tpu.moe.dropfree import grouped_product
+    from deepspeed_tpu.moe import dropfree
 
-    E, K, N, M = 128, 2048, 1536, 192
+    dtype = jnp.dtype(dtype)
     rng = np.random.RandomState(0)
-    sizes = np.bincount(rng.randint(0, E, M), minlength=E).astype(np.int32)
-    rows = jnp.asarray(rng.randn(M, K), jnp.bfloat16)
-    w = jnp.asarray(rng.randn(E, K, N) * 0.02, jnp.bfloat16)
+    layer = n_layers // 2
+    decode = M < 2 * E
+    live = _skewed_sizes(rng, E, M, empty=0 if decode else 3,
+                         sigma=0.3 if decode else 0.6)
+    sizes = np.zeros(n_layers * E, np.int32)
+    sizes[layer * E:(layer + 1) * E] = live
+    key = jax.random.PRNGKey(0)
+    rows = jax.random.normal(key, (M, K), dtype)
+    w = jnp.concatenate([
+        jax.random.normal(jax.random.fold_in(key, l), (E, K, N), dtype) * 0.02
+        for l in range(n_layers)])
 
     def ref(rows, w, sizes):
         ends = jnp.cumsum(sizes)
         idx = jnp.arange(M)
 
         def one(e, acc):
-            mine = (idx >= ends[e] - sizes[e]) & (idx < ends[e])
+            g = layer * E + e
+            mine = (idx >= ends[g] - sizes[g]) & (idx < ends[g])
             return acc + jnp.where(mine[:, None], jnp.dot(
-                rows, w[e], preferred_element_type=jnp.float32), 0.0)
+                rows, w[g], preferred_element_type=jnp.float32,
+                precision=dropfree._precision(dtype)), 0.0)
 
         return jax.lax.fori_loop(0, E, one, jnp.zeros((M, N), jnp.float32))
 
-    return (f"192 rows x [128, 2048, 1536], {int((sizes == 0).sum())} empty "
-            "groups", grouped_product, ref, (rows, w, jnp.asarray(sizes)),
-            0.02)
+    return (f"{M} rows x [{n_layers * E}, {K}, {N}] {dtype.name}, one layer's "
+            f"{E} groups live: largest {int(live.max())}, "
+            f"{int((live == 0).sum())} empty; path "
+            f"{dropfree.product_path(M)}", dropfree.grouped_product, ref,
+            (rows, w, jnp.asarray(sizes)), 0.02,
+            {"ragged_dot": dropfree._ragged_product})
 
 
 CASES = {
-    "grouped expert product (ragged_dot, kanana2 decode)":
-        _grouped_product_case,
+    "grouped expert product (trinity chunk, gate and up)":
+        lambda: _grouped_product_case(4, 2048, 2048, 8192),
+    "grouped expert product (trinity chunk, down)":
+        lambda: _grouped_product_case(4, 1024, 2048, 8192),
+    "grouped expert product (kanana2 chunk, gate and up)":
+        lambda: _grouped_product_case(6, 2048, 1536, 6144),
+    "grouped expert product (kanana2 chunk, down)":
+        lambda: _grouped_product_case(6, 768, 2048, 6144),
+    "grouped expert product (trinity decode, gate and up)":
+        lambda: _grouped_product_case(4, 2048, 2048, 256),
+    "grouped expert product (kanana2 decode, gate and up)":
+        lambda: _grouped_product_case(6, 2048, 1536, 192),
+    "grouped expert product (float32, 1,536 rows a group)":
+        lambda: _grouped_product_case(2, 256, 512, 12288, "float32", E=8),
     "flash fwd+bwd (single kv block)": flash_single_block,
     "flash fwd+bwd (general)": flash_general,
     "jax_flash fwd+bwd": jax_flash,
@@ -365,6 +421,26 @@ CASES = {
 }
 
 
+def _check(name, case):
+    """One case's JSON row; its operands (a stack of experts is GBs) die
+    with this frame, before the next case makes its own."""
+    import jax
+
+    try:
+        geometry, kernel, reference, operands, tol, *others = case()
+        err = _err(jax.jit(kernel)(*operands), jax.jit(reference)(*operands))
+        row = {"kernel": name, "geometry": geometry,
+               "max_err": round(err, 5), "tol": tol, "ok": err <= tol}
+        if others:
+            row["ms"] = {n: _ms(f, operands) for n, f in
+                         dict(kernel=kernel, **others[0]).items()}
+    except Exception as e:
+        traceback.print_exc()
+        row = {"kernel": name, "ok": False,
+               "error": " ".join(str(e).split())[:600]}
+    return row
+
+
 def main():
     import jax
 
@@ -379,16 +455,7 @@ def main():
           flush=True)
     failed = []
     for name, case in CASES.items():
-        try:
-            geometry, kernel, reference, operands, tol = case()
-            err = _err(jax.jit(kernel)(*operands),
-                       jax.jit(reference)(*operands))
-            row = {"kernel": name, "geometry": geometry,
-                   "max_err": round(err, 5), "tol": tol, "ok": err <= tol}
-        except Exception as e:
-            traceback.print_exc()
-            row = {"kernel": name, "ok": False,
-                   "error": " ".join(str(e).split())[:600]}
+        row = _check(name, case)
         if not row["ok"]:
             failed.append(name)
         print(json.dumps(row), flush=True)
