@@ -36,7 +36,7 @@ def init_cache(cfg, batch_size, max_len, dtype=None):
 # ---------------------------------------------------------------------------
 
 def init_paged_cache(cfg, n_blocks, block_size, dtype=None, kv_dtype=None,
-                     n_layers=None):
+                     n_layers=None, geometry=None):
     """Allocate the paged KV pool: ``n_blocks`` physical blocks of
     ``block_size`` tokens each, stacked over layers (a physical block id
     addresses the same block row in EVERY layer, so host allocation is one
@@ -60,10 +60,13 @@ def init_paged_cache(cfg, n_blocks, block_size, dtype=None, kv_dtype=None,
 
     ``n_layers``: the layers of ONE block group, where a model keeps
     several (``models/window_moe.py``: the full layers' group and the window
-    layers' ring, each with its own ``n_blocks``)."""
+    layers' ring, each with its own ``n_blocks``), and ``geometry`` that
+    group's rows where they are not ``cfg.pool_geometry`` (``cfg.
+    group_pool_geometry``: another K/V head count by kind, V rows narrower
+    than K rows)."""
     dtype = dtype or cfg.compute_dtype
     shapes = {name: (n_layers or cfg.n_layers, n_blocks, block_size) + row
-              for name, row in cfg.pool_geometry.items()}
+              for name, row in (geometry or cfg.pool_geometry).items()}
     if kv_dtype == "int8":
         pool = {name: jnp.zeros(s, jnp.int8) for name, s in shapes.items()}
         pool.update({name + "_scale": jnp.zeros(

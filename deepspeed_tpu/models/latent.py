@@ -188,7 +188,8 @@ def attention_uncached(cfg, p, h, rope):
 def dense_cfg(cfg):
     """The leading dense layers' view of the config: the same block with the
     experts off, so the dense FFN of width ``d_ff`` is built and run."""
-    return dataclasses.replace(cfg, n_experts=0, first_k_dense=0)
+    return dataclasses.replace(cfg, n_experts=0, first_k_dense=0,
+                               moe_local_experts=0, moe_expert_offset=0)
 
 
 def _cast_block(cfg, p):

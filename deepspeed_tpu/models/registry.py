@@ -309,6 +309,53 @@ def bert_config(size="base", **overrides):
     return TransformerConfig(**base)
 
 
+def mimo_v2_config(size="flash", **overrides):
+    """XiaomiMiMo/MiMo-V2-Flash (``model_type`` mimo_v2_flash; huggingface.co/
+    XiaomiMiMo/MiMo-V2-Flash config.json): 64 query heads of 192 over K heads
+    of 192 and V heads of 128, in two kinds of layer, five that see the last
+    128 positions (8 K/V heads, rotary base 10,000, a learned sink a head in
+    the softmax) to one that sees all (4 K/V heads, base 5,000,000); q and k
+    rotated over the first 64 dims (``partial_rotary_factor`` 0.334), V
+    scaled by 0.707; two RMS norms a layer, eps 1e-5, no biases, no q/k
+    norms; layer 0 a dense SwiGLU of 16,384, then layers of 256
+    sigmoid-routed experts of 2,048 (top-8 of ``s + b``, weights from ``s``,
+    normalised, no factor, no shared expert); untied head. The three MTP
+    layers of the release are not built. ``layer_types`` follows ``n_layers``
+    in the published ``hybrid_layer_pattern`` (layer 0 and every sixth layer
+    from layer 5 full, the others window) unless given. ``moe_local_experts`` / ``moe_expert_offset`` give a
+    program one chip's share of the experts (``moe/dropfree.py``)."""
+    presets = {
+        "tiny": dict(n_layers=7, d_model=64, n_heads=8, n_kv_heads=2,
+                     n_kv_heads_window=4, head_dim_override=24,
+                     v_head_dim=16, rotary_dim=8, d_ff=128, moe_d_ff=32,
+                     n_experts=16, moe_top_k=4, sliding_window=8,
+                     max_seq_len=256, vocab_size=512,
+                     # published layer 0 and layers 6-11: one whole period
+                     layer_types=("full_attention",)
+                     + ("sliding_attention",) * 5 + ("full_attention",)),
+        "flash": dict(n_layers=48, d_model=4096, n_heads=64, n_kv_heads=4,
+                      n_kv_heads_window=8, head_dim_override=192,
+                      v_head_dim=128, rotary_dim=64, d_ff=16384,
+                      moe_d_ff=2048, n_experts=256, moe_top_k=8,
+                      sliding_window=128),
+    }
+    base = dict(
+        vocab_size=152576, max_seq_len=262144, activation="swiglu",
+        norm="rmsnorm", position_embedding="rope", rope_base=5000000.0,
+        rope_base_window=10000.0, attn_value_scale=0.707,
+        window_block="sink", tie_embeddings=False, use_bias=False,
+        prenorm=True, layernorm_eps=1e-5, first_k_dense=1,
+        moe_routing="dropfree", moe_routed_scale=1.0,
+    )
+    base.update(presets[size])
+    base.update(overrides)
+    base.setdefault("layer_types", tuple(
+        "full_attention" if i == 0 or i % 6 == 5 else "sliding_attention"
+        for i in range(base["n_layers"])))
+    return TransformerConfig(**base)
+
+
+
 MODEL_CONFIGS = {
     "gpt2": gpt2_config,
     "opt": opt_config,
@@ -324,6 +371,7 @@ MODEL_CONFIGS = {
     "gpt2_moe": gpt2_moe_config,
     "kanana2": kanana2_config,
     "trinity": trinity_config,
+    "mimo_v2": mimo_v2_config,
 }
 
 

@@ -210,6 +210,26 @@ class TransformerConfig:
     layer_types: tuple = ()
     sliding_window: int = 0
     embed_scale: float = 1.0
+    # The same two kinds of layer as Xiaomi MiMo-V2 publishes them
+    # (``window_block="sink"``, beside it in models/window_moe.py): two norms
+    # a layer, no q/k norms and no gate; q and k rotated over the first
+    # ``rotary_dim`` dims in BOTH kinds, base ``rope_base`` in a full layer
+    # and ``rope_base_window`` in a window layer; ``n_kv_heads`` K/V heads in
+    # a full layer and ``n_kv_heads_window`` in a window layer; K rows of
+    # ``head_dim`` beside V rows of ``v_head_dim``; V scaled by
+    # ``attn_value_scale``; and in the window layers a learned per-head sink
+    # that joins the softmax's sum and adds nothing to its output.
+    window_block: str = "afmoe"  # afmoe | sink
+    n_kv_heads_window: int = 0    # 0 = n_kv_heads
+    rope_base_window: typing.Optional[float] = None  # None = rope_base
+    attn_value_scale: float = 1.0
+    # The share of a deployment's experts this program holds (moe/dropfree.
+    # py): experts ``[moe_expert_offset, moe_expert_offset +
+    # moe_local_experts)`` of ``n_experts``. The router keeps ``n_experts``
+    # outputs and ``moe_top_k`` a token, weights normalised over all the
+    # chosen; pairs on absent experts are not computed. 0 = all of them.
+    moe_local_experts: int = 0
+    moe_expert_offset: int = 0
 
     def __post_init__(self):
         # a typo here would silently run the exact fp32 path and let a
@@ -258,6 +278,23 @@ class TransformerConfig:
                 "the window and full attention layers of layer_types "
                 "(models/window_moe.py): the cache paths that carry its "
                 "routing are theirs")
+        if self.window_block not in ("afmoe", "sink"):
+            raise ValueError(
+                f"window_block must be 'afmoe' or 'sink', got "
+                f"{self.window_block!r}")
+        if self.window_block == "sink" and not self.layer_types:
+            raise ValueError("window_block='sink' (models/window_moe.py) "
+                             "needs layer_types")
+        if self.moe_local_experts or self.moe_expert_offset:
+            held = self.moe_local_experts or self.n_experts
+            if self.moe_routing != "dropfree" or not (
+                    0 <= self.moe_expert_offset
+                    and self.moe_expert_offset + held <= self.n_experts):
+                raise ValueError(
+                    f"moe_local_experts {self.moe_local_experts} at "
+                    f"moe_expert_offset {self.moe_expert_offset} must name "
+                    f"a range of the {self.n_experts} experts of a "
+                    "drop-free layer (moe_routing='dropfree')")
         if self.first_k_dense and not 0 < self.first_k_dense < self.n_layers:
             raise ValueError(
                 f"first_k_dense {self.first_k_dense} must leave at least one "
@@ -294,6 +331,34 @@ class TransformerConfig:
         return self.moe_d_ff or self.d_ff
 
     @property
+    def held_experts(self):
+        """``(first, count)`` of the experts this program holds."""
+        return self.moe_expert_offset, \
+            self.moe_local_experts or self.n_experts
+
+    @property
+    def sink_window(self):
+        """Window and full layers as MiMo-V2 has them (``window_block``)."""
+        return self.window_block == "sink"
+
+    def kv_geometry(self, window):
+        """``{leaf: (kv_heads, width)}`` of one cached token in one layer of
+        a kind (``window``: a window layer): a model of ``window_block=
+        "sink"`` has another K/V head count in its window layers and V rows
+        narrower than K rows."""
+        heads = self.n_kv_heads_window if window and self.n_kv_heads_window \
+            else self.kv_heads
+        v_width = self.v_head_dim if self.sink_window and self.v_head_dim \
+            else self.head_dim
+        return {"k": (heads, self.head_dim), "v": (heads, v_width)}
+
+    def group_pool_geometry(self, window):
+        """``pool_geometry`` of one block group of a model of two kinds of
+        layer: the merged row of that kind's K/V heads."""
+        return {name: (heads * width,)
+                for name, (heads, width) in self.kv_geometry(window).items()}
+
+    @property
     def cache_geometry(self):
         """``{leaf: (kv_heads, width)}`` of one cached token in one layer:
         what every cache allocator (dense, paged, block writer) sizes its
@@ -303,6 +368,11 @@ class TransformerConfig:
         if self.latent_attention:
             return {"k": (1, self.kv_lora_rank),
                     "v": (1, self.qk_rope_head_dim)}
+        if self.sink_window:
+            # the dense cache holds every layer at the wider kind's head
+            # count; a layer of the other kind fills its own heads
+            return max(self.kv_geometry(True), self.kv_geometry(False),
+                       key=lambda geometry: geometry["k"][0])
         return {"k": (self.kv_heads, self.head_dim),
                 "v": (self.kv_heads, self.head_dim)}
 
@@ -323,6 +393,21 @@ class TransformerConfig:
     def num_params(self):
         """Analytic parameter count (embedding + blocks + final norm)."""
         d, f, v = self.d_model, self.d_ff, self.vocab_size
+        if self.sink_window:
+            # q and o, K and V by the layer's kind, a sink a head in a
+            # window layer, two norms; the experts held, the whole router
+            H, kd = self.n_heads, self.first_k_dense
+            attn = 0
+            for kind in self.layer_types:
+                (g, dk), (_, dv) = self.kv_geometry(
+                    kind == "sliding_attention").values()
+                attn += d * H * dk + H * dv * d + d * g * (dk + dv) + 2 * d \
+                    + (H if kind == "sliding_attention" else 0)
+            E = self.n_experts
+            experts = self.held_experts[1] * 3 * d * self.expert_d_ff \
+                + d * E + E
+            return int(attn + kd * 3 * d * f + (self.n_layers - kd) * experts
+                       + d + (1 if self.tie_embeddings else 2) * v * d)
         per_block = 4 * d * d * (self.kv_heads / self.n_heads if self.n_kv_heads else 1.0)
         # more precisely: q:d*q_dim, k,v:d*kv_dim, o:q_dim*d (q_dim < d for
         # head-pruned models with head_dim_override)
@@ -335,7 +420,7 @@ class TransformerConfig:
                           self.v_head_dim)
             per_block = (d * H * (dn + dr) + d * (r + dr) + r
                          + r * H * (dn + dv) + H * dv * d)
-        if self.window_layers:
+        if self.window_layers:  # (afmoe; the sink form is counted above)
             # the output gate, the q and k norms, the two post-branch norms
             per_block += d * q_dim + 2 * self.head_dim + 2 * d
         ffn = (3 if self.activation == "swiglu" else 2) * d * f
@@ -1050,6 +1135,10 @@ class CausalLM:
         }
         if cfg.first_k_dense:
             params["dense_blocks"] = dense_stack_init(k_blocks, cfg)
+        if cfg.sink_window:
+            from .window_moe import kv_by_kind_init
+
+            params.update(kv_by_kind_init(k_blocks, cfg))
         if cfg.final_layernorm:
             params["ln_f"] = _norm_init(cfg)
         if cfg.position_embedding == "learned":

@@ -1,6 +1,6 @@
 """Window and full attention layers mixed, beside drop-free experts (AFMoE,
-Arcee Trinity): the block, a stack whose layers are of two kinds, and the
-cached forwards.
+Arcee Trinity; Xiaomi MiMo-V2 in the "sink" form, below): the block, a stack
+whose layers are of two kinds, and the cached forwards.
 
 A layer is of one of two kinds (``TransformerConfig.layer_types``, the
 published list):
@@ -17,6 +17,23 @@ projection. A block has four norms, one before and one after each branch:
 ``x += N2(Attn(N1 x)); x += N4(FFN(N3 x))``. The first ``first_k_dense``
 layers have a dense SwiGLU, the rest ``moe/dropfree.py``'s expert layer. The
 embedding is scaled by ``embed_scale``.
+
+The SINK form (``TransformerConfig.window_block="sink"``, MiMo-V2) has the
+same two kinds of layer, the same stack, plan and cache paths, and another
+block: ``x += Attn(N1 x); x += FFN(N2 x)``, no q/k norms, no gate, no
+embedding scale; q and k rotated over the first ``rotary_dim`` dims of the
+head in BOTH kinds (base ``rope_base`` in a full layer, ``rope_base_window``
+in a window layer); ``n_kv_heads`` K/V heads in a full layer and
+``n_kv_heads_window`` in a window layer; K heads of ``head_dim`` beside V
+heads of ``v_head_dim``, V scaled by ``attn_value_scale``; and in a window
+layer a learned SINK a query head, a logit that joins the softmax's maximum
+and sum and has no value. Because a layer's K and V projections (and the
+sink) differ in shape by kind, they are stacked BY KIND (``kv_by_kind_init``:
+``params["kv_window"]`` / ``["kv_full"]``, read at a layer's index in its
+group), and the blocks' ``attn`` holds q and o alone. What the two forms share
+is everything from ``layer_groups`` down (plan, ``blockwise_attention``,
+``view_attention``, the three cached forwards); what they do not is
+``attention_init``, ``project`` and the residual form of ``_block``.
 
 The two kinds keep their K and V apart in the paged pool (``serving/
 kv_pool.py``): a full layer's group holds every token of a request, a window
@@ -68,6 +85,15 @@ Layer = collections.namedtuple("Layer", "index group window")
 # ---------------------------------------------------------------------------
 def attention_init(rng, cfg, out_std):
     d, H, G, dh = cfg.d_model, cfg.n_heads, cfg.kv_heads, cfg.head_dim
+    if cfg.sink_window:
+        # q and o alone: K, V and the sink are stacked by kind
+        dv = cfg.kv_geometry(False)["v"][1]
+        k_q, k_o = jax.random.split(rng)
+        std = cfg.initializer_range
+        return {"q": L.linear_init(k_q, d, H * dh, ("embed", "heads"), False,
+                                   std),
+                "o": L.linear_init(k_o, H * dv, d, ("heads", "embed"), False,
+                                   out_std)}
     p = L.attention_init(rng, d, H, G, False, cfg.initializer_range,
                          out_stddev=out_std, head_dim=dh)
     p["gate"] = L.linear_init(jax.random.fold_in(rng, 4), d, H * dh,
@@ -89,10 +115,44 @@ def block_init(rng, cfg):
         mlp = dropfree_moe_init(k_mlp, cfg)
     else:
         mlp = _mlp_init(k_mlp, cfg)
+    if cfg.sink_window:
+        return {"ln_1": _norm_init(cfg),
+                "attn": attention_init(k_attn, cfg, out_std),
+                "ln_2": _norm_init(cfg), "mlp": mlp}
     return {"ln_1": _norm_init(cfg), "ln_1_post": _norm_init(cfg),
             "attn": attention_init(k_attn, cfg, out_std),
             "ln_2": _norm_init(cfg), "ln_2_post": _norm_init(cfg),
             "mlp": mlp}
+
+
+def kv_by_kind_init(rng, cfg):
+    """The sink form's K and V projections, and the window layers' sinks,
+    stacked by kind: ``{"kv_window": {"k", "v", "sink"}, "kv_full": {"k",
+    "v"}}``, each leaf ``[layers of the kind, ...]`` in the order of
+    ``layer_groups`` (the leading dense layers included). The sink is zero,
+    as a fresh logit is; a benchmark draws it from its seed."""
+    d, std = cfg.d_model, cfg.initializer_range
+    rngs = jax.random.split(jax.random.fold_in(rng, 7), cfg.n_layers)
+    out = {}
+    for name, window, layers in zip(("kv_window", "kv_full"), (True, False),
+                                    layer_groups(cfg)):
+        (g, dk), (_, dv) = cfg.kv_geometry(window).values()
+
+        def one(r, g=g, dk=dk, dv=dv, window=window):
+            k_k, k_v = jax.random.split(r)
+            p = {"k": L.linear_init(k_k, d, g * dk, ("embed", "kv"), False,
+                                    std),
+                 "v": L.linear_init(k_v, d, g * dv, ("embed", "kv"), False,
+                                    std)}
+            if window:
+                p["sink"] = Param(jnp.zeros((cfg.n_heads,), F32), (None,))
+            return p
+
+        stacked = jax.vmap(one)(rngs[jnp.asarray(layers)])
+        out[name] = jax.tree_util.tree_map(
+            lambda q: Param(q.value, ("layers",) + q.axes), stacked,
+            is_leaf=lambda x: isinstance(x, Param))
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -138,6 +198,8 @@ def project(cfg, p, h, positions, window):
     ``positions`` [b, q]; a full layer has no positions."""
     b, q_len, _ = h.shape
     H, G, dh = cfg.n_heads, cfg.kv_heads, cfg.head_dim
+    if cfg.sink_window:
+        return _project_sink(cfg, p, h, positions, window)
     eps = cfg.layernorm_eps
     q = L.rmsnorm_apply(p["q_norm"], L.linear_apply(p["q"], h).reshape(
         b, q_len, H, dh), eps)
@@ -152,19 +214,48 @@ def project(cfg, p, h, positions, window):
     return q, k, v, gate
 
 
+def _project_sink(cfg, p, h, positions, window):
+    """``project`` in the sink form: ``p`` holds the layer's q and o and its
+    kind's k and v. q and k are rotated over the first ``rotary_dim`` dims in
+    both kinds, at the kind's base; v [b, q, G, dv] comes scaled by
+    ``attn_value_scale`` (the same as scaling the attention's output, and
+    what the cache then holds); no gate."""
+    b, q_len, _ = h.shape
+    (G, dh), (_, dv) = cfg.kv_geometry(window).values()
+    q = L.linear_apply(p["q"], h).reshape(b, q_len, cfg.n_heads, dh)
+    k = L.linear_apply(p["k"], h).reshape(b, q_len, G, dh)
+    v = L.linear_apply(p["v"], h).reshape(b, q_len, G, dv)
+    v = v * jnp.asarray(cfg.attn_value_scale, v.dtype)
+    base = cfg.rope_base_window if window and cfg.rope_base_window \
+        else cfg.rope_base
+    cos, sin = L.rotary_embedding(positions, cfg.rotary_dim or dh, base)
+    q = L.apply_rotary(q, cos, sin, cfg.rotary_dim)
+    k = L.apply_rotary(k, cos, sin, cfg.rotary_dim)
+    return q, k, v, None
+
+
 def _out(p, attended, gate):
-    return L.linear_apply(p["o"], attended * gate)
+    return L.linear_apply(p["o"], attended if gate is None
+                          else attended * gate)
 
 
-def blockwise_attention(cfg, q, read, kv, q_start, window):
+def _sink(p, window):
+    """The layer's sinks [H] in float32 (a window layer of the sink form),
+    or None."""
+    return p["sink"] if window and "sink" in p else None
+
+
+def blockwise_attention(cfg, q, read, kv, q_start, window, sink=None):
     """Queries at positions ``q_start + [0, q)`` against context positions
     ``[0, kv)``, given by ``read(start, n) -> (k, v)`` [b, n, G, dh] each:
     one block of ``KV_BLOCK`` positions at a time under an online softmax
     (float32 statistics). The blocks visited run from the one that holds
     the first position any query sees (``q_start - window + 1`` in a window
-    layer) to the one that holds the last query. Returns [b, q, H * dh]."""
+    layer) to the one that holds the last query. ``sink`` [H] float32: a
+    logit a head that the running maximum and sum START from, and that has
+    no value. Returns [b, q, H * dv] (``dv`` the V head's width)."""
     b, q_len, H, dh = q.shape
-    G = cfg.kv_heads
+    (G, _), (_, dv) = cfg.kv_geometry(window).values()
     dtype = q.dtype
     prec = _prec(dtype)
     scale = _scale(cfg)
@@ -201,9 +292,13 @@ def blockwise_attention(cfg, q, read, kv, q_start, window):
 
     shape = (b, G, H // G, q_len)
     init = (jnp.full(shape, -jnp.inf, F32), jnp.zeros(shape, F32),
-            jnp.zeros(shape + (dh,), F32))
-    with jax.named_scope("window_chunk_attn" if window
-                         else "full_chunk_attn"):
+            jnp.zeros(shape + (dv,), F32))
+    if sink is not None:
+        init = (jnp.broadcast_to(sink.reshape(1, G, H // G, 1), shape),
+                jnp.ones(shape, F32), init[2])
+    with jax.named_scope("full_chunk_attn" if not window
+                         else "window_chunk_attn" if sink is None
+                         else "sink_window_chunk_attn"):
         if n_blocks == 1:
             _, l, acc = one_block(0, init)
         else:
@@ -211,7 +306,7 @@ def blockwise_attention(cfg, q, read, kv, q_start, window):
             lo = jnp.maximum(q_start - (w - 1), 0) // blk if w else 0
             _, l, acc = jax.lax.fori_loop(lo, hi, one_block, init)
         out = (acc / l[..., None]).astype(dtype)
-    return out.transpose(0, 3, 1, 2, 4).reshape(b, q_len, H * dh)
+    return out.transpose(0, 3, 1, 2, 4).reshape(b, q_len, H * dv)
 
 
 def ring_positions(pos, n_cols, block_size):
@@ -225,12 +320,14 @@ def ring_positions(pos, n_cols, block_size):
             + jnp.arange(block_size)[None, None, :]).reshape(pos.shape[0], -1)
 
 
-def view_attention(cfg, q, k_view, v_view, k_pos, pos, window):
+def view_attention(cfg, q, k_view, v_view, k_pos, pos, window, sink=None):
     """One query row a slot against its gathered blocks (the new row
-    written): q [S, H, dh]; k_view / v_view [S, T, G, dh]; k_pos [S, T] the
-    position each view row holds; pos [S]. Returns [S, H * dh]."""
+    written): q [S, H, dh]; k_view [S, T, G, dh], v_view [S, T, G, dv];
+    k_pos [S, T] the position each view row holds; pos [S]; ``sink`` [H]
+    float32: one more column of the softmax, dropped from its output.
+    Returns [S, H * dv]."""
     S, H, dh = q.shape
-    G = cfg.kv_heads
+    G = k_view.shape[2]
     dtype = q.dtype
     prec = _prec(dtype)
     qg = q.reshape(S, G, H // G, dh)
@@ -240,10 +337,15 @@ def view_attention(cfg, q, k_view, v_view, k_pos, pos, window):
     if window:
         allowed &= pos[:, None] - k_pos < cfg.sliding_window
     s = jnp.where(allowed[:, None, None, :], s, jnp.finfo(F32).min)
+    if sink is not None:
+        s = jnp.concatenate([s, jnp.broadcast_to(
+            sink.reshape(1, G, H // G, 1), s.shape[:3] + (1,))], axis=-1)
     probs = jax.nn.softmax(s, axis=-1).astype(dtype)
+    if sink is not None:
+        probs = probs[..., :-1]
     out = jnp.einsum("sgrt,stgd->sgrd", probs, v_view.astype(dtype),
                      precision=prec)
-    return out.reshape(S, H * dh)
+    return out.reshape(S, -1)
 
 
 # ---------------------------------------------------------------------------
@@ -264,6 +366,12 @@ def _block(cfg, p, x, carry, attn, layer, stacked=None):
     p = _cast_block(cfg, p)
     a, carry = attn(cfg, p["attn"], _norm_apply(cfg, p["ln_1"], x), carry,
                     layer)
+    if cfg.sink_window:
+        # two norms a layer: the branches join the stream as they are
+        x = x + a
+        y, routed = _ffn(cfg, p["mlp"], _norm_apply(cfg, p["ln_2"], x),
+                         stacked)
+        return x + y, carry, routed
     x = x + _norm_apply(cfg, p["ln_1_post"], a)
     y, routed = _ffn(cfg, p["mlp"], _norm_apply(cfg, p["ln_2"], x), stacked)
     return x + _norm_apply(cfg, p["ln_2_post"], y), carry, routed
@@ -287,6 +395,19 @@ def _run_layers(cfg, params, x, carry, attn):
                              if n not in experts})
     pick = lambda tree, e: jax.tree_util.tree_map(
         lambda a: jax.lax.dynamic_index_in_dim(a, e, 0, False), tree)
+    if cfg.sink_window:
+        # a layer's K and V projections (and sink) are its kind's, at its
+        # index in its group
+        attn_of_kind = attn
+
+        def attn(cfg_l, p_attn, h, carry, layer):
+            kv = pick(params["kv_window" if layer.window else "kv_full"],
+                      layer.group)
+            kv = {n: jax.tree_util.tree_map(
+                lambda w: w.astype(F32 if n == "sink"
+                                   else cfg_l.compute_dtype), a)
+                for n, a in kv.items()}
+            return attn_of_kind(cfg_l, {**p_attn, **kv}, h, carry, layer)
 
     def expert_block(x, carry, e, layer):
         # the expert stacks stay whole (dropfree_moe_apply reads a layer's
@@ -344,7 +465,8 @@ def backbone(model, params, input_ids, positions=None):
         read = lambda start, n: (
             jax.lax.dynamic_slice_in_dim(k, start, n, 1),
             jax.lax.dynamic_slice_in_dim(v, start, n, 1))
-        out = blockwise_attention(cfg_l, q, read, s, 0, layer.window)
+        out = blockwise_attention(cfg_l, q, read, s, 0, layer.window,
+                                  _sink(p, layer.window))
         return _out(p, out, gate), carry
 
     x, _, _ = _run_layers(cfg, params, _embed(cfg, params, input_ids), None,
@@ -377,12 +499,16 @@ def forward_with_cache(model, params, input_ids, cache, pos, kv_len,
             for name, new in (("k", k), ("v", v))}
 
         def read(start, n):
+            # the layer's own heads of the cache's (the sink form's cache
+            # is as wide as its wider kind)
             at = (layer.index, 0, start, 0, 0)
-            size = (1, b, n) + cache["k"].shape[3:]
-            return (jax.lax.dynamic_slice(cache["k"], at, size)[0],
-                    jax.lax.dynamic_slice(cache["v"], at, size)[0])
+            return (jax.lax.dynamic_slice(cache["k"], at,
+                                          (1, b, n) + k.shape[2:])[0],
+                    jax.lax.dynamic_slice(cache["v"], at,
+                                          (1, b, n) + v.shape[2:])[0])
 
-        out = blockwise_attention(cfg_l, q, read, kv_len, pos, layer.window)
+        out = blockwise_attention(cfg_l, q, read, kv_len, pos, layer.window,
+                                  _sink(p, layer.window))
         return _out(p, out, gate), cache
 
     x, cache, routed = _run_layers(cfg, params,
@@ -413,10 +539,12 @@ def forward_with_paged_cache(model, params, input_ids, pool, tables, pos,
                          "verify (several query rows a slot) is not "
                          "implemented")
     table, wtable = tables
-    G, dh = cfg.kv_heads, cfg.head_dim
 
     def attn(cfg_l, p, h, pool, layer):
         q, k, v, gate = project(cfg_l, p, h, pos[:, None], layer.window)
+        G, sink = k.shape[2], _sink(p, layer.window)
+        scope = "full_attn_decode" if not layer.window else \
+            "window_attn_decode" if sink is None else "sink_window_attn_decode"
         names = ("wk", "wv") if layer.window else ("k", "v")
         tab = wtable if layer.window else table
         group = {"k": pool[names[0]], "v": pool[names[1]]}
@@ -427,20 +555,18 @@ def forward_with_paged_cache(model, params, input_ids, pool, tables, pos,
         if kernel:
             from ..ops.pallas.paged_attention import paged_flash_decode
 
-            with jax.named_scope("window_attn_decode" if layer.window
-                                 else "full_attn_decode"):
+            with jax.named_scope(scope):
                 out = paged_flash_decode(
                     q[:, 0], rows["k"], rows["v"], group["k"], group["v"],
                     tab, pos, layer=layer.group, scale=cfg_l.attn_scale,
                     window=cfg_l.sliding_window if layer.window else 0,
                     ring=layer.window, interpret=cfg_l.attention_interpret,
-                    mesh=cfg_l.mesh).reshape(S, -1)
+                    mesh=cfg_l.mesh, sink=sink).reshape(S, -1)
             # the kernel folded the fresh rows in itself; they land after it
             group = write(group)
         else:
             group = write(group)
-            with jax.named_scope("window_attn_decode" if layer.window
-                                 else "full_attn_decode"):
+            with jax.named_scope(scope):
                 views = [_paged_view(group, n, layer.group, tab, G, q.dtype)
                          for n in ("k", "v")]
                 k_pos = ring_positions(pos, tab.shape[1], block_size) \
@@ -448,7 +574,7 @@ def forward_with_paged_cache(model, params, input_ids, pool, tables, pos,
                         jnp.arange(views[0].shape[1])[None, :],
                         views[0].shape[:2])
                 out = view_attention(cfg_l, q[:, 0], views[0], views[1],
-                                     k_pos, pos, layer.window)
+                                     k_pos, pos, layer.window, sink)
         pool = dict(pool, **{names[0]: group["k"], names[1]: group["v"]})
         return _out(p, out[:, None], gate), pool
 

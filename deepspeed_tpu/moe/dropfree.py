@@ -13,6 +13,12 @@ moe_routing="dropfree"``), beside the capacity form of ``sharded_moe.py``:
   a second one down; a group may be empty;
 - ``n_shared_experts`` always-on experts (one SwiGLU of their joint width)
   are added for every token.
+- a SHARE of a deployment's experts (``moe_local_experts`` at
+  ``moe_expert_offset``: what one chip of an expert-parallel layer holds):
+  the router keeps all ``n_experts`` outputs and ``top_k`` a token, the
+  weights are normalised over all the chosen, absent ones included, and the
+  layer computes the pairs on the experts it holds. The others get no row in
+  any group and add nothing; their exchange is another chip's and not here.
 
 The capacity form builds ``[b, s, E, C]`` one-hot tensors; drop-free with it
 means ``C = s`` and E / top_k times the products the tokens need. Here the
@@ -32,9 +38,12 @@ F32 = jnp.float32
 
 def dropfree_moe_init(rng, cfg):
     """``router`` (kernel, and the selection bias: zero, as the published
-    init has it), ``gate_up`` [E, d, 2f] (gate columns first), ``down``
-    [E, f, d], and ``shared``, one SwiGLU of width ``n_shared_experts * f``."""
+    init has it) over all E experts, ``gate_up`` [E_held, d, 2f] (gate
+    columns first) and ``down`` [E_held, f, d] of the experts held here
+    (``cfg.held_experts``: all, unless the layer is a share), and ``shared``,
+    one SwiGLU of width ``n_shared_experts * f``."""
     E, d, f = cfg.n_experts, cfg.d_model, cfg.expert_d_ff
+    held = cfg.held_experts[1]
     k_router, k_gu, k_down, k_shared = jax.random.split(rng, 4)
     std = cfg.initializer_range
     out_std = std / (2.0 * cfg.n_layers) ** 0.5
@@ -42,9 +51,9 @@ def dropfree_moe_init(rng, cfg):
         "router": {"kernel": Param(normal_init(k_router, (d, E), std),
                                    ("embed", "expert_logits")),
                    "bias": Param(jnp.zeros((E,), F32), ("expert_logits",))},
-        "gate_up": Param(normal_init(k_gu, (E, d, 2 * f), std),
+        "gate_up": Param(normal_init(k_gu, (held, d, 2 * f), std),
                          ("expert", "embed", "mlp")),
-        "down": Param(normal_init(k_down, (E, f, d), out_std),
+        "down": Param(normal_init(k_down, (held, f, d), out_std),
                       ("expert", "mlp", "embed")),
     }
     if cfg.n_shared_experts:
@@ -179,9 +188,18 @@ def dropfree_moe_apply(cfg, p, x, ids=None, stacked=None):
     of the stack first (what a scan over the weights does, the grouped
     product being no fusion's operand) copies every expert of the layer
     every step, 1.2 GB at kanana2's widths, 22 of a 60 ms decode step on
-    the chip (PR 29)."""
+    the chip (PR 29).
+
+    A layer that holds a SHARE of the experts (``cfg.held_experts``): the
+    pairs on absent experts sort behind every held one and belong to no
+    group, so the groups' sizes sum to the HELD pairs. The row count stays
+    the static T * k (a token's k choices may all be held, and nothing may be
+    dropped); the grouped kernel's grid is the visits of the groups that own
+    rows, so rows past the last group cost it nothing and are left unwritten:
+    they are masked here before they are weighed."""
     b, s, d = x.shape
-    E, k, f = cfg.n_experts, cfg.moe_top_k, cfg.expert_d_ff
+    k, f = cfg.moe_top_k, cfg.expert_d_ff
+    lo, E = cfg.held_experts
     flat = x.reshape(b * s, d)
     scores = scores_of(p["router"], flat)
     ids = choose(cfg, p["router"], scores) if ids is None \
@@ -191,6 +209,10 @@ def dropfree_moe_apply(cfg, p, x, ids=None, stacked=None):
     # order the T*k pairs by expert; a stable sort keeps token order inside
     # a group, so the result does not depend on how ties are broken
     pair_expert = ids.reshape(-1)
+    share = E != cfg.n_experts
+    if share:
+        held = (pair_expert >= lo) & (pair_expert < lo + E)
+        pair_expert = jnp.where(held, pair_expert - lo, E)
     order = jnp.argsort(pair_expert, stable=True)
     pair_token = order // k
     if stacked is None:
@@ -200,8 +222,12 @@ def dropfree_moe_apply(cfg, p, x, ids=None, stacked=None):
         gate_up = experts["gate_up"].reshape((-1,) + experts["gate_up"].shape[2:])
         down = experts["down"].reshape((-1,) + experts["down"].shape[2:])
         first = layer * E
-    group_sizes = jnp.zeros((gate_up.shape[0],), jnp.int32).at[
-        first + pair_expert].add(1)
+    group_sizes = jnp.zeros((gate_up.shape[0],), jnp.int32)
+    if share:
+        group_sizes = group_sizes.at[first + pair_expert].add(
+            held.astype(jnp.int32), mode="drop")
+    else:
+        group_sizes = group_sizes.at[first + pair_expert].add(1)
     rows = flat[pair_token]                                   # [T*k, d]
     how = (cfg.attention_interpret, cfg.mesh)
     h = grouped_product(rows, gate_up, group_sizes, *how)     # [T*k, 2f]
@@ -209,6 +235,8 @@ def dropfree_moe_apply(cfg, p, x, ids=None, stacked=None):
     out = grouped_product(h, down, group_sizes, *how)         # [T*k, d]
     w_sorted = weights.reshape(-1)[order]
     out = out.astype(F32) * w_sorted[:, None]
+    if share:
+        out = jnp.where(held[order][:, None], out, 0.0)
     # back to token order: pair i of token t sits at row inverse[t * k + i]
     inverse = jnp.zeros_like(order).at[order].set(
         jnp.arange(order.shape[0], dtype=order.dtype))

@@ -52,6 +52,14 @@ a RING (``ring``): block ``j`` of a slot at table column ``j % n_cols``, the
 window group of ``serving/kv_pool.py``, whose table is as wide as the band.
 With ``window`` 0 the program is the one it was before the band existed.
 
+K and V rows may differ in width (MiMo-V2: K heads of 192 beside V heads of
+128; the pool's ``k`` leaf is ``kvh * 192`` wide, its ``v`` leaf ``kvh *
+128``): the scores are taken over the K row, the accumulator and the output
+are as wide as the V row. A per-head SINK (``sink``: one float a query head,
+a learned logit with no key and no value) enters the running maximum and sum
+beside the current token's row and adds nothing to the output. With equal
+widths and no sink the program is the one it was before either existed.
+
 An int8 pool, several query rows a slot (speculative verify) and GPT-Neo's
 per-layer traced local flags take the view path (``fused_decode_supported``
 says why).
@@ -74,11 +82,18 @@ CHUNK_TOKENS = 256
 
 
 def fused_decode_supported(cfg, block_size, *, n_slots=8, blocks_per_slot=8,
-                           tp=1, kv_dtype="", window=0, ring=False):
+                           tp=1, kv_dtype="", window=0, ring=False,
+                           n_layers=None, n_blocks=None):
     """May the decode program attend through the kernel? ``(ok, reason)``.
 
     ``window`` / ``ring``: the band of a window layer whose kind is static
     (``models/window_moe.py``), probed at that group's table width.
+    ``n_layers`` / ``n_blocks``: the pool leaves the engine really holds
+    (one block group's layers and blocks), where they are not ``cfg.n_layers``
+    of ``n_slots * blocks_per_slot + 1`` blocks: a probe over leaves larger
+    than the device (two full layers of 32 slots x 32k positions at K rows of
+    768 are 11 GB a leaf when sized by the table) is refused for its size,
+    not for the kernel.
 
     Structural refusals first (what the kernel does not implement: GPT-Neo's
     traced per-layer local flags, ragged GQA groups, an int8 pool), then the
@@ -97,9 +112,12 @@ def fused_decode_supported(cfg, block_size, *, n_slots=8, blocks_per_slot=8,
         return False, ("local_attention_window > 0: a layer's kind is a "
                        "traced flag there, and the decode kernel's band is "
                        "static")
-    if cfg.n_heads % cfg.kv_heads:
+    # the K/V heads and row widths of the layers probed: a model of two
+    # kinds of layer may give each kind its own (``cfg.kv_geometry``)
+    (kv_heads, dh), (_, dv) = cfg.kv_geometry(bool(window)).values()
+    if cfg.n_heads % kv_heads:
         return False, (f"n_heads {cfg.n_heads} not a multiple of kv_heads "
-                       f"{cfg.kv_heads}")
+                       f"{kv_heads}")
     if kv_dtype:
         return False, (f"a {kv_dtype} pool: the decode kernel reads a pool "
                        "in the engine's dtype")
@@ -108,31 +126,35 @@ def fused_decode_supported(cfg, block_size, *, n_slots=8, blocks_per_slot=8,
     reason = unavailable_reason()
     if reason is not None:
         return False, reason
-    shard = tp if cfg.kv_heads % tp == 0 else 1
-    kvh, nh, dh = cfg.kv_heads // shard, cfg.n_heads // shard, cfg.head_dim
+    shard = tp if kv_heads % tp == 0 else 1
+    kvh, nh = kv_heads // shard, cfg.n_heads // shard
     sds = jax.ShapeDtypeStruct
-    pool = sds((cfg.n_layers, n_slots * blocks_per_slot + 1, block_size,
-                kvh * dh), cfg.compute_dtype)
-    row = sds((n_slots, kvh, dh), cfg.compute_dtype)
+    pool = lambda width: sds(
+        (n_layers or cfg.n_layers,
+         n_blocks or n_slots * blocks_per_slot + 1, block_size,
+         kvh * width), cfg.compute_dtype)
+    row = lambda width: sds((n_slots, kvh, width), cfg.compute_dtype)
     slopes = jnp.ones((nh,), jnp.float32) \
         if cfg.position_embedding == "alibi" else None
+    sink = jnp.zeros((nh,), jnp.float32) \
+        if cfg.sink_window and window else None
 
     def call(q, k_new, v_new, kc, vc, table, pos, layer):
         return paged_flash_decode(q, k_new, v_new, kc, vc, table, pos,
                                   layer=layer, scale=cfg.attn_scale,
                                   alibi_slopes=slopes, window=window,
-                                  ring=ring)
+                                  ring=ring, sink=sink)
 
     ok, reason = compiler_verdict(
-        call, sds((n_slots, nh, dh), cfg.compute_dtype), row, row, pool, pool,
-        sds((n_slots, blocks_per_slot), jnp.int32),
+        call, sds((n_slots, nh, dh), cfg.compute_dtype), row(dh), row(dv),
+        pool(dh), pool(dv), sds((n_slots, blocks_per_slot), jnp.int32),
         sds((n_slots,), jnp.int32), sds((), jnp.int32))
     return ok, reason and f"TPU compiler: {reason}"
 
 
 def _decode_kernel(layer_ref, table_ref, pos_ref, q_ref, kn_ref, vn_ref,
                    *rest, scale, block_size, chunk_blocks, head_dim, alibi,
-                   window, ring):
+                   window, ring, sink=False):
     """One slot: walk its live blocks chunk by chunk, fold each chunk into
     the running (m, l, acc), emit the slot's normalized output rows.
 
@@ -140,7 +162,10 @@ def _decode_kernel(layer_ref, table_ref, pos_ref, q_ref, kn_ref, vn_ref,
     ``kn_ref``/``vn_ref`` [1, 1, W] the current token's fresh row; ``k_hbm``/
     ``v_hbm`` [L, n_blocks, bs, W] stay in HBM and are copied by block;
     ``o_ref`` [1, hq, W]: row j holds, in kv group g's lanes, the output of
-    head ``g * hq + j``. ``kbuf``/``vbuf`` [2, chunk, W] are the two chunk
+    head ``g * hq + j``. (Where V rows are narrower than K rows, every V-side
+    W is ``kvh * head_dim`` with ``head_dim`` the V head's; ``sink``: a
+    ``[n_heads, 1]`` operand after the slopes.)
+    ``kbuf``/``vbuf`` [2, chunk, W] are the two chunk
     buffers, ``cur_ref`` the buffer the next chunk to consume lands in (it
     outlives a grid step: the next live slot's first chunk is already in
     flight when its step begins). ``window`` > 0: the valid pool window is
@@ -148,9 +173,11 @@ def _decode_kernel(layer_ref, table_ref, pos_ref, q_ref, kn_ref, vn_ref,
     ``ring``: block ``j`` sits at table column ``j % n_cols``."""
     if alibi:
         slopes_ref, rest = rest[0], rest[1:]
+    if sink:
+        sink_ref, rest = rest[0], rest[1:]
     k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, acc_scr, cur_ref = rest
     n_slots, n_cols = table_ref.shape
-    n_heads, width = q_ref.shape[1], q_ref.shape[2]
+    n_heads, width = q_ref.shape[1], o_ref.shape[2]
     hq = o_ref.shape[1]
     chunk = chunk_blocks * block_size
 
@@ -225,6 +252,14 @@ def _decode_kernel(layer_ref, table_ref, pos_ref, q_ref, kn_ref, vn_ref,
                      axis=-1, keepdims=True) * scale           # [nh, 1]
         acc_scr[...] = jnp.broadcast_to(vn_ref[0].astype(jnp.float32),
                                         acc_scr.shape)
+        l0 = None
+        if sink:
+            # the sink's logit joins the maximum and the sum beside the
+            # current token's row, which it scales, and has no value row
+            own = m0
+            m0 = jnp.maximum(own, sink_ref[...])
+            l0 = jnp.exp(own - m0) + jnp.exp(sink_ref[...] - m0)
+            acc_scr[...] = acc_scr[...] * jnp.exp(own - m0)
 
         def fold(c, carry):
             m_prev, l_prev = carry
@@ -266,8 +301,8 @@ def _decode_kernel(layer_ref, table_ref, pos_ref, q_ref, kn_ref, vn_ref,
             acc_scr[...] = acc_scr[...] * corr + pv
             return m_new, l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
 
-        _, l_fin = jax.lax.fori_loop(c0, n_chunks, fold,
-                                     (m0, jnp.ones_like(m0)))
+        _, l_fin = jax.lax.fori_loop(
+            c0, n_chunks, fold, (m0, jnp.ones_like(m0) if l0 is None else l0))
         cur_ref[0] = (buf0 + n_chunks - c0) % 2 if window \
             else (buf0 + n_chunks) % 2
 
@@ -285,17 +320,20 @@ def _decode_kernel(layer_ref, table_ref, pos_ref, q_ref, kn_ref, vn_ref,
 
 def paged_flash_decode(q, k_new, v_new, kc, vc, table, pos, *, layer=None,
                        scale=None, alibi_slopes=None, window=0, ring=False,
-                       chunk_tokens=CHUNK_TOKENS, interpret=False, mesh=None):
+                       sink=None, chunk_tokens=CHUNK_TOKENS, interpret=False,
+                       mesh=None):
     """Paged decode attention: softmax(q·K/√d)·V for ONE query row per slot,
     where K/V live in the paged pool and the kernel walks the block table
     itself, reading only the blocks below each slot's cursor.
 
     - ``q``: [S, n_heads, dh] (compute dtype), this step's query rows;
-    - ``k_new``/``v_new``: [S, kvh, dh], the freshly-projected k/v of the
-      current token (NOT yet in the pool; logically at position ``pos[s]``);
+    - ``k_new``/``v_new``: [S, kvh, dh] and [S, kvh, dv], the
+      freshly-projected k/v of the current token (NOT yet in the pool;
+      logically at position ``pos[s]``); ``dv`` may differ from ``dh``;
     - ``kc``/``vc``: the pool leaves WHOLE, [L, n_blocks, block_size,
-      kvh * dh], with ``layer`` (a traced scalar) the layer to read; or one
-      layer [n_blocks, block_size, kvh * dh] with ``layer`` None;
+      kvh * dh] and [.., kvh * dv], with ``layer`` (a traced scalar) the
+      layer to read; or one layer [n_blocks, block_size, ..] with ``layer``
+      None;
     - ``table``: [S, NB] int32 physical block ids; ``pos``: [S] int32
       cursors. Pool positions [0, pos) are attended; everything past the
       cursor (a ragged tail, unbound garbage-block columns) is never read;
@@ -304,6 +342,9 @@ def paged_flash_decode(q, k_new, v_new, kc, vc, table, pos, *, layer=None,
       0), pos)`` are attended, and nothing before them is read. ``ring``
       (static): block ``j`` of a slot sits at table column ``j % NB`` (a
       table as wide as the band; ``NB * block_size >= window + block_size``);
+    - ``sink`` ([n_heads] float, optional): a logit a query head that joins
+      the softmax's maximum and sum and has no value (MiMo-V2's window
+      layers);
     - ``chunk_tokens``: tokens consumed a step of the kernel's inner loop
       (rounded down to whole blocks);
     - ``interpret``: run under the Pallas interpreter (the models'
@@ -314,7 +355,7 @@ def paged_flash_decode(q, k_new, v_new, kc, vc, table, pos, *, layer=None,
       groups split over ``model`` when they divide it: GSPMD cannot
       partition a Mosaic call.
 
-    Returns [S, n_heads, dh] in ``q.dtype``.
+    Returns [S, n_heads, dv] in ``q.dtype``.
     """
     from . import shard_kernel
 
@@ -326,26 +367,32 @@ def paged_flash_decode(q, k_new, v_new, kc, vc, table, pos, *, layer=None,
     at = lambda dim: {dim: head_axes}
     operands = [q, k_new, v_new, kc, vc, table, pos, layer]
     dim_axes = [at(1), at(1), at(1), at(3), at(3), {}, {}, {}]
-    if alibi_slopes is not None:
-        operands.append(jnp.asarray(alibi_slopes, jnp.float32))
-        dim_axes.append(at(0))
+    for per_head in (alibi_slopes, sink):
+        if per_head is not None:
+            operands.append(jnp.asarray(per_head, jnp.float32))
+            dim_axes.append(at(0))
 
-    def per_shard(q, k_new, v_new, kc, vc, table, pos, layer, slopes=None):
+    def per_shard(q, k_new, v_new, kc, vc, table, pos, layer, *per_head):
+        per_head = list(per_head)
+        slopes = per_head.pop(0) if alibi_slopes is not None else None
         return _paged_flash_decode(q, k_new, v_new, kc, vc, table, pos,
                                    layer, slopes, scale, chunk_tokens,
-                                   interpret, window, ring)
+                                   interpret, window, ring,
+                                   per_head[0] if per_head else None)
 
     return shard_kernel(per_shard, mesh, operands, dim_axes, [at(1)])
 
 
 def _paged_flash_decode(q, k_new, v_new, kc, vc, table, pos, layer, slopes,
-                        scale, chunk_tokens, interpret, window=0, ring=False):
+                        scale, chunk_tokens, interpret, window=0, ring=False,
+                        sink=None):
     """One device's share of ``paged_flash_decode`` (local head counts)."""
     s_dim, n_heads, dh = q.shape
-    kvh = k_new.shape[1]
-    block_size, width = kc.shape[2], kc.shape[3]
+    kvh, dv = k_new.shape[1], v_new.shape[2]
+    block_size, width, width_v = kc.shape[2], kc.shape[3], vc.shape[3]
     hq = n_heads // kvh
-    assert width == kvh * dh, (kc.shape, k_new.shape)
+    assert width == kvh * dh and width_v == kvh * dv, \
+        (kc.shape, k_new.shape, vc.shape, v_new.shape)
     scale = scale if scale is not None else 1.0 / math.sqrt(dh)
     alibi = slopes is not None
     chunk_blocks = max(1, min(chunk_tokens // block_size, table.shape[1]))
@@ -354,40 +401,41 @@ def _paged_flash_decode(q, k_new, v_new, kc, vc, table, pos, layer, slopes,
     own = jnp.arange(n_heads)[:, None] // hq == jnp.arange(kvh)[None, :]
     q_bd = jnp.where(own[None, :, :, None], q[:, :, None, :], 0) \
         .reshape(s_dim, n_heads, width).astype(kc.dtype)
-    row = lambda a: a.reshape(s_dim, 1, width)
+    row = lambda a: a.reshape(s_dim, 1, -1)
 
     per_slot = lambda *shape: pl.BlockSpec(
         (1,) + shape, lambda s, *_: (s,) + (0,) * len(shape))
     in_specs = [per_slot(n_heads, width), per_slot(1, width),
-                per_slot(1, width)]
+                per_slot(1, width_v)]
     operands = [q_bd, row(k_new), row(v_new)]
-    if alibi:
-        in_specs.append(pl.BlockSpec((n_heads, 1), lambda s, *_: (0, 0)))
-        operands.append(slopes.reshape(n_heads, 1))
+    for per_head in (slopes, sink):
+        if per_head is not None:
+            in_specs.append(pl.BlockSpec((n_heads, 1), lambda s, *_: (0, 0)))
+            operands.append(per_head.reshape(n_heads, 1))
     in_specs += [pl.BlockSpec(memory_space=pl.ANY)] * 2
     operands += [kc, vc]
 
     chunk = chunk_blocks * block_size
     kernel = functools.partial(
         _decode_kernel, scale=scale, block_size=block_size,
-        chunk_blocks=chunk_blocks, head_dim=dh, alibi=alibi,
-        window=int(window), ring=bool(ring))
+        chunk_blocks=chunk_blocks, head_dim=dv, alibi=alibi,
+        window=int(window), ring=bool(ring), sink=sink is not None)
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(s_dim,),
             in_specs=in_specs,
-            out_specs=per_slot(hq, width),
+            out_specs=per_slot(hq, width_v),
             scratch_shapes=[
                 pltpu.VMEM((2, chunk, width), kc.dtype),
-                pltpu.VMEM((2, chunk, width), vc.dtype),
+                pltpu.VMEM((2, chunk, width_v), vc.dtype),
                 pltpu.SemaphoreType.DMA((2, 2)),
-                pltpu.VMEM((n_heads, width), jnp.float32),
+                pltpu.VMEM((n_heads, width_v), jnp.float32),
                 pltpu.SMEM((1,), jnp.int32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((s_dim, hq, width), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((s_dim, hq, width_v), jnp.float32),
         # slots in order: a step starts the next live slot's first copies
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
@@ -395,5 +443,5 @@ def _paged_flash_decode(q, k_new, v_new, kc, vc, table, pos, layer, slopes,
         name="paged_flash_decode",
     )(layer, table, pos, *operands)
     # [S, hq, kvh, dh] -> head h = g * hq + j
-    return out.reshape(s_dim, hq, kvh, dh).transpose(0, 2, 1, 3) \
-        .reshape(s_dim, n_heads, dh).astype(q.dtype)
+    return out.reshape(s_dim, hq, kvh, dv).transpose(0, 2, 1, 3) \
+        .reshape(s_dim, n_heads, dv).astype(q.dtype)

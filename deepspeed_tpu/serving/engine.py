@@ -108,6 +108,11 @@ class ServingEngine:
         # window and full attention layers mixed (models/window_moe.py):
         # two block groups, the window layers' a ring a slot
         self._window = bool(getattr(mcfg, "window_layers", False))
+        if self._window:
+            from ..models.window_moe import layer_groups
+
+            # (window, full): the model's layers of each kind
+            self._layer_groups = layer_groups(mcfg)
         if self._latent or self._window:
             self._refuse_for_cache_family(engine)
         # the KV store: block allocator + prefix cache on the host, a block
@@ -127,6 +132,8 @@ class ServingEngine:
         # always names the path that runs; a speculative verify step takes
         # the view whatever decode takes.
         self._decode_dispatches = {"kernel": 0, "view": 0}
+        # of those, the decodes dispatched behind the step before theirs
+        self._decode_ahead_dispatches = 0
         self.attn_backend, self.attn_reason = self._choose_attention(engine)
         if self.cfg.scrub_freed_slots:
             # zero each physical block as its last reference drops
@@ -146,6 +153,9 @@ class ServingEngine:
         # preempts the newest request back to the queue
         self.growth = bool(self.cfg.kv_pool.on_demand_growth)
         self._prefill_jobs = collections.deque()
+        # a decode dispatched behind the last step's, for the NEXT step to
+        # read (``_dispatch_decode_ahead``): its outputs, or None
+        self._decode_ahead = None
         self._decode_steps_since_chunk = 1 << 30  # first chunk never waits
         self._admit_seq = 0    # admission order (preemption victim = newest)
         # speculative decoding (serving/speculative.py): a drafter proposes
@@ -203,6 +213,9 @@ class ServingEngine:
         # snapshot()["speculative"], the PR 4 trace==metrics discipline)
         self.metrics.speculative_armed = self.spec
         self.metrics.moe_armed = self._routing
+        if self._routing and mcfg.held_experts[1] != mcfg.n_experts:
+            # the expert layers hold a share of their experts
+            self.metrics.moe_held = mcfg.held_experts
         # expert loads of this step's prefill chunk, still on the device:
         # read after the step's token read-back, never before it
         self._pending_loads = []
@@ -270,11 +283,6 @@ class ServingEngine:
                 "table", "pos", "tok", "active", "remaining", "rng", "temp",
                 "top_k", "top_p", "eos")
             + (("wtable",) if self._window else ())}
-        if self._window:
-            from ..models.window_moe import layer_groups
-
-            # (window, full): the model's layers of each kind
-            self._layer_groups = layer_groups(mcfg)
         self._state = self._init_state()
         # the KV window is not n_slots x max_len: report the REAL capacity
         # (blocks and tokens) so operators see the effective slot multiplier
@@ -305,16 +313,26 @@ class ServingEngine:
         mcfg = engine.module.config
         probe = dict(n_slots=self.n_slots, tp=max(engine.mp_world_size, 1),
                      kv_dtype=self.cfg.kv_pool.kv_dtype)
+        groups = {}
+        if self._window:
+            # each group probed at the leaves it really holds
+            win, full = self._layer_groups
+            groups = {False: dict(n_layers=len(full),
+                                  n_blocks=self.pool_mgr.n_blocks),
+                      True: dict(n_layers=len(win),
+                                 n_blocks=self.window_mgr.n_blocks)}
         ok, reason = fused_decode_supported(
             mcfg, self.pool_mgr.block_size,
-            blocks_per_slot=self.pool_mgr.blocks_per_slot, **probe)
+            blocks_per_slot=self.pool_mgr.blocks_per_slot, **probe,
+            **groups.get(False, {}))
         if ok and self._window:
             # the window layers' calls have their own program: the band,
             # over a table as wide as the ring
             ok, reason = fused_decode_supported(
                 mcfg, self.pool_mgr.block_size,
                 blocks_per_slot=self.window_mgr.ring,
-                window=mcfg.sliding_window, ring=True, **probe)
+                window=mcfg.sliding_window, ring=True, **probe,
+                **groups[True])
         return ("kernel", "") if ok else ("view", reason)
 
     def _refuse_for_cache_family(self, engine):
@@ -415,6 +433,7 @@ class ServingEngine:
         st["attention_backend"] = self.attn_backend
         st["attention_reason"] = self.attn_reason
         st["decode_dispatches"] = dict(self._decode_dispatches)
+        st["decode_ahead_dispatches"] = self._decode_ahead_dispatches
         if self._window:
             # by group: the blocks each holds, those of the live requests
             # (the slots' bindings), and the K/V rows the decode steps read
@@ -441,10 +460,12 @@ class ServingEngine:
             wmgr = self.window_mgr
             cache = init_paged_cache(cfg, mgr.n_blocks, mgr.block_size,
                                      self.engine.dtype,
-                                     n_layers=len(self._layer_groups[1]))
+                                     n_layers=len(self._layer_groups[1]),
+                                     geometry=cfg.group_pool_geometry(False))
             ring = init_paged_cache(cfg, wmgr.n_blocks, mgr.block_size,
                                     self.engine.dtype,
-                                    n_layers=len(self._layer_groups[0]))
+                                    n_layers=len(self._layer_groups[0]),
+                                    geometry=cfg.group_pool_geometry(True))
             cache.update(wk=ring["k"], wv=ring["v"],
                          wtable=jnp.full((s, wmgr.ring), GARBAGE_BLOCK,
                                          jnp.int32))
@@ -749,13 +770,25 @@ class ServingEngine:
             # layers' rows the blocks that hold the band go to the slot's
             # ring (the blocks before them are never read again)
             win, full = (jnp.asarray(g) for g in self._layer_groups)
+            mcfg = model.config
+
+            def of_kind(dense, layers, window):
+                # the kind's layers and, where the dense cache is as wide
+                # as the other kind, the kind's own K/V heads of it
+                heads = mcfg.kv_geometry(window)["k"][0]
+                rows = dense[layers]
+                return rows if heads == rows.shape[3] \
+                    else rows[:, :, :, :heads]
+
             out = insert_block_kv(
                 {"k": state["k"], "v": state["v"]},
-                {"k": dense_k[full], "v": dense_v[full]}, block_ids,
+                {"k": of_kind(dense_k, full, False),
+                 "v": of_kind(dense_v, full, False)}, block_ids,
                 src_blocks, bs, **writer)
             ring = insert_block_kv(
                 {"k": state["wk"], "v": state["wv"]},
-                {"k": dense_k[win], "v": dense_v[win]}, ring_ids, ring_srcs,
+                {"k": of_kind(dense_k, win, True),
+                 "v": of_kind(dense_v, win, True)}, ring_ids, ring_srcs,
                 bs, **writer)
             return dict(state, **out, wk=ring["k"], wv=ring["v"])
 
@@ -1014,6 +1047,8 @@ class ServingEngine:
         (on-demand growth), then run one decode step over the pool. Returns
         the list of TokenEvents produced."""
         events = []
+        # a decode the last step dispatched behind its own: this step's
+        ahead, self._decode_ahead = self._decode_ahead, None
         can_admit = self._make_can_admit()
         admitted = self._maybe_priority_preempt(can_admit)
         if admitted is None:
@@ -1032,7 +1067,7 @@ class ServingEngine:
             if drafts:
                 self._verify_once(events, drafts)
             else:
-                self._decode_once(events)
+                self._decode_once(events, ahead)
             self._decode_steps_since_chunk += 1
             if self._slots and self.cfg.migration.enabled \
                     and self.cfg.migration.snapshot_interval_tokens > 0:
@@ -1453,6 +1488,47 @@ class ServingEngine:
         if job.ahead is None and self._decode_steps_since_chunk + 1 >= \
                 self.cfg.chunked_prefill.decode_steps_between_chunks:
             job.ahead = self._dispatch_chunk(job)
+
+    def _dispatch_decode_ahead(self):
+        """Chunked prefill with no chunk to send, called with this step's
+        decode dispatched and its tokens not yet read: dispatch the NEXT
+        step's decode behind it, where nothing can come between the two.
+        The decode program carries everything a step needs in its state
+        (token, cursor, remaining count, sampler keys), so a run of
+        decode-only steps needs the host only to read what they made; without
+        this the device waits for the host at every such step's edge (2.2 of
+        17.9 ms in the mimo cell, and the step's length is the host's to
+        disturb: PERF.md, PR 37). It decodes for the slots bound NOW: a
+        request inserted in the next step joins the decode after it, and the
+        insert itself lands on the state the ahead decode leaves (a slot's
+        fields are set whole; its blocks were free). What may not happen
+        between the two is a slot LEAVING or the state being read: so only
+        where this step frees no slot (no request ends by length in it, none
+        can end on a token the host has not seen), no prefill job and no
+        queued request waits (then the chunk goes ahead instead), and none
+        of the features that edit or read a running slot's state between
+        steps exists: growth, verify, the health shed, tenants' preemption
+        and the degraded ladder are off, and the cache family is one for
+        which snapshots, evacuation and the hand-off are refused by name
+        (``_refuse_for_cache_family``; the dense path, whose rng and cursors
+        a ``capture_snapshot`` may read between any two steps, waits for
+        ROADMAP S1). A virtual clock has no device to feed and keeps the
+        schedule it always had."""
+        if not self.chunked or self._prefill_jobs or self.queue.depth \
+                or not isinstance(self.clock, WallClock) or self.growth \
+                or self.spec or self._health_shed \
+                or self.degraded_ctl is not None \
+                or self.cfg.tenants.enabled \
+                or not (self._latent or self._window):
+            return
+        if all(r.eos_token_id is None and not r.stop_token_ids
+               and len(r.tokens) + 1 < r.max_new_tokens
+               for r in self._slots.values()):
+            out, self._state = self._decode_jit(self.engine.params,
+                                                self._state)
+            self._decode_ahead = (out, dict(self._slots))
+            self._decode_ahead_dispatches += 1
+            self.metrics.decode_programs += 1
 
     def _job_cache(self, job):
         """The dense b=1 cache a job's chunks carry: seeded from the shared
@@ -2117,26 +2193,38 @@ class ServingEngine:
                 self._scrub_block(mgr.slot_block(slot, j))
                 mgr.scrubbed_blocks += 1
 
-    def _decode_once(self, events):
+    def _decode_once(self, events, ahead=None):
+        """One decode step over the pool. ``ahead``: ``(outputs, {slot:
+        request})`` of this step's decode where the last step dispatched it
+        already: it decoded for the slots bound then, so a slot bound since
+        (this step's insert) gets its next token from the next decode."""
         with self.tracer.span("decode_step", cat="serving",
                               active=len(self._slots)):
-            out, self._state = self._decode_jit(self.engine.params,
-                                                self._state)
+            if ahead is None:
+                out, self._state = self._decode_jit(self.engine.params,
+                                                    self._state)
+                self.metrics.decode_programs += 1
+                live = dict(self._slots)
+            else:
+                out, bound = ahead
+                live = {s: r for s, r in bound.items()
+                        if self._slots.get(s) is r}
             self.clock.advance(self.cfg.virtual_decode_step_cost)
         self.metrics.record_decode_dispatch()
         self._decode_dispatches[self.attn_backend] += 1
         self._dispatch_chunk_ahead()
+        self._dispatch_decode_ahead()
         # one read-back for all the step hands out (a routing model: its
         # expert choices too)
         toks, done_now, nonfinite, sampled, *routed = jax.device_get(out)
         self.metrics.record_sampler_step(bool(sampled))
         if routed:
-            self._book_decode_routing(routed[0])
+            self._book_decode_routing(routed[0], live)
         now = self.clock.now()
         self.metrics.record_health_step(
-            sum(1 for s in self._slots if nonfinite[s] > 0))
-        for slot in sorted(self._slots):
-            req = self._slots[slot]
+            sum(1 for s in live if nonfinite[s] > 0))
+        for slot in sorted(live):
+            req = live[slot]
             t = int(toks[slot])
             if self._health_shed and nonfinite[slot] > 0:
                 self._shed_unhealthy(req, events, now, int(nonfinite[slot]))
@@ -2161,11 +2249,12 @@ class ServingEngine:
             events.append(TokenEvent(req.request_id, t, len(req.tokens) - 1,
                                      True, reason, now))
 
-    def _book_decode_routing(self, routed):
+    def _book_decode_routing(self, routed, live):
         """``routed`` [L_moe, S, 2k]: what this decode step's expert layers
         chose, every slot's (a freed slot routes its dead token too: the
         pairs were computed and their experts read). Counters, and the
-        record of the requests that asked for theirs."""
+        record of the requests (``live``: ``{slot: request}`` the step
+        decoded for) that asked for theirs."""
         cfg = self.engine.module.config
         k = cfg.moe_top_k
         counts = np.stack([np.bincount(layer[:, :k].reshape(-1),
@@ -2173,7 +2262,7 @@ class ServingEngine:
                            for layer in routed])
         self.metrics.record_moe_loads(counts, decode=True)
         self._book_product_path(routed)
-        for slot, req in self._slots.items():
+        for slot, req in live.items():
             # this step fed the slot's last token, at the position before
             # the one the new token takes
             live = req.prompt_len + len(req.tokens)
@@ -2347,6 +2436,7 @@ class ServingEngine:
         self._prefill_programs = OrderedDict()
         self._suffix_programs = OrderedDict()
         self._prefill_jobs = collections.deque()
+        self._decode_ahead = None
         self._slots = {}
         self._free_slots = list(range(self.n_slots - 1, -1, -1))
         self.tracer.flush()

@@ -175,7 +175,16 @@ class ServingMetrics:
         # Armed by the engine for a model that routes (snapshot()["moe"]).
         self.moe_armed = False
         self.moe_dispatches = 0
+        # decode programs DISPATCHED: a step's own, or the next step's sent
+        # behind it (``ServingEngine._dispatch_decode_ahead``); a step books
+        # ``decode_dispatches`` where it reads one
+        self.decode_programs = 0
         self.moe_pairs = 0            # token-expert pairs computed
+        # an expert layer that holds a SHARE of its experts (``(first,
+        # count)``, set by the engine): the pairs the router chose, of which
+        # ``moe_pairs`` are those on experts held here
+        self.moe_held = None
+        self.moe_pairs_chosen = 0
         self.moe_experts_hit = 0      # distinct experts with work, summed
         self.moe_decode_dispatches = 0   # the two above, decode steps alone
         self.moe_decode_pairs = 0
@@ -382,7 +391,12 @@ class ServingMetrics:
     def record_moe_loads(self, counts, decode=False):
         """``counts`` [layers, E]: pairs per expert of each expert layer in
         one program run (made on the device beside the ids; read back with
-        the step's tokens)."""
+        the step's tokens). Where the layer holds a share of the experts,
+        everything but ``moe_pairs_chosen`` counts the held ones."""
+        self.moe_pairs_chosen += int(counts.sum())
+        if self.moe_held is not None:
+            lo, n = self.moe_held
+            counts = counts[..., lo:lo + n]
         hit = counts > 0
         self.moe_dispatches += int(counts.shape[0])
         self.moe_pairs += int(counts.sum())
@@ -411,6 +425,8 @@ class ServingMetrics:
         return {
             "dispatches": self.moe_dispatches,
             "moe_pairs": self.moe_pairs,
+            "moe_pairs_chosen": self.moe_pairs_chosen,
+            "moe_pairs_held": self.moe_pairs,
             "moe_experts_hit": self.moe_experts_hit,
             "decode_dispatches": self.moe_decode_dispatches,
             "decode_pairs": self.moe_decode_pairs,

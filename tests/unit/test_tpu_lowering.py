@@ -116,16 +116,18 @@ def test_pallas_ce_lowers():
 
 
 def _paged_decode_operands(nh, kvh, dh, sharding=None, layers=24, slots=32,
-                           n_blocks=1537, cols=128, bs=16):
+                           n_blocks=1537, cols=128, bs=16, dv=None):
     """The decode kernel's operands at the OPT-1.3B serve cell's geometry
     unless told otherwise: 32 slots of 128 table columns over 1537 blocks
-    of 16 tokens, the pool leaves whole."""
+    of 16 tokens, the pool leaves whole. ``dv``: V heads of another width
+    than K heads."""
     sds = lambda shape, dt, sh=sharding: SDS(shape, dt, sharding=sh)
-    pool = sds((layers, n_blocks, bs, kvh * dh), jnp.bfloat16)
-    row = sds((slots, kvh, dh), jnp.bfloat16)
-    return (sds((slots, nh, dh), jnp.bfloat16), row, row, pool, pool,
-            SDS((slots, cols), jnp.int32), SDS((slots,), jnp.int32),
-            SDS((), jnp.int32))
+    pool = lambda w: sds((layers, n_blocks, bs, kvh * w), jnp.bfloat16)
+    row = lambda w: sds((slots, kvh, w), jnp.bfloat16)
+    dv = dv or dh
+    return (sds((slots, nh, dh), jnp.bfloat16), row(dh), row(dv), pool(dh),
+            pool(dv), SDS((slots, cols), jnp.int32),
+            SDS((slots,), jnp.int32), SDS((), jnp.int32))
 
 
 @pytest.mark.parametrize("nh,kvh,dh,alibi", [
@@ -391,6 +393,28 @@ def test_latent_expert_serving_programs_lower_with_the_grouped_kernel_alone():
         text = lower_for_tpu(fn, *args)
         assert n_mosaic(text) == 2 and "ragged_dot" not in text
         assert text.count("grouped_matmul") == 2
+
+
+@pytest.mark.parametrize("kvh,window,geometry", [
+    # full layers: K rows of 768 beside V rows of 512, 4097 blocks of 128
+    (4, 0, dict(layers=2, n_blocks=4097, cols=256, bs=128)),
+    # window layers: K 1536 / V 1024, a sink a head, band 128, ring of 2
+    (8, 128, dict(layers=5, n_blocks=65, cols=2, bs=128)),
+], ids=["full-K768-V512", "window-K1536-V1024-sink-ring2"])
+def test_paged_decode_lowers_at_mimo_v2_flashs_two_geometries(kvh, window,
+                                                              geometry):
+    """The kernel at the ``mimo-v2-flash-serve`` cell's two pool groups: 64
+    query heads of 192 over K heads of 192 and V heads of 128."""
+    from deepspeed_tpu.ops.pallas.paged_attention import paged_flash_decode
+
+    sink = jnp.zeros((64,), jnp.float32) if window else None
+
+    def call(q, kn, vn, kc, vc, table, pos, layer):
+        return paged_flash_decode(q, kn, vn, kc, vc, table, pos, layer=layer,
+                                  window=window, ring=bool(window), sink=sink)
+
+    assert n_mosaic(lower_for_tpu(call, *_paged_decode_operands(
+        64, kvh, 192, dv=128, **geometry))) == 1
 
 
 def _trinity_cell_programs(sharding=None):

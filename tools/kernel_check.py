@@ -150,7 +150,9 @@ def pallas_ce():
             run("pallas"), run("xla"), (x, emb, labels), 2e-2)
 
 
-def _paged_case(nh, kvh, dh, alibi=False):
+def _paged_case(nh, kvh, dh, alibi=False, dv=None):
+    """``dv``: V heads narrower than K heads (MiMo-V2's full layers: K rows
+    of ``kvh * dh`` beside V rows of ``kvh * dv``)."""
     import jax
     import jax.numpy as jnp
 
@@ -161,14 +163,15 @@ def _paged_case(nh, kvh, dh, alibi=False):
     S, NB, bs, n_layers = 8, 64, 16, 2         # 1024-token window per slot
     n_blocks = S * NB + 1
     dt = jnp.bfloat16
+    dv = dv or dh
     # the pool as the engine keeps it: a token's kv heads merged, leaves whole
     kc = jnp.asarray(rng.randn(n_layers, n_blocks, bs, kvh * dh), dt)
-    vc = jnp.asarray(rng.randn(n_layers, n_blocks, bs, kvh * dh), dt)
+    vc = jnp.asarray(rng.randn(n_layers, n_blocks, bs, kvh * dv), dt)
     table = jnp.asarray(1 + rng.permutation(S * NB).reshape(S, NB), jnp.int32)
     pos = jnp.asarray([1, 15, 16, 17, 500, 777, 1000, 1023], jnp.int32)
     q = jnp.asarray(rng.randn(S, nh, dh) * 0.3, dt)
     k_new = jnp.asarray(rng.randn(S, kvh, dh), dt)
-    v_new = jnp.asarray(rng.randn(S, kvh, dh), dt)
+    v_new = jnp.asarray(rng.randn(S, kvh, dv), dt)
     slopes = L.alibi_slopes(nh) if alibi else None
     layer = jnp.asarray(1, jnp.int32)
 
@@ -181,7 +184,7 @@ def _paged_case(nh, kvh, dh, alibi=False):
         # cursor, exact fp32 softmax over [0, pos]
         f32 = jnp.float32
         view = lambda c: c[layer].astype(f32)[table].reshape(
-            S, NB * bs, kvh, dh)
+            S, NB * bs, kvh, -1)
         put = jax.vmap(lambda c, r, p: jax.lax.dynamic_update_slice(
             c, r[None], (p, 0, 0)))
         kk = put(view(kc), k_new.astype(f32), pos)
@@ -199,16 +202,19 @@ def _paged_case(nh, kvh, dh, alibi=False):
 
     geom = (f"8 slots x 1024-token window, block 16, {nh}/{kvh} heads x "
             f"{dh}, bf16 pool [2, {n_blocks}, 16, {kvh * dh}], layer 1"
-            + (", alibi" if alibi else ""))
+            + (", alibi" if alibi else "")
+            + (f", V rows of {kvh * dv}" if dv != dh else ""))
     return geom, kernel, ref, (q, k_new, v_new, kc, vc, table, pos, layer), \
         3e-2
 
 
-def _paged_band_case(nh, kvh, dh, window, bs):
+def _paged_band_case(nh, kvh, dh, window, bs, dv=None, sink=False):
     """A window layer's calls (``models/window_moe.py``): the band of the
     last ``window`` positions over a RING of blocks a slot, block ``j`` at
     table column ``j % ring``; cursors before the band fills, at its edge
-    and several laps of the ring on."""
+    and several laps of the ring on. ``dv``: V heads narrower than K heads;
+    ``sink``: a logit a head in the softmax's sum (MiMo-V2's window
+    layers)."""
     import jax
     import jax.numpy as jnp
 
@@ -219,8 +225,11 @@ def _paged_band_case(nh, kvh, dh, window, bs):
     ring = -(-window // bs) + 1
     n_blocks = S * ring + 1
     dt = jnp.bfloat16
+    dv = dv or dh
     kc = jnp.asarray(rng.randn(n_layers, n_blocks, bs, kvh * dh), dt)
-    vc = jnp.asarray(rng.randn(n_layers, n_blocks, bs, kvh * dh), dt)
+    vc = jnp.asarray(rng.randn(n_layers, n_blocks, bs, kvh * dv), dt)
+    sinks = jnp.asarray(1.0 + 0.5 * rng.randn(nh), jnp.float32) \
+        if sink else None
     table = jnp.asarray(1 + rng.permutation(S * ring).reshape(S, ring),
                         jnp.int32)
     pos = jnp.asarray([1, bs - 1, window - 1, window, window + 1,
@@ -228,12 +237,13 @@ def _paged_band_case(nh, kvh, dh, window, bs):
                        15 * window - 1], jnp.int32)
     q = jnp.asarray(rng.randn(S, nh, dh) * 0.3, dt)
     k_new = jnp.asarray(rng.randn(S, kvh, dh), dt)
-    v_new = jnp.asarray(rng.randn(S, kvh, dh), dt)
+    v_new = jnp.asarray(rng.randn(S, kvh, dv), dt)
     layer = jnp.asarray(1, jnp.int32)
 
     def kernel(q, k_new, v_new, kc, vc, table, pos, layer):
         return paged_flash_decode(q, k_new, v_new, kc, vc, table, pos,
-                                  layer=layer, window=window, ring=True)
+                                  layer=layer, window=window, ring=True,
+                                  sink=sinks)
 
     def ref(q, k_new, v_new, kc, vc, table, pos, layer):
         # the slot's ring through the table; row (c, o) holds position
@@ -241,7 +251,7 @@ def _paged_band_case(nh, kvh, dh, window, bs):
         # the fresh row beside it; exact fp32 softmax over the band
         f32 = jnp.float32
         view = lambda c: c[layer].astype(f32)[table].reshape(
-            S, ring * bs, kvh, dh)
+            S, ring * bs, kvh, -1)
         cur = pos // bs
         block = cur[:, None] - (cur[:, None] - jnp.arange(ring)[None]) % ring
         k_pos = (block[:, :, None] * bs + jnp.arange(bs)).reshape(S, -1)
@@ -253,14 +263,22 @@ def _paged_band_case(nh, kvh, dh, window, bs):
         sc = jnp.where(seen[:, None, None], sc, -jnp.inf)
         own = jnp.einsum("sgrd,sgd->sgr", qg, k_new.astype(f32)) \
             / np.sqrt(dh)
-        p = jax.nn.softmax(jnp.concatenate([sc, own[..., None]], -1), -1)
-        out = jnp.einsum("sgrt,stgd->sgrd", p[..., :-1], view(vc)) \
-            + p[..., -1:] * v_new.astype(f32)[:, :, None]
-        return out.reshape(S, nh, dh)
+        cols = [sc, own[..., None]]
+        if sink:
+            # one more column a head, with no value row
+            cols.append(jnp.broadcast_to(sinks.reshape(1, kvh, g, 1),
+                                         own.shape + (1,)))
+        p = jax.nn.softmax(jnp.concatenate(cols, -1), -1)
+        n = ring * bs
+        out = jnp.einsum("sgrt,stgd->sgrd", p[..., :n], view(vc)) \
+            + p[..., n:n + 1] * v_new.astype(f32)[:, :, None]
+        return out.reshape(S, nh, dv)
 
     geom = (f"8 slots, band {window} over a ring of {ring} blocks of {bs}, "
             f"{nh}/{kvh} heads x {dh}, bf16 pool [2, {n_blocks}, {bs}, "
-            f"{kvh * dh}], layer 1, cursors to {15 * window - 1}")
+            f"{kvh * dh}], layer 1, cursors to {15 * window - 1}"
+            + (f", V rows of {kvh * dv}" if dv != dh else "")
+            + (", a sink a head" if sink else ""))
     return geom, kernel, ref, (q, k_new, v_new, kc, vc, table, pos, layer), \
         3e-2
 
@@ -414,6 +432,10 @@ CASES = {
     "paged decode (GQA 32/8x128)": lambda: _paged_case(32, 8, 128),
     "paged decode (GQA 32/4x128, band 2048 over a ring)":
         lambda: _paged_band_case(32, 4, 128, 2048, 128),
+    "paged decode (GQA 64/4, K 192 over V 128)":
+        lambda: _paged_case(64, 4, 192, dv=128),
+    "paged decode (GQA 64/8, K 192 over V 128, band 128 + sink, ring 2)":
+        lambda: _paged_band_case(64, 8, 192, 128, 128, dv=128, sink=True),
     "quantized matmul int8": lambda: _qmm_case(8),
     "quantized matmul int4": lambda: _qmm_case(4),
     "kv block write (bf16 pool)": lambda: _block_write_case(False),
