@@ -92,13 +92,18 @@ def _prec(dtype):
 
 
 def expanded_attention(cfg, p, q_nope, q_rope, c_ctx, kr_ctx, q_start,
-                       kv_live=None):
+                       kv_live=None, kernel=False):
     """Causal attention of queries at positions ``q_start + [0, q)`` against
     context rows ``[0, kv)`` given as latents: K and V are expanded from
     ``c_ctx`` [b, kv, r] and ``kr_ctx`` [b, kv, dr] one block of KV_BLOCK
     positions at a time under an online softmax (float32 statistics).
     ``kv_live`` (traced) bounds the blocks visited: rows from it on are in
-    every query's future. Returns [b, q, H * dv]."""
+    every query's future. ``kernel``: the forward is never differentiated
+    (the cached one), so a block may be folded by the chunk kernel where
+    ``ops/pallas/chunk_attention.py:chunk_attention_path`` allows; the
+    training forward keeps these einsums. Returns [b, q, H * dv]."""
+    from ..ops.pallas.chunk_attention import chunk_attention_path
+
     b, q_len, H, dn = q_nope.shape
     dv = cfg.v_head_dim
     kv = c_ctx.shape[1]
@@ -113,6 +118,15 @@ def expanded_attention(cfg, p, q_nope, q_rope, c_ctx, kr_ctx, q_start,
         pad = n_blocks * blk - kv
         c_ctx = jnp.pad(c_ctx, ((0, 0), (0, pad), (0, 0)))
         kr_ctx = jnp.pad(kr_ctx, ((0, 0), (0, pad), (0, 0)))
+    live = n_blocks if kv_live is None else jnp.minimum(
+        (kv_live + blk - 1) // blk, n_blocks)
+    if kernel and chunk_attention_path(q_len, 1, blk, 0,
+                                       cfg.attention_interpret,
+                                       cfg.mesh) == "kernel":
+        with jax.named_scope("latent_attn_expanded"):
+            out = _expanded_kernel(cfg, w, q_nope, q_rope, c_ctx, kr_ctx,
+                                   q_start, n_blocks, live)
+        return out.transpose(0, 2, 1, 3).reshape(b, q_len, H * dv)
 
     def one_block(i, carry):
         m, l, acc = carry
@@ -144,11 +158,45 @@ def expanded_attention(cfg, p, q_nope, q_rope, c_ctx, kr_ctx, q_start,
         if n_blocks == 1:
             _, l, acc = one_block(0, init)
         else:
-            live = n_blocks if kv_live is None else jnp.minimum(
-                (kv_live + blk - 1) // blk, n_blocks)
             _, l, acc = jax.lax.fori_loop(0, live, one_block, init)
         out = (acc / l[..., None]).astype(dtype)
     return out.transpose(0, 2, 1, 3).reshape(b, q_len, H * dv)
+
+
+def _expanded_kernel(cfg, w, q_nope, q_rope, c_ctx, kr_ctx, q_start,
+                     n_blocks, live):
+    """``expanded_attention``'s loop with each block folded by the chunk
+    kernel (``ops/pallas/chunk_attention.py``): a block's K and V are still
+    expanded from its latents by one XLA product, straight into the
+    kernel's per-head layout, K as ``[k_nope | k_rope]`` (the rotated key
+    every head shares) against Q ``[q_nope | q_rope]``, so the kernel sees
+    one score product of width ``dn + dr``. Returns [b, H, q, dv]."""
+    from ..ops.pallas import chunk_attention as C
+
+    b, q_len, H, dn = q_nope.shape
+    dr, dv = q_rope.shape[-1], cfg.v_head_dim
+    dtype = q_nope.dtype
+    prec = _prec(dtype)
+    blk = min(KV_BLOCK, c_ctx.shape[1])
+    q = jnp.concatenate([q_nope, q_rope], -1).transpose(0, 2, 1, 3)
+
+    def one_block(i, carry):
+        start = i * blk
+        c = jax.lax.dynamic_slice_in_dim(c_ctx, start, blk, 1)
+        kr = jax.lax.dynamic_slice_in_dim(kr_ctx, start, blk, 1)
+        kv_h = jnp.einsum("bkr,rhd->bhkd", c, w, precision=prec)
+        k = jnp.concatenate([kv_h[..., :dn], jnp.broadcast_to(
+            kr[:, None].astype(dtype), (b, H, blk, dr))], -1)
+        return C.chunk_attention_block(
+            q, k, kv_h[..., dn:], carry, q_start, start, start, rep=1,
+            scale=_scale(cfg), interpret=cfg.attention_interpret)
+
+    carry = C.initial_carry(b, H, q_len, dv)
+    if n_blocks == 1:
+        carry = one_block(0, carry)
+    else:
+        carry = jax.lax.fori_loop(0, live, one_block, carry)
+    return C.finish(carry, dtype)
 
 
 def absorbed_attention(cfg, p, q_nope, q_rope, c_ctx, kr_ctx, pos):
@@ -309,7 +357,8 @@ def forward_with_cache(model, params, input_ids, cache, pos, kv_len,
                     jnp.broadcast_to(pos, (b,)))[:, None]
             else:
                 out = expanded_attention(cfg_l, p_attn, q_nope, q_rope,
-                                         c_ctx, kr_ctx, pos, kv_live=live)
+                                         c_ctx, kr_ctx, pos, kv_live=live,
+                                         kernel=True)
             return L.linear_apply(p_attn["o"], out), {"k": kc, "v": vc}
 
         return attn

@@ -245,7 +245,8 @@ def _sink(p, window):
     return p["sink"] if window and "sink" in p else None
 
 
-def blockwise_attention(cfg, q, read, kv, q_start, window, sink=None):
+def blockwise_attention(cfg, q, read, kv, q_start, window, sink=None,
+                        kernel=False):
     """Queries at positions ``q_start + [0, q)`` against context positions
     ``[0, kv)``, given by ``read(start, n) -> (k, v)`` [b, n, G, dh] each:
     one block of ``KV_BLOCK`` positions at a time under an online softmax
@@ -253,7 +254,13 @@ def blockwise_attention(cfg, q, read, kv, q_start, window, sink=None):
     the first position any query sees (``q_start - window + 1`` in a window
     layer) to the one that holds the last query. ``sink`` [H] float32: a
     logit a head that the running maximum and sum START from, and that has
-    no value. Returns [b, q, H * dv] (``dv`` the V head's width)."""
+    no value. ``kernel``: the forward is never differentiated (the cached
+    ones), so a block may be folded by the chunk kernel where ``ops/pallas/
+    chunk_attention.py:chunk_attention_path`` allows; the uncached forward
+    keeps these einsums. Returns [b, q, H * dv] (``dv`` the V head's
+    width)."""
+    from ..ops.pallas.chunk_attention import chunk_attention_path
+
     b, q_len, H, dh = q.shape
     (G, _), (_, dv) = cfg.kv_geometry(window).values()
     dtype = q.dtype
@@ -261,9 +268,17 @@ def blockwise_attention(cfg, q, read, kv, q_start, window, sink=None):
     scale = _scale(cfg)
     w = cfg.sliding_window if window else 0
     blk = min(KV_BLOCK, kv)
-    n_blocks = -(-kv // blk)
     qg = q.reshape(b, q_len, G, H // G, dh)
     q_idx = q_start + jnp.arange(q_len)
+    scope = "full_chunk_attn" if not window \
+        else "window_chunk_attn" if sink is None else "sink_window_chunk_attn"
+    if kernel and chunk_attention_path(q_len, H // G, blk, w,
+                                       cfg.attention_interpret,
+                                       cfg.mesh) == "kernel":
+        with jax.named_scope(scope):
+            out = _blockwise_kernel(cfg, qg, read, kv, q_start, w, sink, dv)
+        return out.reshape(b, G, q_len, H // G, dv).transpose(
+            0, 2, 1, 3, 4).reshape(b, q_len, H * dv)
 
     def one_block(i, carry):
         m, l, acc = carry
@@ -296,17 +311,53 @@ def blockwise_attention(cfg, q, read, kv, q_start, window, sink=None):
     if sink is not None:
         init = (jnp.broadcast_to(sink.reshape(1, G, H // G, 1), shape),
                 jnp.ones(shape, F32), init[2])
-    with jax.named_scope("full_chunk_attn" if not window
-                         else "window_chunk_attn" if sink is None
-                         else "sink_window_chunk_attn"):
-        if n_blocks == 1:
-            _, l, acc = one_block(0, init)
-        else:
-            hi = jnp.minimum((q_start + q_len + blk - 1) // blk, n_blocks)
-            lo = jnp.maximum(q_start - (w - 1), 0) // blk if w else 0
-            _, l, acc = jax.lax.fori_loop(lo, hi, one_block, init)
+    with jax.named_scope(scope):
+        _, l, acc = _visit_blocks(one_block, init, kv, q_start, q_len, w)
         out = (acc / l[..., None]).astype(dtype)
     return out.transpose(0, 3, 1, 2, 4).reshape(b, q_len, H * dv)
+
+
+def _visit_blocks(one_block, init, kv, q_start, q_len, w):
+    """``one_block(i, carry)`` over the blocks of ``KV_BLOCK`` positions
+    that hold a position some query sees: from the one that holds ``q_start
+    - w + 1`` (0 without a band) to the one that holds the last query."""
+    blk = min(KV_BLOCK, kv)
+    n_blocks = -(-kv // blk)
+    if n_blocks == 1:
+        return one_block(0, init)
+    hi = jnp.minimum((q_start + q_len + blk - 1) // blk, n_blocks)
+    lo = jnp.maximum(q_start - (w - 1), 0) // blk if w else 0
+    return jax.lax.fori_loop(lo, hi, one_block, init)
+
+
+def _blockwise_kernel(cfg, qg, read, kv, q_start, w, sink, dv):
+    """``blockwise_attention``'s loop with each block folded by the chunk
+    kernel (``ops/pallas/chunk_attention.py``): the same blocks, the same
+    mask, the carry in the kernel's layout. qg [b, q, G, R, dk] -> [b, G,
+    q * R, dv]."""
+    from ..ops.pallas import chunk_attention as C
+
+    b, q_len, G, R, dk = qg.shape
+    dtype = qg.dtype
+    blk = min(KV_BLOCK, kv)
+    rows = q_len * R
+    qk = qg.transpose(0, 2, 1, 3, 4).reshape(b, G, rows, dk)
+    # row j * R + r is head g * R + r: its sink
+    m0 = None if sink is None else jnp.broadcast_to(
+        sink.reshape(1, G, 1, R), (1, G, q_len, R)).reshape(1, G, rows)
+
+    def one_block(i, carry):
+        start = jnp.minimum(i * blk, kv - blk)
+        k, v = read(start, blk)
+        to_groups = lambda a: a.astype(dtype).transpose(0, 2, 1, 3)
+        return C.chunk_attention_block(
+            qk, to_groups(k), to_groups(v), carry, q_start, start, i * blk,
+            rep=R, scale=_scale(cfg), window=w,
+            interpret=cfg.attention_interpret)
+
+    carry = _visit_blocks(one_block, C.initial_carry(b, G, rows, dv, m0),
+                          kv, q_start, q_len, w)
+    return C.finish(carry, dtype)
 
 
 def ring_positions(pos, n_cols, block_size):
@@ -508,7 +559,7 @@ def forward_with_cache(model, params, input_ids, cache, pos, kv_len,
                                           (1, b, n) + v.shape[2:])[0])
 
         out = blockwise_attention(cfg_l, q, read, kv_len, pos, layer.window,
-                                  _sink(p, layer.window))
+                                  _sink(p, layer.window), kernel=True)
         return _out(p, out, gate), cache
 
     x, cache, routed = _run_layers(cfg, params,

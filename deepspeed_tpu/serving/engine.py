@@ -134,6 +134,11 @@ class ServingEngine:
         self._decode_dispatches = {"kernel": 0, "view": 0}
         # of those, the decodes dispatched behind the step before theirs
         self._decode_ahead_dispatches = 0
+        # chunk programs by the body their attention's key blocks were
+        # traced with: the chunk kernel or XLA's; by suffix bucket, what the
+        # trace took (``ops/pallas/chunk_attention.py:traced_paths``)
+        self._chunk_attention_dispatches = {"kernel": 0, "xla": 0}
+        self._chunk_attention_paths = {}
         self.attn_backend, self.attn_reason = self._choose_attention(engine)
         if self.cfg.scrub_freed_slots:
             # zero each physical block as its last reference drops
@@ -434,6 +439,9 @@ class ServingEngine:
         st["attention_reason"] = self.attn_reason
         st["decode_dispatches"] = dict(self._decode_dispatches)
         st["decode_ahead_dispatches"] = self._decode_ahead_dispatches
+        if self._latent or self._window:
+            st["chunk_attention_dispatches"] = dict(
+                self._chunk_attention_dispatches)
         if self._window:
             # by group: the blocks each holds, those of the live requests
             # (the slots' bindings), and the K/V rows the decode steps read
@@ -530,18 +538,28 @@ class ServingEngine:
         """Shared-prefix hit: prefill only the SUFFIX (cache already holds
         the prefix KV gathered from shared blocks) — one compiled program
         per suffix bucket, start position and true length traced."""
+        from ..ops.pallas.chunk_attention import traced_paths
+
         model, max_len = self.engine.module, self.max_len
+
+        def forward(*args, **kwargs):
+            # every layer's key blocks in the chunk kernel, or not
+            with traced_paths() as seen:
+                out = forward_with_cache(*args, **kwargs)
+            self._chunk_attention_paths[padded_len] = \
+                "kernel" if seen == {"kernel"} else "xla"
+            return out
 
         def build():
             def suffix_prefill(params, ids, cache, start_pos, true_len):
-                logits, c = forward_with_cache(model, params, ids, cache,
-                                               start_pos, max_len)
+                logits, c = forward(model, params, ids, cache, start_pos,
+                                    max_len)
                 last = jax.lax.dynamic_slice_in_dim(
                     logits, true_len - 1, 1, axis=1)[:, 0]
                 return last, c
 
             def suffix_routed(params, ids, cache, start_pos, true_len):
-                logits, c, routed = forward_with_cache(
+                logits, c, routed = forward(
                     model, params, ids, cache, start_pos, max_len,
                     last_index=true_len - 1, return_routing=True)
                 return logits[:, 0], c, self._routed_out(routed, true_len)
@@ -1471,6 +1489,9 @@ class ServingEngine:
             np.int32(job.pos), np.int32(n))
         job.cache = out[1]
         self.metrics.record_prefill_chunk(job.pos, n)
+        if self._latent or self._window:
+            self._chunk_attention_dispatches[
+                self._chunk_attention_paths[padded]] += 1
         return n, padded, out
 
     def _dispatch_chunk_ahead(self):

@@ -384,15 +384,22 @@ def _kanana_programs(n_layers, n_blocks, sharding=None):
 
 def test_latent_expert_serving_programs_lower_with_the_grouped_kernel_alone():
     """kanana2's decode step and prefill chunk at the published widths lower
-    for the TPU with two Mosaic calls in the scanned expert layer's body and
-    no other: the grouped expert products are ``ops/pallas/grouped_matmul.py``
-    in both programs (settled on the chip, PR 36: ``moe/dropfree.py``), no
-    ``ragged_dot`` is left, and both attention forms are XLA's."""
+    for the TPU with two Mosaic calls in the scanned expert layer's body for
+    the grouped expert products (``ops/pallas/grouped_matmul.py`` in both
+    programs, settled on the chip: ``moe/dropfree.py``) and no
+    ``ragged_dot``; decode's absorbed attention is XLA's, and the chunk's
+    expanded attention folds its key blocks in the chunk kernel
+    (``ops/pallas/chunk_attention.py``), called in the dense layer's loop
+    and in the scanned body's: one kernel, lowered once, as the two calls
+    have the same shapes."""
     decode, d_args, chunk, c_args = _kanana_programs(3, 257)
-    for fn, args in ((decode, d_args), (chunk, c_args)):
+    for fn, args, n_attn, n_calls in ((decode, d_args, 0, 0),
+                                      (chunk, c_args, 1, 2)):
         text = lower_for_tpu(fn, *args)
-        assert n_mosaic(text) == 2 and "ragged_dot" not in text
+        assert n_mosaic(text) == 2 + n_attn and "ragged_dot" not in text
         assert text.count("grouped_matmul") == 2
+        assert text.count('"chunk_attention"') == n_attn
+        assert text.count("call @chunk_attention_block") == n_calls
 
 
 @pytest.mark.parametrize("kvh,window,geometry", [
@@ -464,11 +471,14 @@ def test_window_expert_serving_programs_lower_with_the_kernel_and_no_view():
     for the TPU: in decode one Mosaic call a layer for attention (the band in
     the four window layers) and two an expert layer for the grouped products
     (``ops/pallas/grouped_matmul.py``; a period's four layers are written
-    out), those eight alone in the chunk, no ``ragged_dot`` left, no ``n_slots x
-    max_len`` tensor in decode (the 32 x 256 x 128 gather of the full group's
-    view, a 32 x 32768 view or score row), and in the chunk no score tensor
-    against the whole ``max_len`` (``[32 heads, 1024, 32768]`` float32 is 4.3
-    GB): the context is visited in blocks of 1024."""
+    out), those eight in the chunk beside one chunk-kernel call a layer (the
+    kernel lowered once a kind: window and full), no
+    ``ragged_dot`` left, no ``n_slots x max_len`` tensor in decode (the 32 x
+    256 x 128 gather of the full group's view, a 32 x 32768 view or score
+    row), and in the chunk no score tensor at all: the context is visited in
+    blocks of 1024, each folded by the chunk kernel, whose scores stay in
+    VMEM (``[32 heads, 1024, 32768]`` float32 would be 4.3 GB; a block's
+    ``[4, 8, 1024, 1024]`` was XLA's)."""
     decode, d_args, chunk, c_args = _trinity_cell_programs()
     text = lower_for_tpu(decode, *d_args)
     assert n_mosaic(text) == 5 + 8 and "ragged_dot" not in text
@@ -477,16 +487,19 @@ def test_window_expert_serving_programs_lower_with_the_kernel_and_no_view():
                  "x32768xbf16"):
         assert view not in text, view
     text = lower_for_tpu(chunk, *c_args)
-    assert n_mosaic(text) == 8 and "ragged_dot" not in text
+    assert n_mosaic(text) == 8 + 2 and "ragged_dot" not in text
+    assert text.count('"chunk_attention"') == 2
+    assert text.count("call @chunk_attention_block") == 5
     assert "5x1x32768x4x128xbf16" in text
-    assert "4x8x1024x1024xf32" in text           # a block of scores
-    for scores in ("1024x32768xf32", "32x1024x32768", "4x8x1024x32768"):
+    for scores in ("1024x32768xf32", "32x1024x32768", "4x8x1024x32768",
+                   "4x8x1024x1024xf32"):
         assert scores not in text, scores
 
 
-def _compile_for(fn, args):
-    """``fn`` compiled for the described chip, its third argument donated,
-    with the persistent cache off (such a compile cannot be read back)."""
+def _compile_for(fn, args, donate=(2,)):
+    """``fn`` compiled for the described chip, its third argument donated
+    (or ``donate``), with the persistent cache off (such a compile cannot be
+    read back)."""
     from jax.experimental.compilation_cache import compilation_cache as cc
 
     cached = jax.config.jax_enable_compilation_cache
@@ -494,7 +507,7 @@ def _compile_for(fn, args):
     cc.reset_cache()
     try:
         with lowering_target("tpu"):
-            return jax.jit(fn, donate_argnums=(2,)).trace(*args) \
+            return jax.jit(fn, donate_argnums=donate).trace(*args) \
                 .lower(lowering_platforms=("tpu",)).compile()
     finally:
         jax.config.update("jax_enable_compilation_cache", cached)
@@ -559,23 +572,99 @@ def test_compiled_chunk_reads_its_experts_in_place(v5e, cell):
     temporaries stay what they were with ``ragged_dot`` (249 MB in trinity,
     343 MB in kanana: the parent's, compiled the same way)."""
     if cell == "trinity":
-        _, _, chunk, c_args = _trinity_cell_programs(sharding=v5e)
         experts, n_calls, stack = TRINITY_EXPERTS, 8, "bf16[512,2048,2048]"
         temp_limit = 256 << 20
     else:
-        _, _, chunk, c_args = _kanana_programs(7, 2561, sharding=v5e)
         experts, n_calls, stack = KANANA_EXPERTS, 2, "bf16[768,2048,1536]"
         temp_limit = 336 << 20
-    compiled = _compile_for(chunk, c_args)
+    compiled = _compiled_chunk(v5e, cell)
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < temp_limit, mem.temp_size_in_bytes
     text = compiled.as_text()
     calls = [line for line in text.splitlines()
-             if 'custom_call_target="tpu_custom_call"' in line]
+             if 'custom_call_target="tpu_custom_call"' in line
+             and "chunk_attention" not in line]
     assert len(calls) == n_calls and "ragged-dot" not in text
     assert all("moe_grouped_matmul/grouped_matmul" in c for c in calls)
     assert any(stack in c for c in calls)       # the whole stack goes in
     _assert_only_passed_along(text, experts)
+
+
+_COMPILED_CHUNKS = {}
+
+
+def _compiled_chunk(v5e, cell):
+    """The 1024-token chunk program of a cell compiled for a v5e, once a
+    process (two tests read it)."""
+    if cell not in _COMPILED_CHUNKS:
+        if cell == "trinity":
+            _, _, chunk, c_args = _trinity_cell_programs(sharding=v5e)
+        else:
+            _, _, chunk, c_args = _kanana_programs(7, 2561, sharding=v5e)
+        _COMPILED_CHUNKS[cell] = _compile_for(chunk, c_args)
+    return _COMPILED_CHUNKS[cell]
+
+
+@pytest.mark.parametrize("cell,scopes,score_blocks", [
+    ("trinity", {"window_chunk_attn": 4, "full_chunk_attn": 1},
+     ("f32[4,8,1024,1024]", "f32[1,4,8,1024,1024]")),
+    ("kanana", {"latent_attn_expanded": 2},
+     ("f32[32,1024,2048]", "f32[1,32,1024,2048]")),
+])
+def test_compiled_chunk_keeps_its_scores_in_vmem(v5e, cell, scopes,
+                                                 score_blocks):
+    """The same chunk programs: every layer's key blocks are folded by the
+    chunk kernel, one call under the layer's attention scope (the scope the
+    runners map the device trace to), and no instruction makes a float32
+    score block ``[.., q_len, blk]`` (XLA's body wrote 268 MB of them a block
+    a layer in kanana2, 134 MB in trinity)."""
+    text = _compiled_chunk(v5e, cell).as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line
+             and "chunk_attention" in line]
+    assert len(calls) == sum(scopes.values())
+    for scope, n in scopes.items():
+        assert sum(f"/{scope}/" in c for c in calls) == n, scope
+    for line in text.splitlines():
+        if " = " in line:
+            shape = line.split(" = ")[1].split()[0].split("{")[0]
+            assert shape not in score_blocks, line[:200]
+
+
+@pytest.mark.parametrize("groups,rep,dk,window,blk", [
+    (32, 1, 192, 0, 2048),      # kanana2
+    (4, 8, 128, 2048, 1024),    # trinity, window layers
+    (4, 8, 128, 0, 1024),       # trinity, full layers
+    (8, 8, 192, 128, 1024),     # mimo, window layers (key tiles of 256)
+    (4, 16, 192, 0, 1024),      # mimo, full layers
+], ids=["kanana", "trinity-window", "trinity-full", "mimo-window",
+        "mimo-full"])
+def test_chunk_kernel_compiles_at_each_configurations_shapes(
+        v5e, groups, rep, dk, window, blk):
+    """The chunk kernel alone, one 1024-token chunk against one key block,
+    COMPILED for a v5e at each served configuration's group shapes (V heads
+    of 128): Mosaic takes the tiles, and the carry is updated in place (both
+    of its arrays aliased)."""
+    from deepspeed_tpu.ops.pallas.chunk_attention import (
+        chunk_attention_block, STAT_LANES)
+
+    rows = 1024 * rep
+    sds = lambda shape, dt: SDS(shape, dt, sharding=v5e)
+
+    def fold(q, k, v, stat, acc, start):
+        return chunk_attention_block(q, k, v, (stat, acc), start + 100,
+                                     start, start, rep=rep, scale=0.1,
+                                     window=window)
+
+    compiled = _compile_for(fold, (
+        sds((1, groups, rows, dk), jnp.bfloat16),
+        sds((1, groups, blk, dk), jnp.bfloat16),
+        sds((1, groups, blk, 128), jnp.bfloat16),
+        sds((1, groups, rows, STAT_LANES), jnp.float32),
+        sds((1, groups, rows, 128), jnp.float32), sds((), jnp.int32)),
+        donate=(3, 4))
+    assert compiled.memory_analysis().alias_size_in_bytes \
+        == 2 * groups * rows * 128 * 4
 
 
 def test_compiler_verdict_carries_the_compilers_words():

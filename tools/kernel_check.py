@@ -3,13 +3,15 @@ is compiled by Mosaic at one production geometry and compared with its XLA
 reference on the same device.
 
     python tools/kernel_check.py          # on the chip machine (one process)
+    python tools/kernel_check.py "chunk attention" "grouped"   # those alone
 
 One JSON line per kernel: ``{"kernel", "geometry", "max_err", "tol", "ok"}``
-(the grouped expert products also ``"ms"``: the kernel and ``ragged_dot``
-timed on the same operands), then a summary; exit code 1 if any kernel
-failed to compile or to match. A TPU is required: off-chip these kernels only
-run under the interpreter, which tier-1 already covers
-(``tests/unit/test_*attention*.py`` etc.), and
+(the grouped expert products and the chunk attention also ``"ms"``: the
+kernel and ``ragged_dot`` / the XLA block body timed on the same operands),
+then a summary; exit code 1 if any kernel failed to compile or to match.
+Arguments keep only the cases whose name holds one of them. A TPU is
+required: off-chip these kernels only run under the interpreter, which
+tier-1 already covers (``tests/unit/test_*attention*.py`` etc.), and
 ``tests/unit/test_tpu_lowering.py`` covers lowering. Tolerances are bf16
 ones: both sides round to bf16 somewhere, in a different order.
 """
@@ -406,7 +408,78 @@ def _grouped_product_case(n_layers, K, N, M, dtype="bfloat16", E=128):
             {"ragged_dot": dropfree._ragged_product})
 
 
+def _chunk_attention_case(groups, rep, dk, window, blk, diagonal=False):
+    """One key block of a 1024-token prefill chunk's attention as the served
+    chunk programs fold it (``ops/pallas/chunk_attention.py`` on a TPU),
+    against the XLA block body the models keep for the uncached forward, on
+    the kernel's layout, from a carry a block of history already filled (V
+    heads of 128). TIMED beside that body on the same operands (the row's
+    ``ms``): a block inside the causal past (no tile masked) or, with
+    ``diagonal``, the block that holds the queries."""
+    import jax
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.ops.pallas import chunk_attention as C
+
+    f32, dt = jnp.float32, jnp.bfloat16
+    q_len, dv, scale = 1024, 128, 1.0 / np.sqrt(dk)
+    rows = q_len * rep
+    start = 4 * blk
+    q_start = start + (0 if diagonal else blk + 1024)
+    key = jax.random.PRNGKey(0)
+    normal = lambda i, shape: jax.random.normal(
+        jax.random.fold_in(key, i), shape, f32).astype(dt)
+    q = normal(0, (1, groups, rows, dk))
+    k, v = normal(1, (1, groups, blk, dk)), normal(2, (1, groups, blk, dv))
+    m = jax.random.normal(jax.random.fold_in(key, 3), (1, groups, rows)) * 2
+    l = 1.0 + jax.random.uniform(jax.random.fold_in(key, 4), m.shape) * 50
+    acc = jax.random.normal(jax.random.fold_in(key, 5), m.shape + (dv,)) * 5
+    stat = C.initial_carry(1, groups, rows, dv, m)[0]
+    stat = jnp.where(jnp.arange(C.STAT_LANES) == 1, l[..., None], stat)
+
+    def kernel(q, k, v, stat, m, l, acc):
+        return C.finish(C.chunk_attention_block(
+            q, k, v, (stat, acc), q_start, start, start, rep=rep,
+            scale=scale, window=window), f32)
+
+    def xla(q, k, v, stat, m, l, acc):
+        q_idx = q_start + jnp.arange(rows) // rep
+        k_idx = start + jnp.arange(blk)
+        s = jnp.einsum("bgqd,bgkd->bgqk", q, k,
+                       preferred_element_type=f32) * scale
+        seen = k_idx[None, :] <= q_idx[:, None]
+        if window:
+            seen &= q_idx[:, None] - k_idx[None, :] < window
+        s = jnp.where(seen, s, -jnp.inf)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+        e = jnp.exp(s - m_new[..., None])
+        fix = jnp.exp(m - m_new)
+        acc = acc * fix[..., None] + jnp.einsum(
+            "bgqk,bgkd->bgqd", e.astype(dt), v, preferred_element_type=f32)
+        return acc / (l * fix + jnp.sum(e, axis=-1))[..., None]
+
+    tq, tk = C.chunk_tiles(q_len, rep, blk, window)
+    return (f"{groups} groups x {rep} heads, K {dk} / V {dv}, a 1024-token "
+            f"chunk against a block of {blk}"
+            + (f", band {window}" if window else "")
+            + (", the diagonal block" if diagonal else ", all in the past")
+            + f"; tiles {tq} x {tk}", kernel, xla,
+            (q, k, v, stat, m, l, acc), 3e-2, {"xla": xla})
+
+
 CASES = {
+    "chunk attention (kanana2, 32 x 1, block 2048)":
+        lambda: _chunk_attention_case(32, 1, 192, 0, 2048),
+    "chunk attention (kanana2, 32 x 1, block 2048, diagonal)":
+        lambda: _chunk_attention_case(32, 1, 192, 0, 2048, diagonal=True),
+    "chunk attention (trinity window, 4 x 8, band 2048)":
+        lambda: _chunk_attention_case(4, 8, 128, 2048, 1024),
+    "chunk attention (trinity full, 4 x 8)":
+        lambda: _chunk_attention_case(4, 8, 128, 0, 1024),
+    "chunk attention (mimo window, 8 x 8, band 128)":
+        lambda: _chunk_attention_case(8, 8, 192, 128, 1024, diagonal=True),
+    "chunk attention (mimo full, 4 x 16)":
+        lambda: _chunk_attention_case(4, 16, 192, 0, 1024),
     "grouped expert product (trinity chunk, gate and up)":
         lambda: _grouped_product_case(4, 2048, 2048, 8192),
     "grouped expert product (trinity chunk, down)":
@@ -476,12 +549,14 @@ def main():
     print(json.dumps({"device_kind": dev.device_kind, "jax": jax.__version__}),
           flush=True)
     failed = []
-    for name, case in CASES.items():
+    cases = {name: case for name, case in CASES.items()
+             if not sys.argv[1:] or any(a in name for a in sys.argv[1:])}
+    for name, case in cases.items():
         row = _check(name, case)
         if not row["ok"]:
             failed.append(name)
         print(json.dumps(row), flush=True)
-    print(f"# {len(CASES) - len(failed)}/{len(CASES)} kernels compiled and "
+    print(f"# {len(cases) - len(failed)}/{len(cases)} kernels compiled and "
           f"matched on {dev.device_kind}"
           + (f"; FAILED: {failed}" if failed else ""), flush=True)
     return 1 if failed else 0
