@@ -1063,48 +1063,67 @@ class ServingEngine:
         advance at most one pending prefill chunk (chunked prefill), grow or
         preempt slots whose cursor reached the end of their blocks
         (on-demand growth), then run one decode step over the pool. Returns
-        the list of TokenEvents produced."""
-        events = []
-        # a decode the last step dispatched behind its own: this step's
-        ahead, self._decode_ahead = self._decode_ahead, None
-        can_admit = self._make_can_admit()
-        admitted = self._maybe_priority_preempt(can_admit)
-        if admitted is None:
-            admitted = self.scheduler.next_admissions(len(self._free_slots),
-                                                      self.clock.now(),
-                                                      can_admit=can_admit)
-        for req in admitted:
-            self._start_request(req, events)
-        if self._prefill_jobs and self._chunk_due():
-            self._advance_prefill(events)
-        if self.growth and self._slots:
-            self._grow_or_preempt()
-        if self._slots:
-            drafts = self._collect_drafts() \
-                if (self.spec and self._spec_on) else None
-            if drafts:
-                self._verify_once(events, drafts)
-            else:
-                self._decode_once(events, ahead)
-            self._decode_steps_since_chunk += 1
-            if self._slots and self.cfg.migration.enabled \
-                    and self.cfg.migration.snapshot_interval_tokens > 0:
-                self._maybe_snapshot()
-        elif not admitted and not self._prefill_jobs and self.queue.depth:
-            # nothing running and the queue head hasn't arrived yet (direct
-            # submit with a future arrival offset): idle the clock forward to
-            # it, or a virtual-clock step() loop would spin forever
-            head = self.queue.peek()
-            if head.arrival_time is not None:
-                gap = head.arrival_time - self.clock.now()
-                if gap > 0:
-                    self.clock.sleep(gap)
-        if self._pending_loads:
-            self._drain_prefill_loads()
-        if self.degraded_ctl is not None:
-            self.degraded_ctl.observe(self.clock.now())
-        self.metrics.observe_step(self.queue.depth, len(self._slots))
-        return events
+        the list of TokenEvents produced.
+
+        The iteration is the span ``serving/step``, and its phases are
+        spans inside it (``telemetry/tracer.py``: profiler annotations
+        whether or not the tracer records): ``admit``, ``prefill`` /
+        ``prefill_chunk`` (a prefill's dispatch and booking), ``insert``,
+        ``decode_step`` (the decode's dispatch), ``ahead`` (the next step's
+        work dispatched behind it), ``read_back`` (every wait for a device
+        result), ``book`` (tokens, finishes, loads) and ``upkeep``. One span
+        a phase a step, never one a slot or a token; an admitted request
+        adds its own prefill, first-token read-back and insert."""
+        span = self.tracer.span
+        with span("step", cat="serving"):
+            events = []
+            # a decode the last step dispatched behind its own: this step's
+            ahead, self._decode_ahead = self._decode_ahead, None
+            with span("admit", cat="serving"):
+                can_admit = self._make_can_admit()
+                admitted = self._maybe_priority_preempt(can_admit)
+                if admitted is None:
+                    admitted = self.scheduler.next_admissions(
+                        len(self._free_slots), self.clock.now(),
+                        can_admit=can_admit)
+                for req in admitted:
+                    self._start_request(req, events)
+            if self._prefill_jobs and self._chunk_due():
+                self._advance_prefill(events)
+            if self.growth and self._slots:
+                with span("upkeep", cat="serving"):
+                    self._grow_or_preempt()
+            if self._slots:
+                drafts = self._collect_drafts() \
+                    if (self.spec and self._spec_on) else None
+                if drafts:
+                    self._verify_once(events, drafts)
+                else:
+                    self._decode_once(events, ahead)
+                self._decode_steps_since_chunk += 1
+            elif not admitted and not self._prefill_jobs and self.queue.depth:
+                # nothing running and the queue head hasn't arrived yet
+                # (direct submit with a future arrival offset): idle the
+                # clock forward to it, or a virtual-clock step() loop would
+                # spin forever
+                head = self.queue.peek()
+                if head.arrival_time is not None:
+                    gap = head.arrival_time - self.clock.now()
+                    if gap > 0:
+                        self.clock.sleep(gap)
+            if self._pending_loads:
+                # no decode read-back came before: reading the loads waits
+                # for the prefill that made them
+                with span("read_back", cat="serving"):
+                    self._drain_prefill_loads()
+            with span("upkeep", cat="serving"):
+                if self._slots and self.cfg.migration.enabled \
+                        and self.cfg.migration.snapshot_interval_tokens > 0:
+                    self._maybe_snapshot()
+                if self.degraded_ctl is not None:
+                    self.degraded_ctl.observe(self.clock.now())
+                self.metrics.observe_step(self.queue.depth, len(self._slots))
+            return events
 
     def _maybe_priority_preempt(self, can_admit):
         """Priority preemption (serving.tenants.preempt): when every slot
@@ -1373,7 +1392,8 @@ class ServingEngine:
             logits, keys[0], np.float32(s.temperature),
             np.int32(s.top_k), np.float32(s.top_p))
         now = self.clock.now()
-        nf = int(nf)
+        with self.tracer.span("read_back", cat="serving"):
+            nf, t = int(nf), int(np.asarray(tok)[0])
         if nf:
             # symmetric with decode: the counter reports whether or not the
             # shed hook is armed
@@ -1395,7 +1415,6 @@ class ServingEngine:
             events.append(TokenEvent(req.request_id, -1, 0, True,
                                      FINISH_UNHEALTHY, now))
             return
-        t = int(np.asarray(tok)[0])
         req.state = RequestState.RUNNING
         req.first_token_time = now
         req.tokens.append(t)
@@ -1431,8 +1450,10 @@ class ServingEngine:
             # RESUMED request keeps its original seniority
             req.admit_seq = self._admit_seq
             self._admit_seq += 1
-        self._insert_paged(req, slot, cache, shared_len, shared_blocks,
-                           tok[0], keys[1], s, eos, req.max_new_tokens - 1)
+        with self.tracer.span("insert", cat="serving"):
+            self._insert_paged(req, slot, cache, shared_len, shared_blocks,
+                               tok[0], keys[1], s, eos,
+                               req.max_new_tokens - 1)
         events.append(TokenEvent(req.request_id, t, 0, False, None, now))
 
     # ----------------------------------------------- chunked prefill driver
@@ -1443,22 +1464,23 @@ class ServingEngine:
         job's cursor against its donated partial cache, bucketed so every
         full chunk shares one compiled program."""
         job = self._prefill_jobs[0]
-        (n, padded, out), job.ahead = \
-            job.ahead or self._dispatch_chunk(job), None
         req = job.req
-        req.chunks += 1
-        req.padding_tokens += padded - n
-        if job.resume:
-            # every replayed position is device work a preemption burned:
-            # it was prefilled (prompt) or decoded (generated) once already
-            req.replay_tokens += n
-        self.metrics.record_prefill_work(padded, n,
-                                         replay=n if job.resume else 0)
         with self.tracer.span("prefill_chunk", cat="serving",
                               request_id=req.request_id,
-                              trace_id=req.trace_id, n=n,
-                              padded_len=padded, start=job.pos,
-                              resume=job.resume):
+                              trace_id=req.trace_id, start=job.pos,
+                              resume=job.resume) as sp:
+            (n, padded, out), job.ahead = \
+                job.ahead or self._dispatch_chunk(job), None
+            sp.set(n=n, padded_len=padded)
+            req.chunks += 1
+            req.padding_tokens += padded - n
+            if job.resume:
+                # every replayed position is device work a preemption
+                # burned: it was prefilled (prompt) or decoded (generated)
+                # once already
+                req.replay_tokens += n
+            self.metrics.record_prefill_work(padded, n,
+                                             replay=n if job.resume else 0)
             logits, _ = self._book_prefill(req, job.pos, n, out)
             self.clock.advance(
                 padded * self.cfg.virtual_prefill_cost_per_token)
@@ -1466,7 +1488,9 @@ class ServingEngine:
         self._decode_steps_since_chunk = 0
         if job.done:
             self._prefill_jobs.popleft()
-            self._complete_job(job, logits, events)
+            # the admission's last part: the first token and the slot
+            with self.tracer.span("admit", cat="serving"):
+                self._complete_job(job, logits, events)
 
     def _dispatch_chunk(self, job):
         """Dispatch the job's next chunk. Its cache is the program's from
@@ -1576,16 +1600,18 @@ class ServingEngine:
         req.state = RequestState.RUNNING
         self._slots[slot] = req
         req.slot = slot
-        rng = jnp.asarray(req.resume_rng)
-        # committed replicated scalar: the fresh path feeds tok[0] straight
-        # out of _sample_first_jit (committed to the mesh via its pinned
-        # out_shardings), and an uncommitted host scalar here would open a
-        # SECOND jit-cache entry for the same aval — breaking the
-        # insert-compiles-once pin
-        tok = jax.device_put(jnp.asarray(req.tokens[-1], jnp.int32),
-                             self._rep_sharding)
-        self._insert_paged(req, slot, job.cache, job.shared_len,
-                           job.shared_blocks, tok, rng, s, eos, remaining)
+        with self.tracer.span("insert", cat="serving"):
+            rng = jnp.asarray(req.resume_rng)
+            # committed replicated scalar: the fresh path feeds tok[0]
+            # straight out of _sample_first_jit (committed to the mesh via
+            # its pinned out_shardings), and an uncommitted host scalar here
+            # would open a SECOND jit-cache entry for the same aval —
+            # breaking the insert-compiles-once pin
+            tok = jax.device_put(jnp.asarray(req.tokens[-1], jnp.int32),
+                                 self._rep_sharding)
+            self._insert_paged(req, slot, job.cache, job.shared_len,
+                               job.shared_blocks, tok, rng, s, eos,
+                               remaining)
         self.tracer.instant("request/resumed", cat="serving",
                             ts=self.clock.now(), request_id=req.request_id,
                             trace_id=req.trace_id,
@@ -2122,68 +2148,71 @@ class ServingEngine:
         for slot, toks in drafts.items():
             dmat[slot, :len(toks)] = toks
             dlen[slot] = len(toks)
-        with self.tracer.span("decode_step", cat="serving",
-                              active=len(self._slots), verify=True,
-                              drafted=int(dlen.sum())):
+        span = self.tracer.span
+        with span("decode_step", cat="serving", active=len(self._slots),
+                  verify=True, drafted=int(dlen.sum())):
             ((toks, n_emit, accepted, done_now, nonfinite, sampled),
              self._state) = self._verify_jit(
                 self.engine.params, self._state, jnp.asarray(dmat),
                 jnp.asarray(dlen))
             self.clock.advance(self.cfg.virtual_decode_step_cost)
-        toks = np.asarray(toks)
-        n_emit = np.asarray(n_emit)
-        accepted = np.asarray(accepted)
-        done_now = np.asarray(done_now)
-        nonfinite = np.asarray(nonfinite)
-        now = self.clock.now()
-        self.metrics.record_health_step(
-            sum(1 for s in self._slots if nonfinite[s] > 0))
-        self.metrics.record_verify_step()
-        self.metrics.record_decode_dispatch()
-        self._decode_dispatches["view"] += 1
-        self.metrics.record_sampler_step(bool(sampled))
-        for slot in sorted(self._slots):
-            req = self._slots[slot]
-            pos0 = req.prompt_len + len(req.tokens) - 1  # this step's cursor
-            n, acc, d = int(n_emit[slot]), int(accepted[slot]), \
-                int(dlen[slot])
-            if d:
-                # booked BEFORE any shed below: the drafted == accepted +
-                # rolled_back invariant must balance on every exit path
-                req.accepted_tokens += acc
-                req.rolled_back_tokens += d - acc
-                self.metrics.record_accept(acc, d - acc)
-            if self._health_shed and nonfinite[slot] > 0:
-                self._shed_unhealthy(req, events, now, int(nonfinite[slot]))
-                continue
-            reason = None
-            for j in range(n):
-                t = int(toks[slot, j])
-                req.tokens.append(t)
-                self.metrics.record_tokens(1, req)
-                self.metrics.record_decode_tokens(1)
-                if j == n - 1 and bool(done_now[slot]):
-                    reason = FINISH_EOS if (req.eos_token_id is not None
-                                            and t == req.eos_token_id) \
-                        else FINISH_LENGTH
-                elif t in req.stop_token_ids:
-                    # host-side stop policy truncates the emitted run; the
-                    # device state is ahead but the slot is freed anyway
-                    reason = FINISH_STOP
-                events.append(TokenEvent(req.request_id, t,
-                                         len(req.tokens) - 1,
-                                         reason is not None, reason, now))
+        with span("read_back", cat="serving"):
+            toks, n_emit, accepted, done_now, nonfinite, sampled = \
+                jax.device_get((toks, n_emit, accepted, done_now, nonfinite,
+                                sampled))
+        with span("book", cat="serving"):
+            now = self.clock.now()
+            self.metrics.record_health_step(
+                sum(1 for s in self._slots if nonfinite[s] > 0))
+            self.metrics.record_verify_step()
+            self.metrics.record_decode_dispatch()
+            self._decode_dispatches["view"] += 1
+            self.metrics.record_sampler_step(bool(sampled))
+            for slot in sorted(self._slots):
+                req = self._slots[slot]
+                # this step's cursor
+                pos0 = req.prompt_len + len(req.tokens) - 1
+                n, acc, d = int(n_emit[slot]), int(accepted[slot]), \
+                    int(dlen[slot])
+                if d:
+                    # booked BEFORE any shed below: the drafted == accepted +
+                    # rolled_back invariant must balance on every exit path
+                    req.accepted_tokens += acc
+                    req.rolled_back_tokens += d - acc
+                    self.metrics.record_accept(acc, d - acc)
+                if self._health_shed and nonfinite[slot] > 0:
+                    self._shed_unhealthy(req, events, now,
+                                         int(nonfinite[slot]))
+                    continue
+                reason = None
+                for j in range(n):
+                    t = int(toks[slot, j])
+                    req.tokens.append(t)
+                    self.metrics.record_tokens(1, req)
+                    self.metrics.record_decode_tokens(1)
+                    if j == n - 1 and bool(done_now[slot]):
+                        reason = FINISH_EOS if (req.eos_token_id is not None
+                                                and t == req.eos_token_id) \
+                            else FINISH_LENGTH
+                    elif t in req.stop_token_ids:
+                        # host-side stop policy truncates the emitted run; the
+                        # device state is ahead but the slot is freed anyway
+                        reason = FINISH_STOP
+                    events.append(TokenEvent(req.request_id, t,
+                                             len(req.tokens) - 1,
+                                             reason is not None, reason, now))
+                    if reason is not None:
+                        break
                 if reason is not None:
-                    break
-            if reason is not None:
-                self._finish(req, reason, now)
-                continue
-            if d >= n:
-                # candidate rows [pos0 + n, pos0 + d] were written but the
-                # cursor rolled back short of them — reclaim at block
-                # granularity
-                self._rollback_stale(slot, new_cursor=pos0 + n,
-                                     written_end=pos0 + d)
+                    self._finish(req, reason, now)
+                    continue
+                if d >= n:
+                    # candidate rows [pos0 + n, pos0 + d] were written but the
+                    # cursor rolled back short of them — reclaim at block
+                    # granularity
+                    self._rollback_stale(slot, new_cursor=pos0 + n,
+                                         written_end=pos0 + d)
+            self._drain_prefill_loads()
 
     def _rollback_stale(self, slot, new_cursor, written_end):
         """Rejected drafts rolled back: the in-graph verify already left
@@ -2218,9 +2247,11 @@ class ServingEngine:
         """One decode step over the pool. ``ahead``: ``(outputs, {slot:
         request})`` of this step's decode where the last step dispatched it
         already: it decoded for the slots bound then, so a slot bound since
-        (this step's insert) gets its next token from the next decode."""
-        with self.tracer.span("decode_step", cat="serving",
-                              active=len(self._slots)):
+        (this step's insert) gets its next token from the next decode. The
+        span ``decode_step`` is the dispatch alone; the wait for its tokens
+        is ``read_back`` and what is done with them ``book``."""
+        span = self.tracer.span
+        with span("decode_step", cat="serving", active=len(self._slots)):
             if ahead is None:
                 out, self._state = self._decode_jit(self.engine.params,
                                                     self._state)
@@ -2233,42 +2264,50 @@ class ServingEngine:
             self.clock.advance(self.cfg.virtual_decode_step_cost)
         self.metrics.record_decode_dispatch()
         self._decode_dispatches[self.attn_backend] += 1
-        self._dispatch_chunk_ahead()
-        self._dispatch_decode_ahead()
+        if self.chunked:
+            with span("ahead", cat="serving"):
+                self._dispatch_chunk_ahead()
+                self._dispatch_decode_ahead()
         # one read-back for all the step hands out (a routing model: its
         # expert choices too)
-        toks, done_now, nonfinite, sampled, *routed = jax.device_get(out)
-        self.metrics.record_sampler_step(bool(sampled))
-        if routed:
-            self._book_decode_routing(routed[0], live)
-        now = self.clock.now()
-        self.metrics.record_health_step(
-            sum(1 for s in live if nonfinite[s] > 0))
-        for slot in sorted(live):
-            req = live[slot]
-            t = int(toks[slot])
-            if self._health_shed and nonfinite[slot] > 0:
-                self._shed_unhealthy(req, events, now, int(nonfinite[slot]))
-                continue
-            req.tokens.append(t)
-            self.metrics.record_tokens(1, req)
-            self.metrics.record_decode_tokens(1)
-            if bool(done_now[slot]):
-                reason = FINISH_EOS if (req.eos_token_id is not None
-                                        and t == req.eos_token_id) \
-                    else FINISH_LENGTH
-            elif t in req.stop_token_ids:
-                # stop sequences are host-side policy (a set, not the single
-                # device-tracked eos id): finish here and deactivate the slot
-                reason = FINISH_STOP
-            else:
+        with span("read_back", cat="serving"):
+            toks, done_now, nonfinite, sampled, *routed = jax.device_get(out)
+        with span("book", cat="serving"):
+            self.metrics.record_sampler_step(bool(sampled))
+            if routed:
+                self._book_decode_routing(routed[0], live)
+            now = self.clock.now()
+            self.metrics.record_health_step(
+                sum(1 for s in live if nonfinite[s] > 0))
+            for slot in sorted(live):
+                req = live[slot]
+                t = int(toks[slot])
+                if self._health_shed and nonfinite[slot] > 0:
+                    self._shed_unhealthy(req, events, now,
+                                         int(nonfinite[slot]))
+                    continue
+                req.tokens.append(t)
+                self.metrics.record_tokens(1, req)
+                self.metrics.record_decode_tokens(1)
+                if bool(done_now[slot]):
+                    reason = FINISH_EOS if (req.eos_token_id is not None
+                                            and t == req.eos_token_id) \
+                        else FINISH_LENGTH
+                elif t in req.stop_token_ids:
+                    # stop sequences are host-side policy (a set, not the
+                    # single device-tracked eos id): finish here and
+                    # deactivate the slot
+                    reason = FINISH_STOP
+                else:
+                    events.append(TokenEvent(req.request_id, t,
+                                             len(req.tokens) - 1, False,
+                                             None, now))
+                    continue
+                self._finish(req, reason, now)
                 events.append(TokenEvent(req.request_id, t,
-                                         len(req.tokens) - 1, False, None,
+                                         len(req.tokens) - 1, True, reason,
                                          now))
-                continue
-            self._finish(req, reason, now)
-            events.append(TokenEvent(req.request_id, t, len(req.tokens) - 1,
-                                     True, reason, now))
+            self._drain_prefill_loads()
 
     def _book_decode_routing(self, routed, live):
         """``routed`` [L_moe, S, 2k]: what this decode step's expert layers

@@ -87,19 +87,13 @@ def phase_table(events, step_key="step"):
 
 
 def counters_by_step(events, name):
-    """Latest value of counter/scalar events named ``name`` per step.
-
-    Accepts both tracer counter events (``ph == "C"`` with a ``step`` arg)
-    and ``TraceFileMonitor`` scalar rows (``{"name", "value", "step"}``)."""
+    """Latest value per step of the ``TraceFileMonitor`` scalar rows
+    (``{"name", "value", "step"}``) named ``name``."""
     out = {}
     for e in events:
         if e.get("name") != name:
             continue
-        if e.get("ph") == "C":
-            step = e.get("args", {}).get("step")
-            value = e.get("args", {}).get("value")
-        else:
-            step, value = e.get("step"), e.get("value")
+        step, value = e.get("step"), e.get("value")
         if step is not None and value is not None:
             out[step] = float(value)
     return out

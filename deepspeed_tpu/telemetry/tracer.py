@@ -21,8 +21,14 @@ execution, not enqueue. The serving tracer instead runs against the
 scheduler's own clock (wall or virtual), which is what makes trace-derived
 TTFT/TPOT bit-identical to ``ServingMetrics`` under the virtual clock.
 
-The tracer is deliberately cheap when disabled (one attribute check, a
-shared null span) so it can stay in the hot loops unconditionally.
+Every span is also a ``jax.profiler.TraceAnnotation`` named
+``<cat>/<name>`` (a name that carries its own prefix, ``checkpoint/save``,
+keeps it), so a profiler trace shows the engine's phases on the device
+trace's own clock (``serving/step``, ``serving/read_back``, ``train/step``,
+...) and an idle gap of the device can be put down to what the host was
+doing. A disabled tracer records nothing but still emits that annotation:
+about half a microsecond a span with the profiler off, so the spans stay in
+the hot loops unconditionally.
 """
 
 import json
@@ -30,28 +36,25 @@ import os
 import threading
 import time
 
+from jax.profiler import TraceAnnotation
+
 from ..utils.logging import logger
 
 
-class _NullSpan:
-    """Reusable no-op span for disabled tracers."""
+def profiler_name(name, cat):
+    """The span's name in a profiler trace: ``<cat>/<name>``, or the name
+    itself where it carries its own prefix."""
+    return name if "/" in name else f"{cat}/{name}"
 
-    __slots__ = ()
 
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
+class _ProfilerSpan(TraceAnnotation):
+    """A disabled tracer's span: the profiler annotation alone."""
 
     def fence(self, value):
         pass
 
     def set(self, **args):
         pass
-
-
-_NULL_SPAN = _NullSpan()
 
 
 def event_to_chrome(e, pid=0):
@@ -66,13 +69,12 @@ def event_to_chrome(e, pid=0):
         ev["dur"] = e.get("dur", 0.0) * 1e6
     elif e["ph"] == "i":
         ev["s"] = "t"
-    elif e["ph"] == "C":
-        ev["args"] = {e["name"]: e.get("args", {}).get("value", 0.0)}
     return ev
 
 
 class _Span:
-    __slots__ = ("tracer", "name", "cat", "sync", "args", "t0", "_fence")
+    __slots__ = ("tracer", "name", "cat", "sync", "args", "t0", "_fence",
+                 "_annotation")
 
     def __init__(self, tracer, name, cat, sync, args):
         self.tracer = tracer
@@ -82,6 +84,7 @@ class _Span:
         self.args = args
         self.t0 = None
         self._fence = None
+        self._annotation = TraceAnnotation(profiler_name(name, cat))
 
     def fence(self, value):
         """Register device value(s) to ``block_until_ready`` at span end
@@ -94,6 +97,7 @@ class _Span:
         self.args.update(args)
 
     def __enter__(self):
+        self._annotation.__enter__()
         self.t0 = self.tracer._now()
         self.tracer._stack().append(self)
         return self
@@ -116,6 +120,7 @@ class _Span:
             "ts": self.t0, "dur": t1 - self.t0,
             "depth": len(stack), "parent": parent, "args": args,
         })
+        self._annotation.__exit__(exc_type, exc, tb)
         return False
 
 
@@ -153,11 +158,12 @@ class SpanTracer:
     @classmethod
     def from_config(cls, cfg, clock=None, sync_fn=None, meta=None):
         """Build from a ``telemetry`` config block (None/disabled -> a
-        null tracer whose spans cost one attribute check). Multi-process
-        runs write per-rank trace dirs (``<job_name>-rank<N>`` past rank
-        0): a shared ``trace.json`` is whole-file rewritten and a shared
-        ``spans.jsonl`` is truncated by each process's first flush, so
-        same-path writers would clobber each other."""
+        tracer that records nothing: its spans are profiler annotations
+        only). Multi-process runs write per-rank trace dirs
+        (``<job_name>-rank<N>`` past rank 0): a shared ``trace.json`` is
+        whole-file rewritten and a shared ``spans.jsonl`` is truncated by
+        each process's first flush, so same-path writers would clobber each
+        other."""
         if cfg is None or not getattr(cfg, "enabled", False):
             return cls(enabled=False)
         job = cfg.job_name
@@ -220,9 +226,11 @@ class SpanTracer:
     def span(self, name, cat="host", sync=False, **args):
         """Context manager recording one complete span. ``sync=True`` fences
         the device (``sp.fence(x)`` value, else the tracer's ``sync_fn``)
-        before the end timestamp."""
+        before the end timestamp. Enabled or not, the span is also a
+        profiler annotation (``profiler_name``); ``args`` go to the record
+        only."""
         if not self.enabled:
-            return _NULL_SPAN
+            return _ProfilerSpan(profiler_name(name, cat))
         return _Span(self, name, cat, sync, args)
 
     def instant(self, name, cat="mark", ts=None, **args):
@@ -233,17 +241,6 @@ class SpanTracer:
             "ph": "i", "name": name, "cat": cat,
             "ts": self._now() if ts is None else ts, "dur": 0.0,
             "depth": len(self._stack()), "parent": None, "args": args,
-        })
-
-    def counter(self, name, value, ts=None, **args):
-        """Counter sample (rendered as a track in Perfetto)."""
-        if not self.enabled:
-            return
-        self._record({
-            "ph": "C", "name": name, "cat": "counter",
-            "ts": self._now() if ts is None else ts, "dur": 0.0,
-            "depth": 0, "parent": None,
-            "args": dict(args, value=float(value)),
         })
 
     # ------------------------------------------------------------- emission

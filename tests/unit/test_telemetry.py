@@ -88,7 +88,6 @@ def test_chrome_trace_schema_valid(tmp_path):
     tr = SpanTracer(clock=FakeClock())
     with tr.span("phase", cat="train", step=3):
         tr.instant("tick")
-    tr.counter("queue_depth", 4, step=3)
     path = tr.write_chrome_trace(str(tmp_path / "trace.json"))
     blob = json.load(open(path))  # must round-trip as plain JSON
     evs = blob["traceEvents"]
@@ -101,8 +100,6 @@ def test_chrome_trace_schema_valid(tmp_path):
         assert e["dur"] >= 0
     inst = [e for e in evs if e["ph"] == "i"]
     assert inst and inst[0]["s"] == "t"
-    ctr = [e for e in evs if e["ph"] == "C"]
-    assert ctr and ctr[0]["args"] == {"queue_depth": 4.0}
     # span ts/dur are microseconds of the 1-tick clock
     assert complete[0]["dur"] == pytest.approx(2e6)
 
