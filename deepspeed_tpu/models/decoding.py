@@ -242,14 +242,14 @@ def forward_with_paged_cache(model, params, input_ids, pool, table, pos,
     if cfg.latent_attention:
         from . import latent
 
-        if draft_len is not None or "k_scale" in pool or kernel:
+        if draft_len is not None or "k_scale" in pool:
             raise ValueError(
-                "latent attention decodes in the absorbed form through its "
-                "own view over a pool in the engine's dtype: speculative "
-                "verify, an int8 pool and the decode kernel are not "
-                "implemented")
+                "latent attention decodes in the absorbed form over a pool "
+                "in the engine's dtype: speculative verify and an int8 pool "
+                "are not implemented")
         logits, pool, ids = latent.forward_with_paged_cache(
-            model, params, input_ids, pool, table, pos, block_size)
+            model, params, input_ids, pool, table, pos, block_size,
+            kernel=kernel)
         return (logits, pool, ids) if return_routing else (logits, pool)
     b, q_len = input_ids.shape
     if kernel and (draft_len is not None or q_len != 1):

@@ -16,7 +16,10 @@ sqrt(dn + dr)``):
   expanded from the latent rows a block of positions at a time, so a chunk
   that attends to a 16k-token prefix never holds that prefix's K and V;
 - absorbed (decode): ``W_uk`` is folded into the query and ``W_uv`` applied
-  after the weighted sum, so the 32 heads read each 576-wide row once.
+  after the weighted sum, so the 32 heads read each 576-wide row once: in
+  place, by the latent form of the decode kernel, where the engine's probe
+  allows it, else from a view of the slots' rows gathered through the
+  block table.
 
 The cache rides the layer loop as its carry and is read and written at
 ``[layer, ...]``: a stack of unlike layers cannot scan the cache as ``xs``
@@ -76,7 +79,8 @@ def project(cfg, p, h, rope):
     return q[..., :dn], q_rope, c, k_rope
 
 
-def _scale(cfg):
+def score_scale(cfg):
+    """``1 / sqrt(qk_nope + qk_rope)``, unless the config names one."""
     return cfg.attn_scale if cfg.attn_scale is not None else 1.0 / math.sqrt(
         cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)
 
@@ -110,7 +114,7 @@ def expanded_attention(cfg, p, q_nope, q_rope, c_ctx, kr_ctx, q_start,
     dtype = q_nope.dtype
     prec = _prec(dtype)
     w = _kv_b(cfg, p).astype(dtype)
-    scale = _scale(cfg)
+    scale = score_scale(cfg)
     q_idx = q_start + jnp.arange(q_len)
     blk = min(KV_BLOCK, kv)
     n_blocks = -(-kv // blk)
@@ -189,7 +193,7 @@ def _expanded_kernel(cfg, w, q_nope, q_rope, c_ctx, kr_ctx, q_start,
             kr[:, None].astype(dtype), (b, H, blk, dr))], -1)
         return C.chunk_attention_block(
             q, k, kv_h[..., dn:], carry, q_start, start, start, rep=1,
-            scale=_scale(cfg), interpret=cfg.attention_interpret)
+            scale=score_scale(cfg), interpret=cfg.attention_interpret)
 
     carry = C.initial_carry(b, H, q_len, dv)
     if n_blocks == 1:
@@ -204,6 +208,43 @@ def absorbed_attention(cfg, p, q_nope, q_rope, c_ctx, kr_ctx, pos):
     pos]``: ``q_lat = W_uk^T q_nope``, scores against ``c`` and ``k_rope``,
     ``o = W_uv (sum p c)``. q_nope [S, H, dn], q_rope [S, H, dr]; c_ctx
     [S, kv, r], kr_ctx [S, kv, dr]; pos [S]. Returns [S, H * dv]."""
+    prec = _prec(q_nope.dtype)
+
+    def attend(q_lat):
+        s = (jnp.einsum("shr,skr->shk", q_lat, c_ctx, precision=prec,
+                        preferred_element_type=F32)
+             + jnp.einsum("shd,skd->shk", q_rope, kr_ctx, precision=prec,
+                          preferred_element_type=F32)) * score_scale(cfg)
+        allowed = jnp.arange(c_ctx.shape[1])[None, :] <= pos[:, None]
+        s = jnp.where(allowed[:, None, :], s, jnp.finfo(F32).min)
+        probs = jax.nn.softmax(s, axis=-1).astype(q_lat.dtype)
+        return jnp.einsum("shk,skr->shr", probs, c_ctx, precision=prec)
+
+    return _absorbed(cfg, p, q_nope, attend)
+
+
+def absorbed_attention_paged(cfg, p, q_nope, q_rope, c, k_rope, pool, table,
+                             pos, layer):
+    """``absorbed_attention`` straight against the paged pool: the latent
+    form of ``ops/pallas/paged_attention.py`` walks each slot's block table
+    and reads the latent rows below its cursor, with the current token's
+    row (``c`` [S, r], ``k_rope`` [S, dr]) beside them. ``pool``: the leaves
+    whole, ``layer`` the traced layer to read. Returns [S, H * dv]."""
+    from ..ops.pallas.paged_attention import paged_latent_decode
+
+    def attend(q_lat):
+        return paged_latent_decode(
+            q_lat, q_rope, c, k_rope, pool["k"], pool["v"], table, pos,
+            layer=layer, scale=score_scale(cfg),
+            interpret=cfg.attention_interpret, mesh=cfg.mesh)
+
+    return _absorbed(cfg, p, q_nope, attend)
+
+
+def _absorbed(cfg, p, q_nope, attend):
+    """``q_lat = W_uk^T q_nope``, ``o_lat = attend(q_lat)`` [S, H, r], ``o =
+    W_uv o_lat``: the absorbed form around either way of attending, under
+    the scope the benchmark's trace reduction reads."""
     S, H, dn = q_nope.shape
     dtype = q_nope.dtype
     prec = _prec(dtype)
@@ -211,14 +252,7 @@ def absorbed_attention(cfg, p, q_nope, q_rope, c_ctx, kr_ctx, pos):
     with jax.named_scope("latent_attn_absorbed"):
         q_lat = jnp.einsum("shd,rhd->shr", q_nope, w[..., :dn],
                            precision=prec)
-        s = (jnp.einsum("shr,skr->shk", q_lat, c_ctx, precision=prec,
-                        preferred_element_type=F32)
-             + jnp.einsum("shd,skd->shk", q_rope, kr_ctx, precision=prec,
-                          preferred_element_type=F32)) * _scale(cfg)
-        allowed = jnp.arange(c_ctx.shape[1])[None, :] <= pos[:, None]
-        s = jnp.where(allowed[:, None, :], s, jnp.finfo(F32).min)
-        probs = jax.nn.softmax(s, axis=-1).astype(dtype)
-        o_lat = jnp.einsum("shk,skr->shr", probs, c_ctx, precision=prec)
+        o_lat = attend(q_lat)
         out = jnp.einsum("shr,rhd->shd", o_lat, w[..., dn:], precision=prec)
     return out.reshape(S, H * cfg.v_head_dim)
 
@@ -370,13 +404,15 @@ def forward_with_cache(model, params, input_ids, cache, pos, kv_len,
 
 
 def forward_with_paged_cache(model, params, input_ids, pool, table, pos,
-                             block_size):
+                             block_size, kernel=False):
     """``decoding.forward_with_paged_cache`` for a latent-attention model:
-    one decode step ([S, 1] tokens) in the absorbed form against the slot's
-    latent rows gathered through the block table; each slot's new row is
-    scattered into the pool at (table[s, pos // bs], pos % bs), freed slots
-    into the garbage block. Returns (logits [S, 1, vocab], pool, routed
-    [L_moe, S, 1, 2k] or None)."""
+    one decode step ([S, 1] tokens) in the absorbed form; each slot's new
+    row is scattered into the pool at (table[s, pos // bs], pos % bs), freed
+    slots into the garbage block. ``kernel``: the decode kernel reads the
+    live latent rows in place (``absorbed_attention_paged``); else the
+    slots' latent rows are gathered through the block table into a view of
+    ``n_slots x max_len`` rows a layer. Returns (logits [S, 1, vocab], pool,
+    routed [L_moe, S, 1, 2k] or None)."""
     cfg = model.config
     S, q_len = input_ids.shape
     if q_len != 1:
@@ -394,8 +430,26 @@ def forward_with_paged_cache(model, params, input_ids, pool, table, pos,
             with jax.named_scope("latent_row_write"):
                 kc = pool["k"].at[layer, bi, off, 0].set(
                     c[:, 0].astype(pool["k"].dtype))
-                vc = pool["v"].at[layer, bi, off, 0].set(
-                    k_rope[:, 0].astype(pool["v"].dtype))
+                # the rope key's whole block is read, given the row and
+                # written back: the device keeps this leaf with its tokens
+                # in the lanes (as the decode kernel reads it), and a
+                # scatter of one 64-wide row can have the compiler re-lay
+                # the whole leaf out with its rows there and back (it does
+                # at 3 of kanana2's layers). A slot's write block is its
+                # own; slots parked on the garbage block write garbage,
+                # whichever of them wins
+                blocks = pool["v"][layer, bi]              # [S, bs, 1, dr]
+                mine = jnp.arange(block_size)[None, :, None, None] \
+                    == off[:, None, None, None]
+                vc = pool["v"].at[layer, bi].set(jnp.where(
+                    mine, k_rope[:, 0, None, None].astype(blocks.dtype),
+                    blocks))
+            if kernel:
+                out = absorbed_attention_paged(
+                    cfg_l, p_attn, q_nope[:, 0], q_rope[:, 0], c[:, 0],
+                    k_rope[:, 0], {"k": kc, "v": vc}, table, pos, layer)
+                return (L.linear_apply(p_attn["o"], out[:, None]),
+                        {"k": kc, "v": vc})
             with jax.named_scope("latent_view_gather"):
                 c_ctx = kc[layer, table][:, :, :, 0].reshape(
                     S, -1, kc.shape[-1]).astype(c.dtype)

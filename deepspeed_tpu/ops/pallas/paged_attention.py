@@ -60,6 +60,19 @@ a learned logit with no key and no value) enters the running maximum and sum
 beside the current token's row and adds nothing to the output. With equal
 widths and no sink the program is the one it was before either existed.
 
+The LATENT form (``paged_latent_decode``: latent attention, MLA, in its
+absorbed form, ``models/latent.py``) is the same walk over other operands.
+The pool holds one latent row ``c`` a token (``k`` leaf, 512 wide) and one
+rotated key ``k_rope`` every head shares (``v`` leaf, 64 wide), so one K/V
+"head" serves all query heads. The scores are ``q_lat . c + q_rope .
+k_rope``, and the value rows are the latent rows themselves: the ``k`` chunk
+buffer feeds both products, the rope buffer the scores alone. The rope leaf
+is read token-minor, ``[dr, block]`` a block, which is how the device keeps
+a leaf that ends in 64 lanes at blocks of 128, so no copy of it is made. The
+output is the slot's ``o_lat`` ``[n_heads, 512]``; ``W_uv`` is applied by
+the caller. Its chunk is sized from the row's bytes (``CHUNK_BYTES``). With
+no latent operands the program is the one it was before the form existed.
+
 An int8 pool, several query rows a slot (speculative verify) and GPT-Neo's
 per-layer traced local flags take the view path (``fused_decode_supported``
 says why).
@@ -79,6 +92,10 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 CHUNK_TOKENS = 256
+# the latent form's chunk: as many bytes as CHUNK_TOKENS of OPT-1.3B's rows
+# (2 x 2048 bf16 a token) carry, whole blocks of it: its rows are a seventh
+# as wide, and a chunk of 256 of them is too little work a step
+CHUNK_BYTES = 2 << 20
 
 
 def fused_decode_supported(cfg, block_size, *, n_slots=8, blocks_per_slot=8,
@@ -108,6 +125,10 @@ def fused_decode_supported(cfg, block_size, *, n_slots=8, blocks_per_slot=8,
     """
     from . import compiler_verdict, unavailable_reason
 
+    if cfg.latent_attention:
+        return _latent_decode_supported(cfg, block_size, n_slots,
+                                        blocks_per_slot, kv_dtype, n_layers,
+                                        n_blocks)
     if cfg.local_attention_window > 0:
         return False, ("local_attention_window > 0: a layer's kind is a "
                        "traced flag there, and the decode kernel's band is "
@@ -152,9 +173,46 @@ def fused_decode_supported(cfg, block_size, *, n_slots=8, blocks_per_slot=8,
     return ok, reason and f"TPU compiler: {reason}"
 
 
+def _latent_decode_supported(cfg, block_size, n_slots, blocks_per_slot,
+                             kv_dtype, n_layers, n_blocks):
+    """``fused_decode_supported`` for a latent-attention model: the latent
+    form put to the compiler at the pool's 5-D leaves ``[L, n_blocks, bs, 1,
+    width]`` as the engine holds them, with the scale and chunk the decode
+    program uses, so that the probe's trace is the program's."""
+    from ...models.latent import score_scale
+    from . import compiler_verdict, unavailable_reason
+
+    if kv_dtype:
+        return False, (f"a {kv_dtype} pool: the decode kernel reads a pool "
+                       "in the engine's dtype")
+    if cfg.attention_interpret:
+        return True, ""
+    reason = unavailable_reason()
+    if reason is not None:
+        return False, reason
+    sds = jax.ShapeDtypeStruct
+    dt = cfg.compute_dtype
+    H, r, dr = cfg.n_heads, cfg.kv_lora_rank, cfg.qk_rope_head_dim
+    pool = lambda width: sds(
+        (n_layers or cfg.n_layers,
+         n_blocks or n_slots * blocks_per_slot + 1, block_size, 1, width), dt)
+
+    def call(q_lat, q_rope, c_new, kr_new, kc, krc, table, pos, layer):
+        return paged_latent_decode(q_lat, q_rope, c_new, kr_new, kc, krc,
+                                   table, pos, layer=layer,
+                                   scale=score_scale(cfg))
+
+    ok, reason = compiler_verdict(
+        call, sds((n_slots, H, r), dt), sds((n_slots, H, dr), dt),
+        sds((n_slots, r), dt), sds((n_slots, dr), dt), pool(r), pool(dr),
+        sds((n_slots, blocks_per_slot), jnp.int32),
+        sds((n_slots,), jnp.int32), sds((), jnp.int32))
+    return ok, reason and f"TPU compiler: {reason}"
+
+
 def _decode_kernel(layer_ref, table_ref, pos_ref, q_ref, kn_ref, vn_ref,
                    *rest, scale, block_size, chunk_blocks, head_dim, alibi,
-                   window, ring, sink=False):
+                   window, ring, sink=False, latent=False):
     """One slot: walk its live blocks chunk by chunk, fold each chunk into
     the running (m, l, acc), emit the slot's normalized output rows.
 
@@ -170,11 +228,18 @@ def _decode_kernel(layer_ref, table_ref, pos_ref, q_ref, kn_ref, vn_ref,
     outlives a grid step: the next live slot's first chunk is already in
     flight when its step begins). ``window`` > 0: the valid pool window is
     ``[max(pos - window + 1, 0), pos)`` and the walk starts at its chunk;
-    ``ring``: block ``j`` sits at table column ``j % n_cols``."""
+    ``ring``: block ``j`` sits at table column ``j % n_cols``.
+    ``latent``: ``q_ref`` [1, n_heads, r] is ``q_lat`` and ``qr_ref`` [1,
+    n_heads, dr] (the last operand before the pool) ``q_rope``, ``kn_ref`` /
+    ``vn_ref`` the fresh ``c`` / ``k_rope``; ``v_hbm`` is the rope leaf
+    token-minor, [L, n_blocks, dr, bs], and ``vbuf`` [2, dr, chunk]; the
+    value rows are ``kbuf``'s and ``o_ref`` [1, n_heads, r] is ``o_lat``."""
     if alibi:
         slopes_ref, rest = rest[0], rest[1:]
     if sink:
         sink_ref, rest = rest[0], rest[1:]
+    if latent:
+        qr_ref, rest = rest[0], rest[1:]
     k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, acc_scr, cur_ref = rest
     n_slots, n_cols = table_ref.shape
     n_heads, width = q_ref.shape[1], o_ref.shape[2]
@@ -211,7 +276,9 @@ def _decode_kernel(layer_ref, table_ref, pos_ref, q_ref, kn_ref, vn_ref,
                 act(pltpu.make_async_copy(
                     k_hbm.at[layer, blk], kbuf.at[buf, rows], sem.at[0, buf]))
                 act(pltpu.make_async_copy(
-                    v_hbm.at[layer, blk], vbuf.at[buf, rows], sem.at[1, buf]))
+                    v_hbm.at[layer, blk],
+                    vbuf.at[buf, :, rows] if latent else vbuf.at[buf, rows],
+                    sem.at[1, buf]))
 
     start = lambda slot, c, buf: for_live_blocks(
         slot, c, buf, lambda copy: copy.start())
@@ -236,8 +303,11 @@ def _decode_kernel(layer_ref, table_ref, pos_ref, q_ref, kn_ref, vn_ref,
     def _first():
         # a masked token's probability is exactly 0, and 0 x what a fresh
         # V buffer holds must be 0: only KV (or these zeros) is ever in one.
-        # (K needs none: a masked score is replaced, whatever it was.)
-        vbuf[...] = jnp.zeros_like(vbuf)
+        # (K needs none: a masked score is replaced, whatever it was. The
+        # latent form's value rows are its K buffer's, and its rope buffer
+        # feeds the scores alone.)
+        values = kbuf if latent else vbuf
+        values[...] = jnp.zeros_like(values)
         cur_ref[0] = 0
         start_next_live(-1, 0)
 
@@ -249,9 +319,16 @@ def _decode_kernel(layer_ref, table_ref, pos_ref, q_ref, kn_ref, vn_ref,
         q = q_ref[0]                                           # [nh, W]
         # the current token's own row (position pos, alibi distance 0)
         m0 = jnp.sum(q.astype(jnp.float32) * kn_ref[0].astype(jnp.float32),
-                     axis=-1, keepdims=True) * scale           # [nh, 1]
-        acc_scr[...] = jnp.broadcast_to(vn_ref[0].astype(jnp.float32),
-                                        acc_scr.shape)
+                     axis=-1, keepdims=True)                   # [nh, 1]
+        if latent:
+            qr = qr_ref[0]                                     # [nh, dr]
+            m0 = m0 + jnp.sum(qr.astype(jnp.float32)
+                              * vn_ref[0].astype(jnp.float32),
+                              axis=-1, keepdims=True)
+        m0 = m0 * scale
+        acc_scr[...] = jnp.broadcast_to(
+            (kn_ref if latent else vn_ref)[0].astype(jnp.float32),
+            acc_scr.shape)
         l0 = None
         if sink:
             # the sink's logit joins the maximum and the sum beside the
@@ -274,9 +351,14 @@ def _decode_kernel(layer_ref, table_ref, pos_ref, q_ref, kn_ref, vn_ref,
                 start_next_live(s, 1 - buf)
 
             wait(s, c, buf)
+            k = kbuf[buf]                                      # [chunk, W]
             sc = jax.lax.dot_general(
-                q, kbuf[buf], (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale    # [nh, chunk]
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)            # [nh, chunk]
+            if latent:
+                sc = sc + jnp.dot(qr, vbuf[buf],
+                                  preferred_element_type=jnp.float32)
+            sc = sc * scale
             t = c * chunk + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
             if alibi:
                 # slopes * (kv_pos - cursor): the same int difference, then
@@ -289,7 +371,7 @@ def _decode_kernel(layer_ref, table_ref, pos_ref, q_ref, kn_ref, vn_ref,
             m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
             p = jnp.exp(sc - m_new)
             corr = jnp.exp(m_prev - m_new)
-            v = vbuf[buf]                                      # [chunk, W]
+            v = k if latent else vbuf[buf]                     # [chunk, W]
             if v.dtype == jnp.bfloat16:
                 hi = p.astype(jnp.bfloat16)
                 lo = (p - hi.astype(jnp.float32)).astype(jnp.bfloat16)
@@ -309,6 +391,10 @@ def _decode_kernel(layer_ref, table_ref, pos_ref, q_ref, kn_ref, vn_ref,
         # head h keeps its own kv group's lanes: row j of the output holds
         # head g * hq + j in the lanes of group g
         acc = acc_scr[...] / l_fin
+        if latent:
+            # one K/V row every head shares: row h is head h's
+            o_ref[0] = acc
+            return
         head = jax.lax.broadcasted_iota(jnp.int32, (n_heads, width), 0)
         group = jax.lax.broadcasted_iota(
             jnp.int32, (n_heads, width), 1) // head_dim
@@ -403,45 +489,123 @@ def _paged_flash_decode(q, k_new, v_new, kc, vc, table, pos, layer, slopes,
         .reshape(s_dim, n_heads, width).astype(kc.dtype)
     row = lambda a: a.reshape(s_dim, 1, -1)
 
-    per_slot = lambda *shape: pl.BlockSpec(
-        (1,) + shape, lambda s, *_: (s,) + (0,) * len(shape))
-    in_specs = [per_slot(n_heads, width), per_slot(1, width),
-                per_slot(1, width_v)]
+    in_specs = [_per_slot(n_heads, width), _per_slot(1, width),
+                _per_slot(1, width_v)]
     operands = [q_bd, row(k_new), row(v_new)]
     for per_head in (slopes, sink):
         if per_head is not None:
             in_specs.append(pl.BlockSpec((n_heads, 1), lambda s, *_: (0, 0)))
             operands.append(per_head.reshape(n_heads, 1))
-    in_specs += [pl.BlockSpec(memory_space=pl.ANY)] * 2
-    operands += [kc, vc]
 
     chunk = chunk_blocks * block_size
-    kernel = functools.partial(
-        _decode_kernel, scale=scale, block_size=block_size,
-        chunk_blocks=chunk_blocks, head_dim=dv, alibi=alibi,
-        window=int(window), ring=bool(ring), sink=sink is not None)
-    out = pl.pallas_call(
-        kernel,
+    out = _call(
+        dict(scale=scale, block_size=block_size, chunk_blocks=chunk_blocks,
+             head_dim=dv, alibi=alibi, window=int(window), ring=bool(ring),
+             sink=sink is not None),
+        in_specs, operands, kc, vc,
+        [pltpu.VMEM((2, chunk, width), kc.dtype),
+         pltpu.VMEM((2, chunk, width_v), vc.dtype)],
+        (hq, width_v), layer, table, pos, interpret)
+    # [S, hq, kvh, dh] -> head h = g * hq + j
+    return out.reshape(s_dim, hq, kvh, dv).transpose(0, 2, 1, 3) \
+        .reshape(s_dim, n_heads, dv).astype(q.dtype)
+
+
+def _per_slot(*shape):
+    """A block of one slot's rows of a ``[S, *shape]`` operand."""
+    return pl.BlockSpec((1,) + shape, lambda s, *_: (s,) + (0,) * len(shape))
+
+
+def _call(kernel_kw, in_specs, operands, kc, vc, buffers, out_rows, layer,
+          table, pos, interpret):
+    """The one ``pallas_call`` of every form: a grid step a slot, the layer,
+    table and cursors scalar-prefetched, the pool leaves ``kc`` / ``vc``
+    whole in HBM after ``operands``, the two chunk ``buffers`` beside the
+    DMA semaphores, the float32 accumulator and the buffer cursor. Returns
+    the float32 ``[S, *out_rows]``."""
+    n_heads, width_v = operands[0].shape[1], out_rows[1]
+    return pl.pallas_call(
+        functools.partial(_decode_kernel, **kernel_kw),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
-            grid=(s_dim,),
-            in_specs=in_specs,
-            out_specs=per_slot(hq, width_v),
-            scratch_shapes=[
-                pltpu.VMEM((2, chunk, width), kc.dtype),
-                pltpu.VMEM((2, chunk, width_v), vc.dtype),
+            grid=(table.shape[0],),
+            in_specs=in_specs + [pl.BlockSpec(memory_space=pl.ANY)] * 2,
+            out_specs=_per_slot(*out_rows),
+            scratch_shapes=buffers + [
                 pltpu.SemaphoreType.DMA((2, 2)),
                 pltpu.VMEM((n_heads, width_v), jnp.float32),
                 pltpu.SMEM((1,), jnp.int32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((s_dim, hq, width_v), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((table.shape[0],) + tuple(out_rows),
+                                       jnp.float32),
         # slots in order: a step starts the next live slot's first copies
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="paged_flash_decode",
-    )(layer, table, pos, *operands)
-    # [S, hq, kvh, dh] -> head h = g * hq + j
-    return out.reshape(s_dim, hq, kvh, dv).transpose(0, 2, 1, 3) \
-        .reshape(s_dim, n_heads, dv).astype(q.dtype)
+    )(layer, table, pos, *operands, kc, vc)
+
+
+def paged_latent_decode(q_lat, q_rope, c_new, kr_new, kc, krc, table, pos, *,
+                        layer, scale, chunk_tokens=None, interpret=False,
+                        mesh=None):
+    """The kernel's LATENT form: absorbed latent attention (MLA) for ONE
+    query row a slot over the paged pool of latent rows, reading only the
+    blocks below each slot's cursor.
+
+    - ``q_lat``: [S, H, r], the query folded through ``W_uk``; ``q_rope``:
+      [S, H, dr], its rotated part;
+    - ``c_new`` / ``kr_new``: [S, r] / [S, dr], the current token's normed
+      latent and rotated key (logically at position ``pos[s]``);
+    - ``kc`` / ``krc``: the pool's leaves whole, [L, n_blocks, bs, 1, r] and
+      [L, n_blocks, bs, 1, dr], and ``layer`` (a traced scalar) the layer to
+      read;
+    - ``table`` / ``pos``, ``chunk_tokens``, ``interpret``, ``mesh``: as
+      ``paged_flash_decode`` (``chunk_tokens`` None: ``CHUNK_BYTES`` of
+      rows). ``scale`` is the score scale, ``1 / sqrt(dn + dr)``.
+
+    Returns ``o_lat`` = softmax(scale (q_lat . c + q_rope . k_rope)) . c,
+    [S, H, r] in ``q_lat.dtype``.
+    """
+    from . import shard_kernel
+
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
+    kc, krc = (leaf.reshape(leaf.shape[:3] + (-1,)) for leaf in (kc, krc))
+    operands = [q_lat, q_rope, c_new, kr_new, kc, krc, table, pos, layer]
+    return shard_kernel(
+        functools.partial(_paged_latent_decode, scale=scale,
+                          chunk_tokens=chunk_tokens, interpret=interpret),
+        mesh, operands, [{}] * len(operands), [{}])
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "chunk_tokens",
+                                             "interpret"))
+def _paged_latent_decode(q_lat, q_rope, c_new, kr_new, kc, krc, table, pos,
+                         layer, *, scale, chunk_tokens, interpret):
+    """``paged_latent_decode`` on one device: a ``jax.jit`` of its own, so
+    that a program's layers, and the engine's probe before them, share one
+    trace and lowering."""
+    s_dim, n_heads, r = q_lat.shape
+    dr = q_rope.shape[2]
+    block_size = kc.shape[2]
+    dt = kc.dtype
+    if chunk_tokens is None:
+        chunk_tokens = CHUNK_BYTES // ((r + dr) * dt.itemsize)
+    chunk_blocks = max(1, min(chunk_tokens // block_size, table.shape[1]))
+    chunk = chunk_blocks * block_size
+    # the rope leaf token-minor, [L, n_blocks, dr, bs]: the device keeps a
+    # leaf that ends in 64 lanes with its 128 tokens there, so this is that
+    # layout's bitcast, and a block's rope keys are one [dr, bs] copy
+    krt = jnp.swapaxes(krc, 2, 3)
+    out = _call(
+        dict(scale=scale, block_size=block_size, chunk_blocks=chunk_blocks,
+             head_dim=r, alibi=False, window=0, ring=False, latent=True),
+        [_per_slot(n_heads, r), _per_slot(1, r), _per_slot(1, dr),
+         _per_slot(n_heads, dr)],
+        [q_lat.astype(dt), c_new.astype(dt).reshape(s_dim, 1, r),
+         kr_new.astype(dt).reshape(s_dim, 1, dr), q_rope.astype(dt)],
+        kc, krt,
+        [pltpu.VMEM((2, chunk, r), dt), pltpu.VMEM((2, dr, chunk), dt)],
+        (n_heads, r), layer, table, pos, interpret)
+    return out.astype(q_lat.dtype)

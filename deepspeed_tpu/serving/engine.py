@@ -310,15 +310,16 @@ class ServingEngine:
         """``(path, reason)`` of the decode program: the kernel where
         the model, the pool and the compiler allow it, else the view with
         the reason (``ops/pallas/paged_attention.fused_decode_supported``)."""
-        if self._latent:
-            return "view", ("latent attention decodes in the absorbed form "
-                            "over its own view (models/latent.py)")
         from ..ops.pallas.paged_attention import fused_decode_supported
 
         mcfg = engine.module.config
         probe = dict(n_slots=self.n_slots, tp=max(engine.mp_world_size, 1),
                      kv_dtype=self.cfg.kv_pool.kv_dtype)
         groups = {}
+        if self._latent:
+            # the latent form, probed at the pool the engine really holds
+            groups = {False: dict(n_layers=mcfg.n_layers,
+                                  n_blocks=self.pool_mgr.n_blocks)}
         if self._window:
             # each group probed at the leaves it really holds
             win, full = self._layer_groups
@@ -345,7 +346,7 @@ class ServingEngine:
         group of per-head K and V refuses here, by name, instead of
         computing something else. Latent attention: the cache holds one
         latent row a token, which only the pool in the engine's dtype, read
-        through its own view on one model shard, knows. Window and full
+        on one model shard, knows. Window and full
         attention layers: two block groups and two tables, which the prefix
         cache (it would share a ring's blocks, overwritten in place), the
         verify step, the int8 pool, growth, the scrub and the snapshot do

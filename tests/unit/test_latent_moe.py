@@ -318,10 +318,13 @@ SERVING = {"n_slots": 4, "max_len": 256, "max_prefills_per_step": 1,
                        "prefix_cache": True, "on_demand_growth": False}}
 
 
-def engine(serving=None, **kw):
+def engine(serving=None, interpret=False, **kw):
+    """``interpret``: the model's kernels run under the Pallas interpreter,
+    so that the engine's probe answers ``kernel`` here."""
     kw.setdefault("dtype", "float32")
     eng = deepspeed_tpu.init_inference(
-        get_model("kanana2", "tiny"), max_tokens=256, seed=3,
+        get_model("kanana2", "tiny", attention_interpret=interpret),
+        max_tokens=256, seed=3,
         prompt_bucket_size=16, prompt_bucket_policy="pow2",
         serving=serving or SERVING, **kw)
     latent_serve_loop.seed_selection_bias(eng.params, 3, 0.02)
@@ -449,27 +452,151 @@ def test_configuration_written_before_pr31_still_loads_for_a_latent_model(
         stale):
     """A ``kv_pool`` block with the two keys the program dropped (as
     ``benchmark/configs/kanana-2-30b-a3b-serve.json`` carries them) loads
-    through ``init_inference``, warns once a key, and the latent model
-    serves through its own view, as without them; the snapshot says which
-    path that is and why. The decode kernel asked for by name (the engine
-    never does) is refused."""
+    through ``init_inference`` and warns once a key; the latent model's
+    decode then takes the kernel, as without them, the snapshot says so and
+    counts every decode under it, and ``kernel=True`` at the call is served,
+    equal to the view."""
     from .conftest import STALE_KV_KEYS, unknown_key_warnings
 
     with unknown_key_warnings() as seen:
-        eng = engine(refused(kv_pool=stale))
+        eng = engine(refused(kv_pool=stale), interpret=True)
     assert sorted(seen) == STALE_KV_KEYS
     sv = eng.serving
-    assert sv.attn_backend == "view" and "latent attention" in sv.attn_reason
+    assert (sv.attn_backend, sv.attn_reason) == ("kernel", "")
+    req = sv.submit(Request(prompt=token_ids(40), max_new_tokens=4))
+    while req.state is not RequestState.FINISHED:
+        sv.step()
     kv = sv.metrics.snapshot()["kv_pool"]
-    assert kv["attention_backend"] == "view"
-    assert kv["decode_dispatches"] == {"kernel": 0, "view": 0}
+    assert kv["attention_backend"] == "kernel"
+    assert kv["decode_dispatches"] == {"kernel": sv.metrics.decode_dispatches,
+                                       "view": 0}
+    assert sv.metrics.decode_dispatches > 0
     model = eng.module
     pool = D.init_paged_cache(model.config, 3, 16, jnp.float32)
-    with pytest.raises(ValueError, match="decode kernel"):
-        D.forward_with_paged_cache(
-            model, eng.params, jnp.zeros((1, 1), jnp.int32), pool,
-            jnp.zeros((1, 2), jnp.int32), jnp.asarray([3], jnp.int32), 16,
-            kernel=True)
+    step = lambda kernel: D.forward_with_paged_cache(
+        model, eng.params, jnp.zeros((1, 1), jnp.int32), pool,
+        jnp.asarray([[1, 2]], jnp.int32), jnp.asarray([3], jnp.int32), 16,
+        kernel=kernel)
+    (lk, pk), (lv, pv) = step(True), step(False)
+    np.testing.assert_allclose(np.asarray(lk), np.asarray(lv), atol=1e-5)
+    for name in pk:
+        # a layer's rows follow the attention of the layer below
+        np.testing.assert_allclose(np.asarray(pk[name]), np.asarray(pv[name]),
+                                   atol=1e-5)
+    eng.destroy()
+
+
+def test_greedy_streams_are_equal_through_the_kernel_and_the_view():
+    """The tiny kanana2 in float32: prefill two prompts, insert their blocks,
+    then greedy decode steps through the decode kernel's latent form and
+    through the view (``kernel=False`` at the call), each fed its own
+    choices; beside them a slot parked on the garbage block at cursor 0.
+    The streams are equal, the logits and the pools within rounding."""
+    model, params, _ = build(jnp.float32, attention_interpret=True)
+    cfg = model.config
+    bs, n_blocks, max_len, steps = 16, 17, 64, 12
+    prompts = [token_ids(37, seed=5), token_ids(16, seed=6)]
+    table = jnp.asarray([[3, 5, 1, 7], [2, 9, 11, 4], [0, 0, 0, 0]],
+                        jnp.int32)
+
+    @jax.jit
+    def prefill(params, ids):
+        cache = D.init_cache(cfg, 1, max_len, jnp.float32)
+        return D.forward_with_cache(model, params, ids, cache, 0, max_len,
+                                    prefill=True)
+
+    def decode(kernel):
+        return jax.jit(lambda params, tok, pool, pos: D.forward_with_paged_cache(
+            model, params, tok, pool, table, pos, bs, kernel=kernel))
+
+    pool = D.init_paged_cache(cfg, n_blocks, bs, jnp.float32)
+    first = []
+    with jax.default_matmul_precision("highest"):
+        for s, ids in enumerate(prompts):
+            logits, cache = prefill(params, jnp.asarray(ids[None]))
+            pool = D.insert_block_kv(pool, cache, table[s],
+                                     jnp.arange(4, dtype=jnp.int32), bs)
+            first.append(int(logits[0, -1].argmax()))
+        streams = {}
+        for kernel in (True, False):
+            run, p = decode(kernel), pool
+            tok = jnp.asarray(first + [0], jnp.int32)[:, None]
+            pos = jnp.asarray([len(q) for q in prompts] + [0], jnp.int32)
+            out, seen = [], []
+            for _ in range(steps):
+                logits, p = run(params, tok, p, pos)
+                tok = jnp.argmax(logits[:, 0], -1).astype(jnp.int32)[:, None]
+                out.append(np.asarray(tok[:2, 0]))
+                seen.append(np.asarray(logits[:2, 0]))
+                pos = pos + jnp.asarray([1, 1, 0], jnp.int32)
+            streams[kernel] = (np.stack(out), np.stack(seen), p)
+    (tk, lk, pk), (tv, lv, pv) = streams[True], streams[False]
+    np.testing.assert_array_equal(tk, tv)
+    np.testing.assert_allclose(lk, lv, atol=1e-5)
+    for name in pk:
+        # the live slots' blocks; the garbage block holds whatever was last
+        # written to it
+        np.testing.assert_allclose(np.asarray(pk[name])[:, 1:],
+                                   np.asarray(pv[name])[:, 1:], atol=1e-5)
+
+
+def test_a_refusal_by_the_probe_leaves_the_view_with_its_reason(monkeypatch):
+    """Where the compiler refuses the latent form, the engine serves
+    through the view and says why, in the compiler's words; the streams are
+    those of an engine that could only take the view."""
+    from deepspeed_tpu.ops import pallas as plx
+
+    monkeypatch.setattr(plx, "unavailable_reason", lambda *a: None)
+    monkeypatch.setattr(plx, "compiler_verdict", lambda fn, *args: (
+        False, "Mosaic failed to compile the latent form."))
+    eng = engine()
+    sv = eng.serving
+    assert sv.attn_backend == "view"
+    assert sv.attn_reason == ("TPU compiler: Mosaic failed to compile the "
+                              "latent form.")
+    monkeypatch.undo()
+    plain = engine()
+    assert plain.serving.attn_backend == "view"
+    reqs = [[s.submit(Request(prompt=token_ids(n, seed=n), max_new_tokens=5))
+             for n in (40, 23)] for s in (sv, plain.serving)]
+    for s, rs in zip((sv, plain.serving), reqs):
+        while any(r.state is not RequestState.FINISHED for r in rs):
+            s.step()
+    assert [r.tokens for r in reqs[0]] == [r.tokens for r in reqs[1]]
+    kv = sv.metrics.snapshot()["kv_pool"]
+    assert kv["attention_reason"] == sv.attn_reason
+    assert kv["decode_dispatches"]["kernel"] == 0
+    eng.destroy()
+    plain.destroy()
+
+
+def test_a_slot_between_its_chunks_walks_nothing():
+    """A request still in chunks holds its slot, but the slot's cursor is 0
+    and its table row the garbage block until the request is inserted: the
+    kernel reads only what decoding slots hold. A slot freed by a finished
+    request is back at 0 too."""
+    eng = engine(interpret=True)
+    sv = eng.serving
+    assert sv.attn_backend == "kernel"
+    rng = np.random.default_rng(2)
+    first = sv.submit(Request(prompt=rng.integers(0, 512, 20, dtype=np.int32),
+                              max_new_tokens=30))
+    while not first.tokens:
+        sv.step()
+    late = sv.submit(Request(prompt=rng.integers(0, 512, 130, dtype=np.int32),
+                             max_new_tokens=3))
+    between = 0
+    while late.state is not RequestState.FINISHED:
+        sv.step()
+        job = sv._prefill_jobs[0] if sv._prefill_jobs else None
+        if job is not None and job.req is late:
+            between += 1
+            assert int(np.asarray(sv._state["pos"])[job.slot]) == 0
+            assert not np.asarray(sv._state["table"])[job.slot].any()
+    assert between >= 2
+    while first.state is not RequestState.FINISHED:
+        sv.step()
+    assert not sv._slots and not np.asarray(sv._state["pos"]).any()
     eng.destroy()
 
 

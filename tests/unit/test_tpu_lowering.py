@@ -136,11 +136,26 @@ def _paged_decode_operands(nh, kvh, dh, sharding=None, layers=24, slots=32,
     (16, 16, 128, True),       # BLOOM-1.7B: alibi
     (32, 8, 128, False),       # GQA
     (8, 1, 128, False),        # MQA
+    # kanana2's latent form: 32 heads over one latent row of 512 and a
+    # rope key of 64, the pool's 5-D leaves as the engine holds them
+    pytest.param(32, 1, 512, "latent", id="kanana2-latent-512-rope64"),
 ])
 def test_paged_decode_lowers(nh, kvh, dh, alibi):
     from deepspeed_tpu.models.layers import alibi_slopes
-    from deepspeed_tpu.ops.pallas.paged_attention import paged_flash_decode
+    from deepspeed_tpu.ops.pallas.paged_attention import (paged_flash_decode,
+                                                          paged_latent_decode)
 
+    if alibi == "latent":
+        sds = lambda *shape: SDS(shape, jnp.bfloat16)
+        pool = lambda w: sds(7, 2561, 128, 1, w)
+        text = lower_for_tpu(
+            lambda *a: paged_latent_decode(*a[:8], layer=a[8],
+                                           scale=192 ** -0.5),
+            sds(32, nh, dh), sds(32, nh, 64), sds(32, dh), sds(32, 64),
+            pool(dh), pool(64), SDS((32, 128), jnp.int32),
+            SDS((32,), jnp.int32), SDS((), jnp.int32))
+        assert n_mosaic(text) == 1 and "7x2561x128x512xbf16" in text
+        return
     slopes = alibi_slopes(nh) if alibi else None
 
     def call(q, kn, vn, kc, vc, table, pos, layer):
@@ -350,9 +365,10 @@ def test_quantized_matmul_lowers(bits):
 
 
 def _kanana_programs(n_layers, n_blocks, sharding=None):
-    """kanana2's decode step and 1024-token chunk at the published widths:
-    32 slots of 16,384 positions over a pool of ``n_blocks`` blocks of 128
-    latent rows, ``n_layers`` of them (layer 0 dense)."""
+    """kanana2's decode step (through the decode kernel's latent form, the
+    engine's choice) and 1024-token chunk at the published widths: 32 slots
+    of 16,384 positions over a pool of ``n_blocks`` blocks of 128 latent
+    rows, ``n_layers`` of them (layer 0 dense)."""
     from deepspeed_tpu.models import decoding as D
 
     model = get_model("kanana2", "30b-a3b", n_layers=n_layers,
@@ -369,7 +385,8 @@ def _kanana_programs(n_layers, n_blocks, sharding=None):
 
     def decode(params, tok, pool, table, pos):
         return D.forward_with_paged_cache(model, params, tok, pool, table,
-                                          pos, bs, return_routing=True)
+                                          pos, bs, kernel=True,
+                                          return_routing=True)
 
     def chunk(params, ids, cache, start, last):
         return D.forward_with_cache(model, params, ids, cache, start, max_len,
@@ -387,19 +404,55 @@ def test_latent_expert_serving_programs_lower_with_the_grouped_kernel_alone():
     for the TPU with two Mosaic calls in the scanned expert layer's body for
     the grouped expert products (``ops/pallas/grouped_matmul.py`` in both
     programs, settled on the chip: ``moe/dropfree.py``) and no
-    ``ragged_dot``; decode's absorbed attention is XLA's, and the chunk's
-    expanded attention folds its key blocks in the chunk kernel
-    (``ops/pallas/chunk_attention.py``), called in the dense layer's loop
-    and in the scanned body's: one kernel, lowered once, as the two calls
-    have the same shapes."""
+    ``ragged_dot``. Decode attends through the paged decode kernel's latent
+    form, lowered once and called by the dense layer and the scanned body
+    (its own ``jax.jit``): no ``32 x 16384`` view of latent rows and no
+    score row over it remain. The chunk's expanded attention folds its key
+    blocks in the chunk kernel (``ops/pallas/chunk_attention.py``), called
+    in the dense layer's loop and in the scanned body's: one kernel, lowered
+    once, as the two calls have the same shapes."""
     decode, d_args, chunk, c_args = _kanana_programs(3, 257)
     for fn, args, n_attn, n_calls in ((decode, d_args, 0, 0),
                                       (chunk, c_args, 1, 2)):
         text = lower_for_tpu(fn, *args)
-        assert n_mosaic(text) == 2 + n_attn and "ragged_dot" not in text
+        n_paged = 1 if fn is decode else 0
+        assert n_mosaic(text) == 2 + n_attn + n_paged
+        assert "ragged_dot" not in text
         assert text.count("grouped_matmul") == 2
         assert text.count('"chunk_attention"') == n_attn
         assert text.count("call @chunk_attention_block") == n_calls
+        assert text.count('"paged_flash_decode"') == n_paged
+        assert text.count("call @_paged_latent_decode(") == 2 * n_paged
+    text = lower_for_tpu(decode, *d_args)
+    for view in ("32x128x128x1x512", "32x16384x512", "32x16384x64",
+                 "32x32x16384"):
+        assert view not in text, view
+
+
+def test_compiled_latent_decode_reads_its_pool_in_place(v5e):
+    """kanana2's decode step through the kernel COMPILED for a v5e over the
+    serve cell's pool of 2,561 blocks of 128, at 3 layers: both leaves are
+    aliased to the output and nothing the size of a leaf is copied, sliced
+    or gathered; the rope leaf keeps its tokens in the lanes, as the kernel
+    reads it. At this depth a scatter of single 64-wide rows had the
+    compiler re-lay the leaf out with its rows in the lanes, at the entry
+    and the exit of every step and around every layer's kernel call (at 7
+    it happened to keep the layout): the row write reads and writes back
+    whole blocks. The temporaries are a few MB (the view program's are 715
+    MB)."""
+    decode, d_args, _, _ = _kanana_programs(3, 2561, sharding=v5e)
+    compiled = _compile_for(decode, d_args)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 3 * 2561 * 128 * (512 + 64) * 2
+    assert mem.temp_size_in_bytes < 16 << 20, mem.temp_size_in_bytes
+    text = compiled.as_text()
+    assert text.count("paged_flash_decode") >= 2
+    _assert_only_passed_along(text, KANANA_EXPERTS + tuple(
+        f"bf16[{lead}2561,{rows}]" for lead in ("3,", "")
+        for rows in ("128,1,512", "128,1,64", "128,512", "128,64", "64,128")))
+    layouts = {name: tuple(compiled.input_formats[0][2][name].layout
+                           .major_to_minor) for name in ("k", "v")}
+    assert layouts == {"k": (0, 1, 3, 2, 4), "v": (0, 1, 3, 4, 2)}
 
 
 @pytest.mark.parametrize("kvh,window,geometry", [

@@ -285,6 +285,82 @@ def _paged_band_case(nh, kvh, dh, window, bs, dv=None, sink=False):
         3e-2
 
 
+def _paged_latent_case(live_slots, live_rows, chunk_tokens=None):
+    """The kernel's latent form (``paged_latent_decode``) as the kanana
+    serve cell's decode calls it: 32 slots over the pool's whole leaves, 7
+    layers of 2,561 blocks of 128 latent rows (512 + a 64-wide rope key),
+    tables of 128 columns, ``live_slots`` slots whose cursors hold
+    ``live_rows`` rows between them and the rest at cursor 0. Against the
+    view in float32: the slots' rows gathered through the table, exact
+    softmax over ``[0, pos]``. TIMED beside the view path's bf16 gather and
+    einsums (``models/latent.py:absorbed_attention``); the geometry names
+    the byte floor, one read of the live rows at 819 GB/s."""
+    import jax
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.ops.pallas.paged_attention import paged_latent_decode
+
+    S, H, r, dr, bs, cols, n_layers, n_blocks = 32, 32, 512, 64, 128, 128, \
+        7, 2561
+    dt, f32 = jnp.bfloat16, jnp.float32
+    scale = 1.0 / np.sqrt(192)
+    rng = np.random.RandomState(0)
+    key = jax.random.PRNGKey(0)
+    normal = lambda i, shape, sd=1.0: (jax.random.normal(
+        jax.random.fold_in(key, i), shape, f32) * sd).astype(dt)
+    # the pool made on the device (2.6 GB), laid out as the engine's is
+    kc = normal(0, (n_layers, n_blocks, bs, 1, r))
+    krc = normal(1, (n_layers, n_blocks, bs, 1, dr))
+    # cursors of 2-16k spread over the live slots, summing to live_rows
+    share = np.linspace(1.0, 7.0, live_slots)
+    cur = np.minimum(np.floor(share / share.sum() * live_rows), cols * bs - 1)
+    cur[-1] += live_rows - cur.sum()
+    pos = np.zeros(S, np.int32)
+    pos[rng.permutation(S)[:live_slots]] = cur.astype(np.int32)
+    table = np.zeros((S, cols), np.int32)
+    free = 1 + rng.permutation(n_blocks - 1)
+    for s in range(S):
+        need = -(-int(pos[s] + 1) // bs) if pos[s] else 0
+        table[s, :need], free = free[:need], free[need:]
+    q_lat, q_rope = normal(2, (S, H, r), 0.3), normal(3, (S, H, dr), 0.3)
+    c_new, kr_new = normal(4, (S, r)), normal(5, (S, dr))
+    layer = jnp.asarray(3, jnp.int32)
+
+    def kernel(q_lat, q_rope, c_new, kr_new, kc, krc, table, pos, layer):
+        return paged_latent_decode(q_lat, q_rope, c_new, kr_new, kc, krc,
+                                   table, pos, layer=layer, scale=scale,
+                                   chunk_tokens=chunk_tokens)
+
+    def view(dtype):
+        def attend(q_lat, q_rope, c_new, kr_new, kc, krc, table, pos, layer):
+            # the slots' rows gathered through the table, the fresh row
+            # written at the cursor, softmax over [0, pos]
+            rows = lambda leaf, new: jax.vmap(
+                lambda v, n, p: jax.lax.dynamic_update_slice(
+                    v, n[None], (p, 0)))(
+                leaf[layer][table][:, :, :, 0].reshape(S, cols * bs, -1)
+                .astype(dtype), new.astype(dtype), pos)
+            c, kr = rows(kc, c_new), rows(krc, kr_new)
+            prec = jax.lax.Precision.HIGHEST if dtype == f32 else None
+            s = (jnp.einsum("shr,skr->shk", q_lat.astype(dtype), c,
+                            precision=prec, preferred_element_type=f32)
+                 + jnp.einsum("shd,skd->shk", q_rope.astype(dtype), kr,
+                              precision=prec, preferred_element_type=f32))
+            seen = jnp.arange(cols * bs)[None, None] <= pos[:, None, None]
+            p = jax.nn.softmax(jnp.where(seen, s * scale, -jnp.inf), -1)
+            return jnp.einsum("shk,skr->shr", p.astype(dtype), c,
+                              precision=prec)
+        return attend
+
+    floor_ms = live_rows * (r + dr) * 2 / 819e9 * 1e3
+    return (f"32 slots, {live_slots} live over {live_rows} latent rows, pool "
+            f"[7, 2561, 128, 1, 512 + 64] bf16, table 128, layer 3, chunk "
+            f"{chunk_tokens or 'by bytes'}; floor {floor_ms:.4f} ms",
+            kernel, view(f32),
+            (q_lat, q_rope, c_new, kr_new, kc, krc, jnp.asarray(table),
+             jnp.asarray(pos), layer), 3e-2, {"view": view(dt)})
+
+
 def _qmm_case(bits):
     import jax
     import jax.numpy as jnp
@@ -509,6 +585,10 @@ CASES = {
         lambda: _paged_case(64, 4, 192, dv=128),
     "paged decode (GQA 64/8, K 192 over V 128, band 128 + sink, ring 2)":
         lambda: _paged_band_case(64, 8, 192, 128, 128, dv=128, sink=True),
+    "paged decode (kanana2 latent, 14 slots over 88k rows)":
+        lambda: _paged_latent_case(14, 88_000),
+    "paged decode (kanana2 latent, 28 slots over 176k rows)":
+        lambda: _paged_latent_case(28, 176_000),
     "quantized matmul int8": lambda: _qmm_case(8),
     "quantized matmul int4": lambda: _qmm_case(4),
     "kv block write (bf16 pool)": lambda: _block_write_case(False),
