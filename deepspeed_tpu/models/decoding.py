@@ -26,6 +26,10 @@ from .transformer import _norm_apply
 def init_cache(cfg, batch_size, max_len, dtype=None):
     """Allocate the KV cache: k/v stacked over layers (matches the stacked block
     params, so layer scan indexes both together)."""
+    if cfg.hybrid_layers:
+        from . import hybrid
+
+        return hybrid.init_cache(cfg, batch_size, max_len, dtype)
     dtype = dtype or cfg.compute_dtype
     return {name: jnp.zeros((cfg.n_layers, batch_size, max_len) + row, dtype)
             for name, row in cfg.cache_geometry.items()}
@@ -227,6 +231,18 @@ def forward_with_paged_cache(model, params, input_ids, pool, table, pos,
     keeps two pool groups: ``pool`` then also holds ``wk`` / ``wv`` and
     ``table`` is the pair (full group's table, window group's ring)."""
     cfg = model.config
+    if cfg.hybrid_layers:
+        from . import hybrid
+
+        if draft_len is not None or "k_scale" in pool:
+            raise ValueError(
+                "hybrid stacks decode one row a slot over a pool in the "
+                "engine's dtype: speculative verify and an int8 pool are "
+                "not implemented")
+        logits, pool, ids = hybrid.forward_with_paged_cache(
+            model, params, input_ids, pool, table, pos, block_size,
+            kernel=kernel)
+        return (logits, pool, ids) if return_routing else (logits, pool)
     if cfg.window_layers:
         from . import window_moe
 
@@ -660,6 +676,13 @@ def forward_with_cache(model, params, input_ids, cache, pos, kv_len,
     also return what the expert layers chose, [L_moe, b, q, 2k] int32.
     """
     cfg = model.config
+    if cfg.hybrid_layers:
+        from . import hybrid
+
+        logits, cache, ids = hybrid.forward_with_cache(
+            model, params, input_ids, cache, pos, kv_len,
+            last_index=last_index)
+        return (logits, cache, ids) if return_routing else (logits, cache)
     if cfg.window_layers:
         from . import window_moe
 

@@ -284,6 +284,8 @@ ACTIVATIONS = {
     "silu": jax.nn.silu,
     "quick_gelu": lambda x: x * jax.nn.sigmoid(1.702 * x),  # CLIP
     "swiglu": None,  # handled structurally in the MLP
+    # squared ReLU, not gated (Nemotron-H's experts: ``mlp_hidden_act``)
+    "relu2": lambda x: jnp.square(jax.nn.relu(x)),
 }
 
 

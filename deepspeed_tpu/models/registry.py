@@ -355,6 +355,51 @@ def mimo_v2_config(size="flash", **overrides):
     return TransformerConfig(**base)
 
 
+NEMOTRON_3_SUPER_PATTERN = (
+    "MEMEMEM*EMEMEMEM*EMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*"
+    "EMEMEMEM*EMEMEMEME")
+
+
+def nemotron_h_config(size="3-super", **overrides):
+    """nvidia/NVIDIA-Nemotron-3-Super-120B-A12B-BF16 (``model_type``
+    nemotron_h; huggingface.co/nvidia/NVIDIA-Nemotron-3-Super-120B-A12B-BF16
+    config.json): 88 layers in ``hybrid_override_pattern``, 40 Mamba-2
+    mixers (128 heads of 64, 8 groups of B and C of state 128, conv 4,
+    chunked scan in blocks of 128), 40 LatentMoE layers (512 sigmoid-routed
+    experts of 2688, top-22 of ``s + b``, weights from ``s``, normalised, x
+    5, run in a 1024-wide latent; one shared expert of 5376 at full width;
+    squared-ReLU experts, not gated) and 8 attention layers (32 heads over 2
+    K/V heads of 128, no positions); each layer ``x += mixer(RMSNorm(x))``,
+    eps 1e-5; untied head over 131,072 rows; no biases. The MTP layers of
+    the release are not built. ``n_layers`` takes the first layers of the
+    published pattern unless ``hybrid_pattern`` is given;
+    ``moe_local_experts`` / ``moe_expert_offset`` give a program one chip's
+    share of the experts (``moe/dropfree.py``)."""
+    presets = {
+        "tiny": dict(n_layers=5, d_model=64, n_heads=4, n_kv_heads=2,
+                     head_dim_override=16, d_ff=32, moe_d_ff=32,
+                     n_experts=16, moe_top_k=4, moe_latent_size=32,
+                     moe_shared_d_ff=48, ssm_heads=4, ssm_head_dim=16,
+                     ssm_state=16, ssm_groups=2, ssm_chunk=8,
+                     max_seq_len=256, vocab_size=512, hybrid_pattern="MEM*E"),
+        "3-super": dict(n_layers=88, d_model=4096, n_heads=32, n_kv_heads=2,
+                        head_dim_override=128, d_ff=2688, moe_d_ff=2688,
+                        n_experts=512, moe_top_k=22, moe_latent_size=1024,
+                        moe_shared_d_ff=5376, ssm_heads=128, ssm_head_dim=64,
+                        ssm_state=128, ssm_groups=8, ssm_chunk=128),
+    }
+    base = dict(
+        vocab_size=131072, max_seq_len=262144, activation="relu2",
+        norm="rmsnorm", position_embedding="none", tie_embeddings=False,
+        use_bias=False, prenorm=True, layernorm_eps=1e-5, ssm_conv=4,
+        n_shared_experts=1, moe_routing="dropfree", moe_routed_scale=5.0,
+    )
+    base.update(presets[size])
+    base.update(overrides)
+    base.setdefault("hybrid_pattern",
+                    NEMOTRON_3_SUPER_PATTERN[:base["n_layers"]])
+    return TransformerConfig(**base)
+
 
 MODEL_CONFIGS = {
     "gpt2": gpt2_config,
@@ -372,6 +417,7 @@ MODEL_CONFIGS = {
     "kanana2": kanana2_config,
     "trinity": trinity_config,
     "mimo_v2": mimo_v2_config,
+    "nemotron_h": nemotron_h_config,
 }
 
 

@@ -230,6 +230,27 @@ class TransformerConfig:
     # chosen; pairs on absent experts are not computed. 0 = all of them.
     moe_local_experts: int = 0
     moe_expert_offset: int = 0
+    # Hybrid stacks of Mamba-2 mixers, expert layers and attention layers
+    # (NVIDIA Nemotron-H, ``models/hybrid.py``): ``hybrid_pattern`` holds one
+    # character a layer, "M" a Mamba-2 mixer, "E" a drop-free expert layer,
+    # "*" a grouped-query attention layer without positions; each layer is
+    # ``x += mixer(RMSNorm(x))``. The mixer has ``ssm_heads`` heads of
+    # ``ssm_head_dim``, ``ssm_groups`` groups of B and C of ``ssm_state``
+    # each, a depthwise causal conv of ``ssm_conv`` and a chunked scan in
+    # blocks of ``ssm_chunk`` positions.
+    hybrid_pattern: str = ""
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_groups: int = 1
+    ssm_conv: int = 4
+    ssm_chunk: int = 128
+    # LatentMoE (moe/dropfree.py): the routed experts run in a
+    # ``moe_latent_size``-wide latent between two projections the layer's
+    # experts share (0 = at d_model); ``moe_shared_d_ff`` is the shared
+    # expert's width where it is not ``n_shared_experts * moe_d_ff``
+    moe_latent_size: int = 0
+    moe_shared_d_ff: typing.Optional[int] = None
 
     def __post_init__(self):
         # a typo here would silently run the exact fp32 path and let a
@@ -271,13 +292,33 @@ class TransformerConfig:
                     "kinds, sliding_window > 0 and per-head K and V (no "
                     "latent attention)")
         if self.n_experts > 0 and self.moe_routing == "dropfree" \
-                and not (self.kv_lora_rank or self.layer_types):
+                and not (self.kv_lora_rank or self.layer_types
+                         or self.hybrid_pattern):
             raise ValueError(
                 "moe_routing='dropfree' is implemented beside latent "
-                "attention (kv_lora_rank > 0, models/latent.py) and beside "
+                "attention (kv_lora_rank > 0, models/latent.py), beside "
                 "the window and full attention layers of layer_types "
-                "(models/window_moe.py): the cache paths that carry its "
-                "routing are theirs")
+                "(models/window_moe.py) and in the hybrid stacks of "
+                "hybrid_pattern (models/hybrid.py): the cache paths that "
+                "carry its routing are theirs")
+        if self.hybrid_pattern:
+            if len(self.hybrid_pattern) != self.n_layers \
+                    or not set(self.hybrid_pattern) <= set("ME*"):
+                raise ValueError(
+                    f"hybrid_pattern must give one of M, E, * for each of "
+                    f"the {self.n_layers} layers, got "
+                    f"{self.hybrid_pattern!r}")
+            if self.layer_types or self.kv_lora_rank or self.first_k_dense \
+                    or not (self.ssm_heads and self.ssm_head_dim
+                            and self.ssm_state) \
+                    or self.ssm_heads % self.ssm_groups \
+                    or ("E" in self.hybrid_pattern
+                        and self.moe_routing != "dropfree"):
+                raise ValueError(
+                    "hybrid_pattern (models/hybrid.py) needs ssm_heads, "
+                    "ssm_head_dim and ssm_state, ssm_heads a multiple of "
+                    "ssm_groups, drop-free expert layers, and no "
+                    "layer_types, latent attention or leading dense layers")
         if self.window_block not in ("afmoe", "sink"):
             raise ValueError(
                 f"window_block must be 'afmoe' or 'sink', got "
@@ -337,6 +378,11 @@ class TransformerConfig:
             self.moe_local_experts or self.n_experts
 
     @property
+    def hybrid_layers(self):
+        """Mamba-2, expert and attention layers mixed (``hybrid_pattern``)."""
+        return bool(self.hybrid_pattern)
+
+    @property
     def sink_window(self):
         """Window and full layers as MiMo-V2 has them (``window_block``)."""
         return self.window_block == "sink"
@@ -393,6 +439,10 @@ class TransformerConfig:
     def num_params(self):
         """Analytic parameter count (embedding + blocks + final norm)."""
         d, f, v = self.d_model, self.d_ff, self.vocab_size
+        if self.hybrid_layers:
+            from .hybrid import param_count
+
+            return param_count(self)
         if self.sink_window:
             # q and o, K and V by the layer's kind, a sink a head in a
             # window layer, two norms; the experts held, the whole router
@@ -1128,6 +1178,10 @@ class CausalLM:
     # -- init ---------------------------------------------------------------------
     def init(self, rng):
         cfg = self.config
+        if cfg.hybrid_layers:
+            from .hybrid import init_params
+
+            return init_params(cfg, rng)
         k_emb, k_pos, k_blocks, k_head = jax.random.split(rng, 4)
         params = {
             "wte": L.embedding_init(k_emb, cfg.vocab_size, cfg.d_model, cfg.initializer_range),
@@ -1172,14 +1226,18 @@ class CausalLM:
                  pld_theta=None):
         """Embedding + blocks + final norm -> ([batch, seq, d_model], aux)."""
         cfg = self.config
-        if cfg.window_layers:
+        if cfg.window_layers or cfg.hybrid_layers:
             if attention_mask is not None or token_type_ids is not None \
                     or pld_theta is not None or not deterministic:
                 raise NotImplementedError(
-                    "window and full attention layers (layer_types) run the "
-                    "plain causal forward: no padding mask, token types, "
-                    "dropout or progressive layer drop")
-            from .window_moe import backbone
+                    "window and full attention layers (layer_types) and "
+                    "hybrid stacks (hybrid_pattern) run the plain causal "
+                    "forward: no padding mask, token types, dropout or "
+                    "progressive layer drop")
+            if cfg.hybrid_layers:
+                from .hybrid import backbone
+            else:
+                from .window_moe import backbone
 
             return backbone(self, params, input_ids, positions), \
                 jnp.zeros((), jnp.float32)

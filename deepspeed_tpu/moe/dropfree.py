@@ -13,6 +13,14 @@ moe_routing="dropfree"``), beside the capacity form of ``sharded_moe.py``:
   a second one down; a group may be empty;
 - ``n_shared_experts`` always-on experts (one SwiGLU of their joint width)
   are added for every token.
+- two static options of Nemotron-H's LatentMoE: an ``activation`` other
+  than ``swiglu`` makes every expert (routed and shared) non-gated,
+  ``down(act(up u))`` (``relu2``: the square of ReLU);
+  ``moe_latent_size`` runs the routed experts in a latent of that width
+  between two projections the layer's experts share (``latent_in`` before
+  the grouped products, ``latent_out`` after the weighted sum: scope
+  ``latent_moe_proj``), while the router and the shared expert
+  (``moe_shared_d_ff`` wide) read the full-width input;
 - a SHARE of a deployment's experts (``moe_local_experts`` at
   ``moe_expert_offset``: what one chip of an expert-parallel layer holds):
   the router keeps all ``n_experts`` outputs and ``top_k`` a token, the
@@ -31,40 +39,66 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..models.layers import Param, linear_apply, linear_init, normal_init
+from ..models.layers import (ACTIVATIONS, Param, linear_apply, linear_init,
+                             normal_init)
 
 F32 = jnp.float32
+
+
+def gated(cfg):
+    """SwiGLU experts; any other ``activation`` makes them non-gated,
+    ``down(act(up u))`` (Nemotron-H's ``relu2``)."""
+    return cfg.activation == "swiglu"
+
+
+def expert_names(cfg):
+    """The expert stacks' leaves: the first product's, then ``down``."""
+    return ("gate_up" if gated(cfg) else "up"), "down"
 
 
 def dropfree_moe_init(rng, cfg):
     """``router`` (kernel, and the selection bias: zero, as the published
     init has it) over all E experts, ``gate_up`` [E_held, d, 2f] (gate
-    columns first) and ``down`` [E_held, f, d] of the experts held here
-    (``cfg.held_experts``: all, unless the layer is a share), and ``shared``,
-    one SwiGLU of width ``n_shared_experts * f``."""
+    columns first; ``up`` [E_held, d, f] for ``relu2`` experts) and ``down``
+    [E_held, f, d] of the experts held here (``cfg.held_experts``: all,
+    unless the layer is a share), and ``shared``, one SwiGLU (or ``relu2``
+    MLP) of width ``n_shared_experts * f`` or ``moe_shared_d_ff``. With
+    ``moe_latent_size`` the experts' ``d`` is the latent's and
+    ``latent_in`` [d_model, latent] / ``latent_out`` [latent, d_model] are
+    added."""
     E, d, f = cfg.n_experts, cfg.d_model, cfg.expert_d_ff
     held = cfg.held_experts[1]
     k_router, k_gu, k_down, k_shared = jax.random.split(rng, 4)
     std = cfg.initializer_range
     out_std = std / (2.0 * cfg.n_layers) ** 0.5
+    lat = cfg.moe_latent_size or d
+    first, _ = expert_names(cfg)
     params = {
         "router": {"kernel": Param(normal_init(k_router, (d, E), std),
                                    ("embed", "expert_logits")),
                    "bias": Param(jnp.zeros((E,), F32), ("expert_logits",))},
-        "gate_up": Param(normal_init(k_gu, (held, d, 2 * f), std),
-                         ("expert", "embed", "mlp")),
-        "down": Param(normal_init(k_down, (held, f, d), out_std),
+        first: Param(normal_init(k_gu, (held, lat, (1 + gated(cfg)) * f),
+                                 std), ("expert", "embed", "mlp")),
+        "down": Param(normal_init(k_down, (held, f, lat), out_std),
                       ("expert", "mlp", "embed")),
     }
+    if cfg.moe_latent_size:
+        k_in, k_out = jax.random.split(jax.random.fold_in(rng, 5))
+        params["latent_in"] = linear_init(k_in, d, lat, ("embed", None),
+                                          False, std)
+        params["latent_out"] = linear_init(k_out, lat, d, (None, "embed"),
+                                           False, std)
     if cfg.n_shared_experts:
         ks = jax.random.split(k_shared, 3)
-        fs = cfg.n_shared_experts * f
+        fs = cfg.moe_shared_d_ff or cfg.n_shared_experts * f
         params["shared"] = {
-            "gate": linear_init(ks[0], d, fs, ("embed", "mlp"), False, std),
             "up": linear_init(ks[1], d, fs, ("embed", "mlp"), False, std),
             "down": linear_init(ks[2], fs, d, ("mlp", "embed"), False,
                                 out_std),
         }
+        if gated(cfg):
+            params["shared"]["gate"] = linear_init(
+                ks[0], d, fs, ("embed", "mlp"), False, std)
     return params
 
 
@@ -202,6 +236,11 @@ def dropfree_moe_apply(cfg, p, x, ids=None, stacked=None):
     lo, E = cfg.held_experts
     flat = x.reshape(b * s, d)
     scores = scores_of(p["router"], flat)
+    # the experts' input: the latent of a LatentMoE layer, else x itself
+    lat = flat
+    if cfg.moe_latent_size:
+        with jax.named_scope("latent_moe_proj"):
+            lat = linear_apply(p["latent_in"], flat)
     ids = choose(cfg, p["router"], scores) if ids is None \
         else ids.reshape(b * s, k)
     weights = pair_weights(cfg, scores, ids)
@@ -215,11 +254,12 @@ def dropfree_moe_apply(cfg, p, x, ids=None, stacked=None):
         pair_expert = jnp.where(held, pair_expert - lo, E)
     order = jnp.argsort(pair_expert, stable=True)
     pair_token = order // k
+    name, _ = expert_names(cfg)
     if stacked is None:
-        gate_up, down, first = p["gate_up"], p["down"], 0
+        gate_up, down, first = p[name], p["down"], 0
     else:
         experts, layer = stacked
-        gate_up = experts["gate_up"].reshape((-1,) + experts["gate_up"].shape[2:])
+        gate_up = experts[name].reshape((-1,) + experts[name].shape[2:])
         down = experts["down"].reshape((-1,) + experts["down"].shape[2:])
         first = layer * E
     group_sizes = jnp.zeros((gate_up.shape[0],), jnp.int32)
@@ -228,10 +268,11 @@ def dropfree_moe_apply(cfg, p, x, ids=None, stacked=None):
             held.astype(jnp.int32), mode="drop")
     else:
         group_sizes = group_sizes.at[first + pair_expert].add(1)
-    rows = flat[pair_token]                                   # [T*k, d]
+    rows = lat[pair_token]                                    # [T*k, d]
     how = (cfg.attention_interpret, cfg.mesh)
     h = grouped_product(rows, gate_up, group_sizes, *how)     # [T*k, 2f]
-    h = jax.nn.silu(h[:, :f]) * h[:, f:]
+    h = jax.nn.silu(h[:, :f]) * h[:, f:] if gated(cfg) \
+        else ACTIVATIONS[cfg.activation](h)
     out = grouped_product(h, down, group_sizes, *how)         # [T*k, d]
     w_sorted = weights.reshape(-1)[order]
     out = out.astype(F32) * w_sorted[:, None]
@@ -240,11 +281,18 @@ def dropfree_moe_apply(cfg, p, x, ids=None, stacked=None):
     # back to token order: pair i of token t sits at row inverse[t * k + i]
     inverse = jnp.zeros_like(order).at[order].set(
         jnp.arange(order.shape[0], dtype=order.dtype))
-    y = out[inverse].reshape(b * s, k, d).sum(axis=1)
+    y = out[inverse].reshape(b * s, k, lat.shape[-1]).sum(axis=1)
+    if cfg.moe_latent_size:
+        with jax.named_scope("latent_moe_proj"):
+            y = linear_apply(p["latent_out"], y.astype(x.dtype)).astype(F32)
     if "shared" in p:
         sp = jax.tree_util.tree_map(lambda a: a.astype(x.dtype), p["shared"])
-        shared = linear_apply(sp["down"], jax.nn.silu(
-            linear_apply(sp["gate"], flat)) * linear_apply(sp["up"], flat))
+        if gated(cfg):
+            shared = linear_apply(sp["down"], jax.nn.silu(
+                linear_apply(sp["gate"], flat)) * linear_apply(sp["up"], flat))
+        else:
+            shared = linear_apply(sp["down"], ACTIVATIONS[cfg.activation](
+                linear_apply(sp["up"], flat)))
         y = y + shared.astype(F32)
     routed = jnp.concatenate(
         [ids, jax.lax.bitcast_convert_type(weights, jnp.int32)], axis=-1)

@@ -49,6 +49,7 @@ from .request import (CLASS_BATCH, CLASS_INTERACTIVE, FINISH_EOS,
                       REJECT_DEGRADED, Request, RequestState, TokenEvent,
                       as_request)
 from .scheduler import ServingScheduler
+from .state_cache import recurrent_state
 
 
 @dataclasses.dataclass
@@ -115,6 +116,12 @@ class ServingEngine:
             self._layer_groups = layer_groups(mcfg)
         if self._latent or self._window:
             self._refuse_for_cache_family(engine)
+        # a model with Mamba layers (models/hybrid.py) holds a recurrent
+        # state a slot beside the K/V blocks: ONE object says what it holds,
+        # inserts, dispatches ahead and refuses (serving/state_cache.py)
+        self._recurrent = recurrent_state(mcfg, self.n_slots, engine.dtype,
+                                          lambda: len(self._slots))
+        self._recurrent.refuse(self.cfg, engine.mp_world_size)
         # the KV store: block allocator + prefix cache on the host, a block
         # table on the device (serving/kv_pool.py)
         self.pool_mgr = KVPoolManager(self.cfg.kv_pool, self.n_slots,
@@ -218,6 +225,7 @@ class ServingEngine:
         # snapshot()["speculative"], the PR 4 trace==metrics discipline)
         self.metrics.speculative_armed = self.spec
         self.metrics.moe_armed = self._routing
+        self.metrics.ssm = self._recurrent.snapshot
         if self._routing and mcfg.held_experts[1] != mcfg.n_experts:
             # the expert layers hold a share of their experts
             self.metrics.moe_held = mcfg.held_experts
@@ -287,7 +295,8 @@ class ServingEngine:
             for name in kv_names + (
                 "table", "pos", "tok", "active", "remaining", "rng", "temp",
                 "top_k", "top_p", "eos")
-            + (("wtable",) if self._window else ())}
+            + (("wtable",) if self._window else ())
+            + self._recurrent.names}
         self._state = self._init_state()
         # the KV window is not n_slots x max_len: report the REAL capacity
         # (blocks and tokens) so operators see the effective slot multiplier
@@ -315,7 +324,9 @@ class ServingEngine:
         mcfg = engine.module.config
         probe = dict(n_slots=self.n_slots, tp=max(engine.mp_world_size, 1),
                      kv_dtype=self.cfg.kv_pool.kv_dtype)
-        groups = {}
+        # the layers whose K/V the pool holds (a model with recurrent layers:
+        # its attention layers alone; None: every layer)
+        groups = {False: dict(n_layers=self._recurrent.kv_layers)}
         if self._latent:
             # the latent form, probed at the pool the engine really holds
             groups = {False: dict(n_layers=mcfg.n_layers,
@@ -393,6 +404,9 @@ class ServingEngine:
         cannot change any committed token."""
         if role not in ("mixed", "prefill", "decode"):
             raise ValueError(f"unknown pool role {role!r}")
+        if role != "mixed":
+            self._recurrent.refuse_feature(
+                f"the disaggregated hand-off (pool role {role!r})")
         if role != "mixed" and (self._latent or self._window):
             raise ValueError(
                 "ServingEngine: latent attention and window and full "
@@ -440,9 +454,12 @@ class ServingEngine:
         st["attention_reason"] = self.attn_reason
         st["decode_dispatches"] = dict(self._decode_dispatches)
         st["decode_ahead_dispatches"] = self._decode_ahead_dispatches
-        if self._latent or self._window:
+        if self._chunk_attention_paths:
             st["chunk_attention_dispatches"] = dict(
                 self._chunk_attention_dispatches)
+        # the attention layers' blocks, and the slots' recurrent state
+        st.update(self._recurrent.groups(
+            st, self.metrics.latent_kv_tokens_read))
         if self._window:
             # by group: the blocks each holds, those of the live requests
             # (the slots' bindings), and the K/V rows the decode steps read
@@ -481,8 +498,9 @@ class ServingEngine:
         else:
             cache = init_paged_cache(cfg, mgr.n_blocks, mgr.block_size,
                                      self.engine.dtype,
-                                     self.cfg.kv_pool.kv_dtype or None)
-        state = dict(cache, **{
+                                     self.cfg.kv_pool.kv_dtype or None,
+                                     n_layers=self._recurrent.kv_layers)
+        state = dict(cache, **self._recurrent.leaves(), **{
             # every slot starts parked on the garbage block: a dead decode
             # write can never land in an allocatable block
             "table": jnp.full((s, mgr.blocks_per_slot), GARBAGE_BLOCK,
@@ -522,7 +540,7 @@ class ServingEngine:
                     last_index=true_len - 1, return_routing=True)
                 return logits[:, 0], c, self._routed_out(routed, true_len)
 
-            cache_sh = {"k": self._cache_sharding, "v": self._cache_sharding}
+            cache_sh = self._dense_cache_shardings()
             with self.engine.mesh:
                 if self._routing:
                     return jax.jit(prefill_routed, out_shardings=(
@@ -547,8 +565,10 @@ class ServingEngine:
             # every layer's key blocks in the chunk kernel, or not
             with traced_paths() as seen:
                 out = forward_with_cache(*args, **kwargs)
-            self._chunk_attention_paths[padded_len] = \
-                "kernel" if seen == {"kernel"} else "xla"
+            if seen:
+                # booked by chunk where the model has a chunk attention
+                self._chunk_attention_paths[padded_len] = \
+                    "kernel" if seen == {"kernel"} else "xla"
             return out
 
         def build():
@@ -565,7 +585,7 @@ class ServingEngine:
                     last_index=true_len - 1, return_routing=True)
                 return logits[:, 0], c, self._routed_out(routed, true_len)
 
-            cache_sh = {"k": self._cache_sharding, "v": self._cache_sharding}
+            cache_sh = self._dense_cache_shardings()
             with self.engine.mesh:
                 if self._routing:
                     return jax.jit(suffix_routed, donate_argnums=(2,),
@@ -579,6 +599,13 @@ class ServingEngine:
         return lru_compiled(self._suffix_programs, padded_len, build,
                             int(self.engine.config.compile_cache_size or 0),
                             "serving suffix prefill")
+
+    def _dense_cache_shardings(self):
+        """``{leaf: sharding}`` of a request's dense b=1 cache: K and V by
+        head, a recurrent state replicated."""
+        return {"k": self._cache_sharding, "v": self._cache_sharding,
+                **{name: self._rep_sharding
+                   for name in self._recurrent.names}}
 
     def _routed_out(self, routed, true_len):
         """What a prefill program of a routing model hands out beside its
@@ -630,6 +657,7 @@ class ServingEngine:
         bs = self.pool_mgr.block_size
         pool_keys = self._pool_leaf_names()
         window = self._window
+        recurrent = self._recurrent
         # a model with window layers reads through two tables
         tables = lambda state: (state["table"], state["wtable"]) \
             if window else state["table"]
@@ -649,7 +677,8 @@ class ServingEngine:
             # [L_moe, S, 1, 2k]
             logits, cache, *routed = forward_with_paged_cache(
                 model, params, state["tok"][:, None],
-                {k: state[k] for k in pool_keys}, tables(state),
+                {k: state[k] for k in pool_keys + recurrent.names},
+                tables(state),
                 state["pos"], bs, kernel=kernel,
                 return_routing=self._routing)
             # in-graph health: per-slot nonfinite-logit count (the serving
@@ -771,12 +800,15 @@ class ServingEngine:
                 "eos": put(state["eos"], eos),
             })
 
-        def insert_blocks(state, dense_k, dense_v, block_ids, src_blocks):
+        def insert_blocks(state, dense_k, dense_v, block_ids, src_blocks,
+                          *dense_state):
             # copy a request's private blocks from its freshly-prefilled
             # dense cache into the pool in ONE dispatch that writes only
             # those blocks: the (traced) [blocks_per_slot] id arrays are
             # padded with ids past the pool, which write nothing, so one
-            # compiled program serves every request size
+            # compiled program serves every request size; a recurrent
+            # state (its dense leaves and the slot) is set whole beside
+            state = dict(state, **recurrent.insert(state, *dense_state))
             pool = {k: state[k] for k in pool_keys}
             return dict(state, **insert_block_kv(
                 pool, {"k": dense_k, "v": dense_v}, block_ids, src_blocks,
@@ -900,8 +932,7 @@ class ServingEngine:
                 self._migrate_in_jit = jax.jit(
                     migrate_in, donate_argnums=(0,), out_shardings=st)
             self._fresh_cache_jit = jax.jit(
-                fresh_cache, out_shardings={"k": self._cache_sharding,
-                                            "v": self._cache_sharding})
+                fresh_cache, out_shardings=self._dense_cache_shardings())
             self._release_jit = jax.jit(release, donate_argnums=(0,),
                                         out_shardings=st)
             self._sample_first_jit = jax.jit(sample_first,
@@ -1309,7 +1340,10 @@ class ServingEngine:
             req.handoff_pending = False
             req.handoffs += 1
         chunk = self.chunk_size
-        if resume or (self.chunked and len(ids_full) - shared_len > chunk):
+        # a recurrent state is carried from chunk to chunk by a job's dense
+        # cache: every prefill of such a model is a job
+        if resume or self._recurrent.jobs_only \
+                or (self.chunked and len(ids_full) - shared_len > chunk):
             # multi-step prefill (chunked and/or resume replay): reserve the
             # slot now, seed the partial cache, and let the step loop drive
             # chunks interleaved with decode steps (_advance_prefill)
@@ -1514,9 +1548,10 @@ class ServingEngine:
             np.int32(job.pos), np.int32(n))
         job.cache = out[1]
         self.metrics.record_prefill_chunk(job.pos, n)
-        if self._latent or self._window:
+        if padded in self._chunk_attention_paths:
             self._chunk_attention_dispatches[
                 self._chunk_attention_paths[padded]] += 1
+        self._recurrent.book_chunk(n, padded)
         return n, padded, out
 
     def _dispatch_chunk_ahead(self):
@@ -1565,7 +1600,7 @@ class ServingEngine:
                 or self.spec or self._health_shed \
                 or self.degraded_ctl is not None \
                 or self.cfg.tenants.enabled \
-                or not (self._latent or self._window):
+                or not (self._latent or self._window or self._recurrent.ahead):
             return
         if all(r.eos_token_id is None and not r.stop_token_ids
                and len(r.tokens) + 1 < r.max_new_tokens
@@ -1580,6 +1615,8 @@ class ServingEngine:
         """The dense b=1 cache a job's chunks carry: seeded from the shared
         prefix blocks (whose references the job holds) or zeroed."""
         if not job.shared_len:
+            # the admission's reset: a recurrent state starts from zero
+            self._recurrent.book_reset()
             return self._fresh_cache_jit()
         mgr = self.pool_mgr
         row = np.full((mgr.blocks_per_slot,), GARBAGE_BLOCK, np.int32)
@@ -1730,7 +1767,8 @@ class ServingEngine:
             row_w[:len(ring)] = ring
             ring_row = (jnp.asarray(row_w),)
         self._state = self._insert_block_jit(
-            self._state, cache["k"], cache["v"], ids, srcs, *ring_args)
+            self._state, cache["k"], cache["v"], ids, srcs, *ring_args,
+            *self._recurrent.insert_args(cache, slot))
         row = np.full((mgr.blocks_per_slot,), GARBAGE_BLOCK, np.int32)
         row[:len(blocks)] = blocks
         self._state = self._insert_jit(
@@ -1776,6 +1814,8 @@ class ServingEngine:
         gathers only — no new compiled program, no device mutation — so a
         capture can run on any step boundary without perturbing the
         stay-put stream."""
+        self._recurrent.refuse_feature("live KV migration (a snapshot of "
+                                       "the blocks and its splice)")
         if self._latent or self._window:
             raise ValueError(
                 "ServingEngine: latent attention and window and full "
@@ -2364,6 +2404,10 @@ class ServingEngine:
         req.finish_reason = reason
         req.finish_time = now
         if req.slot is not None:
+            if req.record_state:
+                # read before the release; no decode was sent ahead of a
+                # step in which a request may end
+                req.final_state = self._recurrent.read(self._state, req.slot)
             del self._slots[req.slot]
             self._free_slots.append(req.slot)
             if self._drafter is not None:

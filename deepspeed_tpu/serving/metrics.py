@@ -184,6 +184,9 @@ class ServingMetrics:
         # count)``, set by the engine): the pairs the router chose, of which
         # ``moe_pairs`` are those on experts held here
         self.moe_held = None
+        # a model with recurrent state: its counters (serving/
+        # state_cache.py), snapshot()["ssm"]
+        self.ssm = None
         self.moe_pairs_chosen = 0
         self.moe_experts_hit = 0      # distinct experts with work, summed
         self.moe_decode_dispatches = 0   # the two above, decode steps alone
@@ -611,6 +614,7 @@ class ServingMetrics:
                 "unhealthy_slots": self.unhealthy_slots,
             },
             **({"moe": self.moe_snapshot()} if self.moe_armed else {}),
+            **({"ssm": self.ssm()} if self.ssm is not None else {}),
             **({"degraded": self.degraded_snapshot()}
                if self.degraded_snapshot is not None else {}),
             **({"kv_pool": self.kv_pool()} if self.kv_pool is not None

@@ -168,6 +168,12 @@ class Request:
     # 2k])`` pieces, on the device until asked for
     record_routing: bool = False
     routing: list = dataclasses.field(default_factory=list)
+    # a model with recurrent state (``serving/state_cache.py``): keep the
+    # slot's state as it stands when the request finishes, every token but
+    # the last fed (``final_state``: ``{leaf: [L_mamba, ...]}``, on the
+    # device)
+    record_state: bool = False
+    final_state: dict = None
 
     def __post_init__(self):
         self.prompt = np.asarray(self.prompt, np.int32).reshape(-1)

@@ -690,8 +690,9 @@ def test_compiled_chunk_keeps_its_scores_in_vmem(v5e, cell, scopes,
     (4, 8, 128, 0, 1024),       # trinity, full layers
     (8, 8, 192, 128, 1024),     # mimo, window layers (key tiles of 256)
     (4, 16, 192, 0, 1024),      # mimo, full layers
+    (2, 16, 128, 0, 1024),      # nemotron, attention layers
 ], ids=["kanana", "trinity-window", "trinity-full", "mimo-window",
-        "mimo-full"])
+        "mimo-full", "nemotron-full"])
 def test_chunk_kernel_compiles_at_each_configurations_shapes(
         v5e, groups, rep, dk, window, blk):
     """The chunk kernel alone, one 1024-token chunk against one key block,
@@ -718,6 +719,60 @@ def test_chunk_kernel_compiles_at_each_configurations_shapes(
         donate=(3, 4))
     assert compiled.memory_analysis().alias_size_in_bytes \
         == 2 * groups * rows * 128 * 4
+
+
+def test_ssm_state_update_compiles_in_place_at_the_published_widths(v5e):
+    """The decode step's Mamba-2 recurrence kernel COMPILED for a v5e at the
+    nemotron cell's widths: the stack of five layers' states, [5, 128
+    slots, 128 heads, 64, 128] float32 (2.7 GB), donated and aliased to the
+    output while one layer is written, one Mosaic call, and no temporary the
+    size of a layer's states (537 MB: XLA's form keeps one and reads the
+    states twice)."""
+    from deepspeed_tpu.ops.pallas.ssm_state_update import ssm_state_update
+
+    sds = lambda shape: SDS(shape, jnp.float32, sharding=v5e)
+    states = (5, 128, 128, 64, 128)
+    compiled = _compile_for(
+        lambda st, da, dtx, b, c: ssm_state_update(st, 2, da, dtx, b, c),
+        (sds(states), sds((128, 128)), sds((128, 128, 64)),
+         sds((128, 8, 128)), sds((128, 8, 128))), donate=(0,))
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == int(np.prod(states)) * 4
+    assert mem.temp_size_in_bytes < 64 << 20, mem.temp_size_in_bytes
+    assert compiled.as_text().count(
+        'custom_call_target="tpu_custom_call"') == 1
+
+
+def test_ssd_chunk_compiles_at_1024_tokens(v5e):
+    """A prefill chunk's Mamba-2 mixer at the published widths (the input
+    projection, the conv over the slot's tail, the chunked scan over 1024
+    tokens in blocks of 128 from the slot's state, the gated norm, the
+    output projection) COMPILED for a v5e: the scan is XLA's einsums (no
+    Mosaic call), and the temporaries stay under 512 MB (the in-block
+    decays, [8 blocks, 8 groups, 16 heads, 128, 128] float32, are 67
+    MB)."""
+    from deepspeed_tpu.models import hybrid
+    from deepspeed_tpu.models.layers import Param
+
+    cfg = get_model("nemotron_h", "3-super", n_layers=1,
+                    hybrid_pattern="M").config
+    params = jax.tree_util.tree_map(
+        lambda a: SDS(a.shape, jnp.bfloat16, sharding=v5e),
+        jax.eval_shape(lambda r: jax.tree_util.tree_map(
+            lambda p: p.value, hybrid.mamba_init(r, cfg, 0.02),
+            is_leaf=lambda x: isinstance(x, Param)), jax.random.PRNGKey(0)))
+    sds = lambda shape, dt: SDS(shape, dt, sharding=v5e)
+    compiled = _compile_for(
+        lambda p, u, ssm, tail, n: hybrid.mamba_chunk(cfg, p, u, ssm, tail,
+                                                      n),
+        (params, sds((1, 1024, 4096), jnp.bfloat16),
+         sds((1, 128, 64, 128), jnp.float32),
+         sds((1, 3, 10240), jnp.bfloat16), sds((), jnp.int32)),
+        donate=(2, 3))
+    assert compiled.memory_analysis().temp_size_in_bytes < 512 << 20
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text
+    assert "ssm_chunk_scan" in text and "ssm_conv" in text
 
 
 def test_compiler_verdict_carries_the_compilers_words():

@@ -543,7 +543,91 @@ def _chunk_attention_case(groups, rep, dk, window, blk, diagonal=False):
             (q, k, v, stat, m, l, acc), 3e-2, {"xla": xla})
 
 
+def _ssm_state_update_case(slots=128, heads=128, head_dim=64, state=128,
+                           groups=8):
+    """The decode step's Mamba-2 recurrence (``ops/pallas/
+    ssm_state_update.py``) at the nemotron cell's widths, one layer of a
+    stack of two, against the XLA form ``models/hybrid.state_update``
+    writing the same layer. (No time: the kernel writes its operand in
+    place, and a call that does not donate it copies the stack first; the
+    cell's trace times it, ``ssm_state_roofline_pct``.)"""
+    import jax
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.models import hybrid
+    from deepspeed_tpu.ops.pallas.ssm_state_update import ssm_state_update
+
+    key = jax.random.PRNGKey(0)
+    f32 = jnp.float32
+    normal = lambda i, shape: jax.random.normal(jax.random.fold_in(key, i),
+                                                shape, f32)
+    states = normal(0, (2, slots, heads, head_dim, state))
+    da = jax.random.uniform(jax.random.fold_in(key, 1), (slots, heads), f32,
+                            0.5, 1.0)
+    dtx = normal(2, (slots, heads, head_dim)) * 0.1
+    b, c = normal(3, (slots, groups, state)), normal(4, (slots, groups, state))
+
+    def xla(states, da, dtx, b, c):
+        y, new = hybrid.state_update(states[1], da, dtx, b, c)
+        return y, states.at[1].set(new)
+
+    return (f"{slots} slots x {heads} heads x [{head_dim}, {state}] float32 "
+            f"in a stack of 2 layers, {groups} groups of B and C",
+            lambda *a: ssm_state_update(a[0], 1, *a[1:]), xla,
+            (states, da, dtx, b, c), 1e-5)
+
+
+def _ssd_case(tokens=1024, heads=128, head_dim=64, state=128, groups=8,
+              block=128):
+    """The prefill chunk's chunked scan (SSD, ``models/hybrid.ssd_scan``,
+    XLA einsums) over ``tokens`` positions at the nemotron cell's widths,
+    from a state that is not zero, against the recurrence token by token
+    (``lax.scan``). TIMED beside that recurrence (the row's ``ms``): what a
+    scan kernel would have to beat."""
+    import dataclasses
+
+    import jax
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.models import hybrid
+
+    @dataclasses.dataclass
+    class Shape:
+        ssm_chunk: int = block
+
+    key = jax.random.PRNGKey(0)
+    f32 = jnp.float32
+    normal = lambda i, shape: jax.random.normal(jax.random.fold_in(key, i),
+                                                shape, f32)
+    x = normal(0, (1, tokens, heads, head_dim))
+    dt = jax.nn.softplus(normal(1, (1, tokens, heads)) - 4.0)
+    A = -jnp.arange(1, heads + 1, dtype=f32)
+    B, C = (normal(i, (1, tokens, groups, state)) * 0.3 for i in (2, 3))
+    s0 = normal(4, (1, heads, head_dim, state))
+
+    def ssd(x, dt, B, C, s0):
+        with jax.default_matmul_precision("highest"):
+            return hybrid.ssd_scan(Shape(), x, dt, A, B, C, s0)
+
+    def recurrence(x, dt, B, C, s0):
+        def one(s, t):
+            xt, dtt, bt, ct = t
+            y, s = hybrid.state_update(s, jnp.exp(dtt * A), dtt[..., None]
+                                       * xt, bt, ct)
+            return s, y
+
+        s, y = jax.lax.scan(one, s0, (x[0][:, None], dt[0][:, None],
+                                      B[0][:, None], C[0][:, None]))
+        return y[:, 0][None], s
+
+    return (f"{tokens} positions in blocks of {block}, {heads} heads x "
+            f"[{head_dim}, {state}], {groups} groups", ssd, recurrence,
+            (x, dt, B, C, s0), 1e-4, {"recurrence": recurrence})
+
+
 CASES = {
+    "ssm state update (nemotron decode, 128 slots)": _ssm_state_update_case,
+    "ssd chunk scan (nemotron chunk, 1024 tokens)": _ssd_case,
     "chunk attention (kanana2, 32 x 1, block 2048)":
         lambda: _chunk_attention_case(32, 1, 192, 0, 2048),
     "chunk attention (kanana2, 32 x 1, block 2048, diagonal)":
