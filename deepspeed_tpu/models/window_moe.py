@@ -219,12 +219,21 @@ def _project_sink(cfg, p, h, positions, window):
     kind's k and v. q and k are rotated over the first ``rotary_dim`` dims in
     both kinds, at the kind's base; v [b, q, G, dv] comes scaled by
     ``attn_value_scale`` (the same as scaling the attention's output, and
-    what the cache then holds); no gate."""
+    what the cache then holds); no gate.
+
+    The three products stay flat behind an ``optimization_barrier`` (an
+    identity): left to it, the TPU compiler carried the head split back
+    into each product, wrote every layer's slice of the stacked q, k and v
+    kernels out anew (0.73 GB a decode step at the published widths) and
+    copied each q slice again
+    (``test_compiled_sink_decode_reads_its_attention_weights_in_place``)."""
     b, q_len, _ = h.shape
     (G, dh), (_, dv) = cfg.kv_geometry(window).values()
-    q = L.linear_apply(p["q"], h).reshape(b, q_len, cfg.n_heads, dh)
-    k = L.linear_apply(p["k"], h).reshape(b, q_len, G, dh)
-    v = L.linear_apply(p["v"], h).reshape(b, q_len, G, dv)
+    q, k, v = jax.lax.optimization_barrier(
+        tuple(L.linear_apply(p[n], h) for n in "qkv"))
+    q = q.reshape(b, q_len, cfg.n_heads, dh)
+    k = k.reshape(b, q_len, G, dh)
+    v = v.reshape(b, q_len, G, dv)
     v = v * jnp.asarray(cfg.attn_value_scale, v.dtype)
     base = cfg.rope_base_window if window and cfg.rope_base_window \
         else cfg.rope_base

@@ -11,6 +11,7 @@ helper leaves a placed cache alone, and an unknown device kind has no peaks.
 """
 
 import os
+import re
 import subprocess
 import sys
 
@@ -519,6 +520,52 @@ def _trinity_cell_programs(sharding=None):
                     sds((), jnp.int32), sds((), jnp.int32)))
 
 
+def _mimo_cell_programs(sharding=None):
+    """mimo_v2's decode step and 1024-token chunk at the published widths
+    and the serve cell's geometry (7 of 48 layers: the dense full layer 0,
+    five window layers and a full one; 16 experts held of 256, a vocabulary
+    of 19,072; 32 slots of 32,768 positions, blocks of 128, a full group of
+    4097 blocks and a window group of 32 rings of 2)."""
+    from deepspeed_tpu.models import decoding as D
+    from deepspeed_tpu.serving.kv_pool import window_ring_blocks
+
+    kinds = ("full_attention",) + ("sliding_attention",) * 5 \
+        + ("full_attention",)
+    model = get_model("mimo_v2", "flash", n_layers=7, vocab_size=19072,
+                      moe_local_experts=16, moe_expert_offset=0,
+                      layer_types=kinds, compute_dtype=jnp.bfloat16)
+    cfg = model.config
+    slots, bs, max_len = 32, 128, 32768
+    ring = window_ring_blocks(cfg.sliding_window, bs)
+    assert ring == 2
+    sds = lambda shape, dt: SDS(shape, dt, sharding=sharding)
+    params = _abstract_params(model, sharding=sharding)
+    pool = {}
+    for names, window, n_layers, n_blocks in (
+            (("k", "v"), False, 2, 4097),
+            (("wk", "wv"), True, 5, slots * ring + 1)):
+        for name, (width,) in zip(names,
+                                  cfg.group_pool_geometry(window).values()):
+            pool[name] = sds((n_layers, n_blocks, bs, width), jnp.bfloat16)
+    cache = {n: sds((7, 1, max_len) + row, jnp.bfloat16)
+             for n, row in cfg.cache_geometry.items()}
+
+    def decode(params, tok, pool, table, wtable, pos):
+        return D.forward_with_paged_cache(
+            model, params, tok, pool, (table, wtable), pos, bs, kernel=True,
+            return_routing=True)
+
+    def chunk(params, ids, cache, start, last):
+        return D.forward_with_cache(model, params, ids, cache, start, max_len,
+                                    last_index=last, return_routing=True)
+
+    return (decode, (params, sds((slots, 1), jnp.int32), pool,
+                     sds((slots, max_len // bs), jnp.int32),
+                     sds((slots, ring), jnp.int32), sds((slots,), jnp.int32)),
+            chunk, (params, sds((1, 1024), jnp.int32), cache,
+                    sds((), jnp.int32), sds((), jnp.int32)))
+
+
 def test_window_expert_serving_programs_lower_with_the_kernel_and_no_view():
     """trinity's decode step and prefill chunk at the PUBLISHED widths lower
     for the TPU: in decode one Mosaic call a layer for attention (the band in
@@ -569,11 +616,26 @@ def _compile_for(fn, args, donate=(2,)):
 
 def _assert_only_passed_along(text, big):
     """No instruction of a compiled program's ``text`` that makes one of
-    the shapes ``big`` copies, slices or gathers it."""
+    the shapes ``big`` copies, slices or gathers it. A TUPLE that holds one
+    comes from a parameter, a loop or a tuple alone: a fusion that returns
+    such a shape among its outputs has written it out."""
+    big = set(big)
     for line in text.splitlines():
         if " = " not in line:
             continue
         made = line.split(" = ")[1]
+        if made.startswith("("):
+            depth = 0
+            for end, c in enumerate(made):
+                depth += {"(": 1, ")": -1}.get(c, 0)
+                if depth == 0:
+                    break
+            held = set(re.findall(r"\w+\[[\d,]*\]", made[:end + 1]))
+            op = made[end + 1:].split("(")[0].strip()
+            if held & big:
+                assert op in ("parameter", "get-tuple-element", "while",
+                              "tuple"), line[:200]
+            continue
         head = made.split("(")[0].split()
         if len(head) < 2:
             continue
@@ -612,6 +674,53 @@ def test_compiled_window_decode_copies_no_experts_and_keeps_its_pools(v5e):
     for name in ("k", "v", "wk", "wv"):
         fmt = compiled.input_formats[0][2][name]
         assert tuple(fmt.layout.major_to_minor) == (0, 1, 2, 3)
+
+
+# the sink form's attention kernels as the layers stack them (q: the dense
+# layer's stack of one, the expert layers' of six) or the kinds do (k, v),
+# one layer's slice of each, and their transposes
+MIMO_QKV = tuple(f"bf16[{s}]" for s in (
+    "1,4096,12288", "6,4096,12288", "12288,4096", "4096,12288", "1536,4096",
+    "4096,1536", "1024,4096", "4096,1024", "768,4096", "4096,768", "512,4096",
+    "4096,512"))
+
+
+def test_compiled_sink_decode_reads_its_attention_weights_in_place(v5e):
+    """mimo's decode step COMPILED for a v5e at the serve cell's geometry:
+    each layer's q, k and v products read their kernels where they are
+    stored, in the stacks, and no instruction copies or slices a stack or
+    writes a layer's slice out. When the head split of the products'
+    outputs reached back into the products, the compiler wrote every
+    layer's slice of the q stack (6 x 101 MB) and of the k and v stacks
+    (126 MB) out anew each step, copied each q slice again into the
+    product's layout and re-laid the dense layer's q kernel out: 711.5 MB
+    of temporaries, 9.5 MB since the products stay flat
+    (``window_moe._project_sink``)."""
+    decode, d_args, _, _ = _mimo_cell_programs(sharding=v5e)
+    compiled = _compile_for(decode, d_args)
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 64 << 20, mem.temp_size_in_bytes
+    text = compiled.as_text()
+    assert text.count("paged_flash_decode") >= 7
+    _assert_only_passed_along(text, MIMO_QKV)
+    # the six expert layers' q products each take the whole stack
+    q_products = [line for line in text.splitlines() if re.search(
+        r"= bf16\[32,1,12288\]\S* fusion\(%params__blocks____attn____q_", line)]
+    assert len(q_products) == 6, len(q_products)
+
+
+def test_compiled_sink_chunk_writes_no_attention_weight_slices(v5e):
+    """mimo's 1024-token chunk program compiled the same way: no layer's
+    slice of the q, k or v stacks is written out (the chunk's activations
+    are ``[1024, 4096]``, so that shape is not looked for), and the
+    temporaries are 475.0 MB, where the slices copied made them 1075.4
+    MB."""
+    _, _, chunk, c_args = _mimo_cell_programs(sharding=v5e)
+    compiled = _compile_for(chunk, c_args)
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 512 << 20, mem.temp_size_in_bytes
+    _assert_only_passed_along(compiled.as_text(), tuple(
+        s for s in MIMO_QKV if "1024" not in s))
 
 
 @pytest.mark.parametrize("cell", ["trinity", "kanana"])
